@@ -65,163 +65,3 @@ def floor_division(x, y):
 def get_exception_message(exc):
     """The message string of an exception object."""
     return str(exc)
-
-
-# ---------------------------------------------------------------------------
-# pinned-toolchain compat (jax): one import site for APIs that moved
-# between the jax versions this framework supports
-# ---------------------------------------------------------------------------
-
-try:  # jax >= 0.6 promoted shard_map to the public namespace
-    from jax import shard_map as _sm
-    _LEGACY_SHARD_MAP = False
-except ImportError:  # pinned 0.4.x: the experimental module
-    from jax.experimental import shard_map as _sm
-    _LEGACY_SHARD_MAP = True
-
-# either import may resolve to the module rather than the function
-_shard_map_impl = getattr(_sm, "shard_map", _sm)
-del _sm
-
-# the replication-check kwarg was renamed check_rep -> check_vma when
-# shard_map went public; the repo is written against the new name, so
-# translate (both directions) to whatever this jax's signature takes
-import functools as _functools
-import inspect as _inspect
-
-_SM_PARAMS = frozenset(_inspect.signature(_shard_map_impl).parameters)
-
-
-@_functools.wraps(_shard_map_impl)
-def shard_map(*args, **kwargs):
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SM_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map_impl(*args, **kwargs)
-
-
-def axis_size(axis):
-    """Concrete size of a named mesh axis from inside a mapped region.
-
-    ``jax.lax.axis_size`` only exists on newer jax; on the pinned 0.4.x
-    the equivalent is ``psum(1, axis)``, which constant-folds to a
-    Python int for non-tracer inputs — concrete, so callers may use it
-    in Python control flow (ring step counts, ppermute tables)."""
-    import jax.lax as _lax
-
-    if hasattr(_lax, "axis_size"):
-        return _lax.axis_size(axis)
-    return _lax.psum(1, axis)
-
-
-def _patch_legacy_shard_map_transpose():
-    """Backport the upstream fix for shard_map's transpose rule on the
-    pinned 0.4.x jax.
-
-    Under jit-of-grad with ``check_rep=False``, scalar residuals are
-    promoted to shape (1,) (``_promote_scalar_residuals``) so their
-    ``{0: axes}`` out-names are valid — but the TRANSPOSE re-runs
-    partial eval on the staged jaxpr, which strips the promoted
-    singleton, so a nonzero residual cotangent comes out scalar while
-    its position's names still claim dim 0, and ``_check_names`` raises
-    ``_SpecError`` (the pipeline/MoE grad paths all hit this).  Fixed
-    upstream when shard_map left experimental; here the rule is
-    re-registered with the one-line repair: re-promote any nonzero
-    scalar cotangent whose position carries axis names.  Registration
-    failure leaves the stock rule in place (no new breakage on a jax
-    whose internals moved)."""
-    import math
-
-    import numpy as _np
-
-    import jax
-    import jax.experimental.shard_map as _smx
-    from jax.tree_util import tree_flatten, tree_unflatten
-    from jax._src import core as _core
-    from jax._src import dtypes as _dtypes
-    from jax._src import linear_util as _lu
-    from jax._src.api_util import flatten_fun_nokwargs
-    from jax._src.interpreters import ad as _ad
-    from jax._src.interpreters import partial_eval as _pe
-    from jax._src.util import partition_list
-
-    # resolve every private helper the rule needs NOW: if this jax's
-    # shard_map internals use other names, the AttributeError lands here
-    # — inside the caller's try, keeping the stock rule — instead of at
-    # grad time inside every shard_map transpose
-    _unmentioned2 = _smx._unmentioned2
-    _shard_aval = _smx._shard_aval
-    _unshard_aval = _smx._unshard_aval
-    _shard_map_p = _smx.shard_map_p
-
-    def _transpose(out_cts, *args, jaxpr, mesh, in_names, out_names,
-                   check_rep, rewrite, auto):
-        mb_div = lambda x, y: x / y if y != 1 else x
-        out_cts = [
-            _ad.Zero(_shard_aval(mesh, ns, x.aval))
-            if type(x) is _ad.Zero
-            else x if rewrite or _dtypes.dtype(x) == _dtypes.float0
-            else mb_div(x, math.prod(map(
-                mesh.shape.get, _unmentioned2(mesh, ns, auto))))
-            for ns, x in zip(out_names, out_cts)]
-        args = [x if type(x) is not _ad.UndefinedPrimal else
-                _ad.UndefinedPrimal(_shard_aval(mesh, ns, x.aval))
-                for ns, x in zip(in_names, args)]
-        all_args, in_tree = tree_flatten((out_cts, args))
-
-        @_lu.wrap_init
-        def fun_trans(out_cts, args):
-            res, undefs = partition_list(
-                list(map(_ad.is_undefined_primal, args)), args)
-            jaxpr_known, jaxpr_unknown, _, _ = _pe.partial_eval_jaxpr_nounits(
-                _pe.close_jaxpr(jaxpr),
-                list(map(_ad.is_undefined_primal, args)), False)
-            res_reshaped = _core.jaxpr_as_fun(jaxpr_known)(*res)
-            out = _ad.backward_pass(
-                jaxpr_unknown.jaxpr, False, (), (*res_reshaped, *undefs),
-                out_cts)
-            out = [
-                _ad.Zero(_unshard_aval(mesh, ns, x.aval))
-                if type(x) is _ad.Zero
-                else x if rewrite
-                else jax.lax.psum(x, tuple(
-                    _unmentioned2(mesh, ns, auto)))
-                for ns, x in zip(in_names, out)]
-            # THE FIX: the re-partial-eval above strips the promoted
-            # residual singleton, so a nonzero residual ct can be scalar
-            # while its names claim dim 0 — re-promote it (a genuinely
-            # scalar input can never carry names, so this is exact)
-            out = [jax.lax.broadcast(x, (1,))
-                   if (type(x) is not _ad.Zero and ns
-                       and _np.ndim(x) == 0) else x
-                   for ns, x in zip(in_names, out)]
-            return out
-
-        fun_trans, nz_arg_cts = _ad.nonzero_outputs(fun_trans)
-        fun_trans_flat, out_tree = flatten_fun_nokwargs(fun_trans, in_tree)
-        new_in_names = \
-            [n for n, x in zip(out_names, out_cts)
-             if type(x) is not _ad.Zero] + \
-            [n for n, x in zip(in_names, args)
-             if type(x) is not _ad.UndefinedPrimal]
-
-        def new_out_names_thunk():
-            return tuple(names for names, nz in zip(in_names, nz_arg_cts())
-                         if nz)
-
-        out_flat = _shard_map_p.bind(
-            fun_trans_flat, *all_args, mesh=mesh,
-            in_names=tuple(new_in_names),
-            out_names_thunk=new_out_names_thunk, check_rep=check_rep,
-            rewrite=rewrite, auto=auto)
-        return tree_unflatten(out_tree(), out_flat)
-
-    _ad.primitive_transposes[_shard_map_p] = _transpose
-
-
-if _LEGACY_SHARD_MAP:
-    try:
-        _patch_legacy_shard_map_transpose()
-    except Exception:  # noqa: BLE001 - internals moved: keep stock rule
-        pass

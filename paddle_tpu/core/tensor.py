@@ -82,9 +82,7 @@ class Tensor:
             dev = None
         if dev is None:
             return current_place()
-        from .place import _platform_name
-
-        return Place(_platform_name(dev), dev.id)
+        return Place(dev.platform, dev.id)
 
     @property
     def is_leaf(self):

@@ -25,7 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from ..core.tensor import Tensor
 from .env import get_mesh
@@ -124,11 +124,7 @@ class prim:
     def send_recv_ring(x, group=None, shift=1):
         """x_i → x_{(i+shift) mod n}: the pipeline/ring-attention edge move."""
         ax = _axis_of(group)
-        n = jax.lax.axis_size(ax) if hasattr(jax.lax, "axis_size") else None
-        if n is None:
-            from .env import axis_size as _as
-
-            n = _as(ax)
+        n = jax.lax.axis_size(ax)
         perm = [(i, (i + shift) % n) for i in range(n)]
         return jax.lax.ppermute(x, ax, perm)
 

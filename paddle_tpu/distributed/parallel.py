@@ -81,7 +81,7 @@ class Reducer:
                  find_unused_parameters: bool = False, on_flush=None):
         import weakref
 
-        from ..compat import shard_map
+        from jax import shard_map
 
         self.axis = axis
         self._find_unused = find_unused_parameters

@@ -25,7 +25,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
 
 
 def make_pipeline_loss(embed_fn, stage_fn, head_loss_fn, n_micro: int, pp_size: int,

@@ -539,12 +539,10 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5):
         b = next(it) if bias is not None else None
         if nd == 1 and a.ndim >= 2 and \
                 os.environ.get("PADDLE_TPU_FUSED_LN", "") == "1":
-            # Pallas row-statistics kernel when available (TPU, aligned
-            # shapes); fused_layer_norm probes once per config and falls
-            # back to this same XLA expression otherwise.  Opt-in until the
-            # on-device parity check (tools/check_flash_tpu.py) has passed
-            # on real hardware — a compiling-but-wrong kernel must never be
-            # able to contaminate a bench headline silently.
+            # Pallas row-statistics kernel on a TPU for aligned shapes
+            # (fused_layer_norm's static gate; this same XLA expression
+            # otherwise).  Opt-in; on-device parity against the XLA
+            # oracle is the kernels phase of chip_smoke.py.
             from ...ops.fused_norm import fused_layer_norm
 
             return fused_layer_norm(a, weight=w, bias=b, eps=epsilon)

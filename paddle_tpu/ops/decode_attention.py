@@ -21,17 +21,18 @@ VMEM in T-blocks with the same online-softmax recurrence as
   inside the kernel right after the VMEM load — HBM reads a quarter of
   the fp32 bytes, and no dequantized copy is ever written back.
 
-Forward-only by design (decode is inference).  Availability probing +
-XLA fallback follow ops/flash_attention.py; the routing gate is
+Forward-only by design (decode is inference).  Routing follows
+ops/_pallas.py (platform + static shape gate, no probe); the flag is
 ``PADDLE_TPU_FLASH_DECODE`` (read by text/generate.py, which keeps its
-original einsum math as the off/fallback path).
+original einsum math as the flag-off / gate-rejected path).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-_FALLBACK: dict = {}
+from . import _pallas
+
 _INTERPRET = False  # tests flip this to run the kernel on CPU (interpret)
 
 _NEG = -1e30  # large-negative instead of -inf (flash_attention's rule)
@@ -54,23 +55,16 @@ def supported(q_shape, kv_shape) -> bool:
     """Static shape gate: q [B, Tq, Hq, hd] against cache [B, T, Hkv, hd]."""
     B, Tq, Hq, hd = q_shape
     T, Hkv = kv_shape[1], kv_shape[2]
-    return (hd in (64, 128, 256) and Hq % Hkv == 0
+    return (hd in (128, 256) and Hq % Hkv == 0
             and Tq * (Hq // Hkv) <= _R_CAP
             and _kv_block(T) is not None)
 
 
 def available(q_shape, kv_shape) -> bool:
-    """supported() + a backend that can run the kernel (TPU, or interpret
-    mode for CPU tests).  The per-configuration probe runs inside
-    ``decode_attention`` — this is the cheap trace-time routing check
-    text/generate.py consults before leaving its einsum path."""
-    if not supported(q_shape, kv_shape):
-        return False
-    if _INTERPRET:
-        return True
-    from ._pallas_probe import tpu_backend
-
-    return tpu_backend()
+    """supported() + a backend that runs the kernel (a TPU, or interpret
+    mode when a test flipped ``_INTERPRET``) — the trace-time routing
+    check text/generate.py consults before leaving its einsum path."""
+    return supported(q_shape, kv_shape) and (_INTERPRET or _pallas.on_tpu())
 
 
 # ---------------------------------------------------------------------------
@@ -157,26 +151,91 @@ def _xla_decode(q, k, v, pos, k_scale, v_scale, scale):
 # ---------------------------------------------------------------------------
 # kernel
 # ---------------------------------------------------------------------------
+#
+# Layout rules the v5e compiler enforces (tests/test_chip_compile.py keeps
+# them checked): the last two dims of every block are (8k, 128k) or the
+# array's own.  So the cache is viewed [.., T, Hkv*hd] and a cell takes
+# its head as the h-th hd-wide LANE chunk of a T-block (hd % 128 == 0 —
+# the shape gate); the int8 scales come in as whole [T-block, Hkv] tiles
+# and the cell picks its head's column; q rows pad up to a sublane tile.
 
 
-def _probe(q_dtype, kv_dtype, Tq: int, G: int, hd: int, BT: int) -> bool:
-    """True = fall back.  Probes the exact (block shapes, dtypes)
-    configuration the real call lowers with, per _pallas_probe's rules."""
-    from ._pallas_probe import probe_once
+def _rows_first(q, Hkv: int):
+    """q [B, Tq, Hq, hd] -> [B, Hkv, Rp, hd]: the rows for kv head h are
+    its whole query group, causally ordered (row r = tq * G + g; the
+    mask recovers tq as r // G), zero-padded to a sublane multiple."""
+    B, Tq, Hq, hd = q.shape
+    G = Hq // Hkv
+    R = Tq * G
+    qh = q.reshape(B, Tq, Hkv, G, hd).swapaxes(1, 2).reshape(B, Hkv, R, hd)
+    Rp = _pallas.pad_rows(R)
+    if Rp != R:
+        qh = jnp.pad(qh, ((0, 0), (0, 0), (0, Rp - R), (0, 0)))
+    return qh
 
-    def thunk():
-        quant = jnp.dtype(kv_dtype) == jnp.int8
-        q = jax.device_put(jnp.zeros((1, Tq, G, hd), q_dtype))
-        k = jax.device_put(jnp.zeros((1, BT, 1, hd), kv_dtype))
-        ks = (jax.device_put(jnp.ones((1, BT, 1), jnp.float32))
-              if quant else None)
-        pos = jax.device_put(jnp.zeros((1,), jnp.int32))
-        return _decode_call(q, k, k, pos, ks, ks, None)
 
-    return probe_once(
-        _FALLBACK,
-        (jnp.dtype(q_dtype).name, jnp.dtype(kv_dtype).name,
-         int(Tq), int(G), int(hd), int(BT)), thunk)
+def _rows_last(out, q_shape):
+    B, Tq, Hq, hd = q_shape
+    Hkv = out.shape[1]
+    G = Hq // Hkv
+    return (out[:, :, :Tq * G].reshape(B, Hkv, Tq, G, hd).swapaxes(1, 2)
+            .reshape(B, Tq, Hq, hd))
+
+
+def _scratch(Rp: int, hd: int):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM((Rp, 1), jnp.float32),     # running max
+            pltpu.VMEM((Rp, 1), jnp.float32),     # running denominator
+            pltpu.VMEM((Rp, hd), jnp.float32)]    # running numerator
+
+
+def _init_scratch(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, _NEG)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _finish(o_ref, l_scr, acc_scr):
+    l = l_scr[...]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+
+
+def _head_col(s_blk, h):
+    """Column ``h`` of a [BT, Hkv] scale tile as [BT, 1] (a masked
+    lane-sum: the head index is a grid value, not a static slice)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, s_blk.shape, 1)
+    return jnp.sum(jnp.where(lane == h, s_blk, 0.0), axis=1, keepdims=True)
+
+
+def _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref, ks_ref,
+                  vs_ref, m_scr, l_scr, acc_scr):
+    """One KV block of the online-softmax recurrence, shared by the
+    contiguous and the paged kernel: q_ref [1, 1, Rp, hd], k/v_ref
+    [1, BT, hd] (this head's lane chunk), scales [1, BT, Hkv] or None;
+    ``base`` is the block's first LOGICAL cache row."""
+    qb = q_ref[0, 0].astype(jnp.float32)               # [Rp, hd]
+    kb = k_ref[0].astype(jnp.float32)                  # [BT, hd]
+    vb = v_ref[0].astype(jnp.float32)
+    if ks_ref is not None:
+        kb = kb * _head_col(ks_ref[0], h)
+        vb = vb * _head_col(vs_ref[0], h)
+    s = scale * jax.lax.dot_general(
+        qb, kb, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)            # [Rp, BT]
+    rows_tq = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // G
+    cols = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(cols <= p_b + rows_tq, s, _NEG)
+    m_prev = m_scr[...]
+    m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_cur)
+    alpha = jnp.exp(m_prev - m_cur)
+    l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+        p, vb, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_scr[...] = m_cur
 
 
 def decode_attention(q, k, v, pos, k_scale=None, v_scale=None, scale=None):
@@ -184,19 +243,32 @@ def decode_attention(q, k, v, pos, k_scale=None, v_scale=None, scale=None):
     (q.dtype).  ``pos`` [B] int32: q row i of batch b attends cache rows
     t <= pos[b] + i (decode passes Tq=1 and the current position; verify/
     chunked-prefill pass the chunk and its first position).  int8 caches
-    pass per-row ``k_scale``/``v_scale`` [B, T, Hkv].  Falls back to the
-    XLA expression when the Pallas path is unavailable.
-
-    Not jitted itself: the availability probe must execute eagerly
-    (flash_attention's rule — it still works when tracing)."""
+    pass per-row ``k_scale``/``v_scale`` [B, T, Hkv].  Shapes the static
+    gate rejects take the XLA expression; a shape it accepts compiles
+    the kernel, and a compiler refusal raises to the caller."""
     if not supported(q.shape, k.shape):
         return _xla_decode(q, k, v, pos, k_scale, v_scale, scale)
-    G = q.shape[2] // k.shape[2]
-    BT = _kv_block(k.shape[1])
-    if not _INTERPRET and _probe(q.dtype, k.dtype, q.shape[1], G,
-                                 q.shape[-1], BT):
-        return _xla_decode(q, k, v, pos, k_scale, v_scale, scale)
-    return _decode_call(q, k, v, pos, k_scale, v_scale, scale)
+    return _per_head_shard(
+        lambda *a: _decode_call(*a, scale), k.shape[2],
+        (q, 2), (k, 2), (v, 2), (pos, None), (k_scale, 2), (v_scale, 2))
+
+
+def _per_head_shard(call, Hkv: int, *args_and_head_dims):
+    """Run ``call`` on the args, per shard of the heads axis when the step
+    being traced is partitioned (ops/_pallas.py): each arg comes with
+    the dim its heads sit on (None = replicated).  A heads axis that
+    does not divide Hkv leaves the cache replicated (generate's
+    sharded_cache_specs), and then every shard attends all heads."""
+    args = [a for a, _ in args_and_head_dims]
+    part = _pallas.partition()
+    if part is None:
+        return call(*args)
+    split = Hkv % part.size(part.heads) == 0
+    specs = tuple(
+        None if a is None else
+        part.spec(a.ndim, heads=d if split else None)
+        for a, d in args_and_head_dims)
+    return part.shard_map(call, specs, specs[0])(*args)
 
 
 def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
@@ -206,95 +278,68 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
     B, Tq, Hq, hd = q.shape
     T, Hkv = k.shape[1], k.shape[2]
     G = Hq // Hkv
-    R = Tq * G
     BT = _kv_block(T)
     nt = T // BT
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
     quant = k_scale is not None
 
-    # rows for kv head h are its whole query group, causally ordered:
-    # row r = tq * G + g  (mask recovers tq as r // G)
-    qh = q.reshape(B, Tq, Hkv, G, hd).swapaxes(1, 2).reshape(B, Hkv, R, hd)
-    pos2 = pos.reshape(B, 1).astype(jnp.int32)
+    qh = _rows_first(q, Hkv)
+    Rp = qh.shape[2]
+    # [B, 1, 1] so the (1, 1, 1) SMEM block's last two dims are the
+    # array's own (a [B, 1] operand is refused: block (1, 1) of (B, 1))
+    pos3 = pos.reshape(B, 1, 1).astype(jnp.int32)
 
     def kernel(pos_ref, q_ref, k_ref, v_ref, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+            ks_ref, vs_ref, o_ref, *scr = rest
         else:
-            o_ref, m_scr, l_scr, acc_scr = rest
+            ks_ref = vs_ref = None
+            o_ref, *scr = rest
+        h = pl.program_id(0) % Hkv
         ti = pl.program_id(1)
 
         @pl.when(ti == 0)
         def _init():
-            m_scr[:] = jnp.full_like(m_scr, _NEG)
-            l_scr[:] = jnp.zeros_like(l_scr)
-            acc_scr[:] = jnp.zeros_like(acc_scr)
+            _init_scratch(*scr)
 
-        p_b = pos_ref[0, 0]
+        p_b = pos_ref[0, 0, 0]
         base = ti * BT
 
         # skip KV blocks entirely past the causal frontier
         @pl.when(base <= p_b + Tq - 1)
         def _run():
-            qb = q_ref[0, 0].astype(jnp.float32)           # [R, hd]
-            kb = k_ref[0, :, 0, :].astype(jnp.float32)     # [BT, hd]
-            vb = v_ref[0, :, 0, :].astype(jnp.float32)
-            if quant:
-                kb = kb * ks_ref[0, :, 0][:, None]
-                vb = vb * vs_ref[0, :, 0][:, None]
-            s = scale * jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [R, BT]
-            rows_tq = jax.lax.broadcasted_iota(jnp.int32, (R, BT), 0) // G
-            cols = base + jax.lax.broadcasted_iota(jnp.int32, (R, BT), 1)
-            s = jnp.where(cols <= p_b + rows_tq, s, _NEG)
-            m_prev = m_scr[:, 0]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_cur[:, None])
-            alpha = jnp.exp(m_prev - m_cur)
-            l_scr[:, 0] = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-            acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[:, 0] = m_cur
+            _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref,
+                          ks_ref, vs_ref, *scr)
 
         @pl.when(ti == nt - 1)
         def _fin():
-            l = l_scr[:, 0]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, 0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+            _finish(o_ref, *scr[1:])
 
+    q_spec = pl.BlockSpec((1, 1, Rp, hd),
+                          lambda i, t: (i // Hkv, i % Hkv, 0, 0))
+    kv_spec = pl.BlockSpec((1, BT, hd), lambda i, t: (i // Hkv, t, i % Hkv))
     in_specs = [
-        pl.BlockSpec((1, 1), lambda i, t: (i // Hkv, 0),
+        pl.BlockSpec((1, 1, 1), lambda i, t: (i // Hkv, 0, 0),
                      memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, 1, R, hd), lambda i, t: (i // Hkv, i % Hkv, 0, 0)),
-        pl.BlockSpec((1, BT, 1, hd), lambda i, t: (i // Hkv, t, i % Hkv, 0)),
-        pl.BlockSpec((1, BT, 1, hd), lambda i, t: (i // Hkv, t, i % Hkv, 0)),
+        q_spec, kv_spec, kv_spec,
     ]
-    args = [pos2, qh, k, v]
+    args = [pos3, qh, k.reshape(B, T, Hkv * hd), v.reshape(B, T, Hkv * hd)]
     if quant:
-        in_specs += [
-            pl.BlockSpec((1, BT, 1), lambda i, t: (i // Hkv, t, i % Hkv)),
-            pl.BlockSpec((1, BT, 1), lambda i, t: (i // Hkv, t, i % Hkv)),
-        ]
+        s_spec = pl.BlockSpec((1, BT, Hkv), lambda i, t: (i // Hkv, t, 0))
+        in_specs += [s_spec, s_spec]
         args += [k_scale, v_scale]
 
     out = pl.pallas_call(
         kernel,
         grid=(B * Hkv, nt),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, R, hd),
-                               lambda i, t: (i // Hkv, i % Hkv, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, hd), jnp.float32),
-        ],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        scratch_shapes=_scratch(Rp, hd),
         interpret=_INTERPRET,
+        name="decode_attention",
     )(*args)
-    return (out.reshape(B, Hkv, Tq, G, hd).swapaxes(1, 2)
-            .reshape(B, Tq, Hq, hd))
+    return _rows_last(out, q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -320,22 +365,18 @@ def paged_supported(q_shape, pool_shape) -> bool:
     a pool [N, bs, Hkv, hd] (the KV block is the pool's own block)."""
     B, Tq, Hq, hd = q_shape
     N, bs, Hkv = pool_shape[0], pool_shape[1], pool_shape[2]
-    return (hd in (64, 128, 256) and Hq % Hkv == 0
+    return (hd in (128, 256) and Hq % Hkv == 0
             and Tq * (Hq // Hkv) <= _R_CAP
             and bs >= 8 and bs % 8 == 0)
 
 
 def paged_available(q_shape, pool_shape) -> bool:
-    """paged_supported + a backend that can run the kernel (TPU, or
-    interpret mode for CPU tests) — the trace-time routing check
-    text/kv_pool.py consults before leaving the gather-einsum path."""
-    if not paged_supported(q_shape, pool_shape):
-        return False
-    if _INTERPRET:
-        return True
-    from ._pallas_probe import tpu_backend
-
-    return tpu_backend()
+    """paged_supported + a backend that runs the kernel (a TPU, or
+    interpret mode when a test flipped ``_INTERPRET``) — the trace-time
+    routing check text/kv_pool.py consults before leaving the
+    gather-einsum path."""
+    return (paged_supported(q_shape, pool_shape)
+            and (_INTERPRET or _pallas.on_tpu()))
 
 
 def _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
@@ -349,28 +390,6 @@ def _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
     return _xla_decode(q, k, v, pos, ks, vs, scale)
 
 
-def _paged_probe(q_dtype, kv_dtype, Tq: int, G: int, hd: int,
-                 bs: int) -> bool:
-    """True = fall back.  Probes the exact paged configuration the real
-    call lowers with (block geometry + dtypes + scalar-prefetch path)."""
-    from ._pallas_probe import probe_once
-
-    def thunk():
-        quant = jnp.dtype(kv_dtype) == jnp.int8
-        q = jax.device_put(jnp.zeros((1, Tq, G, hd), q_dtype))
-        kp = jax.device_put(jnp.zeros((2, bs, 1, hd), kv_dtype))
-        ks = (jax.device_put(jnp.ones((2, bs, 1), jnp.float32))
-              if quant else None)
-        tables = jax.device_put(jnp.zeros((1, 1), jnp.int32))
-        pos = jax.device_put(jnp.zeros((1,), jnp.int32))
-        return _paged_call(q, kp, kp, tables, pos, ks, ks, None)
-
-    return probe_once(
-        _FALLBACK,
-        ("paged", jnp.dtype(q_dtype).name, jnp.dtype(kv_dtype).name,
-         int(Tq), int(G), int(hd), int(bs)), thunk)
-
-
 def paged_decode_attention(q, k_pool, v_pool, tables, pos,
                            k_scale=None, v_scale=None, scale=None):
     """Block-table decode attention: q [B, Tq, Hq, hd] against a pooled
@@ -379,25 +398,20 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     [B, Tq, Hq, hd] (q.dtype).  ``pos`` [B] as in :func:`decode_attention`
     — logical row t of slot b is table[b, t // bs] row t % bs, and rows
     t <= pos[b] + i are attended.  int8 pools pass per-row scales
-    [N, bs, Hkv].  Falls back to gather + the XLA reference when the
-    Pallas path is unavailable.
+    [N, bs, Hkv].  Shapes the static gate rejects take gather + the XLA
+    reference; a shape it accepts compiles the kernel.
 
-    Not jitted itself (the probe must execute eagerly — decode_attention's
-    rule); the grid cell resolves its T-block THROUGH the table via
-    scalar prefetch, so the HBM read is each slot's mapped blocks only —
-    never a materialized [B, T] gather — and causally-dead or unmapped
-    blocks are skipped."""
+    The grid cell resolves its T-block THROUGH the table via scalar
+    prefetch, so the HBM read is each slot's mapped blocks only — never
+    a materialized [B, T] gather — and causally-dead or unmapped blocks
+    are skipped."""
     if not paged_supported(q.shape, k_pool.shape):
         return _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
                           scale)
-    G = q.shape[2] // k_pool.shape[2]
-    bs = k_pool.shape[1]
-    if not _INTERPRET and _paged_probe(q.dtype, k_pool.dtype, q.shape[1],
-                                       G, q.shape[-1], bs):
-        return _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                          scale)
-    return _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                       scale)
+    return _per_head_shard(
+        lambda *a: _paged_call(*a, scale), k_pool.shape[2],
+        (q, 2), (k_pool, 2), (v_pool, 2), (tables, None), (pos, None),
+        (k_scale, 2), (v_scale, 2))
 
 
 def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
@@ -407,29 +421,28 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
     B, Tq, Hq, hd = q.shape
     N, bs, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     G = Hq // Hkv
-    R = Tq * G
     nmax = tables.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
     quant = k_scale is not None
 
-    qh = q.reshape(B, Tq, Hkv, G, hd).swapaxes(1, 2).reshape(B, Hkv, R, hd)
+    qh = _rows_first(q, Hkv)
+    Rp = qh.shape[2]
     tab = tables.astype(jnp.int32)
     pos2 = pos.reshape(B).astype(jnp.int32)
 
     def kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+            ks_ref, vs_ref, o_ref, *scr = rest
         else:
-            o_ref, m_scr, l_scr, acc_scr = rest
+            ks_ref = vs_ref = None
+            o_ref, *scr = rest
         i = pl.program_id(0)
         ti = pl.program_id(1)
-        b = i // Hkv
+        b, h = i // Hkv, i % Hkv
 
         @pl.when(ti == 0)
         def _init():
-            m_scr[:] = jnp.full_like(m_scr, _NEG)
-            l_scr[:] = jnp.zeros_like(l_scr)
-            acc_scr[:] = jnp.zeros_like(acc_scr)
+            _init_scratch(*scr)
 
         p_b = pos_ref[b]
         base = ti * bs          # LOGICAL row base of this block
@@ -440,33 +453,12 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
         # stale row is already behind the mask like a slab's)
         @pl.when((base <= p_b + Tq - 1) & (tab_ref[b, ti] >= 0))
         def _run():
-            qb = q_ref[0, 0].astype(jnp.float32)           # [R, hd]
-            kb = k_ref[0, :, 0, :].astype(jnp.float32)     # [bs, hd]
-            vb = v_ref[0, :, 0, :].astype(jnp.float32)
-            if quant:
-                kb = kb * ks_ref[0, :, 0][:, None]
-                vb = vb * vs_ref[0, :, 0][:, None]
-            s = scale * jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [R, bs]
-            rows_tq = jax.lax.broadcasted_iota(jnp.int32, (R, bs), 0) // G
-            cols = base + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
-            s = jnp.where(cols <= p_b + rows_tq, s, _NEG)
-            m_prev = m_scr[:, 0]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_cur[:, None])
-            alpha = jnp.exp(m_prev - m_cur)
-            l_scr[:, 0] = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-            acc_scr[:] = acc_scr[:] * alpha[:, None] + jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[:, 0] = m_cur
+            _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref,
+                          ks_ref, vs_ref, *scr)
 
         @pl.when(ti == nmax - 1)
         def _fin():
-            l = l_scr[:, 0]
-            l_safe = jnp.where(l == 0.0, 1.0, l)
-            o_ref[0, 0] = (acc_scr[:] / l_safe[:, None]).astype(o_ref.dtype)
+            _finish(o_ref, *scr[1:])
 
     # the pool block for grid cell (i, t) is resolved THROUGH the
     # prefetched table: physical block tab[b, t] (clamped — the kernel
@@ -474,43 +466,36 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
     # still needs an in-bounds address)
     def _kv_idx(i, t, tab_ref, pos_ref):
         pb = jnp.clip(tab_ref[i // Hkv, t], 0, N - 1)
-        return (pb, 0, i % Hkv, 0)
+        return (pb, 0, i % Hkv)
 
     def _ks_idx(i, t, tab_ref, pos_ref):
         pb = jnp.clip(tab_ref[i // Hkv, t], 0, N - 1)
-        return (pb, 0, i % Hkv)
+        return (pb, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 1, R, hd),
-                     lambda i, t, tab_ref, pos_ref: (i // Hkv, i % Hkv,
-                                                     0, 0)),
-        pl.BlockSpec((1, bs, 1, hd), _kv_idx),
-        pl.BlockSpec((1, bs, 1, hd), _kv_idx),
-    ]
-    args = [qh, k_pool, v_pool]
+    q_spec = pl.BlockSpec(
+        (1, 1, Rp, hd),
+        lambda i, t, tab_ref, pos_ref: (i // Hkv, i % Hkv, 0, 0))
+    in_specs = [q_spec, pl.BlockSpec((1, bs, hd), _kv_idx),
+                pl.BlockSpec((1, bs, hd), _kv_idx)]
+    args = [qh, k_pool.reshape(N, bs, Hkv * hd),
+            v_pool.reshape(N, bs, Hkv * hd)]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), _ks_idx),
-                     pl.BlockSpec((1, bs, 1), _ks_idx)]
+        in_specs += [pl.BlockSpec((1, bs, Hkv), _ks_idx),
+                     pl.BlockSpec((1, bs, Hkv), _ks_idx)]
         args += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B * Hkv, nmax),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, 1, R, hd),
-            lambda i, t, tab_ref, pos_ref: (i // Hkv, i % Hkv, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, hd), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=_scratch(Rp, hd),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, R, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=_INTERPRET,
+        name="paged_decode_attention",
     )(tab, pos2, *args)
-    return (out.reshape(B, Hkv, Tq, G, hd).swapaxes(1, 2)
-            .reshape(B, Tq, Hq, hd))
+    return _rows_last(out, q.shape)

@@ -18,7 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-_FALLBACK: dict = {}
+from . import _pallas
+
 _INTERPRET = False  # tests flip this to run the kernels on CPU (interpret)
 
 
@@ -33,34 +34,23 @@ def _shape_supported(q_shape, s_len) -> bool:
     return T % 128 == 0 and s_len % 128 == 0 and D in (64, 128, 256)
 
 
-def _probe(dtype, causal: bool, D: int) -> bool:
-    """Eagerly compile+run a tiny fwd+bwd pair once per (dtype, causal, D)
-    configuration; True = must fall back.  Keyed per config so e.g. a
-    bf16- or causal-specific lowering failure can't hide behind a healthy
-    fp32 non-causal probe; execution discipline (ensure_compile_time_eval,
-    platform gate) lives in ops/_pallas_probe.py."""
-    from ._pallas_probe import probe_once
-
-    def thunk():
-        z = jax.device_put(jnp.zeros((1, 128, 1, D), dtype))
-        out, vjp_fn = jax.vjp(
-            lambda a, b, c: _flash(a, b, c, causal, None), z, z, z)
-        return vjp_fn(out)
-
-    return probe_once(_FALLBACK,
-                      (jnp.dtype(dtype).name, bool(causal), int(D)), thunk)
-
-
 def flash_attention(q, k, v, causal: bool = False, scale=None):
-    """q,k,v: [B, T, H, D] → [B, T, H, D].  Falls back to XLA attention if the
-    Pallas path is unavailable (non-TPU backend or unsupported shape).
-
-    Not jitted itself: the availability probe must execute eagerly (it still
-    works when tracing — the probe runs on its own concrete arrays)."""
-    if not _shape_supported(q.shape, k.shape[1]) \
-            or (not _INTERPRET and _probe(q.dtype, causal, q.shape[-1])):
+    """q,k,v: [B, T, H, D] → [B, T, H, D].  Off a TPU, and for shapes the
+    static gate rejects, this is XLA attention; otherwise the kernel is
+    compiled with the caller's step and a refusal raises."""
+    if not (_INTERPRET or _pallas.on_tpu()):
         return _xla(q, k, v, causal, scale)
-    return _flash(q, k, v, causal, scale)
+
+    def local(q, k, v):
+        if not _shape_supported(q.shape, k.shape[1]):
+            return _xla(q, k, v, causal, scale)
+        return _flash(q, k, v, causal, scale)
+
+    part = _pallas.partition()
+    if part is None:
+        return local(q, k, v)
+    sp = part.spec(4, batch=0, heads=2)
+    return part.shard_map(local, (sp, sp, sp), sp)(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -24,7 +24,7 @@ ParallelCrossEntropy) stays on the XLA+psum path in
 distributed/megatron.py — there the shard-local max/sum reductions are
 tiny and the collectives dominate, so a Pallas body buys nothing.
 
-Availability probing + XLA fallback follow ops/flash_attention.py.
+Routing (platform + static shape gate, no probe) follows ops/_pallas.py.
 """
 from __future__ import annotations
 
@@ -33,10 +33,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._pallas_probe import pad_rows as _pad_rows
-from ._pallas_probe import row_block as _row_block_for
+from . import _pallas
+from ._pallas import pad_rows as _pad_rows
+from ._pallas import row_block as _row_block_for
 
-_FALLBACK: dict = {}
 _INTERPRET = False  # tests flip this to run the kernels on CPU (interpret)
 
 
@@ -57,44 +57,47 @@ def _xla_ce(logits, labels):
     return -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
 
 
-def _probe(dtype, V: int, BN: int) -> bool:
-    """True = fall back.  Probes the SAME kernel configuration the real
-    call will use (the row-block size changes the Mosaic lowering);
-    shared scaffolding in ops/_pallas_probe.py."""
-    from ._pallas_probe import probe_once
-
-    def thunk():
-        x = jax.device_put(jnp.zeros((BN, V), dtype))
-        lbl = jax.device_put(jnp.zeros((BN,), jnp.int32))
-        loss, vjp_fn = jax.vjp(lambda a: _fused_ce(a, lbl), x)
-        return vjp_fn(loss)
-
-    return probe_once(_FALLBACK, (jnp.dtype(dtype).name, int(V), int(BN)),
-                      thunk)
-
-
 def fused_softmax_ce(logits, labels):
     """Per-row cross-entropy: logits [..., V], int labels [...] → loss
     [...] float32.  Rows are padded up to the kernel's row-block multiple
     (pad rows' cotangents are zero by construction, so dlogits stays
     exact — without this, GPT-style row counts like B*(T-1) would
-    silently miss the fused path); falls back to the XLA expression when
-    the Pallas path is unavailable (non-TPU backend, unaligned vocab)."""
+    silently miss the fused path).  Off a TPU, and for an unaligned
+    vocab, this is the XLA expression; otherwise the kernel compiles
+    with the caller's step and a refusal raises."""
     V = logits.shape[-1]
-    lead = logits.shape[:-1]
-    N = 1
-    for d in lead:
-        N *= d
-    l2 = logits.reshape(N, V)
-    lbl = labels.reshape(N).astype(jnp.int32)
-    Np = _pad_rows(N)
-    blk = _blocks(Np, V)
-    if blk is None or (not _INTERPRET and _probe(logits.dtype, V, blk[0])):
-        return _xla_ce(l2, lbl).reshape(lead)
-    if Np != N:
-        l2 = jnp.pad(l2, ((0, Np - N), (0, 0)))
-        lbl = jnp.pad(lbl, (0, Np - N))
-    return _fused_ce(l2, lbl)[:N].reshape(lead)
+
+    def xla(logits, labels):
+        return _xla_ce(logits.reshape(-1, V),
+                       labels.reshape(-1).astype(jnp.int32)
+                       ).reshape(labels.shape)
+
+    def local(logits, labels):
+        lead = logits.shape[:-1]
+        N = 1
+        for d in lead:
+            N *= d
+        Np = _pad_rows(N)
+        if _blocks(Np, V) is None:
+            return xla(logits, labels)
+        l2 = logits.reshape(N, V)
+        lbl = labels.reshape(N).astype(jnp.int32)
+        if Np != N:
+            l2 = jnp.pad(l2, ((0, Np - N), (0, 0)))
+            lbl = jnp.pad(lbl, (0, Np - N))
+        return _fused_ce(l2, lbl)[:N].reshape(lead)
+
+    part = _pallas.partition()
+    if not (_INTERPRET or _pallas.on_tpu()) \
+            or (part is not None and part.heads is not None):
+        # a heads (tensor-parallel) axis shards the vocab: the kernel
+        # needs whole rows, so GSPMD keeps its own sharded reduction
+        return xla(logits, labels)
+    if part is None:
+        return local(logits, labels)
+    ls = part.spec(logits.ndim, batch=0)
+    ys = part.spec(labels.ndim, batch=0)
+    return part.shard_map(local, (ls, ys), ys)(logits, labels)
 
 
 @jax.custom_vjp

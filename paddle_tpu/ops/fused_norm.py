@@ -16,9 +16,9 @@ per kernel pair; here XLA already fuses the surrounding elementwise ops and
 the Pallas kernel only takes over the row-statistics pattern XLA handles
 with an extra HBM round-trip.
 
-Like ops/flash_attention.py, the public entry probes availability once per
-configuration and falls back to the plain XLA expression (non-TPU backends,
-unsupported shapes), so it is safe to call from any path.
+Like ops/flash_attention.py, the public entry routes by platform and static
+shape (ops/_pallas.py): the plain XLA expression off a TPU and for
+unsupported shapes, the kernel otherwise — no probe, no retry on XLA.
 """
 from __future__ import annotations
 
@@ -27,10 +27,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ._pallas_probe import pad_rows as _pad_rows
-from ._pallas_probe import row_block as _row_block_for
+from . import _pallas
+from ._pallas import pad_rows as _pad_rows
+from ._pallas import row_block as _row_block_for
 
-_FALLBACK: dict = {}
 _INTERPRET = False  # tests flip this to run the kernels on CPU (interpret)
 
 
@@ -41,33 +41,11 @@ def _row_block(N: int, F: int) -> int | None:
 def _xla_ln(x, g, b, eps):
     # cast back: fp32 affine params promote a bf16 x to fp32, but the
     # public contract is output dtype == x.dtype (what the Pallas path
-    # returns) — a probe-triggered mid-stack fallback must not flip the
-    # residual-stream dtype (it broke the fused GPT rungs' scan carry on
-    # the chip, round-5 window 2)
+    # returns) — a shape-gated site must not flip the residual-stream
+    # dtype (it breaks the GPT scan carry)
     m = jnp.mean(x, axis=-1, keepdims=True)
     v = jnp.var(x, axis=-1, keepdims=True)
     return ((x - m) * jax.lax.rsqrt(v + eps) * g + b).astype(x.dtype)
-
-
-def _probe(dtype, gdtype, bdtype, F: int, BN: int) -> bool:
-    """True = fall back.  Probes the SAME kernel configuration the real
-    call will use (the row-block size and each parameter dtype change the
-    Mosaic lowering); shared scaffolding in ops/_pallas_probe.py."""
-    from ._pallas_probe import probe_once
-
-    def thunk():
-        x = jax.device_put(jnp.zeros((BN, F), dtype))
-        g = jax.device_put(jnp.ones((F,), gdtype))
-        b = jax.device_put(jnp.zeros((F,), bdtype))
-        out, vjp_fn = jax.vjp(lambda a, w, c: _fused_ln(a, w, c, 1e-5),
-                              x, g, b)
-        return vjp_fn(out)
-
-    return probe_once(
-        _FALLBACK,
-        (jnp.dtype(dtype).name, jnp.dtype(gdtype).name,
-         jnp.dtype(bdtype).name, int(F), int(BN)),
-        thunk)
 
 
 def fused_layer_norm(x, weight=None, bias=None, eps: float = 1e-5):
@@ -75,25 +53,34 @@ def fused_layer_norm(x, weight=None, bias=None, eps: float = 1e-5):
 
     ``weight``/``bias`` are optional [F] affine parameters.  Rows are
     padded up to the kernel's row-block multiple (pad rows' cotangents are
-    zero by construction, so grads stay exact); falls back to the XLA
-    expression when the Pallas path is unavailable (non-TPU backend,
-    unaligned feature width)."""
+    zero by construction, so grads stay exact).  Off a TPU, and for an
+    unaligned feature width, this is the XLA expression; otherwise the
+    kernel compiles with the caller's step and a refusal raises."""
     F = x.shape[-1]
-    N = 1
-    for d in x.shape[:-1]:
-        N *= d
     g = jnp.ones((F,), x.dtype) if weight is None else weight
     b = jnp.zeros((F,), x.dtype) if bias is None else bias
-    Np = _pad_rows(N)
-    BN = _row_block(Np, F) if F % 128 == 0 else None
-    if x.ndim < 2 or BN is None or \
-            (not _INTERPRET and _probe(x.dtype, g.dtype, b.dtype, F, BN)):
+    if x.ndim < 2 or not (_INTERPRET or _pallas.on_tpu()):
         return _xla_ln(x, g, b, eps)
-    x2 = x.reshape(N, F)
-    if Np != N:
-        x2 = jnp.pad(x2, ((0, Np - N), (0, 0)))
-    y2d = _fused_ln(x2, g, b, eps)
-    return y2d[:N].reshape(x.shape)
+
+    def local(x, g, b):
+        N = 1
+        for d in x.shape[:-1]:
+            N *= d
+        Np = _pad_rows(N)
+        if F % 128 or _row_block(Np, F) is None:
+            return _xla_ln(x, g, b, eps)
+        x2 = x.reshape(N, F)
+        if Np != N:
+            x2 = jnp.pad(x2, ((0, Np - N), (0, 0)))
+        return _fused_ln(x2, g, b, eps)[:N].reshape(x.shape)
+
+    part = _pallas.partition()
+    if part is None:
+        return local(x, g, b)
+    # rows go with the batch; over a heads axis x is replicated and each
+    # shard normalizes its own copy
+    xs = part.spec(x.ndim, batch=0)
+    return part.shard_map(local, (xs, _pallas.P(), _pallas.P()), xs)(x, g, b)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -6,8 +6,7 @@ the same control as a saveable-predicate policy on ``jax.checkpoint``.
 One resolver serves every surface that takes a policy name —
 GPTConfig.remat_policy (text/gpt.py), DistributedStrategy
 .recompute_configs.policy (distributed/fleet/strategy.py), the generic
-PipelineLayer remat, and the on-device A/B tool
-(tools/remat_compile_check.py via PADDLE_TPU_REMAT_POLICY).
+PipelineLayer remat, and the ``PADDLE_TPU_REMAT_POLICY`` override.
 
 Accepted names (aliases map to the same policy):
 * ``None`` / ``"none"`` / ``"full"`` / ``"nothing_saveable"`` — save

@@ -20,15 +20,16 @@ HBM reads the int4 bytes ONCE and never writes a dequantized copy.
 Forward-only by design: packed int4 weights exist only on the frozen
 decode path (training and LoRA fine-tuning keep float masters).
 
-Availability probing + XLA fallback follow ops/flash_attention.py; the
-routing gate lives in ``woq.mm`` (env ``PADDLE_TPU_W4_KERNEL``).
+Routing follows ops/_pallas.py (platform + static shape gate, no probe);
+the flag lives in ``woq.mm`` (env ``PADDLE_TPU_W4_KERNEL``).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-_FALLBACK: dict = {}
+from . import _pallas
+
 _INTERPRET = False  # tests flip this to run the kernel on CPU (interpret)
 
 _N_CAP = 256  # decode/serving batches; prefill-sized N stays on XLA
@@ -64,26 +65,12 @@ def _xla_w4(x, packed, scale):
     return x @ w
 
 
-def _probe(dtype, N: int, Kp: int, M: int, gs: int) -> bool:
-    """True = fall back; probes the exact tiling the real call uses."""
-    from ._pallas_probe import probe_once
-
-    def thunk():
-        x = jax.device_put(jnp.zeros((N, Kp * 2), dtype))
-        pk = jax.device_put(jnp.zeros((Kp, M), jnp.int8))
-        s = jax.device_put(jnp.ones((Kp * 2 // gs, 1, M), jnp.float32))
-        return _w4_call(x, pk, s, gs)
-
-    return probe_once(
-        _FALLBACK,
-        (jnp.dtype(dtype).name, int(N), int(Kp), int(M), int(gs)), thunk)
-
-
 def w4_matmul(x, packed, scale):
     """x [..., K] @ dequant(packed [K/2, M] int8, scale [G, 1, M]) →
-    [..., M] in x.dtype.  Rows pad to the sublane multiple; falls back
-    to the XLA dequant+matmul when the Pallas path is unavailable
-    (non-TPU backend, unaligned shapes, prefill-sized N)."""
+    [..., M] in x.dtype.  Rows pad to the sublane multiple.  Off a TPU,
+    and for unaligned shapes or a prefill-sized N, this is the XLA
+    dequant+matmul; otherwise the kernel compiles with the caller's
+    step and a refusal raises."""
     lead = x.shape[:-1]
     K = x.shape[-1]
     Kp, M = packed.shape
@@ -98,8 +85,9 @@ def w4_matmul(x, packed, scale):
     x2 = x.reshape(N, K)
     Np = -(-N // 8) * 8
     blk = _blocks(Np, Kp, M, gs)
-    if blk is None or (not _INTERPRET
-                       and _probe(x.dtype, Np, Kp, M, gs)):
+    # a step partitioned over several chips shards K or M: XLA's matmul
+    if blk is None or not (_INTERPRET or _pallas.on_tpu()) \
+            or _pallas.partition() is not None:
         return _xla_w4(x2, packed, scale).reshape(*lead, M)
     if Np != N:
         x2 = jnp.pad(x2, ((0, Np - N), (0, 0)))

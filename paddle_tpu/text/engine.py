@@ -276,6 +276,33 @@ def _shard_kw(shard, n_extra: int, outs: str,
             "out_shardings": out if len(outs) > 1 else out[0]}
 
 
+def _traced_per_shard(shard, fn):
+    """A tensor-parallel step's Pallas kernels run per shard of the
+    server's mesh (GSPMD cannot partition a Mosaic call): the partition
+    context is entered around every call and every ``lower``, so it is
+    active whenever the jit traces.  Single-chip steps come back as
+    they are."""
+    if not isinstance(shard, _ShardCtx):
+        return fn
+    from ..ops import _pallas
+
+    per_shard = functools.partial(_pallas.partitioned, shard.mesh,
+                                  heads=shard.mp)
+
+    @functools.wraps(fn)
+    def call(*a, **k):
+        with per_shard():
+            return fn(*a, **k)
+
+    def lower(*a, **k):
+        with per_shard():
+            return fn.lower(*a, **k)
+
+    call.lower = lower
+    call._cache_size = fn._cache_size
+    return call
+
+
 def _shard_key(shard):
     """Step-cache key fragment for a server's placement: the mesh
     fingerprint under TP, the device id tuple for a pinned single-chip
@@ -1108,13 +1135,17 @@ class Engine:
         ENGINE lint fails any ``jax.jit`` outside this module."""
         entry = _REGISTRY[kind]
         key = entry.key(spec)
+
+        def build():
+            return _watch_jit(entry.name(spec), key, _traced_per_shard(
+                spec.shard, entry.build(spec)))
+
         if not entry.cached:
-            return _watch_jit(entry.name(spec), key, entry.build(spec))
+            return build()
         cache = self._domain(entry)
         fn = cache.get(key)
         if fn is None:
-            fn = _watch_jit(entry.name(spec), key, entry.build(spec))
-            cache[key] = fn
+            fn = cache[key] = build()
         return fn
 
     def jit(self, name: str, key, fn, *, cache: bool = True,
@@ -1177,8 +1208,8 @@ class Engine:
         requests without a pool.
 
         This also warms the flash-decode kernel variants: tracing the
-        step executables runs the split-KV Pallas kernel's availability
-        probe (ops/decode_attention) and compiles the kernel for this
+        step executables compiles the split-KV Pallas kernel
+        (ops/decode_attention) for this
         server's exact (cache length, head, KV-dtype) configuration —
         under ``PADDLE_TPU_FLASH_DECODE``/``PADDLE_TPU_KV_DTYPE`` the
         first tick pays device time only, like every other executable
@@ -1247,6 +1278,11 @@ class Engine:
             timings[name] = round(_time.perf_counter() - t0, 3)
 
         tok, pos = jnp.asarray(zi), jnp.asarray(zi)
+        # the async kinds' "previous device tokens" argument, built the way
+        # the server builds a fresh one: under a mesh its type carries the
+        # mesh, and warming with a plain array would leave the first
+        # steady tick to compile again
+        pv0 = srv._prev_feed(None)
         moe = srv.cfg.moe is not None
         if moe:
             # the joint-routing kinds' extra runtime inputs: an all-False
@@ -1272,7 +1308,7 @@ class Engine:
                               tspec(paged=srv._paged, pkey=pk))
                 warm("adapter_async_step", lambda: fn(
                     srv.params, srv.cache, ad, ids0, tok,
-                    jnp.asarray(zb), tok, pos, key, jnp.asarray(zf),
+                    jnp.asarray(zb), pv0, pos, key, jnp.asarray(zf),
                     jnp.asarray(zi), jnp.asarray(of)))
             # the sync greedy step also serves async servers' stepwise
             # constraint fallback, so warm it unconditionally
@@ -1290,14 +1326,14 @@ class Engine:
         elif srv._async and moe:
             fn = self.get("moe_async", tspec(paged=srv._paged))
             warm("moe_async_step", lambda: fn(
-                srv.params, srv.cache, tok, jnp.asarray(zb), tok, pos,
+                srv.params, srv.cache, tok, jnp.asarray(zb), pv0, pos,
                 key, jnp.asarray(zf), jnp.asarray(zi), jnp.asarray(of),
                 mact, mst))
             # constrained x MoE is rejected at submit — nothing to warm
         elif srv._async:
             fn = self.get("async", tspec(paged=srv._paged))
             warm("async_step", lambda: fn(
-                srv.params, srv.cache, tok, jnp.asarray(zb), tok, pos,
+                srv.params, srv.cache, tok, jnp.asarray(zb), pv0, pos,
                 key, jnp.asarray(zf), jnp.asarray(zi), jnp.asarray(of)))
             if constrained:
                 # async constrained traffic drains to the SYNC masked
@@ -1360,14 +1396,14 @@ class Engine:
                 fn = self.get("async_block",
                               tspec(paged=srv._paged, k=k))
                 warm(f"async_block{k}", lambda fn=fn: fn(
-                    srv.params, srv.cache, tok, jnp.asarray(zb), tok,
+                    srv.params, srv.cache, tok, jnp.asarray(zb), pv0,
                     pos)[:2])
                 if sample:
                     fn = self.get("async_sample_block",
                                   tspec(paged=srv._paged, k=k))
                     warm(f"async_sample_block{k}", lambda fn=fn: fn(
                         srv.params, srv.cache, tok, jnp.asarray(zb),
-                        tok, pos, srv._base_key, jnp.asarray(0),
+                        pv0, pos, srv._base_key, jnp.asarray(0),
                         jnp.asarray(zf), jnp.asarray(zi),
                         jnp.asarray(of)))
             else:

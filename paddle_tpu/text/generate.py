@@ -107,10 +107,10 @@ def _store_rows(k_rows, v_rows, cfg: gpt.GPTConfig) -> dict:
 
 def _use_decode_kernel(cfg: gpt.GPTConfig, q_shape, kv_shape) -> bool:
     """Route this cached-attention site through the split-KV Pallas
-    kernel?  Flag + backend/shape gate (ops/decode_attention.available);
-    the per-config probe then runs inside the op itself.  False keeps the
-    site on its original einsum math — bit-identical to pre-kernel
-    behavior (and the only path off-TPU outside interpret tests)."""
+    kernel?  Flag + backend/shape gate (ops/decode_attention.available).
+    False keeps the site on its original einsum math — bit-identical to
+    pre-kernel behavior (and the only path off-TPU outside interpret
+    tests)."""
     from ..ops import decode_attention as da
 
     return _flags.flash_decode() and da.available(q_shape, kv_shape)
@@ -565,14 +565,19 @@ def build_sharded_decode(params, cfg: gpt.GPTConfig, mesh, mp: str = "mp",
     repl = P()
 
     def _step(p, cache, token, pos):
-        if lay == "paged":
-            from . import kv_pool as _kvp
+        from ..ops import _pallas
 
-            pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
-                                     token.shape)
-            return _kvp.paged_decode_step_batched(p, cache, token, pos_b,
-                                                  cfg)
-        return decode_step(p, cache, token, pos, cfg)
+        # kernels run per shard of the heads axis (GSPMD cannot
+        # partition a Mosaic call)
+        with _pallas.partitioned(mesh, heads=mp):
+            if lay == "paged":
+                from . import kv_pool as _kvp
+
+                pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32),
+                                         token.shape)
+                return _kvp.paged_decode_step_batched(p, cache, token,
+                                                      pos_b, cfg)
+            return decode_step(p, cache, token, pos, cfg)
 
     # the sharded cache is donated like the single-chip steps' — in and
     # out shardings match, so aliasing is exact per shard

@@ -123,7 +123,7 @@ def sample_block_batched(params, cache, tok, pos, base_key, off, temp, topk,
 
 def decode_block_batched(params, cache, tok, pos, k: int, cfg: gpt.GPTConfig):
     """``k`` greedy decode steps entirely ON DEVICE (round-4 verdict Weak
-    #3: fetching the argmax to numpy every tick makes tunnel decode
+    #3: fetching the argmax to numpy every tick makes decode
     latency host-round-trip-bound).  Each step's argmax feeds the next
     step inside one jitted ``lax.scan`` — the host sees one [B, k] token
     block per call instead of k scalar fetches.
@@ -4333,7 +4333,12 @@ class DecodeServer:
         """The [B] device token array feeding off the in-flight dispatch
         (step: its tokens; block: the block's last column)."""
         if prev is None:
-            return jnp.zeros((self.max_batch,), jnp.int32)
+            feed = jnp.zeros((self.max_batch,), jnp.int32)
+            if isinstance(self._shard, _ShardCtx):
+                # same type as a step's own output under the mesh, so
+                # the first tick and every later one share an executable
+                feed = jax.device_put(feed, self._shard.repl)
+            return feed
         return prev["feed"]
 
     def _rollback_dispatch(self, snap, n):
@@ -4606,8 +4611,8 @@ class DecodeServer:
         requests without a pool.
 
         This also warms the flash-decode kernel variants: tracing the
-        step executables runs the split-KV Pallas kernel's availability
-        probe (ops/decode_attention) and compiles the kernel for this
+        step executables compiles the split-KV Pallas kernel
+        (ops/decode_attention) for this
         server's exact (cache length, head, KV-dtype) configuration —
         under ``PADDLE_TPU_FLASH_DECODE``/``PADDLE_TPU_KV_DTYPE`` the
         first tick pays device time only, like every other executable

@@ -7,11 +7,8 @@ from ..core.tensor import Tensor
 
 def to_dlpack(tensor):
     """Tensor → DLPack capsule (zero-copy where the backend allows)."""
-    import jax
-
     v = tensor.value if isinstance(tensor, Tensor) else tensor
-    return jax.dlpack.to_dlpack(v) if hasattr(jax.dlpack, "to_dlpack") \
-        else v.__dlpack__()
+    return v.__dlpack__()
 
 
 def from_dlpack(capsule_or_array) -> Tensor:

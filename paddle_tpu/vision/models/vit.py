@@ -3,8 +3,7 @@
 The reference's vision zoo (python/paddle/vision/models/) is conv-only
 (LeNet/VGG/ResNet/MobileNet).  On TPU a ViT is the natural flagship
 vision model: the whole network is LayerNorm + dense matmuls — exactly
-the MXU's shape — where ResNet's small-channel convs measured MFU 0.088
-on v5 lite (EVIDENCE_r05.md lever #4).  Built entirely from the existing
+the MXU's shape — where ResNet's small-channel convs use it poorly.  Built entirely from the existing
 transformer stack (`nn.TransformerEncoder`, pre-LN) so the encoder is
 the SAME code path the text models exercise.
 """

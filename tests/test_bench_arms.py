@@ -1,219 +1,124 @@
-"""Per-arm subprocess isolation for the decode/serving benches
-(bench.py::_arm_results / _assemble_arm_record).
+"""The decode/serving arms and the harness around them (bench.py).
 
-Tested like the rung ladder (test_bench_ladder.py): the child
-subprocess is faked, and the assembler's contract — tok_s fields,
-ratios, labeled headline fallback — is pinned so drift between the
-decode and serving records can't reappear.
+One process for each chip: arms and rungs run in bench.py's own process, in
+order, and nothing a config raises is recorded and carried past.  The
+assembler's contract — tok_s fields, ratios, the headline arm — is pinned so
+drift between the decode and serving records can't reappear.
 """
+import ast
 import importlib.util
 import json
 import os
 import subprocess
+import sys
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(REPO, "bench.py")
 
 
 @pytest.fixture()
-def bench(monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        "bench_arms_under_test", os.path.join(REPO, "bench.py"))
+def bench():
+    spec = importlib.util.spec_from_file_location("bench_arms_under_test",
+                                                  BENCH)
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)
-    monkeypatch.delenv("BENCH_ARM", raising=False)
-    monkeypatch.delenv("BENCH_ARM_ISOLATE", raising=False)
-    monkeypatch.delenv("BENCH_ARM_TIMEOUT", raising=False)
     return m
 
 
-class _TpuDev:
-    platform = "tpu"
-    device_kind = "fake v5e"
+def test_arms_run_in_this_process_in_order(bench, monkeypatch):
+    def no_children(*a, **k):
+        raise AssertionError("an arm started a child process")
 
-
-class _CpuDev:
-    platform = "cpu"
-    device_kind = "cpu"
-
-
-class _Done:
-    def __init__(self, rc=0, stdout="", stderr=""):
-        self.returncode, self.stdout, self.stderr = rc, stdout, stderr
-
-
-def _fake_children(m, monkeypatch, by_arm):
-    """by_arm[arm] -> dict (json result), int (rc), 'timeout', or
-    'garbage' (rc 0, non-JSON stdout)."""
+    monkeypatch.setattr(bench.subprocess, "run", no_children)
+    monkeypatch.setattr(bench.subprocess, "Popen", no_children)
     calls = []
 
-    def fake_run(argv, capture_output, text, timeout):
-        arm = argv[argv.index("--arm") + 1].split(":")[1]
+    def measure(arm):
         calls.append(arm)
-        spec = by_arm[arm]
-        if spec == "timeout":
-            raise subprocess.TimeoutExpired(argv, timeout)
-        if spec == "garbage":
-            return _Done(stdout="not json\n")
-        if isinstance(spec, int):
-            return _Done(rc=spec, stderr="boom\nRan out of memory in "
-                                         "memory space hbm. Used 20G of "
-                                         "15.75G hbm.\ntail")
-        return _Done(stdout=json.dumps(spec) + "\n")
+        return {"tok_s": 10.0 * len(calls), "first_token_ms": 1.0}
 
-    monkeypatch.setattr(m.subprocess, "run", fake_run)
-    return calls
+    res = bench._arm_results(["float", "int8"], measure)
+    assert calls == ["float", "int8"]
+    assert res == {"float": {"tok_s": 10.0, "first_token_ms": 1.0},
+                   "int8": {"tok_s": 20.0, "first_token_ms": 1.0}}
 
 
-def test_tpu_arms_run_in_subprocesses(bench, monkeypatch):
-    calls = _fake_children(bench, monkeypatch, {
-        "a": {"arm": "a", "tok_s": 100.0},
-        "b": {"arm": "b", "tok_s": 50.0}})
-    res = bench._arm_results("decode", ["a", "b"],
-                             lambda arm: 1 / 0, False, _TpuDev())
-    assert calls == ["a", "b"]
-    assert res == {"a": {"arm": "a", "tok_s": 100.0},
-                   "b": {"arm": "b", "tok_s": 50.0}}
+def test_bare_tok_s_and_w4_flag_are_recorded(bench, monkeypatch):
+    monkeypatch.setenv("PADDLE_TPU_W4_KERNEL", "1")
+    res = bench._arm_results(["int4"], lambda arm: 5.0)
+    assert res == {"int4": {"tok_s": 5.0, "w4": {"enabled": True}}}
 
 
-def test_cpu_arms_run_in_process(bench, monkeypatch):
-    def no_subprocess(*a, **k):
-        raise AssertionError("CPU path must not spawn children")
-    monkeypatch.setattr(bench.subprocess, "run", no_subprocess)
-    res = bench._arm_results("decode", ["a"], lambda arm: 42.0, False,
-                             _CpuDev())
-    assert res == {"a": {"tok_s": 42.0}}
+def test_an_arm_that_raises_ends_the_run(bench):
+    """No record-and-continue: the healthy arms' numbers do not paper over
+    a broken one."""
+    def measure(arm):
+        if arm == "int8":
+            raise RuntimeError("kernel refused")
+        return 1.0
 
-
-def test_hung_arm_is_killed_and_recorded(bench, monkeypatch):
-    monkeypatch.setenv("BENCH_ARM_TIMEOUT", "7")
-    _fake_children(bench, monkeypatch, {
-        "a": "timeout", "b": {"arm": "b", "tok_s": 9.0}})
-    res = bench._arm_results("serving", ["a", "b"],
-                             lambda arm: 1 / 0, False, _TpuDev())
-    assert "timeout" in res["a"]["error"]
-    assert res["b"]["tok_s"] == 9.0  # later arms still run after a hang
-
-
-def test_crashed_arm_reports_oom_line(bench, monkeypatch):
-    _fake_children(bench, monkeypatch, {"a": 1})
-    res = bench._arm_results("decode", ["a"], lambda arm: 1 / 0, False,
-                             _TpuDev())
-    assert "Used 20G of 15.75G" in res["a"]["error"]
-
-
-def test_garbage_stdout_is_an_error_not_a_crash(bench, monkeypatch):
-    _fake_children(bench, monkeypatch, {"a": "garbage"})
-    res = bench._arm_results("decode", ["a"], lambda arm: 1 / 0, False,
-                             _TpuDev())
-    assert "error" in res["a"]
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        bench._arm_results(["float", "int8", "int4"], measure)
 
 
 def test_assembler_ratio_and_headline_contract(bench):
     out = bench._assemble_arm_record(
         {}, {"float": {"tok_s": 100.0}, "int8": {"tok_s": 150.0},
-             "int4": {"tok_s": 80.0}},
+             "int4": {"tok_s": 80.0, "w4": {"enabled": True}}},
         ["float", "int8", "int4"], "float", "int8", "t")
     assert out["value"] == 150.0 and out["value_arm"] == "int8"
     assert out["int8_vs_float"] == 1.5 and out["int4_vs_float"] == 0.8
+    assert out["int4_w4"] == {"enabled": True}
     assert "float_vs_float" not in out
+    assert not any(k.endswith("_error") for k in out)
 
 
-def test_assembler_headline_falls_back_labeled(bench):
-    out = bench._assemble_arm_record(
-        {}, {"bf16": {"error": "x"}, "int8": {"tok_s": 70.0},
-             "int4": {"error": "y"}},
-        ["bf16", "int8", "int4"], "bf16", "bf16", "t")
-    assert out["value"] == 70.0 and out["value_arm"] == "int8"
-    assert out["bf16_error"] == "x" and out["int4_error"] == "y"
-    assert "int8_vs_bf16" not in out  # no reference arm: no ratio
+def test_bench_starts_no_copy_of_itself():
+    """Source scan: the only subprocess call left is git's, and no argv
+    names this file or the interpreter."""
+    tree = ast.parse(open(BENCH, encoding="utf-8").read())
+    calls = [n for n in ast.walk(tree)
+             if isinstance(n, ast.Call) and isinstance(n.func, ast.Attribute)
+             and isinstance(n.func.value, ast.Name)
+             and n.func.value.id == "subprocess"]
+    assert [ast.unparse(c.args[0])[:8] for c in calls] == ["['git', "]
+    src = open(BENCH, encoding="utf-8").read()
+    for gone in ("--arm", "sys.executable", "BENCH_ARM", "_cpu_fallback",
+                 "JAX_PLATFORMS\"] = \"cpu"):
+        assert gone not in src, gone
 
 
-def test_assembler_total_failure_yields_zero(bench):
-    out = bench._assemble_arm_record(
-        {}, {"a": {"error": "x"}}, ["a"], "a", "a", "t")
-    assert out["value"] == 0.0 and out["value_arm"] is None
+def _bench(*args, **env):
+    e = {k: v for k, v in os.environ.items() if not k.startswith("BENCH_")}
+    e.update(JAX_PLATFORMS="cpu", **env)
+    return subprocess.run([sys.executable, BENCH, *args], cwd=REPO, env=e,
+                          capture_output=True, text=True, timeout=600)
 
 
-def test_child_env_flag_disables_isolation(bench, monkeypatch):
-    """A child (--arm) must never recurse into more subprocesses."""
-    monkeypatch.setenv("BENCH_ARM", "int8")
-    assert not bench._arms_isolated(_TpuDev())
+def test_without_cpu_and_without_a_chip_nothing_is_measured():
+    out = _bench("--small")
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""          # no JSON line, no number
+    assert "no TPU" in out.stderr and "Nothing was measured" in out.stderr
+    assert "[bench] device=" not in out.stderr  # before any config ran
 
 
-# ---------------------------------------------------------------------------
-# _probe_backend fail-fast on a known-wedged tunnel
-# ---------------------------------------------------------------------------
+def test_a_config_that_raises_exits_nonzero(tmp_path):
+    """--config with an unknown rung name: SystemExit, not a record."""
+    out = _bench("--cpu", "--small", "--gpt-rung", "no_such_rung")
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "unknown rung" in out.stderr
 
 
-def _fake_probe_log(bench, monkeypatch, entries):
-    class _FakeProbeTool:
-        @staticmethod
-        def read_log(n=None):
-            return entries if n is None else entries[-n:]
-
-    monkeypatch.setattr(bench, "_tool",
-                        lambda name: _FakeProbeTool
-                        if name == "probe_tpu" else (1 / 0))
-
-
-def _ts(age_s):
-    import datetime
-
-    return (datetime.datetime.now(datetime.timezone.utc)
-            - datetime.timedelta(seconds=age_s)).isoformat(
-                timespec="seconds")
-
-
-def test_recent_wedge_detected(bench, monkeypatch):
-    _fake_probe_log(bench, monkeypatch,
-                    [{"ts": _ts(120), "ok": False,
-                      "detail": "timeout after 240s"}])
-    assert bench._recent_probe_wedge()
-
-
-def test_healthy_or_stale_log_means_full_ladder(bench, monkeypatch):
-    # most recent entry healthy: no fail-fast, even with older failures
-    _fake_probe_log(bench, monkeypatch,
-                    [{"ts": _ts(300), "ok": False, "detail": "timeout"},
-                     {"ts": _ts(60), "ok": True, "detail": {}}])
-    assert not bench._recent_probe_wedge()
-    # failure, but outside the window: evidence is stale
-    _fake_probe_log(bench, monkeypatch,
-                    [{"ts": _ts(7200), "ok": False, "detail": "timeout"}])
-    assert not bench._recent_probe_wedge()
-    # empty/absent log
-    _fake_probe_log(bench, monkeypatch, [])
-    assert not bench._recent_probe_wedge()
-
-
-def test_probe_backend_fail_fast_single_short_attempt(bench, monkeypatch):
-    """With a fresh failed probe already on record, _probe_backend makes
-    ONE short attempt instead of the 2x240 s retry ladder."""
-    import sys as _sys
-
-    calls = []
-
-    def fake_probe(timeout, source=""):
-        calls.append(timeout)
-        return {"ok": False, "detail": "still wedged", "elapsed_s": 1}
-
-    # a REAL probe_tpu module instance with only probe() faked, so the
-    # test still drives the actual retry policy (probe_with_retry ->
-    # resilience.retry) end to end
-    fake_mod = bench._tool("probe_tpu")
-    monkeypatch.setattr(fake_mod, "probe", fake_probe)
-    monkeypatch.setitem(_sys.modules, "probe_tpu", fake_mod)
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    _fake_probe_log(bench, monkeypatch,
-                    [{"ts": _ts(60), "ok": False,
-                      "detail": "timeout after 240s"}])
-    assert bench._probe_backend() is None
-    assert calls == [90]  # one attempt, short (but cold-init-sized) timeout
-
-    # and without wedge evidence: the full ladder (2 x 240)
-    calls.clear()
-    _fake_probe_log(bench, monkeypatch, [])
-    assert bench._probe_backend() is None
-    assert calls == [240, 240]
+def test_cpu_small_is_the_rehearsal_and_says_so():
+    out = _bench("--cpu", "--small", "--config", "mnist")
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = json.loads(out.stdout.strip().splitlines()[-1])
+    prov = line["provenance"]
+    assert prov["platform"] == "cpu" and line["device"] == "cpu"
+    assert set(prov) == {"ts", "platform", "device_kind", "jax", "jaxlib",
+                         "python", "git_rev", "flags"}
+    assert "_cpu_fallback" not in line["metric"]

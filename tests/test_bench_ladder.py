@@ -3,13 +3,13 @@
 The ladder's rung order encodes an MFU *guess*; the tournament measures up
 to BENCH_LADDER_TOP fitting rungs and headlines the best MEASURED MFU, so
 a wrong guess costs a few extra minutes instead of the round's headline
-number.  Control flow is tested like product code (cf. test_watchdog.py):
-rung children, the HBM pre-filter, and the wedge-abort are faked.
+number.  Rungs run in bench.py's own process (a chip belongs to one
+process): a rung that runs out of device memory is stepped past, anything
+else it raises ends the run.  Control flow is tested like product code: the
+rung runner and the HBM pre-filter are faked.
 """
 import importlib.util
-import json
 import os
-import subprocess
 
 import pytest
 
@@ -28,7 +28,11 @@ def bench(monkeypatch):
         m, "_gpt_rung_fits",
         lambda name, cfg_kwargs, B, T, sd, hbm, accum=1, fused=False: True)
     monkeypatch.delenv("BENCH_LADDER_TOP", raising=False)
-    monkeypatch.delenv("BENCH_RUNG_TIMEOUT", raising=False)
+
+    def no_children(*a, **k):
+        raise AssertionError("the ladder started a child process")
+
+    monkeypatch.setattr(m.subprocess, "run", no_children)
     return m
 
 
@@ -38,27 +42,25 @@ def _rungs(m, monkeypatch, names):
         lambda: [(n, {}, 8, 2048, 10, "bfloat16", 1, False) for n in names])
 
 
-class _Done:
-    def __init__(self, rc=0, stdout="", stderr=""):
-        self.returncode, self.stdout, self.stderr = rc, stdout, stderr
+OOM = RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in memory space "
+                   "hbm. Used 20G of 15.75G hbm.")
 
 
-def _child_results(m, monkeypatch, by_name):
-    """Fake the per-rung subprocess: by_name[rung] is a result dict, an
-    int (nonzero rc), or 'timeout'."""
+def _rung_results(m, monkeypatch, by_name):
+    """Fake the in-process rung runner: by_name[rung] is a result dict or
+    an exception to raise."""
     calls = []
+    names = [r[0] for r in m._gpt_rungs()]
 
-    def fake_run(argv, capture_output, text, timeout):
-        name = argv[argv.index("--gpt-rung") + 1]
+    def fake_rung(idx):
+        name = names[idx]
         calls.append(name)
         spec = by_name[name]
-        if spec == "timeout":
-            raise subprocess.TimeoutExpired(argv, timeout)
-        if isinstance(spec, int):
-            return _Done(rc=spec)
-        return _Done(stdout=json.dumps(spec) + "\n")
+        if isinstance(spec, BaseException):
+            raise spec
+        return spec
 
-    monkeypatch.setattr(m.subprocess, "run", fake_run)
+    monkeypatch.setattr(m, "_run_gpt_rung", fake_rung)
     return calls
 
 
@@ -69,7 +71,7 @@ def _r(name, mfu, device="tpu"):
 
 def test_headline_is_best_mfu_not_first_success(bench, monkeypatch):
     _rungs(bench, monkeypatch, ["a", "b", "c", "d"])
-    calls = _child_results(bench, monkeypatch, {
+    calls = _rung_results(bench, monkeypatch, {
         "a": _r("a", 0.21), "b": _r("b", 0.34), "c": _r("c", 0.28),
         "d": _r("d", 0.9)})
     out = bench.bench_gpt(small=False)
@@ -81,38 +83,27 @@ def test_headline_is_best_mfu_not_first_success(bench, monkeypatch):
 
 def test_failed_rungs_dont_count_toward_top_k(bench, monkeypatch):
     _rungs(bench, monkeypatch, ["a", "b", "c", "d"])
-    calls = _child_results(bench, monkeypatch, {
-        "a": 1, "b": _r("b", 0.2), "c": 1, "d": _r("d", 0.3)})
+    calls = _rung_results(bench, monkeypatch, {
+        "a": OOM, "b": _r("b", 0.2), "c": OOM, "d": _r("d", 0.3)})
     out = bench.bench_gpt(small=False)
     assert calls == ["a", "b", "c", "d"]
     assert out["metric"] == "tokens_per_sec_per_chip_d"
 
 
-def test_two_timeouts_abort_with_best_so_far(bench, monkeypatch):
-    _rungs(bench, monkeypatch, ["a", "b", "c", "d"])
-    calls = _child_results(bench, monkeypatch, {
-        "a": _r("a", 0.25), "b": "timeout", "c": "timeout",
-        "d": _r("d", 0.5)})
-    out = bench.bench_gpt(small=False)
-    # wedge abort after b+c; a's measurement survives as the headline
-    assert calls == ["a", "b", "c"]
-    assert out["metric"] == "tokens_per_sec_per_chip_a"
-    assert "candidates" not in out  # single result: no tournament table
-
-
-def test_cpu_child_aborts_ladder_keeps_best(bench, monkeypatch):
-    _rungs(bench, monkeypatch, ["a", "b", "c"])
-    calls = _child_results(bench, monkeypatch, {
-        "a": _r("a", 0.25), "b": _r("b", 0.9, device="cpu"),
-        "c": _r("c", 0.95)})
-    out = bench.bench_gpt(small=False)
-    assert calls == ["a", "b"]  # CPU fallback child ends the ladder
-    assert out["metric"] == "tokens_per_sec_per_chip_a"
+def test_a_rung_that_raises_anything_else_ends_the_run(bench, monkeypatch):
+    """Only an OOM is the ladder's own business: a compiler refusal (or any
+    other error) is never stepped past to a number from a lesser rung."""
+    _rungs(bench, monkeypatch, ["a", "b"])
+    calls = _rung_results(bench, monkeypatch, {
+        "a": ValueError("Mosaic: block shape refused"), "b": _r("b", 0.5)})
+    with pytest.raises(ValueError, match="refused"):
+        bench.bench_gpt(small=False)
+    assert calls == ["a"]
 
 
 def test_all_rungs_failing_raises(bench, monkeypatch):
     _rungs(bench, monkeypatch, ["a", "b"])
-    _child_results(bench, monkeypatch, {"a": 1, "b": 1})
+    _rung_results(bench, monkeypatch, {"a": OOM, "b": OOM})
     with pytest.raises(RuntimeError):
         bench.bench_gpt(small=False)
 
@@ -120,7 +111,7 @@ def test_all_rungs_failing_raises(bench, monkeypatch):
 def test_top_k_env_override(bench, monkeypatch):
     monkeypatch.setenv("BENCH_LADDER_TOP", "1")
     _rungs(bench, monkeypatch, ["a", "b"])
-    calls = _child_results(bench, monkeypatch, {
+    calls = _rung_results(bench, monkeypatch, {
         "a": _r("a", 0.2), "b": _r("b", 0.8)})
     out = bench.bench_gpt(small=False)
     assert calls == ["a"]
@@ -131,7 +122,7 @@ def test_unfit_rungs_are_skipped_entirely(bench, monkeypatch):
     bench._gpt_rung_fits = (
         lambda name, cfg_kwargs, B, T, sd, hbm, accum=1, fused=False: False)
     _rungs(bench, monkeypatch, ["a"])
-    _child_results(bench, monkeypatch, {})
+    _rung_results(bench, monkeypatch, {})
     with pytest.raises(RuntimeError):
         bench.bench_gpt(small=False)
 
@@ -151,7 +142,6 @@ def test_calibrated_walk_matches_on_device_outcomes(monkeypatch):
         "bench_calibration_test", os.path.join(REPO, "bench.py"))
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    bench._fused_kernels_ok = lambda: True
     rungs = {r[0]: r for r in bench._gpt_rungs()}
     hbm = 16.9e9  # 15.75 GiB in decimal bytes
 
@@ -212,36 +202,19 @@ def test_calibrated_walk_matches_on_device_outcomes(monkeypatch):
                                                     fused, policy), name
 
 
-def test_prefer_ladder_headline_reorders_walk(bench, monkeypatch):
-    monkeypatch.setenv("BENCH_PREFER_LADDER_HEADLINE", "1")
-    monkeypatch.setenv("BENCH_LADDER_TOP", "1")
-    monkeypatch.setattr(bench, "_watchdog_tpu_result", lambda: {
-        "headline": {"metric": "tokens_per_sec_per_chip_c"}})
-    _rungs(bench, monkeypatch, ["a", "b", "c"])
-    calls = _child_results(bench, monkeypatch, {
-        "a": _r("a", 0.2), "b": _r("b", 0.3), "c": _r("c", 0.1)})
-    out = bench.bench_gpt(small=False)
-    assert calls == ["c"]  # the main ladder's headline rung goes first
-    assert out["metric"] == "tokens_per_sec_per_chip_c"
-
-
-def test_prefer_headline_without_watchdog_result_keeps_order(bench,
-                                                             monkeypatch):
-    monkeypatch.setenv("BENCH_PREFER_LADDER_HEADLINE", "1")
-    monkeypatch.setenv("BENCH_LADDER_TOP", "1")
-    monkeypatch.setattr(bench, "_watchdog_tpu_result", lambda: None)
-    _rungs(bench, monkeypatch, ["a", "b"])
-    calls = _child_results(bench, monkeypatch, {
-        "a": _r("a", 0.2), "b": _r("b", 0.3)})
-    out = bench.bench_gpt(small=False)
-    assert calls == ["a"]
-    assert out["metric"] == "tokens_per_sec_per_chip_a"
+def test_fused_rungs_exist_without_any_marker_file(bench):
+    """Which rungs exist is decided by code in git, not by a git-ignored
+    file a checker once wrote."""
+    names = [r[0] for r in bench._gpt_rungs()]
+    assert names[0] == "gpt_1.3b_fused_acc8_b8"
+    assert sum(r[7] for r in bench._gpt_rungs()) >= 10  # fused=True rungs
+    assert not os.path.exists(os.path.join(REPO, "FUSED_KERNELS_OK.json"))
 
 
 def test_tournament_budget_stops_after_banked_result(bench, monkeypatch):
     monkeypatch.setenv("BENCH_TOURNAMENT_BUDGET", "0")  # instant exhaustion
     _rungs(bench, monkeypatch, ["a", "b", "c"])
-    calls = _child_results(bench, monkeypatch, {
+    calls = _rung_results(bench, monkeypatch, {
         "a": _r("a", 0.2), "b": _r("b", 0.8), "c": _r("c", 0.9)})
     out = bench.bench_gpt(small=False)
     # the first rung banks a result; the exhausted budget stops the rest

@@ -1,0 +1,287 @@
+"""Ask the chip's compiler, without the chip.
+
+Every Pallas kernel on the main path, one decode step of the Engine and one
+``hybrid.train_step`` are compiled here for a *described* ``v5e:2x2`` at the
+widths of ``gpt.gpt_1p3b()`` (hidden 2048, 16 heads of 128, vocab 50304,
+seq 2048).  What the compiler refuses here it refuses on the chip; interpret
+mode cannot show that (the two decode kernels passed every interpret test
+while both were refused for their block shapes).  Nothing runs, so nothing
+here says anything about results or times.
+
+Rules this file keeps (``/opt/skills/guides/on-chip-measurement``): the
+topology is described inside a module-scoped fixture that skips when it
+cannot be, never at import; shapes and shardings are built in fixtures or
+tests; the persistent compile cache is off around the compiles (an entry
+written for a described chip cannot be read back without one); everything
+compiles in the test's own process; and all such tests live in this one
+file, because the TPU library belongs to one process at a time.
+"""
+import dataclasses
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, SingleDeviceSharding
+
+B, H, HD, T, D, V = 8, 16, 128, 2048, 2048, 50304
+BF, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+@pytest.fixture()
+def shape(one_chip, no_persistent_cache):
+    """``shape((8, 128), dtype)`` -> a ShapeDtypeStruct on the described
+    chip (there is no device to hold an array)."""
+    return lambda dims, dt: jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """Whole programs ask ``_pallas.on_tpu()`` and would take their CPU
+    branch here: steer it in the test, not through an option."""
+    from paddle_tpu.ops import _pallas
+
+    monkeypatch.setattr(_pallas, "on_tpu", lambda: True)
+    monkeypatch.setenv("PADDLE_TPU_FUSED_LN", "1")
+    monkeypatch.setenv("PADDLE_TPU_FUSED_CE", "1")
+
+
+def kernels_in(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; how many Mosaic kernels the
+    executable holds."""
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        "tpu_custom_call")
+
+
+# --------------------------------------------------------------------------
+# training kernels
+# --------------------------------------------------------------------------
+
+
+def test_flash_fwd_bwd_causal(shape):
+    from paddle_tpu.ops import flash_attention as fa
+
+    q = shape((1, T, H, HD), BF)
+    n = kernels_in(
+        lambda q, k, v: jax.vjp(
+            lambda a, b, c: fa._flash(a, b, c, True, None), q, k, v)[1](q),
+        q, q, q)
+    assert n == 3  # fwd, dq, dk/dv
+
+
+def test_fused_layer_norm_fwd_bwd(shape):
+    from paddle_tpu.ops import fused_norm as fn
+
+    x, g = shape((T, D), BF), shape((D,), BF)
+    n = kernels_in(
+        lambda x, g, b: jax.vjp(
+            lambda a, w, c: fn._fused_ln(a, w, c, 1e-5), x, g, b)[1](x),
+        x, g, g)
+    assert n == 2
+
+
+def test_fused_softmax_ce_fwd_bwd(shape):
+    from paddle_tpu.ops import fused_ce as fce
+
+    n = kernels_in(
+        lambda l, y: jax.vjp(lambda a: fce._fused_ce(a, y), l)[1](
+            jnp.ones((T,), F32)),
+        shape((T, V), BF), shape((T,), I32))
+    assert n == 2
+
+
+def test_w4_matmul(shape):
+    from paddle_tpu.ops import woq_matmul as wm
+
+    n = kernels_in(lambda x, p, s: wm._w4_call(x, p, s, 128),
+                   shape((8, D), BF), shape((D // 2, 4 * D), I8),
+                   shape((D // 128, 1, 4 * D), F32))
+    assert n == 1
+
+
+# --------------------------------------------------------------------------
+# decode kernels: refused on the parent commit for their block shapes
+# (pos block (1, 1) of (8, 1); KV block (1, bs, 1, 128) of [N, bs, 16, 128])
+# --------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("Tq", [1, 4])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("Hkv", [16, 4])
+def test_decode_kernel(shape, Hkv, kv, Tq):
+    from paddle_tpu.ops import decode_attention as da
+
+    q = shape((B, Tq, H, HD), BF)
+    k = shape((B, T, Hkv, HD), BF if kv == "bf16" else I8)
+    sc = shape((B, T, Hkv), F32) if kv == "int8" else None
+    assert da.supported(q.shape, k.shape)
+    n = kernels_in(
+        lambda q, k, v, p, a, b: da._decode_call(q, k, v, p, a, b, None),
+        q, k, k, shape((B,), I32), sc, sc)
+    assert n == 1
+
+
+@pytest.mark.parametrize("Tq", [1, 4])
+@pytest.mark.parametrize("bs", [16, 128])
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_decode_kernel(shape, kv, bs, Tq):
+    from paddle_tpu.ops import decode_attention as da
+
+    nmax = T // bs
+    q = shape((B, Tq, H, HD), BF)
+    pool = shape((B * nmax, bs, H, HD), BF if kv == "bf16" else I8)
+    sc = shape((B * nmax, bs, H), F32) if kv == "int8" else None
+    assert da.paged_supported(q.shape, pool.shape)
+    n = kernels_in(
+        lambda q, k, v, t, p, a, b: da._paged_call(q, k, v, t, p, a, b,
+                                                   None),
+        q, pool, pool, shape((B, nmax), I32), shape((B,), I32), sc, sc)
+    assert n == 1
+
+
+# --------------------------------------------------------------------------
+# whole programs at reduced depth, through the entry points' own builders
+# --------------------------------------------------------------------------
+
+
+def _abstract(tree, sharding=None, dtype=None):
+    def one(x):
+        dt = dtype if dtype is not None and x.dtype == F32 else x.dtype
+        return jax.ShapeDtypeStruct(x.shape, dt, sharding=sharding)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
+def _gpt(layers):
+    from paddle_tpu.text import gpt
+
+    return dataclasses.replace(gpt.gpt_1p3b(), num_layers=layers)
+
+
+def _param_shapes(cfg):
+    from paddle_tpu.text import gpt
+
+    return jax.eval_shape(lambda k: gpt.init_params(cfg, k),
+                          jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+
+@pytest.fixture()
+def purge_engine():
+    from paddle_tpu.text import engine
+
+    cfgs = []
+    yield cfgs.append
+    engine.ENGINE.purge(*cfgs)
+
+
+def _decode_step(cfg, layout, shard, sharding):
+    """(jitted decode step of the Engine, its abstract arguments)."""
+    from paddle_tpu.text import engine, generate
+
+    fn = engine.ENGINE.get("step", engine.StepSpec(
+        cfg=cfg, paged=(layout == "paged"), shard=shard))
+    params = _abstract(_param_shapes(cfg), sharding, dtype=BF)
+    cache = _abstract(jax.eval_shape(
+        lambda: generate.init_cache(cfg, B, T, layout=layout)), sharding)
+    tok = jax.ShapeDtypeStruct((B,), I32, sharding=sharding)
+    return fn, (params, cache, tok, tok)
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_engine_decode_step(one_chip, no_persistent_cache, as_on_tpu,
+                            purge_engine, layout):
+    cfg = _gpt(2)
+    purge_engine(cfg)
+    fn, args = _decode_step(cfg, layout, None, one_chip)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text  # the decode-attention kernel
+
+
+def _train_step(cfg, mesh):
+    from paddle_tpu.optimizer import AdamW
+    from paddle_tpu.text import gpt_hybrid
+
+    opt = AdamW(learning_rate=1e-3)
+    _, step_fn, _ = gpt_hybrid.build_gpt_train_step(cfg, mesh, opt)
+    p = _param_shapes(cfg)
+    state = gpt_hybrid.GPTTrainState(
+        p, jax.eval_shape(opt.init_state, p),
+        jax.ShapeDtypeStruct((), I32))
+    batch = mesh.shape.get("dp", 1)  # one sequence per data shard
+    return step_fn.lower(
+        state, jax.ShapeDtypeStruct((batch, T + 1), I32),
+        jax.eval_shape(lambda: jax.random.PRNGKey(0)),
+        jax.ShapeDtypeStruct((), F32)).compile()
+
+
+def test_train_step_one_chip(topo, no_persistent_cache, as_on_tpu):
+    compiled = _train_step(_gpt(2), Mesh(np.array(topo.devices[:1]),
+                                         ("dp",)))
+    # flash fwd/dq/dkv + fused LN fwd/bwd (two sites) + fused CE fwd/bwd
+    assert compiled.as_text().count("tpu_custom_call") >= 7
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+# --------------------------------------------------------------------------
+# four chips: GSPMD cannot partition a Mosaic kernel, so each runs per shard
+# (ops/_pallas.partitioned); both sharded programs must still hold kernels
+# AND collectives
+# --------------------------------------------------------------------------
+
+
+def test_train_step_dp2_mp2(topo, no_persistent_cache, as_on_tpu):
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "mp"))
+    compiled = _train_step(_gpt(2), mesh)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 5  # CE stays on XLA under mp
+    assert "all-reduce" in text
+    one = _train_step(_gpt(2), Mesh(np.array(topo.devices[:1]), ("dp",)))
+    # per-device argument bytes shrink: the mp-sharded weights are halved
+    assert (compiled.memory_analysis().argument_size_in_bytes
+            < 0.75 * one.memory_analysis().argument_size_in_bytes)
+
+
+@pytest.mark.parametrize("layout", ["contiguous", "paged"])
+def test_engine_decode_step_mp4(topo, no_persistent_cache, as_on_tpu,
+                                purge_engine, layout):
+    from paddle_tpu.text import engine, generate
+
+    cfg = _gpt(2)
+    purge_engine(cfg)
+    mesh = Mesh(np.array(topo.devices), ("mp",))
+    cache = jax.eval_shape(
+        lambda: generate.init_cache(cfg, B, T, layout=layout))
+    shard = engine._ShardCtx(mesh, cfg, _param_shapes(cfg), cache)
+    fn, args = _decode_step(cfg, layout, shard, None)
+    text = fn.lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text

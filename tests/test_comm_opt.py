@@ -4,7 +4,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from paddle_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.distributed.fleet.comm_opt import (DGCState, LocalSGD,

@@ -5,7 +5,7 @@ conftest pins it), so these tests exercise the real kernel body, not the
 XLA fallback: GQA parity against the einsum path across num_kv_heads
 {1, H/4, None}, long caches (>= 2k), every cache storage dtype, and
 donation.  The on-device certification twin is
-tools/check_flash_tpu.py's decode family.
+chip_smoke.py's kernels phase.
 """
 import os
 
@@ -55,7 +55,7 @@ def kv_env(monkeypatch):
 
 
 def _cfg(**kw):
-    base = dict(vocab_size=64, hidden_size=256, num_layers=2, num_heads=4,
+    base = dict(vocab_size=64, hidden_size=512, num_layers=2, num_heads=4,
                 max_seq_len=2304)
     base.update(kw)
     return gpt.GPTConfig(**base)
@@ -69,7 +69,7 @@ def _cfg(**kw):
 @pytest.mark.parametrize("Hkv,G_", [(1, 8), (2, 4), (8, 1)])
 @pytest.mark.parametrize("kv", ["fp32", "bf16", "int8"])
 def test_kernel_matches_oracle_long_cache(interpret, Hkv, G_, kv):
-    Hq, hd, B, T = Hkv * G_, 64, 2, 2048
+    Hq, hd, B, T = Hkv * G_, 128, 2, 2048
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, hd), jnp.bfloat16)
     kc = jax.random.normal(ks[1], (B, T, Hkv, hd), jnp.float32)
@@ -91,7 +91,7 @@ def test_kernel_matches_oracle_long_cache(interpret, Hkv, G_, kv):
 
 def test_kernel_small_tq_chunk(interpret):
     """Tq > 1 (the verify-chunk shape): per-row causal frontier."""
-    B, Tq, Hq, Hkv, hd, T = 1, 8, 8, 2, 64, 256
+    B, Tq, Hq, Hkv, hd, T = 1, 8, 8, 2, 128, 256
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, Tq, Hq, hd), jnp.float32)
     kc = jax.random.normal(ks[1], (B, T, Hkv, hd), jnp.float32)
@@ -173,7 +173,7 @@ def test_greedy_tokens_bit_identical_markov(interpret, kv_env, markov_gpt):
 
 def test_greedy_tokens_bit_identical_kernel_engaged(interpret, kv_env):
     """The same acceptance on a config the kernel actually covers
-    (hd=64, cache length 8-aligned), with engagement asserted."""
+    (hd=128, cache length 8-aligned), with engagement asserted."""
     cfg = _cfg(num_kv_heads=2, max_seq_len=64)
     params = gpt.init_params(cfg, jax.random.PRNGKey(1))
     prompt = [[5, 9, 3]]
@@ -215,7 +215,7 @@ def test_init_cache_rounds_to_tileable_length(kv_env):
     # and the rounded lengths actually pass the kernel's shape gate
     for n in (10, 513, 1000):
         T = G.init_cache(cfg, 1, n)["k"].shape[2]
-        assert da.supported((1, 1, 4, 64), (1, T, 4, 64)), (n, T)
+        assert da.supported((1, 1, 4, 128), (1, T, 4, 128)), (n, T)
 
 
 def test_kernel_engages_on_unaligned_generate_total(interpret, kv_env):
@@ -635,3 +635,69 @@ def test_spec_serving_flash_verify_greedy_parity(interpret, kv_env):
         da._decode_call = orig
     assert calls["n"] >= 1, "flash-verify never engaged in serving"
     assert got == want
+
+
+# ---------------------------------------------------------------------------
+# no quiet fallback: a refusal reaches the caller, a shape gate picks XLA
+# ---------------------------------------------------------------------------
+
+
+def _refuse(*a, **k):
+    raise ValueError("Mosaic refused this block shape")
+
+
+def _decode_operands(hd):
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (2, 1, 4, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (2, 64, 4, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (2, 64, 4, hd), jnp.float32)
+    return q, k, v, jnp.asarray([10, 63], jnp.int32)
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_refusal_in_the_kernel_build_propagates(interpret, monkeypatch,
+                                                paged):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    q, k, v, pos = _decode_operands(128)
+    with pytest.raises(ValueError, match="Mosaic refused"):
+        if paged:
+            tables = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+            da.paged_decode_attention(q, k.reshape(8, 16, 4, 128),
+                                      v.reshape(8, 16, 4, 128), tables, pos)
+        else:
+            da.decode_attention(q, k, v, pos)
+    assert not hasattr(da, "_probe") and not hasattr(da, "_paged_probe")
+    assert not hasattr(da, "_FALLBACK")
+
+
+def test_failed_shape_gate_still_picks_xla(interpret, monkeypatch):
+    """hd=64 is off the kernel's lane-chunk layout (a cell takes its head
+    as an hd-wide chunk of 128-lane rows): the static gate says XLA, for
+    the slab and the pool alike, and no kernel is built."""
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    q, k, v, pos = _decode_operands(64)
+    assert not da.supported(q.shape, k.shape)
+    assert not da.available(q.shape, k.shape)
+    np.testing.assert_allclose(
+        np.asarray(da.decode_attention(q, k, v, pos)),
+        np.asarray(da._xla_decode(q, k, v, pos, None, None, None)),
+        atol=1e-6)
+    kp, vp = k.reshape(8, 16, 4, 64), v.reshape(8, 16, 4, 64)
+    tables = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
+    assert not da.paged_supported(q.shape, kp.shape)
+    np.testing.assert_allclose(
+        np.asarray(da.paged_decode_attention(q, kp, vp, tables, pos)),
+        np.asarray(da._xla_decode(q, k, v, pos, None, None, None)),
+        atol=1e-6)
+
+
+def test_off_a_tpu_the_gate_is_closed_without_interpret():
+    """On the CPU, outside interpret mode, routing never reaches Pallas."""
+    assert da._INTERPRET is False
+    assert da.supported((1, 1, 4, 128), (1, 64, 4, 128))
+    assert not da.available((1, 1, 4, 128), (1, 64, 4, 128))
+    assert not da.paged_available((1, 1, 4, 128), (8, 16, 4, 128))

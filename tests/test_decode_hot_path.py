@@ -248,33 +248,54 @@ def test_chunked_prefill_warmup_single_executable(small_model):
 # ---------------------------------------------------------------------------
 
 
-def test_init_compile_cache_path_and_idempotence(tmp_path):
-    from paddle_tpu.framework import platform
-
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_inited = platform._cache_inited
-    try:
-        p = str(tmp_path / "xla")
-        got = platform.init_compile_cache(p)
-        assert got == p and os.path.isdir(p)
-        assert jax.config.jax_compilation_cache_dir == p
-        # idempotent: a later argless call returns the configured dir
-        assert platform.init_compile_cache() == p
-    finally:
-        platform._cache_inited = old_inited
-        jax.config.update("jax_compilation_cache_dir", old_dir)
+_CACHE_DIR_SNIPPET = (
+    "import jax; from paddle_tpu.framework import platform; "
+    "d = platform.init_compile_cache(); "
+    "print(d); print(jax.config.jax_compilation_cache_dir)")
 
 
-def test_init_compile_cache_off_switch(monkeypatch):
+def _cache_dir_in_child(env_dir):
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _CACHE_DIR_SNIPPET],
+                         env=env, capture_output=True, text=True,
+                         check=True, cwd="/")
+    returned, configured = out.stdout.split()[-2:]
+    return repo, returned, configured
+
+
+def test_compile_cache_default_is_inside_checkout_and_stable():
+    """Unset env: <checkout>/.jax_cache, the same in two processes (the
+    directory is part of the cache key), whatever the cwd or HOME."""
+    repo, d1, c1 = _cache_dir_in_child(None)
+    _, d2, c2 = _cache_dir_in_child(None)
+    assert d1 == d2 == c1 == c2 == os.path.join(repo, ".jax_cache")
+
+
+def test_compile_cache_env_dir_is_left_alone(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets no directory."""
+    want = str(tmp_path / "placed")
+    _, returned, configured = _cache_dir_in_child(want)
+    assert returned == configured == want
+
+
+def test_compile_cache_has_no_private_knob(monkeypatch):
     from paddle_tpu.framework import platform
 
     monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", "off")
-    old_inited = platform._cache_inited
-    platform._cache_inited = None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    old = jax.config.jax_compilation_cache_dir
     try:
-        assert platform.init_compile_cache() is None
+        assert platform.init_compile_cache() == platform._DEFAULT_CACHE_DIR
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
     finally:
-        platform._cache_inited = old_inited
+        jax.config.update("jax_compilation_cache_dir", old)
 
 
 # ---------------------------------------------------------------------------

@@ -42,7 +42,7 @@ print(f"rank {rank} resumed at step {start}", flush=True)
 # real training steps carry collectives: when a peer dies, the survivor's
 # next psum fails instead of letting it race ahead solo and pollute the
 # checkpoint dir with rank-partial saves
-from paddle_tpu.compat import shard_map
+from jax import shard_map
 couple = jax.jit(shard_map(lambda v: jax.lax.psum(v, "dp"), mesh=mesh,
                            in_specs=P("dp"), out_specs=P(),
                            check_vma=False))

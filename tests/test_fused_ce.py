@@ -3,7 +3,8 @@
 Same testing stance as tests/test_fused_norm.py: the kernel bodies run
 under ``interpret=True`` so the CPU suite exercises the online-softmax
 sweep, the label-pick iota compare, and the blockwise backward — the
-on-device Mosaic lowering is checked by tools/check_flash_tpu.py.
+the Mosaic lowering is checked by tests/test_chip_compile.py, on-device
+parity by chip_smoke.py.
 
 Reference parity target: operators/softmax_with_cross_entropy_op.cu.
 """
@@ -148,3 +149,56 @@ class TestGPTRoute:
         without = gpt.loss_fn(params, toks, cfg)
         np.testing.assert_allclose(np.asarray(with_fused),
                                    np.asarray(without), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# no quiet fallback: a refusal reaches the caller, a shape gate picks XLA
+# ---------------------------------------------------------------------------
+
+
+def _refuse(*a, **k):
+    raise ValueError("Mosaic refused this block shape")
+
+
+def _logits_labels(N, V):
+    logits = jax.random.normal(jax.random.PRNGKey(0), (N, V))
+    labels = jax.random.randint(jax.random.PRNGKey(1), (N,), 0, V)
+    return logits, labels
+
+
+def test_refusal_in_the_kernel_build_propagates(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    logits, labels = _logits_labels(64, 512)
+    with pytest.raises(ValueError, match="Mosaic refused"):
+        fused_ce.fused_softmax_ce(logits, labels)
+    assert not hasattr(fused_ce, "_probe")
+
+
+def test_failed_shape_gate_still_picks_xla(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    logits, labels = _logits_labels(64, 500)  # vocab off the lane grid
+    np.testing.assert_allclose(
+        np.asarray(fused_ce.fused_softmax_ce(logits, labels)),
+        np.asarray(fused_ce._xla_ce(logits, labels)), atol=1e-6)
+
+
+def test_vocab_sharded_step_keeps_the_xla_reduction(monkeypatch):
+    """Under a tensor-parallel partition the vocab is sharded: the kernel
+    needs whole rows, so the entry leaves the reduction to GSPMD."""
+    from jax.experimental import pallas as pl
+    from jax.sharding import Mesh
+
+    from paddle_tpu.ops import _pallas
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    logits, labels = _logits_labels(64, 512)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
+    with _pallas.partitioned(mesh, heads="mp"):
+        out = fused_ce.fused_softmax_ce(logits, labels)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(fused_ce._xla_ce(logits, labels)),
+                               atol=1e-6)

@@ -1,7 +1,7 @@
 """Pallas fused LayerNorm kernel vs the XLA reference, in interpret mode.
 
 Unlike the flash-attention kernel (whose Mosaic lowering can only run
-on-device, checked by tools/check_flash_tpu.py), the fused LayerNorm kernels
+on the chip, checked by chip_smoke.py), the fused LayerNorm kernels
 run here under ``interpret=True`` so the CPU suite always exercises the
 actual kernel bodies — forward statistics, the custom_vjp plumbing, and the
 revisited-block dgamma/dbeta accumulator.
@@ -179,3 +179,44 @@ class TestFunctionalRoute:
         ref = fused_norm._xla_ln(jnp.asarray(x.numpy()), jnp.ones(256),
                                  jnp.full(256, 0.5), 1e-5)
         np.testing.assert_allclose(out.numpy(), np.asarray(ref), atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# no quiet fallback: a refusal reaches the caller, a shape gate picks XLA
+# ---------------------------------------------------------------------------
+
+
+def _refuse(*a, **k):
+    raise ValueError("Mosaic refused this block shape")
+
+
+class TestNoFallback:
+    def test_refusal_in_the_kernel_build_propagates(self, monkeypatch):
+        from jax.experimental import pallas as pl
+
+        monkeypatch.setattr(pl, "pallas_call", _refuse)
+        with pytest.raises(ValueError, match="Mosaic refused"):
+            fused_norm.fused_layer_norm(_rand((64, 256)))
+        with pytest.raises(ValueError, match="Mosaic refused"):
+            jax.jit(fused_norm.fused_layer_norm)(_rand((64, 256)))
+
+    def test_failed_shape_gate_still_picks_xla(self, monkeypatch):
+        from jax.experimental import pallas as pl
+
+        monkeypatch.setattr(pl, "pallas_call", _refuse)
+        x = _rand((64, 200))  # feature width off the 128-lane grid
+        np.testing.assert_allclose(
+            np.asarray(fused_norm.fused_layer_norm(x)),
+            np.asarray(fused_norm._xla_ln(x, jnp.ones((200,)),
+                                          jnp.zeros((200,)), 1e-5)),
+            atol=1e-6)
+
+    def test_off_a_tpu_without_interpret_is_xla_not_a_probe(
+            self, monkeypatch):
+        from jax.experimental import pallas as pl
+
+        monkeypatch.setattr(fused_norm, "_INTERPRET", False)
+        monkeypatch.setattr(pl, "pallas_call", _refuse)
+        assert not hasattr(fused_norm, "_probe")
+        assert not hasattr(fused_norm, "_FALLBACK")
+        fused_norm.fused_layer_norm(_rand((64, 256)))  # the CPU: XLA

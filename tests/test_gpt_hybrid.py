@@ -13,7 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax import lax
-from paddle_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from paddle_tpu.distributed import megatron as mt

@@ -103,7 +103,7 @@ def test_moe_manual_matches_gspmd():
     computes exactly what GSPMD derives from the shardings."""
     import functools
 
-    from paddle_tpu.compat import shard_map
+    from jax import shard_map
     from paddle_tpu.text.moe import moe_ffn_manual
 
     cfg = MoEConfig(num_experts=8, capacity_factor=4.0, top_k=2)
@@ -159,7 +159,7 @@ class TestMoEPipeline:
         assert abs(float(loss) - ref) < 3e-4, (float(loss), ref)
 
     def test_1f1b_grads_match_dense(self):
-        from paddle_tpu.compat import shard_map
+        from jax import shard_map
 
         toks, params, key = self._setup()
         gref = jax.grad(lambda p: gpt.loss_fn(p, toks, GPT_MOE,

@@ -32,7 +32,7 @@ import paddle_tpu as paddle
 # drives jax.distributed.initialize inside init_parallel_env
 paddle.distributed.init_parallel_env({"dp": 2})
 import jax.numpy as jnp
-from paddle_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 mesh = paddle.distributed.get_mesh()

@@ -379,10 +379,6 @@ def test_wedge_watchdog_recovery_and_healthz_flip(monkeypatch,
     tl.reset()
     monkeypatch.setenv("PADDLE_TPU_STEP_BUDGET_S", "0.3")
     monkeypatch.setenv("PADDLE_TPU_FAULT_WEDGE_S", "1.0")
-    # point probe-health at an empty log: /healthz must reflect the
-    # RUNTIME wedge, not whatever the repo's probe history says
-    monkeypatch.setenv("PADDLE_TPU_PROBE_LOG",
-                       str(tmp_path / "probe.jsonl"))
     faults.install("wedge:tick:1")
     try:
         srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=32,
@@ -820,64 +816,6 @@ def test_crash_mid_save_never_corrupts_last_good(tmp_path, monkeypatch):
     np.testing.assert_array_equal(fio.load(path)["w"], np.arange(4.0))
     import os
     assert not [f for f in os.listdir(tmp_path) if ".tmp." in f]
-
-
-# ---------------------------------------------------------------------------
-# probe-wedge evidence TTL + probe retry
-# ---------------------------------------------------------------------------
-
-def _probe_entry(ts, ok):
-    return {"ts": ts, "ok": ok, "elapsed_s": 1.0, "source": "t",
-            "detail": "x"}
-
-
-def test_recent_probe_wedge_ttl(tmp_path, monkeypatch):
-    import datetime
-    import sys
-
-    sys.path.insert(0, str(__import__("pathlib").Path(
-        __file__).resolve().parents[1]))
-    import bench
-
-    ptu = bench._tool("probe_tpu")
-    log = tmp_path / "probe.jsonl"
-    monkeypatch.setattr(ptu, "LOG", str(log))
-    # _tool loads a FRESH module per call; pin ours so the patched LOG
-    # is the one _recent_probe_wedge reads
-    monkeypatch.setattr(bench, "_tool", lambda name: ptu)
-    now = datetime.datetime.now(datetime.timezone.utc)
-    old = (now - datetime.timedelta(hours=10)).isoformat(
-        timespec="seconds")
-    fresh = now.isoformat(timespec="seconds")
-    # a long-past wedge: NOT evidence (the TTL expired)
-    log.write_text(json.dumps(_probe_entry(old, False)) + "\n")
-    assert bench._recent_probe_wedge() == ""
-    # a fresh wedge IS evidence
-    log.write_text(json.dumps(_probe_entry(fresh, False)) + "\n")
-    assert bench._recent_probe_wedge() == fresh
-    # the TTL knob shrinks the window
-    monkeypatch.setenv("PADDLE_TPU_WEDGE_TTL_S", "0")
-    assert bench._recent_probe_wedge() == ""
-    monkeypatch.delenv("PADDLE_TPU_WEDGE_TTL_S")
-    # a healthy entry after the wedge: no evidence either
-    with open(log, "a") as f:
-        f.write(json.dumps(_probe_entry(fresh, True)) + "\n")
-    assert bench._recent_probe_wedge() == ""
-
-
-def test_probe_health_wedge_ttl(tmp_path, monkeypatch):
-    import datetime
-
-    now = datetime.datetime.now(datetime.timezone.utc)
-    old = (now - datetime.timedelta(hours=10)).isoformat(
-        timespec="seconds")
-    log = tmp_path / "probe.jsonl"
-    log.write_text(json.dumps(_probe_entry(old, False)) + "\n")
-    h = tl.probe_health(path=str(log))
-    assert h["status"] == "stale"            # expired evidence: not wedged
-    fresh = now.isoformat(timespec="seconds")
-    log.write_text(json.dumps(_probe_entry(fresh, False)) + "\n")
-    assert tl.probe_health(path=str(log))["status"] == "wedged"
 
 
 # ---------------------------------------------------------------------------

@@ -165,3 +165,40 @@ def test_decode_identical_with_kernel_forced(markov_gpt, monkeypatch):
                            temperature=0.0)
     generate._GEN_CACHE.clear()
     assert np.array_equal(np.asarray(off), np.asarray(on))
+
+
+# ---------------------------------------------------------------------------
+# no quiet fallback: a refusal reaches the caller, a shape gate picks XLA
+# ---------------------------------------------------------------------------
+
+
+def _refuse(*a, **k):
+    raise ValueError("Mosaic refused this block shape")
+
+
+def _w4_operands(N, K, M, gs=64):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(N, K)), jnp.float32)
+    packed = jnp.asarray(woq.pack_int4_halves(rng.integers(-7, 8, (K, M))))
+    scale = jnp.asarray(rng.uniform(0.01, 0.1, (K // gs, 1, M))
+                        .astype(np.float32))
+    return x, packed, scale
+
+
+def test_refusal_in_the_kernel_build_propagates(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    with pytest.raises(ValueError, match="Mosaic refused"):
+        wm.w4_matmul(*_w4_operands(8, 256, 256))
+    assert not hasattr(wm, "_probe") and not hasattr(wm, "_FALLBACK")
+
+
+def test_failed_shape_gate_still_picks_xla(monkeypatch):
+    from jax.experimental import pallas as pl
+
+    monkeypatch.setattr(pl, "pallas_call", _refuse)
+    x, packed, scale = _w4_operands(8, 256, 192)  # M off the lane grid
+    np.testing.assert_allclose(np.asarray(wm.w4_matmul(x, packed, scale)),
+                               np.asarray(wm._xla_w4(x, packed, scale)),
+                               rtol=1e-6)
