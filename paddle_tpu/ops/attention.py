@@ -49,12 +49,17 @@ def xla_attention(q, k, v, mask=None, is_causal=False, scale=None):
 
 
 def attention_array(q, k, v, mask=None, is_causal=False, scale=None):
-    """Array-level entry used by jitted model code (GPT flagship)."""
-    if mask is None and _use_flash(q.shape):
-        from . import flash_attention as fa
+    """Array-level entry used by jitted model code (GPT flagship).  The
+    ``attn`` scope of the training and prefill paths is here: the
+    attention itself, not its projections."""
+    with jax.named_scope("attn"):
+        if mask is None and _use_flash(q.shape):
+            from . import flash_attention as fa
 
-        return fa.flash_attention(q, k, v, causal=is_causal, scale=scale)
-    return xla_attention(q, k, v, mask=mask, is_causal=is_causal, scale=scale)
+            return fa.flash_attention(q, k, v, causal=is_causal,
+                                      scale=scale)
+        return xla_attention(q, k, v, mask=mask, is_causal=is_causal,
+                             scale=scale)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,

@@ -323,7 +323,9 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
                      memory_space=pltpu.SMEM),
         q_spec, kv_spec, kv_spec,
     ]
-    args = [pos3, qh, k.reshape(B, T, Hkv * hd), v.reshape(B, T, Hkv * hd)]
+    with jax.named_scope("kv_gather"):   # see _paged_call
+        args = [pos3, qh, k.reshape(B, T, Hkv * hd),
+                v.reshape(B, T, Hkv * hd)]
     if quant:
         s_spec = pl.BlockSpec((1, BT, Hkv), lambda i, t: (i // Hkv, t, 0))
         in_specs += [s_spec, s_spec]
@@ -477,8 +479,12 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
         lambda i, t, tab_ref, pos_ref: (i // Hkv, i % Hkv, 0, 0))
     in_specs = [q_spec, pl.BlockSpec((1, bs, hd), _kv_idx),
                 pl.BlockSpec((1, bs, hd), _kv_idx)]
-    args = [qh, k_pool.reshape(N, bs, Hkv * hd),
-            v_pool.reshape(N, bs, Hkv * hd)]
+    # the kernel's view of the layer's pool, heads folded into the lane
+    # dimension: on the chip a relayout copy of the whole slice, so it is
+    # counted with the pool's gathers, not with the attention
+    with jax.named_scope("kv_gather"):
+        args = [qh, k_pool.reshape(N, bs, Hkv * hd),
+                v_pool.reshape(N, bs, Hkv * hd)]
     if quant:
         in_specs += [pl.BlockSpec((1, bs, Hkv), _ks_idx),
                      pl.BlockSpec((1, bs, Hkv), _ks_idx)]

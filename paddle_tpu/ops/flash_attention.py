@@ -173,6 +173,7 @@ def _flash_fwd_impl(q, k, v, causal, scale):
             pltpu.VMEM((BQ, D), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="flash_attention_fwd",
     )(qh, kh, vh)
     return _heads_last(out, B, H), lse
 
@@ -251,6 +252,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, scale):
         out_shape=jax.ShapeDtypeStruct((B * H, T, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((BQ, D), jnp.float32)],
         interpret=_INTERPRET,
+        name="flash_attention_bwd_dq",
     )(qh, kh, vh, doh, lse, delta)
 
     # -- dk/dv: grid (BH, nk, nq), accumulate over q blocks -----------------
@@ -313,6 +315,7 @@ def _flash_bwd_impl(q, k, v, out, lse, do, causal, scale):
             pltpu.VMEM((BK, D), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="flash_attention_bwd_dkv",
     )(qh, kh, vh, doh, lse, delta)
 
     return (_heads_last(dq, B, H), _heads_last(dk, B, H), _heads_last(dv, B, H))

@@ -180,6 +180,7 @@ def _ce_fwd_impl(logits, labels):
             pltpu.VMEM((BN, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="fused_softmax_ce_fwd",
     )(logits, lbl2)
     return (lse - pick)[:, 0], lse
 
@@ -212,5 +213,6 @@ def _ce_bwd_impl(logits, labels, lse, dloss):
         out_specs=pl.BlockSpec((BN, BV), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N, V), logits.dtype),
         interpret=_INTERPRET,
+        name="fused_softmax_ce_bwd",
     )(logits, lbl2, lse, dl2)
     return dx

@@ -147,6 +147,7 @@ def _ln_fwd_impl(x, g, b, eps):
             jax.ShapeDtypeStruct((N, 1), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="fused_layer_norm_fwd",
     )(x, g2, b2)
     return y, mu, rstd
 
@@ -207,5 +208,6 @@ def _ln_bwd_impl(x, g, mu, rstd, dy):
             jax.ShapeDtypeStruct((1, F), jnp.float32),
         ],
         interpret=_INTERPRET,
+        name="fused_layer_norm_bwd",
     )(x, g2, mu, rstd, dy)
     return dx, dg.reshape(F), db.reshape(F)

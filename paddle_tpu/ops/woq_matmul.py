@@ -156,4 +156,5 @@ def _w4_call(x, packed, scale, gs):
         out_shape=jax.ShapeDtypeStruct((N, M), dt),
         scratch_shapes=[pltpu.VMEM((N, BM), jnp.float32)],
         interpret=_INTERPRET,
+        name="w4_matmul",
     )(x_lo, x_hi, packed, s_lo, s_hi)

@@ -7,9 +7,9 @@ chrome-trace export (profiler.proto); CUPTI device correlation
 
 TPU-native: device-side tracing IS ``jax.profiler`` (XPlane, viewable in
 TensorBoard/Perfetto — the CUPTI role is played by the TPU runtime itself);
-``RecordEvent`` wraps ``jax.profiler.TraceAnnotation`` so host spans land in
-the same timeline, and a lightweight host-event table + chrome-trace JSON
-covers the report/export surface.
+``RecordEvent`` enters the same ``TraceAnnotation`` helper as
+``telemetry.span`` so host spans land in the same timeline, and a lightweight
+host-event table + chrome-trace JSON covers the report/export surface.
 """
 from __future__ import annotations
 
@@ -19,6 +19,8 @@ import os
 import threading
 import time
 from collections import defaultdict
+
+from . import telemetry as _telemetry
 
 _state = threading.local()
 _events: list = []  # (name, start_s, stop_s, thread_id)
@@ -48,9 +50,7 @@ class RecordEvent:
     def __enter__(self):
         t0 = time.perf_counter()
         try:
-            import jax
-
-            ann = jax.profiler.TraceAnnotation(self.name)
+            ann = _telemetry._annotation(self.name)
             ann.__enter__()
         except Exception:
             ann = None
@@ -132,8 +132,6 @@ def capture_device_trace(ms: float = 500.0,
     metrics endpoint serves as ``POST /profile?ms=...`` — the reference
     enabled its CUPTI device tracer this way (EnableProfiler around a
     window of work)."""
-    from . import telemetry as _telemetry
-
     return _telemetry.capture_device_profile(ms, log_dir)
 
 

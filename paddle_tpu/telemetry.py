@@ -89,12 +89,15 @@ from __future__ import annotations
 import bisect
 import contextlib
 import functools
+import itertools
 import json
 import math
 import os
+import re
 import threading
 import time
 import warnings
+import weakref
 from collections import deque
 
 from . import flags as _flags
@@ -102,7 +105,8 @@ from .framework import monitor as _monitor
 
 __all__ = [
     "enabled", "reset", "hist", "gauge", "observe", "set_gauge", "count",
-    "event", "span", "record_compile", "instrument_compile", "snapshot",
+    "event", "span", "events", "NO_SPAN", "record_compile",
+    "instrument_compile", "executable_scopes", "hlo_op_scopes", "snapshot",
     "latency_summary", "render_prometheus", "serve_metrics",
     "chrome_events", "dump_chrome_trace", "Histogram", "Gauge",
     "MetricsServer", "note_step_time", "sample_device_stats",
@@ -508,19 +512,71 @@ def _jsonl_write(rec: dict) -> None:
         _log_fh.flush()
 
 
-def event(name: str, t0: float, t1: float, tid: int = 0, **args) -> None:
-    """Record a completed host span [t0, t1] (``time.perf_counter``
-    seconds — the same clock profiler.py stamps, so the two event streams
-    merge onto one timeline).  Ring-buffered in memory, appended to the
-    ``PADDLE_TPU_TELEMETRY_LOG`` JSONL when set."""
-    if not enabled():
-        return
-    rec = {"name": name, "t0": t0, "t1": t1, "tid": int(tid)}
+# One span primitive.  A span has an id, the id of the span that caused it
+# (the innermost span open on the same thread) and, where it belongs to a
+# request, ``rid`` among its args (inherited from the enclosing span when
+# not given).  ``span`` also enters a ``jax.profiler.TraceAnnotation`` with
+# the same name, id and parent, so the span is in the profiler's trace on
+# the clock the device planes share; ``event`` records a span that is
+# already over (a request's lifetime), ring only.
+_span_ids = itertools.count(1)          # next() is atomic under the GIL
+_open = threading.local()               # .stack: [(span id, rid)] per thread
+_TraceAnnotation = None                 # jax's class, looked up once
+_SCALARS = (str, int, float, bool)
+
+
+def _stack() -> list:
+    st = getattr(_open, "stack", None)
+    if st is None:
+        st = _open.stack = []
+    return st
+
+
+def _caused_by(args: dict):
+    """The id of the span open on this thread (None: none); ``args``
+    inherits its ``rid`` unless it brings its own."""
+    st = _stack()
+    if not st:
+        return None
+    parent, rid = st[-1]
+    if rid is not None:
+        args.setdefault("rid", rid)
+    return parent
+
+
+def _annotation(name: str, **args):
+    """The one place a ``TraceAnnotation`` is constructed: a host span in
+    the profiler's own trace (its keyword arguments arrive as the event's
+    stats).  With no profile session it costs one Python call."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        import jax
+
+        _TraceAnnotation = jax.profiler.TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
+
+def _record(name: str, t0: float, t1: float, tid: int, sid: int,
+            parent, args: dict) -> None:
+    rec = {"name": name, "t0": t0, "t1": t1, "tid": int(tid), "id": sid}
+    if parent is not None:
+        rec["parent"] = parent
     if args:
         rec["args"] = args
     with _lock:
         _events.append(rec)
     _jsonl_write(rec)
+
+
+def event(name: str, t0: float, t1: float, tid: int = 0, **args) -> None:
+    """Record a completed host span [t0, t1] (``time.perf_counter``
+    seconds — the same clock profiler.py stamps, so the two event streams
+    merge onto one timeline) as a child of the span open on this thread.
+    Ring-buffered in memory, appended to the ``PADDLE_TPU_TELEMETRY_LOG``
+    JSONL when set."""
+    if not enabled():
+        return
+    _record(name, t0, t1, tid, next(_span_ids), _caused_by(args), args)
 
 
 def _counter_event(name: str, values: dict) -> None:
@@ -537,18 +593,69 @@ def _counter_event(name: str, values: dict) -> None:
     _jsonl_write(rec)
 
 
-@contextlib.contextmanager
+class _Span:
+    """An open span (what ``with telemetry.span(...) as sp`` yields).
+    ``sp.args`` may be filled until the span closes: what is known only
+    at the end (the step kind a tick dispatched) reaches the ring; the
+    profiler's annotation carries what was known on entry."""
+
+    __slots__ = ("name", "tid", "args", "id", "parent", "t0", "_ann")
+
+    def __init__(self, name: str, tid: int, args: dict):
+        self.name, self.tid, self.args = name, tid, args
+
+    def __enter__(self):
+        args = self.args
+        self.parent = _caused_by(args)
+        self.id = next(_span_ids)
+        _stack().append((self.id, args.get("rid")))
+        self._ann = _annotation(
+            self.name, span_id=self.id, parent=self.parent or 0,
+            **{k: v for k, v in args.items() if isinstance(v, _SCALARS)})
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        _stack().pop()
+        _record(self.name, self.t0, t1, self.tid, self.id, self.parent,
+                self.args)
+        return False
+
+
+class _NoSpan:
+    """``span`` with telemetry off: nothing is stamped, entered or kept."""
+
+    __slots__ = ()
+    args: dict = {}                      # writes to it are never read
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
 def span(name: str, tid: int = 0, **args):
-    """``with telemetry.span("prefill", rid=3): ...`` — records an event
-    on exit (no-op when disabled)."""
+    """``with telemetry.span("serving.tick", rid=3) as sp: ...`` — the
+    one way the program records a host span: into the ring on exit, and
+    into the profiler's trace (a no-op when telemetry is disabled)."""
     if not enabled():
-        yield
-        return
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        event(name, t0, time.perf_counter(), tid=tid, **args)
+        return NO_SPAN
+    return _Span(name, tid, args)
+
+
+def events() -> list:
+    """The ring's raw records, oldest first: spans as ``{"name", "t0",
+    "t1", "tid", "id"[, "parent"][, "args"]}`` in ``time.perf_counter``
+    seconds, counter samples as ``{"name", "ph": "C", "t", "args"}``."""
+    with _lock:
+        return list(_events)
 
 
 def chrome_events(pid: int = 1, shift: float = 0.0) -> list:
@@ -569,8 +676,10 @@ def chrome_events(pid: int = 1, shift: float = 0.0) -> list:
         ev = {"name": e["name"], "ph": "X", "pid": pid, "tid": e["tid"],
               "ts": (e["t0"] + shift) * 1e6,
               "dur": (e["t1"] - e["t0"]) * 1e6}
-        if "args" in e:
-            ev["args"] = e["args"]
+        args = dict(e.get("args", ()), span_id=e["id"])
+        if "parent" in e:
+            args["parent"] = e["parent"]
+        ev["args"] = args
         out.append(ev)
     return out
 
@@ -757,30 +866,41 @@ def _key_diff(old: tuple, new: tuple) -> str:
 
 
 def record_compile(name: str, key, flags_key=None,
-                   seconds: float | None = None) -> None:
-    """Record one jit-cache-miss compile: counter + wall-time histogram +
-    timeline span, and the recompile watch — if this (name, cfg-part)
-    compiled before under a DIFFERENT flags key, the compile is a
-    mid-process flag-flip retrace: warn (rate-limited) with the key diff.
-    A fresh config compiling for the first time never warns."""
+                   seconds: float | None = None, retrace: bool = False
+                   ) -> None:
+    """Record one compile: counter + wall-time histogram + a ``compile``
+    span on the timeline (under the span in which the stall happened),
+    and the recompile watch — if this (name, cfg-part) compiled before
+    under a DIFFERENT flags key, the compile is a mid-process flag-flip
+    retrace: warn (rate-limited) with the key diff.  A fresh config
+    compiling for the first time never warns.  ``retrace`` marks a call
+    that grew the jit cache of an executable already built (a new
+    argument type or shape under the same key)."""
     if not enabled():
         return
     count("compile.count")
-    if seconds is not None:
-        hist("compile.ms").observe(seconds * 1e3)
-        now = time.perf_counter()
-        event(f"compile:{name}", now - seconds, now, key=repr(key))
+    last = None
     with _compile_lock:
         _compile_log.append({"name": name, "key": repr(key),
                              "seconds": None if seconds is None
                              else round(seconds, 4)})
-        if flags_key is None:
-            return
-        base = (name, _strip_flags(key, flags_key))
-        last = _compile_seen.get(base)
-        _compile_seen[base] = flags_key
-        if last is None or last == flags_key:
-            return
+        if flags_key is not None:
+            base = (name, _strip_flags(key, flags_key))
+            last = _compile_seen.get(base)
+            _compile_seen[base] = flags_key
+    flipped = last is not None and last != flags_key
+    if seconds is not None:
+        hist("compile.ms").observe(seconds * 1e3)
+        now = time.perf_counter()
+        args = {"fn": name, "seconds": round(seconds, 4)}
+        if flipped:
+            args["key_diff"] = _key_diff(last, flags_key)
+        if retrace:
+            args["retrace"] = True
+        event("compile", now - seconds, now, **args)
+    if not flipped:
+        return
+    with _compile_lock:
         now = time.monotonic()
         rate_ok = now - _warn_last.get(name, -math.inf) >= _WARN_INTERVAL_S
         if rate_ok:
@@ -798,40 +918,148 @@ def record_compile(name: str, key, flags_key=None,
 def instrument_compile(name: str, key, flags_key, fn):
     """Wrap a freshly built jitted callable from a jit-cache MISS: the
     first call (where tracing + XLA compilation actually happen) is timed
-    and recorded via :func:`record_compile`; later calls pay one ``if``.
-    Returns ``fn`` unchanged when telemetry is off — the hot path
-    compiles down to the raw executable.  The original jit function stays
-    reachable as ``wrapper._telemetry_inner`` (``jax.export`` callers
-    must unwrap through that attribute — NOT ``__wrapped__``, which a
-    raw ``jax.jit`` result also carries, pointing past the jit)."""
+    and recorded via :func:`record_compile`, and so is any later call
+    that grows the function's jit cache (a retrace for a new argument
+    type, which builds no new wrapper); other calls pay one clock read
+    and one ``_cache_size()``.  Returns ``fn`` unchanged when telemetry
+    is off — the hot path compiles down to the raw executable.  The
+    original jit function stays reachable as ``wrapper._telemetry_inner``
+    (``jax.export`` callers must unwrap through that attribute — NOT
+    ``__wrapped__``, which a raw ``jax.jit`` result also carries,
+    pointing past the jit)."""
     if not enabled():
         return fn
 
-    done = False
+    entries = getattr(fn, "_cache_size", None)
+    seen = -1                            # jit-cache entries after last call
 
     @functools.wraps(fn)
     def wrapper(*a, **k):
-        nonlocal done
-        if done:
-            return fn(*a, **k)
-        t0 = time.perf_counter()
+        nonlocal seen
+        t0 = wrapper._telemetry_last_call = time.perf_counter()
         out = fn(*a, **k)
-        done = True
-        record_compile(name, key, flags_key, time.perf_counter() - t0)
-        with _device_lock:
-            # the caller's wall around THIS call includes the compile —
-            # note_step_time must discard it, not seed the EWMA with it
-            _skip_first_wall.add(name)
-        _capture_analysis(name, fn, a, k)
+        n = entries() if entries is not None else 1
+        if n <= seen:                    # nothing compiled (or the cache
+            seen = n                     # was cleared under us)
+            return out
+        first, seen = seen < 0, n
+        record_compile(name, key, flags_key, time.perf_counter() - t0,
+                       retrace=not first)
+        # what executable_scopes() lowers again, on demand: shapes only
+        # (best effort: a reader's question never breaks a step)
+        with contextlib.suppress(Exception):
+            wrapper._telemetry_specs = _arg_specs((a, k))
+        if first:
+            with _device_lock:
+                # the caller's wall around THIS call includes the compile
+                # — note_step_time must discard it, not seed the EWMA
+                _skip_first_wall.add(name)
+            _capture_analysis(name, fn, a, k)
         return out
 
     wrapper._telemetry_inner = fn
+    wrapper._telemetry_name = name
+    _instrumented.add(wrapper)
     # the AOT surface of the jitted function, so an instrumented step can
     # still be lowered and compiled ahead of time (for a described chip)
     for attr in ("lower", "trace", "eval_shape"):
         if hasattr(fn, attr):
             setattr(wrapper, attr, getattr(fn, attr))
     return wrapper
+
+
+# ---------------------------------------------------------------------------
+# which part of the model a device op belongs to
+# ---------------------------------------------------------------------------
+# A device trace names an op by its HLO text, which carries no op_name
+# (read off a v5e trace: an ``XLA Ops`` event has device_offset_ps,
+# device_duration_ps and nothing else).  The compiled module's text does:
+# ``metadata={op_name="jit(<lambda>)/serving.async_step/attn/..."}``.  So a
+# trace reader asks the program, after the trace, for {HLO op name:
+# op_name path} of the executables that ran, and joins on the op's name.
+
+_instrumented: "weakref.WeakSet" = weakref.WeakSet()   # live wrappers
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%([\w.\-]+)")
+
+
+def hlo_op_scopes(text: str) -> dict:
+    """{HLO instruction name: op_name path} of a compiled module's text.
+    An instruction without metadata of its own (some fusions, an async
+    start) takes the path of the computation it calls: its root's, else
+    the most frequent among its instructions.  One that names no path
+    either way (a copy, a buffer allocation) is left out."""
+    own: dict = {}                 # instruction -> path
+    calls: dict = {}               # instruction without a path -> callee
+    root: dict = {}                # computation -> its root's path
+    inside: dict = {}              # computation -> its instructions' paths
+    comp = None
+    for line in text.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if m is None:
+            c = _HLO_COMPUTATION.match(line)
+            if c is not None:
+                comp = c.group(1)
+            continue
+        path = _HLO_OP_NAME.search(line)
+        if path is not None:
+            own[m.group(2)] = path.group(1)
+            inside.setdefault(comp, []).append(path.group(1))
+            if m.group(1):
+                root[comp] = path.group(1)
+        else:
+            callee = _HLO_CALLS.search(line)
+            if callee is not None:
+                calls[m.group(2)] = callee.group(1)
+    for ins, callee in calls.items():
+        paths = inside.get(callee)
+        if paths:
+            own[ins] = root.get(callee) or max(set(paths), key=paths.count)
+    return own
+
+
+def _arg_specs(tree):
+    """Shapes, dtypes and (where committed) shardings of a call's
+    arguments: enough to lower the same program again, no buffer kept."""
+    import jax
+
+    def spec(x):
+        if isinstance(x, jax.Array) and not isinstance(x, jax.core.Tracer):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=x.weak_type,
+                sharding=x.sharding if x.committed else None)
+        if hasattr(x, "shape") and hasattr(x, "dtype"):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        return x
+
+    return jax.tree_util.tree_map(spec, tree)
+
+
+def executable_scopes(since: float | None = None) -> list:
+    """For every instrumented executable called at or after ``since``
+    (``time.perf_counter`` seconds; None = ever): ``{"name": instrument
+    name, "module": XLA module name, "ops": {HLO op name: op_name
+    path}}``, from the text of the program compiled again for the shapes
+    of its last compile (a read from the persistent compile cache where
+    that is on).  Costs nothing until asked; an executable that cannot
+    be lowered again is left out."""
+    out = []
+    for w in list(_instrumented):
+        specs = getattr(w, "_telemetry_specs", None)
+        if specs is None or (since is not None and
+                             getattr(w, "_telemetry_last_call", 0.0) < since):
+            continue
+        try:
+            text = w.lower(*specs[0], **specs[1]).compile().as_text()
+        except Exception:  # noqa: BLE001 - a reader's question, never fatal
+            continue
+        head = re.match(r"HloModule ([\w.\-]+)", text)
+        out.append({"name": w._telemetry_name,
+                    "module": head.group(1) if head else "",
+                    "ops": hlo_op_scopes(text)})
+    return out
 
 
 def _capture_analysis(name: str, fn, args, kwargs) -> None:

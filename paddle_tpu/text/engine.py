@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os as _os
+import threading
 import time as _time
 from typing import Any, Callable
 
@@ -74,7 +75,6 @@ class _LRU:
 
     def __init__(self, maxsize: int):
         import collections
-        import threading
 
         self._d = collections.OrderedDict()
         self._mu = threading.Lock()
@@ -148,6 +148,29 @@ def _watch_jit(name: str, key, fn):
     untouched."""
     return _telemetry.instrument_compile(name, key,
                                          _flags.decode_jit_key(), fn)
+
+
+_building = threading.local()    # .scope: the step being built, see _scoped
+
+
+def _scoped(fn):
+    """``fn`` with its body under ``jax.named_scope(<instrument name>)`` of
+    the step kind :meth:`Engine.get` is building, so that every device
+    op's ``op_name`` path starts with the step it belongs to
+    (``jit(<lambda>)/serving.async_step/attn/...``).  ``@`` becomes ``_``
+    (XLA cuts an op_name at ``@``: ``serving.block@8`` is
+    ``serving.block_8``).  The function keeps its name, and with it the
+    XLA module's (``jit__lambda``): HLO metadata is all that changes."""
+    scope = _building.scope.replace("@", "_")
+
+    @functools.wraps(fn)
+    def scoped(*a, **k):
+        with jax.named_scope(scope):
+            return fn(*a, **k)
+
+    if isinstance(fn, functools.partial):   # no __name__ for wraps to copy
+        scoped.__name__ = fn.func.__name__
+    return scoped
 
 
 def cfg_key(cfg):
@@ -443,8 +466,8 @@ def _build_prefill(spec: StepSpec):
     from . import generate
 
     return jax.jit(
-        lambda p, c, t, ln, sl, _cfg=spec.cfg:
-        generate.prefill_slot(p, c, t, ln, sl, _cfg),
+        _scoped(lambda p, c, t, ln, sl, _cfg=spec.cfg:
+                generate.prefill_slot(p, c, t, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 3, "rc"))
 
@@ -465,8 +488,8 @@ def _build_prefill_chunk(spec: StepSpec):
     from . import generate
 
     return jax.jit(
-        lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
-        generate.prefill_slot_chunk(p, c, t, p0, ln, sl, _cfg),
+        _scoped(lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
+                generate.prefill_slot_chunk(p, c, t, p0, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc"))
 
@@ -483,8 +506,8 @@ def _build_paged_prefill(spec: StepSpec):
     from . import kv_pool
 
     return jax.jit(
-        lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
-        kv_pool.paged_prefill_chunk(p, c, t, p0, ln, sl, _cfg),
+        _scoped(lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
+                kv_pool.paged_prefill_chunk(p, c, t, p0, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc"))
 
@@ -499,7 +522,7 @@ def _build_kv_copy(spec: StepSpec):
     from . import kv_pool
 
     return jax.jit(
-        lambda c, s, d: kv_pool.copy_blocks(c, s, d),
+        _scoped(lambda c, s, d: kv_pool.copy_blocks(c, s, d)),
         donate_argnums=donate_cache() and (0,),
         **_shard_kw(spec.shard, 2, "c", with_params=False))
 
@@ -530,7 +553,7 @@ def _build_inject(spec: StepSpec):
                 ((jnp.arange(_b) >= st)
                  & (jnp.arange(_b) < ln))[None, :])  # noqa: E731
     return jax.jit(
-        body, donate_argnums=donate_cache() and (0,),
+        _scoped(body), donate_argnums=donate_cache() and (0,),
         **_shard_kw(spec.shard, 4, "c", with_params=False))
 
 
@@ -542,8 +565,8 @@ def _build_block(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, _cfg=spec.cfg, _k=spec.k:
-        serving.decode_block_batched(p, c, t, s, _k, _cfg),
+        _scoped(lambda p, c, t, s, _cfg=spec.cfg, _k=spec.k:
+                serving.decode_block_batched(p, c, t, s, _k, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 2, "rcrr"))
 
@@ -556,8 +579,8 @@ def _build_sample(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, ky, te, tk, tp, _cfg=spec.cfg:
-        serving.sample_step_batched(p, c, t, s, ky, te, tk, tp, _cfg),
+        _scoped(lambda p, c, t, s, ky, te, tk, tp, _cfg=spec.cfg:
+                serving.sample_step_batched(p, c, t, s, ky, te, tk, tp, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 6, "rc"))
 
@@ -570,9 +593,10 @@ def _build_sample_block(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, ky, off, te, tk, tp, _cfg=spec.cfg, _k=spec.k:
-        serving.sample_block_batched(p, c, t, s, ky, off, te, tk, tp, _k,
-                                     _cfg),
+        _scoped(lambda p, c, t, s, ky, off, te, tk, tp, _cfg=spec.cfg,
+                _k=spec.k:
+                serving.sample_block_batched(p, c, t, s, ky, off, te, tk, tp,
+                                             _k, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 7, "rc"))
 
@@ -592,8 +616,8 @@ def _build_step(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, _cfg=spec.cfg:
-        serving.decode_step_batched(p, c, t, s, _cfg),
+        _scoped(lambda p, c, t, s, _cfg=spec.cfg:
+                serving.decode_step_batched(p, c, t, s, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 2, "rc"))
 
@@ -613,9 +637,9 @@ def _build_async(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, ht, pm, pv, s, ky, te, tk, tp, _cfg=spec.cfg:
-        serving.sample_step_batched(p, c, jnp.where(pm, pv, ht), s,
-                                    ky, te, tk, tp, _cfg),
+        _scoped(lambda p, c, ht, pm, pv, s, ky, te, tk, tp, _cfg=spec.cfg:
+                serving.sample_step_batched(p, c, jnp.where(pm, pv, ht), s,
+                                            ky, te, tk, tp, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 8, "rc"))
 
@@ -630,9 +654,9 @@ def _build_async_block(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, ht, pm, pv, s, _cfg=spec.cfg, _k=spec.k:
-        serving.decode_block_batched(p, c, jnp.where(pm, pv, ht), s, _k,
-                                     _cfg),
+        _scoped(lambda p, c, ht, pm, pv, s, _cfg=spec.cfg, _k=spec.k:
+                serving.decode_block_batched(p, c, jnp.where(pm, pv, ht), s,
+                                             _k, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rcrr"))
 
@@ -647,10 +671,10 @@ def _build_async_sample_block(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, ht, pm, pv, s, ky, off, te, tk, tp, _cfg=spec.cfg,
-        _k=spec.k:
-        serving.sample_block_batched(p, c, jnp.where(pm, pv, ht), s,
-                                     ky, off, te, tk, tp, _k, _cfg),
+        _scoped(lambda p, c, ht, pm, pv, s, ky, off, te, tk, tp, _cfg=spec.cfg,
+                _k=spec.k:
+                serving.sample_block_batched(p, c, jnp.where(pm, pv, ht), s,
+                                             ky, off, te, tk, tp, _k, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 9, "rc"))
 
@@ -669,8 +693,8 @@ def _build_spec_verify(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, _cfg=spec.cfg:
-        serving.spec_verify_batched(p, c, t, s, _cfg),
+        _scoped(lambda p, c, t, s, _cfg=spec.cfg:
+                serving.spec_verify_batched(p, c, t, s, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 2, "rc"))
 
@@ -693,8 +717,8 @@ def _build_spec_tree_verify(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, m, d, s, _cfg=spec.cfg:
-        serving.spec_tree_verify_batched(p, c, t, m, d, s, _cfg),
+        _scoped(lambda p, c, t, m, d, s, _cfg=spec.cfg:
+                serving.spec_tree_verify_batched(p, c, t, m, d, s, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc"))
 
@@ -714,7 +738,7 @@ def _build_spec_tree_commit(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda c, src, s: serving.spec_tree_commit_batched(c, src, s),
+        _scoped(lambda c, src, s: serving.spec_tree_commit_batched(c, src, s)),
         donate_argnums=donate_cache() and (0,),
         **_shard_kw(spec.shard, 2, "c", with_params=False))
 
@@ -730,9 +754,9 @@ def _build_masked_step(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, ky, te, tk, tp, m, _cfg=spec.cfg:
-        serving.sample_step_batched(p, c, t, s, ky, te, tk, tp, _cfg,
-                                    mask=m),
+        _scoped(lambda p, c, t, s, ky, te, tk, tp, m, _cfg=spec.cfg:
+                serving.sample_step_batched(p, c, t, s, ky, te, tk, tp, _cfg,
+                                            mask=m)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 7, "rc"))
 
@@ -763,8 +787,8 @@ def _build_moe_step(spec: StepSpec):
     from . import moe_serving
 
     return jax.jit(
-        lambda p, c, t, s, a, st, _cfg=spec.cfg:
-        moe_serving.moe_decode_step_batched(p, c, t, s, a, st, _cfg),
+        _scoped(lambda p, c, t, s, a, st, _cfg=spec.cfg:
+                moe_serving.moe_decode_step_batched(p, c, t, s, a, st, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rcr"))
 
@@ -779,9 +803,9 @@ def _build_moe_sample(spec: StepSpec):
     from . import moe_serving
 
     return jax.jit(
-        lambda p, c, t, s, ky, te, tk, tp, a, st, _cfg=spec.cfg:
-        moe_serving.moe_sample_step_batched(p, c, t, s, ky, te, tk, tp,
-                                            a, st, _cfg),
+        _scoped(lambda p, c, t, s, ky, te, tk, tp, a, st, _cfg=spec.cfg:
+                moe_serving.moe_sample_step_batched(p, c, t, s, ky, te, tk, tp,
+                                                    a, st, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 8, "rcr"))
 
@@ -797,8 +821,9 @@ def _build_moe_block(spec: StepSpec):
     from . import moe_serving
 
     return jax.jit(
-        lambda p, c, t, s, a, st, _cfg=spec.cfg, _k=spec.k:
-        moe_serving.moe_decode_block_batched(p, c, t, s, a, st, _k, _cfg),
+        _scoped(lambda p, c, t, s, a, st, _cfg=spec.cfg, _k=spec.k:
+                moe_serving.moe_decode_block_batched(p, c, t, s, a, st, _k,
+                                                     _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rcrrr"))
 
@@ -814,10 +839,11 @@ def _build_moe_async(spec: StepSpec):
     from . import moe_serving
 
     return jax.jit(
-        lambda p, c, ht, pm, pv, s, ky, te, tk, tp, a, st, _cfg=spec.cfg:
-        moe_serving.moe_sample_step_batched(p, c, jnp.where(pm, pv, ht),
-                                            s, ky, te, tk, tp, a, st,
-                                            _cfg),
+        _scoped(lambda p, c, ht, pm, pv, s, ky, te, tk, tp, a, st,
+                _cfg=spec.cfg:
+                moe_serving.moe_sample_step_batched(
+                    p, c, jnp.where(pm, pv, ht), s, ky, te, tk, tp, a, st,
+                    _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 10, "rcr"))
 
@@ -834,8 +860,8 @@ def _build_moe_prefill(spec: StepSpec):
     from . import generate
 
     return jax.jit(
-        lambda p, c, t, ln, sl, _cfg=spec.cfg:
-        generate.prefill_slot(p, c, t, ln, sl, _cfg),
+        _scoped(lambda p, c, t, ln, sl, _cfg=spec.cfg:
+                generate.prefill_slot(p, c, t, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 3, "rc"))
 
@@ -851,8 +877,8 @@ def _build_moe_prefill_chunk(spec: StepSpec):
     from . import generate
 
     return jax.jit(
-        lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
-        generate.prefill_slot_chunk(p, c, t, p0, ln, sl, _cfg),
+        _scoped(lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
+                generate.prefill_slot_chunk(p, c, t, p0, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc"))
 
@@ -866,8 +892,8 @@ def _build_moe_paged_prefill(spec: StepSpec):
     from . import kv_pool
 
     return jax.jit(
-        lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
-        kv_pool.paged_prefill_chunk(p, c, t, p0, ln, sl, _cfg),
+        _scoped(lambda p, c, t, p0, ln, sl, _cfg=spec.cfg:
+                kv_pool.paged_prefill_chunk(p, c, t, p0, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc"))
 
@@ -886,8 +912,8 @@ def _build_moe_verify(spec: StepSpec):
     from . import serving
 
     return jax.jit(
-        lambda p, c, t, s, _cfg=spec.cfg:
-        serving.spec_verify_batched(p, c, t, s, _cfg),
+        _scoped(lambda p, c, t, s, _cfg=spec.cfg:
+                serving.spec_verify_batched(p, c, t, s, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 2, "rc"))
 
@@ -916,8 +942,9 @@ def _build_adapter_step(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, ids, t, s, _cfg=spec.cfg:
-        _adapters.adapter_decode_step_batched(p, c, ad, ids, t, s, _cfg),
+        _scoped(lambda p, c, ad, ids, t, s, _cfg=spec.cfg:
+                _adapters.adapter_decode_step_batched(p, c, ad, ids, t, s,
+                                                      _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 3, "rc", adapters=True))
 
@@ -933,9 +960,9 @@ def _build_adapter_sample(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, ids, t, s, ky, te, tk, tp, m, _cfg=spec.cfg:
-        _adapters.adapter_sample_step_batched(
-            p, c, ad, ids, t, s, ky, te, tk, tp, m, _cfg),
+        _scoped(lambda p, c, ad, ids, t, s, ky, te, tk, tp, m, _cfg=spec.cfg:
+                _adapters.adapter_sample_step_batched(
+                    p, c, ad, ids, t, s, ky, te, tk, tp, m, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 8, "rc", adapters=True))
 
@@ -949,9 +976,9 @@ def _build_adapter_block(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, ids, t, s, _cfg=spec.cfg, _k=spec.k:
-        _adapters.adapter_decode_block_batched(p, c, ad, ids, t, s, _k,
-                                               _cfg),
+        _scoped(lambda p, c, ad, ids, t, s, _cfg=spec.cfg, _k=spec.k:
+                _adapters.adapter_decode_block_batched(p, c, ad, ids, t, s, _k,
+                                                       _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 3, "rcrr", adapters=True))
 
@@ -968,11 +995,11 @@ def _build_adapter_async(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, ids, ht, pm, pv, s, ky, te, tk, tp,
-        _cfg=spec.cfg:
-        _adapters.adapter_sample_step_batched(
-            p, c, ad, ids, jnp.where(pm, pv, ht), s, ky, te, tk,
-            tp, None, _cfg),
+        _scoped(lambda p, c, ad, ids, ht, pm, pv, s, ky, te, tk, tp,
+                _cfg=spec.cfg:
+                _adapters.adapter_sample_step_batched(
+                    p, c, ad, ids, jnp.where(pm, pv, ht), s, ky, te, tk,
+                    tp, None, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 9, "rc", adapters=True))
 
@@ -989,8 +1016,9 @@ def _build_adapter_spec_verify(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, ids, t, s, _cfg=spec.cfg:
-        _adapters.adapter_spec_verify_batched(p, c, ad, ids, t, s, _cfg),
+        _scoped(lambda p, c, ad, ids, t, s, _cfg=spec.cfg:
+                _adapters.adapter_spec_verify_batched(p, c, ad, ids, t, s,
+                                                      _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 3, "rc", adapters=True))
 
@@ -1006,8 +1034,9 @@ def _build_adapter_prefill(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, aid, t, ln, sl, _cfg=spec.cfg:
-        _adapters.adapter_prefill_slot(p, c, ad, aid, t, ln, sl, _cfg),
+        _scoped(lambda p, c, ad, aid, t, ln, sl, _cfg=spec.cfg:
+                _adapters.adapter_prefill_slot(p, c, ad, aid, t, ln, sl,
+                                               _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 4, "rc", adapters=True))
 
@@ -1025,9 +1054,9 @@ def _build_adapter_prefill_chunk(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, aid, t, p0, ln, sl, _cfg=spec.cfg:
-        _adapters.adapter_prefill_slot_chunk(p, c, ad, aid, t, p0,
-                                             ln, sl, _cfg),
+        _scoped(lambda p, c, ad, aid, t, p0, ln, sl, _cfg=spec.cfg:
+                _adapters.adapter_prefill_slot_chunk(p, c, ad, aid, t, p0,
+                                                     ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 5, "rc", adapters=True))
 
@@ -1041,9 +1070,9 @@ def _build_adapter_paged_prefill(spec: StepSpec):
     from . import adapters as _adapters
 
     return jax.jit(
-        lambda p, c, ad, aid, t, p0, ln, sl, _cfg=spec.cfg:
-        _adapters.adapter_paged_prefill_chunk(
-            p, c, ad, aid, t, p0, ln, sl, _cfg),
+        _scoped(lambda p, c, ad, aid, t, p0, ln, sl, _cfg=spec.cfg:
+                _adapters.adapter_paged_prefill_chunk(
+                    p, c, ad, aid, t, p0, ln, sl, _cfg)),
         donate_argnums=donate_cache(),
         **_shard_kw(spec.shard, 5, "rc", adapters=True))
 
@@ -1060,9 +1089,9 @@ def _build_generate(spec: StepSpec):
     from . import generate as _g
 
     max_new_tokens, top_k, top_p = spec.extra
-    return jax.jit(functools.partial(
-        _g._generate_impl, cfg=spec.cfg, max_new_tokens=max_new_tokens,
-        top_k=top_k, top_p=float(top_p)))
+    return jax.jit(_scoped(functools.partial(
+                _g._generate_impl, cfg=spec.cfg, max_new_tokens=max_new_tokens,
+                top_k=top_k, top_p=float(top_p))))
 
 
 @register("beam", domain="gen",
@@ -1072,10 +1101,10 @@ def _build_beam(spec: StepSpec):
     from . import generate as _g
 
     max_new_tokens, num_beams, length_penalty, eos_id = spec.extra
-    return jax.jit(functools.partial(
-        _g._beam_impl, cfg=spec.cfg, max_new_tokens=max_new_tokens,
-        num_beams=num_beams, length_penalty=length_penalty,
-        eos_id=eos_id))
+    return jax.jit(_scoped(functools.partial(
+                _g._beam_impl, cfg=spec.cfg, max_new_tokens=max_new_tokens,
+                num_beams=num_beams, length_penalty=length_penalty,
+                eos_id=eos_id)))
 
 
 @register("jit_by_cfg", domain="gen",
@@ -1087,7 +1116,7 @@ def _build_jit_by_cfg(spec: StepSpec):
     ...), so the fn itself rides in ``payload`` un-keyed."""
     fn = spec.payload
     return jax.jit(
-        lambda p, c, t, s, _cfg=spec.cfg: fn(p, c, t, s, _cfg),
+        _scoped(lambda p, c, t, s, _cfg=spec.cfg: fn(p, c, t, s, _cfg)),
         donate_argnums=donate_cache())
 
 
@@ -1102,7 +1131,7 @@ def _build_sharded_decode(spec: StepSpec):
     fresh instrumented wrapper (jax's trace cache still dedupes the
     underlying executable), matching the pre-Engine behavior."""
     fn, jit_kwargs = spec.payload
-    return jax.jit(fn, **jit_kwargs)
+    return jax.jit(_scoped(fn), **jit_kwargs)
 
 
 class Engine:
@@ -1137,7 +1166,8 @@ class Engine:
         key = entry.key(spec)
 
         def build():
-            return _watch_jit(entry.name(spec), key, _traced_per_shard(
+            name = _building.scope = entry.name(spec)
+            return _watch_jit(name, key, _traced_per_shard(
                 spec.shard, entry.build(spec)))
 
         if not entry.cached:

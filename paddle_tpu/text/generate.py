@@ -126,38 +126,39 @@ def _attend_cache(q, full, pos, cfg: gpt.GPTConfig):
     Kernel path: ops/decode_attention (GQA-aware split-KV streaming,
     int8 dequant in-kernel).  Fallback: the original grouped einsum —
     int8 caches dequantize via the shared helper first."""
-    B, Tq, H, hd = q.shape
-    dt = cfg.dtype
-    k_all, v_all = full["k"], full["v"]
-    ks, vs = full.get("k_s"), full.get("v_s")
-    if _use_decode_kernel(cfg, q.shape, k_all.shape):
-        from ..ops import decode_attention as da
+    with jax.named_scope("attn"):
+        B, Tq, H, hd = q.shape
+        dt = cfg.dtype
+        k_all, v_all = full["k"], full["v"]
+        ks, vs = full.get("k_s"), full.get("v_s")
+        if _use_decode_kernel(cfg, q.shape, k_all.shape):
+            from ..ops import decode_attention as da
 
-        pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
-        out = da.decode_attention(q, k_all, v_all, pos_b,
-                                  k_scale=ks, v_scale=vs)
-        return out.astype(dt).reshape(B, Tq, H * hd)
-    if ks is not None:
-        from ..ops import decode_attention as da
+            pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+            out = da.decode_attention(q, k_all, v_all, pos_b,
+                                      k_scale=ks, v_scale=vs)
+            return out.astype(dt).reshape(B, Tq, H * hd)
+        if ks is not None:
+            from ..ops import decode_attention as da
 
-        k_all = da.dequantize_kv(k_all, ks, dt)
-        v_all = da.dequantize_kv(v_all, vs, dt)
-    # a non-compute storage dtype (fp32/bf16 flag) joins the einsums in
-    # the COMPUTE dtype — the residual stream's dtype is a scan-carry
-    # invariant, and mixed-dtype einsums would silently promote it
-    k_all = k_all.astype(dt)
-    v_all = v_all.astype(dt)
-    T = k_all.shape[1]
-    Hkv = k_all.shape[2]
-    g = H // Hkv
-    qg = q.reshape(B, Tq, Hkv, g, hd)
-    scores = jnp.einsum("bikgd,btkd->bkgit", qg, k_all) / jnp.sqrt(
-        jnp.asarray(hd, jnp.float32)).astype(dt)
-    mask = (jnp.arange(T)[None, :]
-            <= pos + jnp.arange(Tq)[:, None])[None, None, None]
-    scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
-    w = jax.nn.softmax(scores, axis=-1).astype(dt)
-    return jnp.einsum("bkgit,btkd->bikgd", w, v_all).reshape(B, Tq, -1)
+            k_all = da.dequantize_kv(k_all, ks, dt)
+            v_all = da.dequantize_kv(v_all, vs, dt)
+        # a non-compute storage dtype (fp32/bf16 flag) joins the einsums in
+        # the COMPUTE dtype — the residual stream's dtype is a scan-carry
+        # invariant, and mixed-dtype einsums would silently promote it
+        k_all = k_all.astype(dt)
+        v_all = v_all.astype(dt)
+        T = k_all.shape[1]
+        Hkv = k_all.shape[2]
+        g = H // Hkv
+        qg = q.reshape(B, Tq, Hkv, g, hd)
+        scores = jnp.einsum("bikgd,btkd->bkgit", qg, k_all) / jnp.sqrt(
+            jnp.asarray(hd, jnp.float32)).astype(dt)
+        mask = (jnp.arange(T)[None, :]
+                <= pos + jnp.arange(Tq)[:, None])[None, None, None]
+        scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)
+        w = jax.nn.softmax(scores, axis=-1).astype(dt)
+        return jnp.einsum("bkgit,btkd->bikgd", w, v_all).reshape(B, Tq, -1)
 
 
 def _embed_step(params, token, pos, cfg: gpt.GPTConfig):
@@ -925,9 +926,10 @@ def verify_chunk_batched(params, cache, tokens, pos, cfg: gpt.GPTConfig):
 
         full = {name: jax.vmap(splice)(csl[name], val[:, 0], pos)
                 for name, val in rows.items()}
-        attn = da.decode_attention(
-            q3.reshape(B, K, H, hd), full["k"], full["v"], pos,
-            k_scale=full.get("k_s"), v_scale=full.get("v_s"))
+        with jax.named_scope("attn"):
+            attn = da.decode_attention(
+                q3.reshape(B, K, H, hd), full["k"], full["v"], pos,
+                k_scale=full.get("k_s"), v_scale=full.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, K, H * hd)
 
         def post(xb, ab):

@@ -259,10 +259,11 @@ def _norm(x, p, prefix: str, cfg):
     (params carry no ``<prefix>_b``) and never takes the fused-LN kernel
     (different math)."""
     dt = cfg.dtype
-    if cfg.norm == "rmsnorm":
-        return _rms_norm(x.astype(jnp.float32),
-                         p[prefix + "_g"]).astype(dt)
-    return _ln(x, p[prefix + "_g"], p[prefix + "_b"], dt)
+    with jax.named_scope("ln"):
+        if cfg.norm == "rmsnorm":
+            return _rms_norm(x.astype(jnp.float32),
+                             p[prefix + "_g"]).astype(dt)
+        return _ln(x, p[prefix + "_g"], p[prefix + "_b"], dt)
 
 
 def apply_rope(x, positions, base: float = 10000.0):
@@ -374,14 +375,16 @@ def _ffn_body(h, p, cfg: GPTConfig):
     (down(silu(gate) * up)); the single implementation the train block
     and every decode-path block share."""
     dt = cfg.dtype
-    if cfg.activation == "swiglu":
-        gate = jax.nn.silu(woq.mm(h, p, "gate_w", dt)
-                           + p["gate_b"].astype(dt))
-        up = woq.mm(h, p, "fc_w", dt) + p["fc_b"].astype(dt)
-        h = gate * up
-    else:
-        h = jax.nn.gelu(woq.mm(h, p, "fc_w", dt) + p["fc_b"].astype(dt))
-    return woq.mm(h, p, "out_w", dt) + p["out_b"].astype(dt)
+    with jax.named_scope("mlp"):
+        if cfg.activation == "swiglu":
+            gate = jax.nn.silu(woq.mm(h, p, "gate_w", dt)
+                               + p["gate_b"].astype(dt))
+            up = woq.mm(h, p, "fc_w", dt) + p["fc_b"].astype(dt)
+            h = gate * up
+        else:
+            h = jax.nn.gelu(woq.mm(h, p, "fc_w", dt)
+                            + p["fc_b"].astype(dt))
+        return woq.mm(h, p, "out_w", dt) + p["out_b"].astype(dt)
 
 
 def _ffn_dense(x, p, cfg: GPTConfig):
@@ -428,11 +431,14 @@ def _ffn_tail(x, p, cfg: GPTConfig, valid=None, capacity=_LEGACY,
             n_tokens *= d
         capacity = n_tokens if valid is not None else None
     if stats is None:
-        y, _aux = moe_ffn(p["moe"], h, cfg.moe, key=None, valid=valid,
-                          capacity=capacity)
+        with jax.named_scope("mlp"):
+            y, _aux = moe_ffn(p["moe"], h, cfg.moe, key=None, valid=valid,
+                              capacity=capacity)
         return x + y
-    y, _aux, delta = moe_ffn(p["moe"], h, cfg.moe, key=None, valid=valid,
-                             capacity=capacity, with_stats=True)
+    with jax.named_scope("mlp"):
+        y, _aux, delta = moe_ffn(p["moe"], h, cfg.moe, key=None,
+                                 valid=valid, capacity=capacity,
+                                 with_stats=True)
     stats = {"dropped": stats["dropped"] + delta["dropped"],
              "load": stats["load"] + delta["load"]}
     return x + y, stats
@@ -459,9 +465,10 @@ def _block(x, p, cfg: GPTConfig, dropout_key=None):
     if cfg.moe is not None:
         from .moe import moe_ffn
 
-        h, aux = moe_ffn(p["moe"], h, cfg.moe,
-                         key=(jax.random.fold_in(dropout_key, 2)
-                              if dropout_key is not None else None))
+        with jax.named_scope("mlp"):
+            h, aux = moe_ffn(p["moe"], h, cfg.moe,
+                             key=(jax.random.fold_in(dropout_key, 2)
+                                  if dropout_key is not None else None))
     else:
         h = _ffn_body(h, p, cfg)
         aux = jnp.zeros((), jnp.float32)
@@ -532,16 +539,18 @@ def loss_fn(params: dict, tokens, cfg: GPTConfig, act_sharding=None, key=None):
     logits, aux = forward_with_aux(params, tokens[:, :-1], cfg,
                                    act_sharding=act_sharding, key=key)
     tgt = tokens[:, 1:]
-    if os.environ.get("PADDLE_TPU_FUSED_CE", "") == "1":
-        # Pallas blockwise loss head: no [B, T, V] fp32 log-softmax in HBM
-        # (ops/fused_ce.py; falls back to the expression below off-TPU).
-        # Opt-in until the on-device parity check has passed on hardware.
-        from ..ops.fused_ce import fused_softmax_ce
+    with jax.named_scope("loss"):
+        if os.environ.get("PADDLE_TPU_FUSED_CE", "") == "1":
+            # Pallas blockwise loss head: no [B, T, V] fp32 log-softmax in
+            # HBM (ops/fused_ce.py; falls back to the expression below
+            # off-TPU).  Opt-in until the on-device parity check has
+            # passed on hardware.
+            from ..ops.fused_ce import fused_softmax_ce
 
-        return jnp.mean(fused_softmax_ce(logits, tgt)) + aux
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll) + aux
+            return jnp.mean(fused_softmax_ce(logits, tgt)) + aux
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, tgt[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll) + aux
 
 
 def count_params(cfg: GPTConfig) -> int:

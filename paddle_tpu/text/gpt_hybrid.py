@@ -739,8 +739,9 @@ def build_gpt_train_step(cfg: gpt.GPTConfig, mesh: Mesh, optimizer,
                 t, k = xs
                 l, g = jax.value_and_grad(loss_fn)(state.params, t, k)
                 cl, cg = carry
-                cg = jax.tree_util.tree_map(
-                    lambda a, b: a + (b * inv).astype(a.dtype), cg, g)
+                with jax.named_scope("grad_accum"):
+                    cg = jax.tree_util.tree_map(
+                        lambda a, b: a + (b * inv).astype(a.dtype), cg, g)
                 return (cl + l * inv, cg), None
 
             zero_g = jax.tree_util.tree_map(
@@ -752,8 +753,10 @@ def build_gpt_train_step(cfg: gpt.GPTConfig, mesh: Mesh, optimizer,
                                                       key)
         if grad_shardings is not None:
             grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
-        new_p, new_o = optimizer.apply_gradients(
-            grads, state.params, state.opt_state, lr=lr, step=state.step + 1)
+        with jax.named_scope("optimizer"):
+            new_p, new_o = optimizer.apply_gradients(
+                grads, state.params, state.opt_state, lr=lr,
+                step=state.step + 1)
         return GPTTrainState(new_p, new_o, state.step + 1), loss
 
     repl = NamedSharding(mesh, P())

@@ -135,7 +135,8 @@ def _gather_slot(pool_leaf, trow):
     with the oracle/fallback paths."""
     from ..ops import decode_attention as da
 
-    return da.gather_paged_view(pool_leaf, trow[None])
+    with jax.named_scope("kv_gather"):
+        return da.gather_paged_view(pool_leaf, trow[None])
 
 
 def _scatter_rows(cache: dict, rows: dict, phys) -> dict:
@@ -145,12 +146,13 @@ def _scatter_rows(cache: dict, rows: dict, phys) -> dict:
     [L, N, bs, Hkv(, hd)]; the single row-write every paged decode/
     prefill path funnels through (the ``generate._write_rows`` twin)."""
     out = dict(cache)
-    for name, val in rows.items():
-        arr = cache[name]
-        L, NR = arr.shape[0], arr.shape[1] * arr.shape[2]
-        flat = arr.reshape((L, NR) + arr.shape[3:])
-        flat = flat.at[:, phys].set(val.astype(arr.dtype), mode="drop")
-        out[name] = flat.reshape(arr.shape)
+    with jax.named_scope("kv_gather"):
+        for name, val in rows.items():
+            arr = cache[name]
+            L, NR = arr.shape[0], arr.shape[1] * arr.shape[2]
+            flat = arr.reshape((L, NR) + arr.shape[3:])
+            flat = flat.at[:, phys].set(val.astype(arr.dtype), mode="drop")
+            out[name] = flat.reshape(arr.shape)
     return out
 
 
@@ -242,20 +244,22 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
         # scatter the fresh rows into layer li BEFORE attending: the
         # kernel then reads exactly what later steps will read back
         # (scatter-then-attend == the slab path's splice-then-write)
-        new_pool = {}
-        for n, val in rows.items():
-            arr = pool[n]
-            NR = arr.shape[1] * arr.shape[2]
-            flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
-            flat = flat.at[li, phys].set(val[:, 0].astype(arr.dtype),
-                                         mode="drop")
-            new_pool[n] = flat.reshape(arr.shape)
-        pool = new_pool
+        with jax.named_scope("kv_gather"):
+            new_pool = {}
+            for n, val in rows.items():
+                arr = pool[n]
+                NR = arr.shape[1] * arr.shape[2]
+                flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
+                flat = flat.at[li, phys].set(val[:, 0].astype(arr.dtype),
+                                             mode="drop")
+                new_pool[n] = flat.reshape(arr.shape)
+            pool = new_pool
+            layer_kv = {n: v[li] for n, v in pool.items()}
         q = q3.reshape(B, 1, cfg.num_heads, hd)
-        attn = da.paged_decode_attention(
-            q, pool["k"][li], pool["v"][li], tables, pos,
-            k_scale=pool["k_s"][li] if "k_s" in pool else None,
-            v_scale=pool["v_s"][li] if "v_s" in pool else None)
+        with jax.named_scope("attn"):
+            attn = da.paged_decode_attention(
+                q, layer_kv["k"], layer_kv["v"], tables, pos,
+                k_scale=layer_kv.get("k_s"), v_scale=layer_kv.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, 1, cfg.num_heads * hd)
 
         def post(xb, ab):
@@ -524,20 +528,23 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
             return generate._chunk_pre_attn(xb, p, p0, cfg)
 
         q3, rows = jax.vmap(pre)(x, pos)  # q3 [B, 1, K, H, hd]
-        new_pool = {}
-        for n, val in rows.items():
-            arr = pool[n]
-            NR = arr.shape[1] * arr.shape[2]
-            flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
-            v = val[:, 0].reshape((B * K,) + val.shape[3:])
-            flat = flat.at[li, phys].set(v.astype(arr.dtype), mode="drop")
-            new_pool[n] = flat.reshape(arr.shape)
-        pool = new_pool
-        attn = da.paged_decode_attention(
-            q3.reshape(B, K, H, hd), pool["k"][li], pool["v"][li],
-            tables, pos,
-            k_scale=pool["k_s"][li] if "k_s" in pool else None,
-            v_scale=pool["v_s"][li] if "v_s" in pool else None)
+        with jax.named_scope("kv_gather"):
+            new_pool = {}
+            for n, val in rows.items():
+                arr = pool[n]
+                NR = arr.shape[1] * arr.shape[2]
+                flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
+                v = val[:, 0].reshape((B * K,) + val.shape[3:])
+                flat = flat.at[li, phys].set(v.astype(arr.dtype),
+                                             mode="drop")
+                new_pool[n] = flat.reshape(arr.shape)
+            pool = new_pool
+            layer_kv = {n: v[li] for n, v in pool.items()}
+        with jax.named_scope("attn"):
+            attn = da.paged_decode_attention(
+                q3.reshape(B, K, H, hd), layer_kv["k"], layer_kv["v"],
+                tables, pos,
+                k_scale=layer_kv.get("k_s"), v_scale=layer_kv.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, K, H * hd)
 
         def post(xb, ab):

@@ -21,6 +21,7 @@ step routes through the same woq accessors.
 """
 from __future__ import annotations
 
+import contextlib
 import os as _os
 import time
 from typing import Any
@@ -84,12 +85,14 @@ def _sample_batched(logits, key, temp, topk, topp, mask=None):
     (temp == 0) slots take the argmax of the MASKED logits: one
     executable serves constrained-greedy and constrained-sampled.  An
     all-zero row is exactly the unconstrained math."""
-    if mask is not None:
-        logits = logits + mask
-    scaled = generate._filter_logits(logits, temp, topk, topp)
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return jnp.where(temp > 0.0, sampled, greedy)
+    with jax.named_scope("sample"):
+        if mask is not None:
+            logits = logits + mask
+        scaled = generate._filter_logits(logits, temp, topk, topp)
+        sampled = jax.random.categorical(key, scaled,
+                                         axis=-1).astype(jnp.int32)
+        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jnp.where(temp > 0.0, sampled, greedy)
 
 
 def sample_step_batched(params, cache, tok, pos, key, temp, topk, topp,
@@ -142,6 +145,10 @@ def decode_block_batched(params, cache, tok, pos, k: int, cfg: gpt.GPTConfig):
     (cache, tok, pos), toks = jax.lax.scan(body, (cache, tok, pos), None,
                                            length=k)
     return toks.T, cache, tok, pos
+
+
+# a tick that dispatched no step is named by what it did instead
+_KIND_WITHOUT_STEP = {"admit": "admit_only", "wait": "fetch_only"}
 
 
 def _hits_stop(st: dict) -> bool:
@@ -555,6 +562,12 @@ class DecodeServer:
         # per-server distributions the process-global registry can't
         # give.  Both empty and untouched when no trace/telemetry.
         self._span_ring = _telemetry.SpanRing()
+        # program spans: the open ``serving.tick`` span (None between
+        # ticks), the open admit phase, and the tokens that reached the
+        # host since the last ``serving.emit`` record as (rid, n, stamp)
+        self._tick_sp = None
+        self._admit_sp = None
+        self._emit: list = []
         self._hist_local: dict = {}
         self._counts_local: dict = {}
         self.cfg = cfg
@@ -879,11 +892,6 @@ class DecodeServer:
         self._dropped: set[int] = set()          # rids abandoned by close()
         self._streams: dict[int, dict] = {}      # rid -> open handoff stream
         self._next_rid = 0
-        # decode-gap probe (the stall the budget exists to kill): host
-        # timestamp of the last tick that appended decode tokens; the
-        # next appending tick observes the gap as serving.decode_gap_ms.
-        # None while idle — an empty server's first tick is not a stall.
-        self._gap_anchor: float | None = None
         # resilience layer (PADDLE_TPU_RESILIENCE=0 restores fail-fast):
         # per-request deadlines shed expired queued work, an OOM on a
         # tick engages the degradation chain (drop to sync dispatch ->
@@ -1219,6 +1227,19 @@ class DecodeServer:
                              reason=reason)
 
     def _admit(self):
+        """Queued requests take free slots.  With something queued this
+        is an ``admit`` phase: queue pop, slot claim, block allocation,
+        the prefill dispatch and its first-token fetch."""
+        if not (self._tel and self._queue):
+            return self._admit_queue()
+        with self._phase("admit") as sp:
+            self._admit_sp = sp
+            try:
+                return self._admit_queue()
+            finally:
+                self._admit_sp = None
+
+    def _admit_queue(self):
         self._shed_expired()
         # the OOM-chain cap binds every class (it is a memory bound);
         # the controller's ladder cap is SHED pressure and binds class-0
@@ -1249,6 +1270,8 @@ class DecodeServer:
             slot = self._free.pop()
             req = self._queue.pop(0)
             t_admit = time.perf_counter()
+            if self._admit_sp is not None:
+                self._admit_sp.args.setdefault("rids", []).append(req["rid"])
             st = {
                 "rid": req["rid"], "prompt": req["prompt"],
                 "max_new": req["max_new"], "stop": req.get("stop", []),
@@ -1505,13 +1528,8 @@ class DecodeServer:
                     # the argmax/choice above already fetched the host
                     # value, so "now" IS the first-token time — TTFT and
                     # the prefill span cost zero extra syncs
-                    now = time.perf_counter()
-                    st["t_first"] = st["t_last"] = now
-                    self._observe(
-                        "serving.ttft_ms", (now - st["t_submit"]) * 1e3)
-                    _telemetry.event("serving.prefill", t_admit, now,
-                                     tid=slot, rid=st["rid"],
-                                     prompt_len=n)
+                    now = self._tel_first_token(st, slot, t_admit,
+                                                prefill_name)
                     self._span_ring.record(
                         st.get("trace"), "prefill", t_admit, now,
                         rid=st["rid"], prompt_len=n)
@@ -1650,6 +1668,11 @@ class DecodeServer:
                 break
         if st is None:
             return False
+        with self._phase("admit", rids=[st["rid"]]):
+            self._advance_admit_chunk(slot, st)
+        return True
+
+    def _advance_admit_chunk(self, slot, st):
         t0 = time.perf_counter()
         prompt = st["prompt"]
         n = len(prompt)
@@ -1707,7 +1730,6 @@ class DecodeServer:
                 _telemetry.count("kv_pool.prefill_rows", len(chunk))
         if st["admit_i"] == len(st["admit_starts"]):
             self._graduate_admitting(slot, st, logits, t0, kind)
-        return True
 
     def _graduate_admitting(self, slot, st, logits, t0, kind):
         """The last chunk landed: fetch the admission logits, draw the
@@ -1756,13 +1778,8 @@ class DecodeServer:
             # without a draft cache the catch-up feeds from 0
             st["spec_dpos"] = n if self._draft_cache is not None else 0
         if self._tel:
-            now = time.perf_counter()
-            st["t_first"] = st["t_last"] = now
-            self._observe("serving.ttft_ms",
-                          (now - st["t_submit"]) * 1e3)
-            _telemetry.event("serving.prefill",
-                             st.get("t_admit", t0), now, tid=slot,
-                             rid=st["rid"], prompt_len=n)
+            now = self._tel_first_token(st, slot, st.get("t_admit", t0),
+                                        kind)
             self._span_ring.record(
                 st.get("trace"), "prefill", st.get("t_admit", t0), now,
                 rid=st["rid"], prompt_len=n)
@@ -2025,13 +2042,8 @@ class DecodeServer:
             # round's catch-up feeds it the sequence from 0
             st["spec_dpos"] = 0
         if self._tel:
-            now = time.perf_counter()
-            st["t_first"] = st["t_last"] = now
-            self._observe("serving.ttft_ms",
-                          (now - st["t_submit"]) * 1e3)
-            _telemetry.event("serving.prefill",
-                             st.get("t_admit", now), now, tid=slot,
-                             rid=rid, prompt_len=n)
+            self._tel_first_token(st, slot,
+                                  st.get("t_admit", time.perf_counter()))
             _telemetry.count("serving.tokens_generated")
             self._count_local("serving.tokens_generated")
         fin = self._constraint_push(st, t)
@@ -2800,8 +2812,10 @@ class DecodeServer:
             st = self._slots.pop(slot)
             self._fail_request(st, slot, "non-finite spec-verify logits")
         steps = max([kept for _, kept in appended], default=1)
-        self._tel_tokens(appended, t0, steps=max(steps, 1), kind=kind)
-        self._retire(done)
+        with self._phase("book") as ph:
+            self._book(ph, appended, done, t0, steps=max(steps, 1),
+                       kind=kind)
+        self._refill()
 
     # -- draft-tree speculation: one verify pass over a token tree ----------
 
@@ -3249,8 +3263,10 @@ class DecodeServer:
             self._fail_request(st, slot,
                                "non-finite spec-tree-verify logits")
         steps = max([kept for _, kept in appended], default=1)
-        self._tel_tokens(appended, t0, steps=max(steps, 1), kind=kind)
-        self._retire(done)
+        with self._phase("book") as ph:
+            self._book(ph, appended, done, t0, steps=max(steps, 1),
+                       kind=kind)
+        self._refill()
 
     def close(self):
         """Release this server's compiled executables and KV cache.
@@ -3682,6 +3698,7 @@ class DecodeServer:
         return cst.exhausted
 
     def _retire(self, done):
+        """Free the finished slots (the end of a tick's book phase)."""
         for slot in done:
             st = self._slots.pop(slot)
             self._results[st["rid"]] = st["generated"]
@@ -3691,8 +3708,98 @@ class DecodeServer:
                 self._pool.free_slot(slot)
             self._free.append(slot)
             self._tel_retire(st, slot)
+
+    def _book(self, ph, appended, done, t0, steps: int = 1, kind=None):
+        """The end of a book phase: the fetched tokens' records, then
+        the finished slots; the phase's span says what it booked."""
+        self._tel_tokens(appended, t0, steps=steps, kind=kind)
+        if self._tel:
+            ph.args.update(tokens=sum(n for _, n in appended),
+                           retired=[self._slots[s]["rid"] for s in done])
+            if self._tick_sp is not None and kind is not None:
+                # a speculative round has no dispatch phase to name it
+                self._tick_sp.args.setdefault("kind", kind)
+        self._retire(done)
+
+    def _refill(self):
+        """After a tick's bookkeeping: queued requests take the slots
+        just freed (an admit phase of its own), then the gauges."""
         self._admit()
         self._tel_gauges()
+
+    # -- program spans: one serving.tick a tick, disjoint phases below it --
+
+    @contextlib.contextmanager
+    def _tick_scope(self):
+        """The ``serving.tick`` span around one guarded tick.  Its args
+        are known when it closes: ``kind`` (the step kind dispatched, or
+        ``admit_only`` / ``fetch_only`` / ``idle``), live slots, queue
+        depth.  The tick's ``serving.emit`` record is written inside it.
+        A ``tick_block`` that falls back to stepwise ``tick()`` calls
+        stays one tick; a tick of a server with nothing pending records
+        nothing (a caller polling an idle server must not flush the
+        ring)."""
+        if not self._tel or self._tick_sp is not None or not (
+                self._slots or self._queue or self._inflight is not None):
+            yield
+            return
+        with _telemetry.span("serving.tick") as sp:
+            self._tick_sp = sp
+            try:
+                yield
+            finally:
+                self._tick_sp = None
+                sp.args.setdefault("kind", "idle")
+                sp.args.update(slots=len(self._slots),
+                               queue=len(self._queue))
+                self._emit_flush()
+
+    def _phase(self, name: str, **args):
+        """One phase of a tick as a child span, ``serving.tick.<name>``:
+        admit, feed, dispatch, wait, book.  Phases never nest, so a
+        tick's children are disjoint.  Outside a tick (an admission
+        inside ``submit``) the span is ``serving.<name>``."""
+        if not self._tel:
+            return _telemetry.NO_SPAN
+        tick = self._tick_sp
+        if tick is None:
+            return _telemetry.span("serving." + name, **args)
+        if "kind" in args:
+            tick.args["kind"] = args["kind"]
+        elif name in _KIND_WITHOUT_STEP:
+            tick.args.setdefault("kind", _KIND_WITHOUT_STEP[name])
+        return _telemetry.span("serving.tick." + name, **args)
+
+    def _emit_flush(self) -> None:
+        """One ``serving.emit`` record for the tokens since the last:
+        parallel lists of rids, counts and stamps, from which a reader
+        rebuilds every request's token times."""
+        if not self._emit:
+            return
+        rids, ns, ts = zip(*self._emit)
+        self._emit = []
+        _telemetry.event("serving.emit", min(ts), max(ts), rids=list(rids),
+                         n=list(ns), t=list(ts))
+
+    def _tel_first_token(self, st, slot, t_admit: float, kind=None) -> float:
+        """A request's first token is on the host (admission logits
+        fetched and sampled): TTFT, the ``serving.prefill`` span (slot
+        claimed -> now) and the token's emission stamp (the caller
+        counts the token).  Returns the stamp."""
+        now = time.perf_counter()
+        st["t_first"] = st["t_last"] = now
+        n = len(st["prompt"])
+        self._observe("serving.ttft_ms", (now - st["t_submit"]) * 1e3)
+        args = {"rid": st["rid"], "prompt_len": n}
+        if kind is not None:
+            args["kind"] = kind
+            if self._admit_sp is not None:
+                self._admit_sp.args.setdefault("kinds", []).append(kind)
+        _telemetry.event("serving.prefill", t_admit, now, tid=slot, **args)
+        self._emit.append((st["rid"], 1, now))
+        if self._tick_sp is None:        # an admission inside submit()
+            self._emit_flush()
+        return now
 
     # -- telemetry sampling (host values only — never a device sync) --------
 
@@ -3785,39 +3892,29 @@ class DecodeServer:
                 tokens=len(st["generated"]))
 
     def _tel_tokens(self, appended, t0, steps: int = 1, kind=None):
-        """Per-tick records from the host bookkeeping that JUST ran on
-        the already-fetched token block: tick latency, first-token time
-        for slots whose first kept token arrived this tick (the
-        ``prefill=False`` path — prefill admission stamps TTFT itself),
-        and per-token latency = tick wall / steps (each slot decoded
-        every step of the block it was fed into).
+        """Per-fetch records from the host bookkeeping that JUST ran on
+        the already-fetched token block.  ``appended`` is [(slot state,
+        tokens kept)]; all of them reached the host at one stamp, which
+        goes into the tick's ``serving.emit`` record.  From the stamps:
+        ``serving.decode_gap_ms``, the time since the same request's
+        previous tokens (one sample a request a fetch — a batch stalled
+        by an admission shows in every live request), and
+        ``serving.tpot_ms``, that gap per token (n samples).  First-token
+        time for slots whose first kept token arrived here (the
+        ``prefill=False`` path — prefill admission stamps TTFT itself).
 
         ``kind`` names the executable that ran (serving.<kind> — the
-        instrument_compile name) so the device feed can join this wall,
-        which genuinely covers dispatch→token-fetch even on the async
-        path, with the executable's captured FLOPs into a live MFU."""
+        instrument_compile name) so the device feed can join the wall
+        since ``t0``, which genuinely covers dispatch→token-fetch even on
+        the async path, with the executable's captured FLOPs."""
         if not self._tel:
             return
         now = time.perf_counter()
-        dt_ms = (now - t0) * 1e3
-        self._observe("serving.tick_ms", dt_ms)
         if kind is not None:
-            _telemetry.note_step_time(f"serving.{kind}", dt_ms / 1e3)
-        if appended:
-            # decode-gap: wall time between consecutive rounds that
-            # appended decode tokens — THE stall metric budgeted
-            # admission exists to bound (a monolithic long-prompt
-            # admission shows up as one huge gap here).  The anchor
-            # resets to None on idle returns so a quiet queue doesn't
-            # masquerade as a stall.
-            if self._gap_anchor is not None:
-                self._observe("serving.decode_gap_ms",
-                              (now - self._gap_anchor) * 1e3)
-            self._gap_anchor = now
+            _telemetry.note_step_time(f"serving.{kind}", now - t0)
         if not appended:
             return
         total = 0
-        per_tok = dt_ms / max(steps, 1)
         spec = kind is not None and "spec" in kind
         for st, n in appended:
             total += n
@@ -3827,11 +3924,16 @@ class DecodeServer:
                     "serving.ttft_ms",
                     (now - st.get("t_submit", t0)) * 1e3)
                 if n > 1:
-                    self._observe("serving.tpot_ms", per_tok,
-                                  n=n - 1)
+                    # the first token has no gap; the rest of its block
+                    # came a step apart
+                    self._observe("serving.tpot_ms",
+                                  (now - t0) * 1e3 / max(steps, 1), n=n - 1)
             else:
-                self._observe("serving.tpot_ms", per_tok, n=n)
+                gap_ms = (now - st["t_last"]) * 1e3
+                self._observe("serving.decode_gap_ms", gap_ms)
+                self._observe("serving.tpot_ms", gap_ms / n, n=n)
             st["t_last"] = now
+            self._emit.append((st["rid"], n, now))
             if spec and st.get("trace"):
                 # one span per traced slot per speculative round:
                 # the tick wall bounds every slot's draft+verify work
@@ -3840,6 +3942,8 @@ class DecodeServer:
                     rid=st["rid"], accepted=n)
         _telemetry.count("serving.tokens_generated", total)
         self._count_local("serving.tokens_generated", total)
+        if self._tick_sp is None:        # a drain outside any tick
+            self._emit_flush()
 
     # -- resilience: guarded ticks, the OOM chain, wedge recovery -----------
 
@@ -4103,7 +4207,8 @@ class DecodeServer:
             self._adm.control_tick(
                 idle=not self._slots and not self._queue)
         self._rss_guard()
-        self._guarded(self._tick_impl)
+        with self._tick_scope():
+            self._guarded(self._tick_impl)
 
     def _tick_impl(self):
         if self._spec_on:
@@ -4137,7 +4242,6 @@ class DecodeServer:
         if not self._slots:
             self._admit()
             if not self._slots:
-                self._gap_anchor = None   # idle, not stalled
                 return
         # budgeted admission: at most ONE prefill chunk per round,
         # before the decode step — the stall-free interleaving
@@ -4146,46 +4250,21 @@ class DecodeServer:
                                   for st in self._slots.values()):
             return   # nothing decodable this round (pure admission)
         t0 = time.perf_counter()
-        self._ensure_decode_blocks(1)
-        tok, pos = self._feed_arrays()
-        temp, tk, tp = self._sampling_arrays()
-        mask = self._mask_array()
+        with self._phase("feed"):
+            self._ensure_decode_blocks(1)
+            tok, pos = self._feed_arrays()
+            temp, tk, tp = self._sampling_arrays()
+            mask = self._mask_array()
         n = self._step_no
+        # the step kind, then its call (enqueue only): every branch
+        # leaves the tokens on the device as ``nxt``
+        logits = None
         if self._adapters is not None:
             # pool attached: every step gathers per-slot (a, b) pairs
             # by id — base-only batches gather row 0 (the zero delta)
             # and reproduce the plain server's tokens
-            pk = self._adapters.pool_key()
-            ad = self._adapters.stacks()
-            ids = self._gather_adapter_ids()
-            if temp.any() or mask is not None:
-                kind = "adapter_sample_step"
-                self._fault_check(kind)
-                fn = _get_adapter_sample_step_fn(
-                    self.cfg, pk, self._paged, self._shard)
-                if mask is None:
-                    # the executable takes the mask unconditionally
-                    # (ONE compiled shape); all-zeros is the identity
-                    mask = np.zeros(
-                        (self.max_batch, self.cfg.vocab_size),
-                        np.float32)
-                nxt, self.cache = fn(
-                    self.params, self.cache, ad, jnp.asarray(ids),
-                    jnp.asarray(tok), jnp.asarray(pos),
-                    jax.random.fold_in(self._base_key, n),
-                    jnp.asarray(temp), jnp.asarray(tk),
-                    jnp.asarray(tp), jnp.asarray(mask))
-                nxt = np.asarray(nxt)
-                logits = None
-            else:
-                kind = "adapter_step"
-                self._fault_check(kind)
-                fn = _get_adapter_step_fn(self.cfg, pk, self._paged,
-                                          self._shard)
-                logits, self.cache = fn(
-                    self.params, self.cache, ad, jnp.asarray(ids),
-                    jnp.asarray(tok), jnp.asarray(pos))
-                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            kind = ("adapter_sample_step" if temp.any() or mask is not None
+                    else "adapter_step")
         elif mask is not None:
             # constrained decode without a pool: the plain step plus
             # the [B, V] mask input.  Greedy slots take the masked
@@ -4193,39 +4272,67 @@ class DecodeServer:
             # fold_in(n) key like the sampled path (all-greedy batches
             # draw nothing from it)
             kind = "masked_step"
-            self._fault_check(kind)
-            fn = _get_masked_step_fn(self.cfg, self._paged, self._shard)
-            nxt, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(pos), jax.random.fold_in(self._base_key, n),
-                jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp),
-                jnp.asarray(mask))
-            nxt = np.asarray(nxt)
-            logits = None
         elif temp.any():
-            if self.cfg.moe is not None:
-                kind = "moe_sample_step"
-                self._fault_check(kind)
-                fn = self._moe_wrap(_get_moe_sample_step_fn(
-                    self.cfg, self._paged, self._shard))
-            else:
-                kind = "sample_step"
-                self._fault_check(kind)
-                fn = _get_sample_step_fn(self.cfg, self._paged,
-                                         self._shard)
-            nxt, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(pos), jax.random.fold_in(self._base_key, n),
-                jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
-            nxt = np.asarray(nxt)
-            logits = None
+            kind = ("moe_sample_step" if self.cfg.moe is not None
+                    else "sample_step")
         else:
             kind = "step"
+        with self._phase("dispatch", kind=kind):
             self._fault_check(kind)
-            logits, self.cache = self._step(self.params, self.cache,
-                                            jnp.asarray(tok),
-                                            jnp.asarray(pos))
-            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            if kind == "adapter_sample_step":
+                fn = _get_adapter_sample_step_fn(
+                    self.cfg, self._adapters.pool_key(), self._paged,
+                    self._shard)
+                if mask is None:
+                    # the executable takes the mask unconditionally
+                    # (ONE compiled shape); all-zeros is the identity
+                    mask = np.zeros(
+                        (self.max_batch, self.cfg.vocab_size),
+                        np.float32)
+                nxt, self.cache = fn(
+                    self.params, self.cache, self._adapters.stacks(),
+                    jnp.asarray(self._gather_adapter_ids()),
+                    jnp.asarray(tok), jnp.asarray(pos),
+                    jax.random.fold_in(self._base_key, n),
+                    jnp.asarray(temp), jnp.asarray(tk),
+                    jnp.asarray(tp), jnp.asarray(mask))
+            elif kind == "adapter_step":
+                fn = _get_adapter_step_fn(
+                    self.cfg, self._adapters.pool_key(), self._paged,
+                    self._shard)
+                logits, self.cache = fn(
+                    self.params, self.cache, self._adapters.stacks(),
+                    jnp.asarray(self._gather_adapter_ids()),
+                    jnp.asarray(tok), jnp.asarray(pos))
+            elif kind == "masked_step":
+                fn = _get_masked_step_fn(self.cfg, self._paged,
+                                         self._shard)
+                nxt, self.cache = fn(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos),
+                    jax.random.fold_in(self._base_key, n),
+                    jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp),
+                    jnp.asarray(mask))
+            elif kind == "step":
+                logits, self.cache = self._step(self.params, self.cache,
+                                                jnp.asarray(tok),
+                                                jnp.asarray(pos))
+            else:
+                if kind == "moe_sample_step":
+                    fn = self._moe_wrap(_get_moe_sample_step_fn(
+                        self.cfg, self._paged, self._shard))
+                else:
+                    fn = _get_sample_step_fn(self.cfg, self._paged,
+                                             self._shard)
+                nxt, self.cache = fn(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos),
+                    jax.random.fold_in(self._base_key, n),
+                    jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
+            if logits is not None:
+                nxt = jnp.argmax(logits, axis=-1)
+        with self._phase("wait"):
+            nxt = np.asarray(nxt)     # the one device-to-host fetch
         # the step counter advances only AFTER the step call returned:
         # a failed call (real or injected OOM) leaves host state exactly
         # as before the tick, so the guard's retry is bit-exact
@@ -4247,34 +4354,36 @@ class DecodeServer:
         done = []
         failed = []
         appended = []
-        for slot, st in self._slots.items():
-            if st.get("admitting"):
-                # rode the step at its prefill frontier: pos is owned by
-                # the admission machinery, the output token discarded,
-                # and a (mathematically valid, differently-rounded)
-                # logits row must not trip the NaN guard collaterally
-                continue
-            i = st["pos"]
-            st["pos"] = i + 1
-            if i < len(st["prompt"]) - 1:
-                continue                # still feeding prompt; logits unused
-            if slot in nan_slots:
-                # AFTER the prompt-feed skip: a mid-prompt slot never
-                # consumes this tick's logits, so a non-finite row there
-                # must not kill it collaterally
-                failed.append(slot)
-                continue
-            t = int(nxt[slot])
-            st["generated"].append(t)
-            appended.append((st, 1))
-            fin = self._constraint_push(st, t)
-            if self._finished(st, t) or fin:
-                done.append(slot)
-        for slot in failed:
-            st = self._slots.pop(slot)
-            self._fail_request(st, slot, "non-finite tick logits")
-        self._tel_tokens(appended, t0, kind=kind)
-        self._retire(done)
+        with self._phase("book") as ph:
+            for slot, st in self._slots.items():
+                if st.get("admitting"):
+                    # rode the step at its prefill frontier: pos is owned
+                    # by the admission machinery, the output token
+                    # discarded, and a (mathematically valid,
+                    # differently-rounded) logits row must not trip the
+                    # NaN guard collaterally
+                    continue
+                i = st["pos"]
+                st["pos"] = i + 1
+                if i < len(st["prompt"]) - 1:
+                    continue            # still feeding prompt; logits unused
+                if slot in nan_slots:
+                    # AFTER the prompt-feed skip: a mid-prompt slot never
+                    # consumes this tick's logits, so a non-finite row
+                    # there must not kill it collaterally
+                    failed.append(slot)
+                    continue
+                t = int(nxt[slot])
+                st["generated"].append(t)
+                appended.append((st, 1))
+                fin = self._constraint_push(st, t)
+                if self._finished(st, t) or fin:
+                    done.append(slot)
+            for slot in failed:
+                st = self._slots.pop(slot)
+                self._fail_request(st, slot, "non-finite tick logits")
+            self._book(ph, appended, done, t0, kind=kind)
+        self._refill()
 
     # -- async dispatch: one step/block in flight ---------------------------
 
@@ -4353,47 +4462,36 @@ class DecodeServer:
         self._step_no = n
 
     def _dispatch_step_async(self, prev):
-        self._ensure_decode_blocks(1)
-        ht, pm, pos, temp, tk, tp, snap = self._dispatch_feed(prev)
+        with self._phase("feed"):
+            self._ensure_decode_blocks(1)
+            ht, pm, pos, temp, tk, tp, snap = self._dispatch_feed(prev)
         n = self._step_no
         self._step_no = n + 1
+        # async pipelining composes with the adapter pool (gather rides
+        # the in-flight select); constrained slots never reach here —
+        # _tick_impl drains to sync first
+        fname = ("adapter_async_step" if self._adapters is not None
+                 else "moe_async_step" if self.cfg.moe is not None
+                 else "async_step")
         try:
-            if self._adapters is not None:
-                # async pipelining composes with the pool (gather rides
-                # the in-flight select); constrained slots never reach
-                # here — _tick_impl drains to sync first
-                fname = "adapter_async_step"
+            with self._phase("dispatch", kind=fname):
                 self._fault_check(fname)
-                fn = _get_adapter_async_step_fn(
-                    self.cfg, self._adapters.pool_key(), self._paged,
-                    self._shard)
+                if self._adapters is not None:
+                    fn = _get_adapter_async_step_fn(
+                        self.cfg, self._adapters.pool_key(), self._paged,
+                        self._shard)
+                    lead = (self._adapters.stacks(),
+                            jnp.asarray(self._gather_adapter_ids()))
+                elif self.cfg.moe is not None:
+                    fn = self._moe_wrap(_get_moe_async_step_fn(
+                        self.cfg, self._paged, self._shard))
+                    lead = ()
+                else:
+                    fn = _get_async_step_fn(self.cfg, self._paged,
+                                            self._shard)
+                    lead = ()
                 nxt, self.cache = fn(
-                    self.params, self.cache, self._adapters.stacks(),
-                    jnp.asarray(self._gather_adapter_ids()),
-                    jnp.asarray(ht), jnp.asarray(pm),
-                    self._prev_feed(prev), jnp.asarray(pos),
-                    jax.random.fold_in(self._base_key, n),
-                    jnp.asarray(temp), jnp.asarray(tk),
-                    jnp.asarray(tp))
-            elif self.cfg.moe is not None:
-                fname = "moe_async_step"
-                self._fault_check(fname)
-                fn = self._moe_wrap(_get_moe_async_step_fn(
-                    self.cfg, self._paged, self._shard))
-                nxt, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(ht),
-                    jnp.asarray(pm),
-                    self._prev_feed(prev), jnp.asarray(pos),
-                    jax.random.fold_in(self._base_key, n),
-                    jnp.asarray(temp),
-                    jnp.asarray(tk), jnp.asarray(tp))
-            else:
-                fname = "async_step"
-                self._fault_check(fname)
-                fn = _get_async_step_fn(self.cfg, self._paged,
-                                        self._shard)
-                nxt, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(ht),
+                    self.params, self.cache, *lead, jnp.asarray(ht),
                     jnp.asarray(pm),
                     self._prev_feed(prev), jnp.asarray(pos),
                     jax.random.fold_in(self._base_key, n),
@@ -4407,33 +4505,35 @@ class DecodeServer:
                           "snap": snap, "t_disp": time.perf_counter()}
 
     def _dispatch_block_async(self, prev, block: int):
-        self._ensure_decode_blocks(block)
-        ht, pm, pos, temp, tk, tp, snap = self._dispatch_feed(prev, block)
+        with self._phase("feed"):
+            self._ensure_decode_blocks(block)
+            ht, pm, pos, temp, tk, tp, snap = self._dispatch_feed(prev,
+                                                                  block)
         n = self._step_no
         self._step_no = n + block
+        fname = (f"async_sample_block@{block}" if temp.any()
+                 else f"async_block@{block}")
         try:
-            if temp.any():
-                fname = f"async_sample_block@{block}"
+            with self._phase("dispatch", kind=fname):
                 self._fault_check(fname)
-                fn = _get_async_sample_block_fn(self.cfg, block,
-                                                self._paged, self._shard)
-                toks, self.cache = fn(
-                    self.params, self.cache, jnp.asarray(ht),
-                    jnp.asarray(pm),
-                    self._prev_feed(prev), jnp.asarray(pos),
-                    self._base_key,
-                    jnp.asarray(n), jnp.asarray(temp), jnp.asarray(tk),
-                    jnp.asarray(tp))
-                feed = toks[:, -1]  # the block's last token per slot
-            else:
-                fname = f"async_block@{block}"
-                self._fault_check(fname)
-                fn = _get_async_block_fn(self.cfg, block, self._paged,
-                                         self._shard)
-                toks, self.cache, feed, _ = fn(
-                    self.params, self.cache, jnp.asarray(ht),
-                    jnp.asarray(pm),
-                    self._prev_feed(prev), jnp.asarray(pos))
+                if temp.any():
+                    fn = _get_async_sample_block_fn(
+                        self.cfg, block, self._paged, self._shard)
+                    toks, self.cache = fn(
+                        self.params, self.cache, jnp.asarray(ht),
+                        jnp.asarray(pm),
+                        self._prev_feed(prev), jnp.asarray(pos),
+                        self._base_key,
+                        jnp.asarray(n), jnp.asarray(temp),
+                        jnp.asarray(tk), jnp.asarray(tp))
+                    feed = toks[:, -1]  # the block's last token per slot
+                else:
+                    fn = _get_async_block_fn(self.cfg, block, self._paged,
+                                             self._shard)
+                    toks, self.cache, feed, _ = fn(
+                        self.params, self.cache, jnp.asarray(ht),
+                        jnp.asarray(pm),
+                        self._prev_feed(prev), jnp.asarray(pos))
         except Exception:
             self._rollback_dispatch(snap, n)
             raise
@@ -4453,16 +4553,17 @@ class DecodeServer:
         # _recover_wedge instead of blocking.  Budget 0 (default) is the
         # plain inline fetch — zero overhead, today's behavior.
         try:
-            if self._resil and (self._step_budget > 0
-                                or _faults.active()):
-                def _fetch():
-                    _faults.hang("tick", "serving.fetch")
-                    return np.asarray(prev["toks"])
+            with self._phase("wait"):
+                if self._resil and (self._step_budget > 0
+                                    or _faults.active()):
+                    def _fetch():
+                        _faults.hang("tick", "serving.fetch")
+                        return np.asarray(prev["toks"])
 
-                toks = _resilience.call_with_budget(
-                    _fetch, self._step_budget, name="serving.fetch")
-            else:
-                toks = np.asarray(prev["toks"])
+                    toks = _resilience.call_with_budget(
+                        _fetch, self._step_budget, name="serving.fetch")
+                else:
+                    toks = np.asarray(prev["toks"])
         except _resilience.WedgeError as e:
             self._recover_wedge(prev, e)
             return
@@ -4481,37 +4582,39 @@ class DecodeServer:
             raise
         done = []
         appended = []
-        for slot, st, i in prev["snap"]:
-            if self._slots.get(slot) is not st:
-                continue  # retired/replaced while this step was in flight
-            if prev["kind"] == "step":
-                if i < len(st["prompt"]) - 1:
-                    continue  # still feeding prompt; logits-token unused
-                t = int(toks[slot])
-                st["generated"].append(t)
-                appended.append((st, 1))
-                # constrained slots never dispatch async (the sync
-                # fallback gate) — the push is a no-op kept for the
-                # drain-on-transition edge
-                fin = self._constraint_push(st, t)
-                if self._finished(st, t) or fin:
-                    done.append(slot)
-            else:
-                kept = 0
-                for j in range(prev["block"]):
-                    t = int(toks[slot, j])
+        with self._phase("book") as ph:
+            for slot, st, i in prev["snap"]:
+                if self._slots.get(slot) is not st:
+                    continue  # retired/replaced while this was in flight
+                if prev["kind"] == "step":
+                    if i < len(st["prompt"]) - 1:
+                        continue  # still feeding prompt; token unused
+                    t = int(toks[slot])
                     st["generated"].append(t)
-                    kept += 1
+                    appended.append((st, 1))
+                    # constrained slots never dispatch async (the sync
+                    # fallback gate) — the push is a no-op kept for the
+                    # drain-on-transition edge
                     fin = self._constraint_push(st, t)
                     if self._finished(st, t) or fin:
                         done.append(slot)
-                        break
-                appended.append((st, kept))
-        # latency window: dispatch -> this fetch (the async pipeline's
-        # real step time, overlap included)
-        self._tel_tokens(appended, prev.get("t_disp", time.perf_counter()),
-                         steps=prev.get("block", 1), kind=prev.get("fn"))
-        self._retire(done)
+                else:
+                    kept = 0
+                    for j in range(prev["block"]):
+                        t = int(toks[slot, j])
+                        st["generated"].append(t)
+                        kept += 1
+                        fin = self._constraint_push(st, t)
+                        if self._finished(st, t) or fin:
+                            done.append(slot)
+                            break
+                    appended.append((st, kept))
+            # latency window: dispatch -> this fetch (the async
+            # pipeline's real step time, overlap included)
+            self._book(ph, appended, done,
+                       prev.get("t_disp", time.perf_counter()),
+                       steps=prev.get("block", 1), kind=prev.get("fn"))
+        self._refill()
 
     def _tick_async(self):
         """One async tick: dispatch step N+1 FIRST (feeding the in-flight
@@ -4524,7 +4627,6 @@ class DecodeServer:
         if not self._slots:
             self._admit()
             if not self._slots:
-                self._gap_anchor = None
                 return
         try:
             # one prefill chunk per round, before the dispatch (the
@@ -4562,7 +4664,6 @@ class DecodeServer:
         if not self._slots:
             self._admit()
             if not self._slots:
-                self._gap_anchor = None
                 return
         if self._adapters is not None or self._constrained_active() \
                 or self.cfg.moe is not None \
@@ -4658,7 +4759,8 @@ class DecodeServer:
             self._adm.control_tick(
                 idle=not self._slots and not self._queue)
         self._rss_guard()
-        self._guarded(lambda: self._tick_block_impl(block))
+        with self._tick_scope():
+            self._guarded(lambda: self._tick_block_impl(block))
 
     def _tick_block_impl(self, block: int):
         if self._spec_on:
@@ -4689,7 +4791,6 @@ class DecodeServer:
         if not self._slots:
             self._admit()
             if not self._slots:
-                self._gap_anchor = None
                 return
         # a slot at pos == len(prompt)-1 is fine for block decode (its feed
         # token is the prompt's last; everything after is feedback) — only
@@ -4714,62 +4815,67 @@ class DecodeServer:
                     break
             return
         t0 = time.perf_counter()
-        self._ensure_decode_blocks(block)
-        tok, pos = self._feed_arrays()
-        temp, tk, tp = self._sampling_arrays()
+        with self._phase("feed"):
+            self._ensure_decode_blocks(block)
+            tok, pos = self._feed_arrays()
+            temp, tk, tp = self._sampling_arrays()
         n = self._step_no
-        if self._adapters is not None:
-            # greedy adapter block: gather once per step inside the
-            # on-device scan — one host fetch for ``block`` tokens
-            kind = f"adapter_block@{block}"
+        kind = ("adapter_block" if self._adapters is not None
+                else "sample_block" if temp.any()
+                else "moe_block" if self.cfg.moe is not None
+                else "block") + f"@{block}"
+        with self._phase("dispatch", kind=kind):
             self._fault_check(kind)
-            fn = _get_adapter_block_fn(
-                self.cfg, block, self._adapters.pool_key(),
-                self._paged, self._shard)
-            toks, self.cache, _, _ = fn(
-                self.params, self.cache, self._adapters.stacks(),
-                jnp.asarray(self._gather_adapter_ids()),
-                jnp.asarray(tok), jnp.asarray(pos))
-        elif temp.any():
-            kind = f"sample_block@{block}"
-            self._fault_check(kind)
-            fn = _get_sample_block_fn(self.cfg, block, self._paged,
-                                      self._shard)
-            toks, self.cache = fn(
-                self.params, self.cache, jnp.asarray(tok),
-                jnp.asarray(pos), self._base_key, jnp.asarray(n),
-                jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
-        elif self.cfg.moe is not None:
-            # greedy MoE block: k joint-routing steps, the occupancy
-            # mask frozen at dispatch (every slot here is past its
-            # prompt — see the fallback above — so occupancy only
-            # shrinks mid-block, the documented block-overrun tradeoff)
-            kind = f"moe_block@{block}"
-            self._fault_check(kind)
-            fn = self._moe_wrap(_get_moe_block_fn(
-                self.cfg, block, self._paged, self._shard))
-            toks, self.cache, _, _ = fn(self.params, self.cache,
-                                        jnp.asarray(tok), jnp.asarray(pos))
-        else:
-            kind = f"block@{block}"
-            self._fault_check(kind)
-            fn = _get_block_fn(self.cfg, block, self._paged, self._shard)
-            toks, self.cache, _, _ = fn(self.params, self.cache,
-                                        jnp.asarray(tok), jnp.asarray(pos))
+            if self._adapters is not None:
+                # greedy adapter block: gather once per step inside the
+                # on-device scan — one host fetch for ``block`` tokens
+                fn = _get_adapter_block_fn(
+                    self.cfg, block, self._adapters.pool_key(),
+                    self._paged, self._shard)
+                toks, self.cache, _, _ = fn(
+                    self.params, self.cache, self._adapters.stacks(),
+                    jnp.asarray(self._gather_adapter_ids()),
+                    jnp.asarray(tok), jnp.asarray(pos))
+            elif temp.any():
+                fn = _get_sample_block_fn(self.cfg, block, self._paged,
+                                          self._shard)
+                toks, self.cache = fn(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos), self._base_key, jnp.asarray(n),
+                    jnp.asarray(temp), jnp.asarray(tk), jnp.asarray(tp))
+            elif self.cfg.moe is not None:
+                # greedy MoE block: k joint-routing steps, the occupancy
+                # mask frozen at dispatch (every slot here is past its
+                # prompt — see the fallback above — so occupancy only
+                # shrinks mid-block, the documented block-overrun
+                # tradeoff)
+                fn = self._moe_wrap(_get_moe_block_fn(
+                    self.cfg, block, self._paged, self._shard))
+                toks, self.cache, _, _ = fn(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos))
+            else:
+                fn = _get_block_fn(self.cfg, block, self._paged,
+                                   self._shard)
+                toks, self.cache, _, _ = fn(
+                    self.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos))
         self._step_no = n + block   # after the call: see _tick_impl
-        toks = np.asarray(toks)  # the block's single device->host fetch
+        with self._phase("wait"):
+            toks = np.asarray(toks)  # the block's one device->host fetch
         done = []
         appended = []
-        for slot, st in self._slots.items():
-            kept = 0
-            for j in range(block):
-                t = int(toks[slot, j])
-                st["generated"].append(t)
-                st["pos"] += 1
-                kept += 1
-                if self._finished(st, t):
-                    done.append(slot)
-                    break
-            appended.append((st, kept))
-        self._tel_tokens(appended, t0, steps=block, kind=kind)
-        self._retire(done)
+        with self._phase("book") as ph:
+            for slot, st in self._slots.items():
+                kept = 0
+                for j in range(block):
+                    t = int(toks[slot, j])
+                    st["generated"].append(t)
+                    st["pos"] += 1
+                    kept += 1
+                    if self._finished(st, t):
+                        done.append(slot)
+                        break
+                appended.append((st, kept))
+            self._book(ph, appended, done, t0, steps=block, kind=kind)
+        self._refill()

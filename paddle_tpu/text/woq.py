@@ -25,6 +25,8 @@ from __future__ import annotations
 
 import os
 
+import jax
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -258,21 +260,25 @@ def mm_stacked(h, p: dict, name: str, dt):
 
 
 def embed(params: dict, token, dt):
-    """wte[token] in compute dtype, dequantizing per-row scales if int8."""
-    e = params["wte"][token].astype(dt)
-    if params["wte"].dtype == jnp.int8:
-        e = e * params["wte_s"][token].astype(dt)
-    return e
+    """wte[token] in compute dtype, dequantizing per-row scales if int8.
+    Every path embeds through here, so the ``embed`` scope is here too."""
+    with jax.named_scope("embed"):
+        e = params["wte"][token].astype(dt)
+        if params["wte"].dtype == jnp.int8:
+            e = e * params["wte_s"][token].astype(dt)
+        return e
 
 
 def logits(x, params: dict, dt):
     """Tied-head logits x @ wte.T; per-row wte scales factor out of the
     contraction and apply on the [..., V] output (cheaper than scaling the
-    weight, exactly equal)."""
-    y = x @ params["wte"].T.astype(dt)
-    if params["wte"].dtype == jnp.int8:
-        y = y * params["wte_s"].reshape(-1).astype(dt)
-    return y
+    weight, exactly equal).  Every path's head, so the ``lm_head`` scope
+    is here."""
+    with jax.named_scope("lm_head"):
+        y = x @ params["wte"].T.astype(dt)
+        if params["wte"].dtype == jnp.int8:
+            y = y * params["wte_s"].reshape(-1).astype(dt)
+        return y
 
 
 def is_quantized(params: dict) -> bool:

@@ -16,7 +16,9 @@ written for a described chip cannot be read back without one); everything
 compiles in the test's own process; and all such tests live in this one
 file, because the TPU library belongs to one process at a time.
 """
+import contextlib
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -75,11 +77,24 @@ def as_on_tpu(monkeypatch):
     monkeypatch.setenv("PADDLE_TPU_FUSED_CE", "1")
 
 
-def kernels_in(fn, *args) -> int:
+def kernels_in(fn, *args, names=()) -> int:
     """Compile ``fn`` for the described chip; how many Mosaic kernels the
-    executable holds."""
-    return jax.jit(fn).lower(*args).compile().as_text().count(
-        "tpu_custom_call")
+    executable holds.  Each of ``names`` (a kernel's ``name=``) must name
+    one of its custom calls: a device trace tells kernels apart by it."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    for name in names:
+        assert _names_a_kernel(name, text), name
+    return text.count("tpu_custom_call")
+
+
+def _names_a_kernel(name: str, text: str) -> bool:
+    """A custom call of the compiled text is named after the kernel
+    (``%flash_attention_fwd.3``; under a bare ``jax.vjp`` the name carries
+    the transformation, ``%jvp_flash_attention_fwd_.1``) and its op_name
+    path holds the kernel's name as a scope of its own."""
+    return bool(re.search(
+        rf'%\w*{name}_*(\.\d+)? = .* custom-call\(.*'
+        rf'op_name="[^"]*\b{name}\)*/pallas_call"', text))
 
 
 # --------------------------------------------------------------------------
@@ -94,7 +109,8 @@ def test_flash_fwd_bwd_causal(shape):
     n = kernels_in(
         lambda q, k, v: jax.vjp(
             lambda a, b, c: fa._flash(a, b, c, True, None), q, k, v)[1](q),
-        q, q, q)
+        q, q, q, names=("flash_attention_fwd", "flash_attention_bwd_dq",
+                        "flash_attention_bwd_dkv"))
     assert n == 3  # fwd, dq, dk/dv
 
 
@@ -105,7 +121,7 @@ def test_fused_layer_norm_fwd_bwd(shape):
     n = kernels_in(
         lambda x, g, b: jax.vjp(
             lambda a, w, c: fn._fused_ln(a, w, c, 1e-5), x, g, b)[1](x),
-        x, g, g)
+        x, g, g, names=("fused_layer_norm_fwd", "fused_layer_norm_bwd"))
     assert n == 2
 
 
@@ -115,7 +131,8 @@ def test_fused_softmax_ce_fwd_bwd(shape):
     n = kernels_in(
         lambda l, y: jax.vjp(lambda a: fce._fused_ce(a, y), l)[1](
             jnp.ones((T,), F32)),
-        shape((T, V), BF), shape((T,), I32))
+        shape((T, V), BF), shape((T,), I32),
+        names=("fused_softmax_ce_fwd", "fused_softmax_ce_bwd"))
     assert n == 2
 
 
@@ -124,7 +141,7 @@ def test_w4_matmul(shape):
 
     n = kernels_in(lambda x, p, s: wm._w4_call(x, p, s, 128),
                    shape((8, D), BF), shape((D // 2, 4 * D), I8),
-                   shape((D // 128, 1, 4 * D), F32))
+                   shape((D // 128, 1, 4 * D), F32), names=("w4_matmul",))
     assert n == 1
 
 
@@ -224,6 +241,15 @@ def test_engine_decode_step(one_chip, no_persistent_cache, as_on_tpu,
     fn, args = _decode_step(cfg, layout, None, one_chip)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text  # the decode-attention kernel
+    # the accepted decode metrics find the step by these names
+    kernel = "paged_decode_attention" if layout == "paged" \
+        else "decode_attention"
+    assert _names_a_kernel(kernel, text)
+    assert text.startswith("HloModule jit__lambda,")
+    # every op's op_name path starts with the step it belongs to
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    assert any(p.startswith("jit(<lambda>)/serving.step/") and "/attn/" in p
+               for p in paths)
 
 
 def _train_step(cfg, mesh):
@@ -247,8 +273,54 @@ def test_train_step_one_chip(topo, no_persistent_cache, as_on_tpu):
     compiled = _train_step(_gpt(2), Mesh(np.array(topo.devices[:1]),
                                          ("dp",)))
     # flash fwd/dq/dkv + fused LN fwd/bwd (two sites) + fused CE fwd/bwd
-    assert compiled.as_text().count("tpu_custom_call") >= 7
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 7
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+    assert text.startswith("HloModule jit_step_fn,")
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert _names_a_kernel(kernel, text)
+
+
+def _opcodes(text) -> dict:
+    """{opcode: count} over a compiled module's instructions."""
+    out: dict = {}
+    for m in re.finditer(r"^\s+(?:ROOT )?%[\w.\-]+ = .*?\s([a-z][a-z\-]*)\(",
+                         text, re.M):
+        out[m.group(1)] = out.get(m.group(1), 0) + 1
+    return out
+
+
+@pytest.mark.parametrize("program", ["decode", "train"])
+def test_scopes_change_metadata_only(topo, one_chip, no_persistent_cache,
+                                     as_on_tpu, purge_engine, monkeypatch,
+                                     program):
+    """The named scopes on the model's parts and around every step kind
+    are HLO metadata: without them the same program compiles, op for op
+    and byte for byte of device memory."""
+    def build():
+        if program == "train":
+            return _train_step(_gpt(2), Mesh(np.array(topo.devices[:1]),
+                                             ("dp",)))
+        cfg = _gpt(2)
+        purge_engine(cfg)
+        fn, args = _decode_step(cfg, "paged", None, one_chip)
+        compiled = fn.lower(*args).compile()
+        from paddle_tpu.text import engine
+
+        engine.ENGINE.purge(cfg)
+        return compiled
+
+    with_scopes = build()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    without = build()
+    assert "serving.step" not in without.as_text()
+    assert _opcodes(with_scopes.as_text()) == _opcodes(without.as_text())
+    a, b = with_scopes.memory_analysis(), without.memory_analysis()
+    for field in ("argument_size_in_bytes", "output_size_in_bytes",
+                  "temp_size_in_bytes", "alias_size_in_bytes"):
+        assert getattr(a, field) == getattr(b, field), field
 
 
 # --------------------------------------------------------------------------
