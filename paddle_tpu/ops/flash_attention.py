@@ -7,6 +7,13 @@ HBM; it also emits the per-row logsumexp.  The backward recomputes blockwise
 scores from q/k and the saved logsumexp — two kernels, one accumulating dq
 over k-blocks, one accumulating dk/dv over q-blocks.
 
+Under ``jax.checkpoint`` the forward rule's two kernel outputs carry the
+names ``SAVED_OUT`` / ``SAVED_LSE``: the kernel's ``out`` is the ``p @ v``
+product a matmul-saving policy would have kept on the XLA path, and ``lse``
+the few bytes that make it usable.  ``ops/remat_policies`` adds both names
+to ``"dots"``, so that policy runs the forward kernel once a layer; a
+policy that saves nothing still recomputes it.
+
 This is the TPU-native replacement for the reference's fused attention CUDA
 kernels (operators/fused/multihead_matmul_op.cu,
 operators/math/bert_encoder_functor).
@@ -17,10 +24,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from . import _pallas
 
 _INTERPRET = False  # tests flip this to run the kernels on CPU (interpret)
+
+# checkpoint_name tags of the forward rule's residuals (see the header)
+SAVED_OUT = "flash_attention_out"
+SAVED_LSE = "flash_attention_lse"
 
 
 def _xla(q, k, v, causal, scale):
@@ -61,6 +73,8 @@ def _flash(q, k, v, causal, scale):
 
 def _flash_fwd(q, k, v, causal, scale):
     out, lse = _flash_fwd_impl(q, k, v, causal, scale)
+    out = checkpoint_name(out, SAVED_OUT)
+    lse = checkpoint_name(lse, SAVED_LSE)
     return out, (q, k, v, out, lse)
 
 

@@ -12,15 +12,25 @@ Accepted names (aliases map to the same policy):
 * ``None`` / ``"none"`` / ``"full"`` / ``"nothing_saveable"`` — save
   nothing: full recompute, maximum memory saving;
 * ``"dots"`` / ``"dots_saveable"`` — keep matmul outputs, recompute only
-  cheap elementwise ops;
+  cheap elementwise ops.  A matmul output is kept also when a kernel made
+  it: the flash-attention forward's ``out`` (the ``p @ v`` product) and its
+  log-sum-exp come out of a ``pallas_call``, which ``checkpoint_dots``
+  cannot see, so the two names ``ops/flash_attention`` tags them with are
+  saved as well and the backward pass does not run the forward kernel
+  again.  Cost per checkpointed layer: ``B*T*H*D`` activations plus
+  ``B*H*T`` floats; without flash attention the names match nothing;
 * ``"dots_no_batch"`` / ``"dots_with_no_batch_dims_saveable"`` — keep
-  only non-batch matmul outputs (weights-stationary contractions);
+  only non-batch matmul outputs (weights-stationary contractions; the
+  attention products carry batch dimensions and are recomputed, on the
+  kernel path too);
 * ``"everything"`` / ``"everything_saveable"`` — keep all residuals
   (checkpoint becomes a no-op; useful for A/B isolation).
 """
 from __future__ import annotations
 
 import jax
+
+from .flash_attention import SAVED_LSE, SAVED_OUT
 
 _ALIASES = {
     None: None, "none": None, "full": None, "nothing_saveable": None,
@@ -48,9 +58,11 @@ def resolve(name: str | None):
     canon = canonical(name)
     if canon is None:
         return None
+    cp = jax.checkpoint_policies
     return {
-        "dots": jax.checkpoint_policies.checkpoint_dots,
-        "dots_no_batch":
-            jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
-        "everything": jax.checkpoint_policies.everything_saveable,
+        "dots": cp.save_from_both_policies(
+            cp.checkpoint_dots,
+            cp.save_only_these_names(SAVED_OUT, SAVED_LSE)),
+        "dots_no_batch": cp.checkpoint_dots_with_no_batch_dims,
+        "everything": cp.everything_saveable,
     }[canon]

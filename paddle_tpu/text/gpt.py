@@ -50,7 +50,10 @@ class GPTConfig:
     # None = save nothing (full recompute, max memory saving);
     # "dots" = keep matmul outputs (recompute only cheap elementwise —
     #   a middle rung that may also sidestep backends where FULL-remat
-    #   programs fail to compile);
+    #   programs fail to compile); on the flash-attention path that
+    #   includes the kernel's two residuals, flash_attention.SAVED_OUT
+    #   (the p @ v product) and SAVED_LSE, so the forward kernel runs
+    #   once a layer;
     # "dots_no_batch" = keep only non-batch matmuls (weights-stationary)
     remat_policy: str | None = None
     use_flash: bool = True

@@ -87,14 +87,18 @@ def kernels_in(fn, *args, names=()) -> int:
     return text.count("tpu_custom_call")
 
 
-def _names_a_kernel(name: str, text: str) -> bool:
-    """A custom call of the compiled text is named after the kernel
-    (``%flash_attention_fwd.3``; under a bare ``jax.vjp`` the name carries
-    the transformation, ``%jvp_flash_attention_fwd_.1``) and its op_name
-    path holds the kernel's name as a scope of its own."""
-    return bool(re.search(
-        rf'%\w*{name}_*(\.\d+)? = .* custom-call\(.*'
+def _kernel_calls(name: str, text: str) -> int:
+    """How many custom calls of the compiled text are named after the
+    kernel (``%flash_attention_fwd.3``; under a bare ``jax.vjp`` the name
+    carries the transformation, ``%jvp_flash_attention_fwd_.1``) with an
+    op_name path that holds the kernel's name as a scope of its own."""
+    return len(re.findall(
+        rf'%\w*{name}_*(?:\.\d+)? = .* custom-call\(.*'
         rf'op_name="[^"]*\b{name}\)*/pallas_call"', text))
+
+
+def _names_a_kernel(name: str, text: str) -> bool:
+    return _kernel_calls(name, text) > 0
 
 
 # --------------------------------------------------------------------------
@@ -252,17 +256,19 @@ def test_engine_decode_step(one_chip, no_persistent_cache, as_on_tpu,
                for p in paths)
 
 
-def _train_step(cfg, mesh):
+def _train_step(cfg, mesh, accum=1):
     from paddle_tpu.optimizer import AdamW
     from paddle_tpu.text import gpt_hybrid
 
     opt = AdamW(learning_rate=1e-3)
-    _, step_fn, _ = gpt_hybrid.build_gpt_train_step(cfg, mesh, opt)
+    _, step_fn, _ = gpt_hybrid.build_gpt_train_step(cfg, mesh, opt,
+                                                    accum=accum)
     p = _param_shapes(cfg)
     state = gpt_hybrid.GPTTrainState(
         p, jax.eval_shape(opt.init_state, p),
         jax.ShapeDtypeStruct((), I32))
-    batch = mesh.shape.get("dp", 1)  # one sequence per data shard
+    # one sequence per data shard and micro-batch
+    batch = mesh.shape.get("dp", 1) * accum
     return step_fn.lower(
         state, jax.ShapeDtypeStruct((batch, T + 1), I32),
         jax.eval_shape(lambda: jax.random.PRNGKey(0)),
@@ -280,6 +286,28 @@ def test_train_step_one_chip(topo, no_persistent_cache, as_on_tpu):
     for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
                    "flash_attention_bwd_dkv"):
         assert _names_a_kernel(kernel, text)
+
+
+def test_train_step_dots_keeps_flash_residuals(topo, no_persistent_cache,
+                                               monkeypatch):
+    """The benchmark's train cell checkpoints its block under ``"dots"``
+    with ``accum`` 8.  That policy keeps the flash forward's ``out`` and
+    ``lse`` (ops/remat_policies), so the step holds the forward kernel
+    once, not a second time inside the backward pass's recomputation."""
+    from paddle_tpu.ops import _pallas
+
+    monkeypatch.setattr(_pallas, "on_tpu", lambda: True)
+    cfg = dataclasses.replace(_gpt(2), remat=True, remat_policy="dots")
+    compiled = _train_step(cfg, Mesh(np.array(topo.devices[:1]), ("dp",)),
+                           accum=8)
+    text = compiled.as_text()
+    for kernel in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                   "flash_attention_bwd_dkv"):
+        assert _kernel_calls(kernel, text) == 1, kernel
+    assert text.count("tpu_custom_call") == 3
+    # 2,580,986,880 B when this was written; 2,563,167,232 B with the
+    # forward recomputed: the residuals of two layers are 17.8 MB more
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.7e9
 
 
 def _opcodes(text) -> dict:

@@ -523,39 +523,65 @@ def test_gradient_accumulation_matches_full_batch():
                                    rtol=5e-2, atol=5e-3)
 
 
+def _remat_loss_and_grads(base, **kw):
+    cfg = gpt.GPTConfig(**base, **kw)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    toks = jax.random.randint(jax.random.PRNGKey(1),
+                              (2, cfg.max_seq_len + 1), 0, cfg.vocab_size)
+    f = jax.value_and_grad(lambda p: gpt.loss_fn(p, toks, cfg))
+    loss, g = jax.jit(f)(params)
+    return float(loss), g, str(jax.make_jaxpr(f)(params))
+
+
+_REMAT_CASES = [dict(remat=True), dict(remat=True, remat_policy="dots"),
+                dict(remat=True, remat_policy="dots_no_batch"),
+                dict(remat=True, remat_policy="everything")]
+
+
 class TestRematPolicies:
+    def _assert_inert(self, base, kw):
+        l0, g0, _ = _remat_loss_and_grads(base, remat=False)
+        l1, g1, jaxpr = _remat_loss_and_grads(base, **kw)
+        assert abs(l0 - l1) < 1e-5, (kw, l0, l1)
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_allclose(
+                np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
+            g0, g1)
+        return jaxpr
+
     def test_remat_policies_match_no_remat(self, monkeypatch):
         """Selective checkpointing (remat_policy) must be numerically
         inert: loss AND grads identical to the un-checkpointed forward
         for every policy (only memory/recompute scheduling changes)."""
         monkeypatch.delenv("PADDLE_TPU_REMAT_POLICY", raising=False)
-        import jax
-        import jax.numpy as jnp
-
-        from paddle_tpu.text import gpt
-
         base = dict(vocab_size=128, hidden_size=32, num_layers=2,
                     num_heads=2, max_seq_len=32, dtype=jnp.float32)
-        key = jax.random.PRNGKey(0)
-        toks = jax.random.randint(jax.random.PRNGKey(1), (2, 33), 0, 128)
+        for kw in _REMAT_CASES[:3]:
+            assert "flash_attention" not in self._assert_inert(base, kw)
 
-        def run(**kw):
-            cfg = gpt.GPTConfig(**base, **kw)
-            params = gpt.init_params(cfg, key)
-            loss, g = jax.jit(jax.value_and_grad(
-                lambda p: gpt.loss_fn(p, toks, cfg)))(params)
-            return float(loss), g
+    @pytest.mark.parametrize(
+        "kw,fwd_calls", zip(_REMAT_CASES, (2, 1, 2, 1)),
+        ids=["full", "dots", "dots_no_batch", "everything"])
+    def test_flash_forward_runs_once_where_matmuls_are_kept(
+            self, monkeypatch, kw, fwd_calls):
+        """On the kernel path the policies stay inert, and a policy that
+        keeps the attention matmuls keeps the flash kernel's ``out`` and
+        ``lse`` too: the scanned block's gradient holds the forward
+        kernel once (the layer scan traces its body once, so a count is
+        per layer); a policy that recomputes them holds it twice."""
+        from paddle_tpu.ops import _pallas, flash_attention as fa
 
-        l0, g0 = run(remat=False)
-        for kw in (dict(remat=True),
-                   dict(remat=True, remat_policy="dots"),
-                   dict(remat=True, remat_policy="dots_no_batch")):
-            l1, g1 = run(**kw)
-            assert abs(l0 - l1) < 1e-5, (kw, l0, l1)
-            jax.tree_util.tree_map(
-                lambda a, b: np.testing.assert_allclose(
-                    np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-6),
-                g0, g1)
+        monkeypatch.delenv("PADDLE_TPU_REMAT_POLICY", raising=False)
+        monkeypatch.delenv("PADDLE_TPU_NO_FLASH", raising=False)
+        monkeypatch.setattr(_pallas, "on_tpu", lambda: True)
+        monkeypatch.setattr(fa, "_INTERPRET", True)
+        # shapes the static gate takes: T % 128 == 0, head_dim 128
+        base = dict(vocab_size=128, hidden_size=256, num_layers=2,
+                    num_heads=2, max_seq_len=128, dtype=jnp.float32)
+        jaxpr = self._assert_inert(base, kw)
+        assert jaxpr.count("name=flash_attention_fwd") == fwd_calls
+        assert jaxpr.count("name=flash_attention_bwd_dq") == 1
+        assert jaxpr.count("name=flash_attention_bwd_dkv") == 1
 
     def test_unknown_policy_is_loud(self, monkeypatch):
         monkeypatch.delenv("PADDLE_TPU_REMAT_POLICY", raising=False)
