@@ -1,5 +1,6 @@
-"""What every cell needs: files found by name, the device and its peaks,
-percentiles, compile counting, and the last line.
+"""What every cell needs: files found by name (the configuration's family
+and its entry point among them), the device and its peaks, percentiles,
+compile counting, and the last line.
 
 Copies of sound pieces of the program live here so that a later PR cannot
 move the yardstick: ``compile_count`` (chip_smoke.py), the peaks table
@@ -61,6 +62,28 @@ def cell(name: str) -> dict:
         "end_to_end": [m for m in man["end_to_end"] if here(m)],
         "per_layer": [m for m in man["per_layer"] if here(m)],
     }
+
+
+def family(config: dict):
+    """The module that knows the configuration's architecture:
+    ``families/<family>.py`` (``families/__init__.py`` has what it gives).
+    A file that states no family, or one that has no module, is an error,
+    not a default."""
+    name = config.get("family")
+    if not name:
+        raise SystemExit("benchmark: the configuration file states no "
+                         "\"family\" (benchmarks/families/<family>.py)")
+    if not os.path.isfile(os.path.join(HERE, "families", name + ".py")):
+        raise SystemExit(f"benchmark: no module for family {name!r}: "
+                         f"benchmarks/families/{name}.py is not there")
+    return importlib.import_module("benchmarks.families." + name)
+
+
+def entry_point(config: dict):
+    """What the configuration's ``entry_point.call`` names in the program
+    (``paddle_tpu.text.serving.DecodeServer``), imported."""
+    module, _, name = config["entry_point"]["call"].rpartition(".")
+    return getattr(importlib.import_module(module), name)
 
 
 # --------------------------------------------------------------------------
@@ -172,7 +195,7 @@ def read_layers(cell_: dict, run_data: dict) -> dict:
     return out
 
 
-def emit(ctx: dict, values: dict, correct: bool, attempted: int,
+def emit(ctx: dict, values: dict, compared: dict, attempted: int,
          failed: int, device: dict, run_data: dict | None = None) -> None:
     """Print the result as the last line of stdout.  ``values`` holds
     every number the run produced by metric name; the line carries the
@@ -180,7 +203,13 @@ def emit(ctx: dict, values: dict, correct: bool, attempted: int,
     (``--trace 1``, read from ``run_data`` by the metrics' own readers),
     and leaves out a per-layer metric whose reader found nothing.  A
     rehearsal on the CPU prints under ``cpu_rehearsal.*``, never under a
-    device metric's name."""
+    device metric's name.
+
+    ``compared`` decides ``correct``: {name: (number, limit)}, each number
+    correct where it is at most its limit.  Every one is printed beside its
+    limit as the last lines of stderr and under the line's last key."""
+    correct = bool(compared) and all(
+        math.isfinite(v) and v <= limit for v, limit in compared.values())
     cell_, trace, rehearse = (ctx["cell"], bool(ctx["args"].trace),
                               ctx["args"].rehearse)
     line = {"correct": bool(correct), "attempted": int(attempted),
@@ -192,7 +221,8 @@ def emit(ctx: dict, values: dict, correct: bool, attempted: int,
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         line["breakdown"] = trace_.breakdown(reduced)
         run_data = dict(run_data, values=values, peaks=None if rehearse
-                        else peaks(device["kind"]))
+                        else peaks(device["kind"]),
+                        family=family(cell_["config"]))
         values = dict(values, **read_layers(cell_, run_data))
     for m in cell_["per_layer"] if trace else cell_["end_to_end"]:
         v = values.get(m["name"])
@@ -203,5 +233,13 @@ def emit(ctx: dict, values: dict, correct: bool, attempted: int,
             continue
         name = ("cpu_rehearsal." + m["name"]) if rehearse else m["name"]
         line["metrics"][name] = {"value": float(v), "unit": m["unit"]}
+    # a number that is not finite is null in the line (JSON has no nan)
+    line["compared"] = {
+        k: {"value": float(v) if math.isfinite(v) else None,
+            "limit": float(limit)} for k, (v, limit) in compared.items()}
     sys.stdout.flush()
+    for k, (v, limit) in compared.items():
+        print(f"[compared] {k} {float(v)!r} limit {float(limit)!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)

@@ -1,5 +1,9 @@
-"""From a configuration file to the program's ``GPTConfig``, the weights
-from the seed, and the arithmetic of what a step has to do.
+"""The ``gpt`` family (``families/__init__.py`` has the contract): GPT-2's
+architecture as ``paddle_tpu/text/gpt.py`` builds it: learned positions,
+LayerNorm, gelu, multi-head attention, a tied head.  From a configuration
+file to the program's ``GPTConfig``, the weights from the seed, the server
+and the train step, correctness through ``reference_gpt.py``, and the
+arithmetic of what a step has to do.
 
 The operation and byte counts live here (a copy of ``gpt.flops_per_token``,
 plus the decode step's), so that a later PR cannot change what 100%
@@ -7,6 +11,9 @@ means."""
 from __future__ import annotations
 
 import numpy as np
+
+from .. import common
+from . import TrainStep
 
 
 def sizes(config: dict) -> dict:
@@ -53,15 +60,62 @@ def bf16_params(cfg, seed: int):
     return cast(jax.random.PRNGKey(seed))
 
 
-def token_stream(rng, B: int, T: int, vocab: int) -> np.ndarray:
-    """[B, T + 1] tokens of the deterministic stream next = (3 tok + 1)
-    mod 13, spread over the vocabulary (chip_smoke.token_stream)."""
-    t = rng.integers(0, 13, (B, 1))
-    rows = [t]
-    for _ in range(T):
-        t = (t * 3 + 1) % 13
-        rows.append(t)
-    return (np.concatenate(rows, 1) * (vocab // 13)).astype(np.int32)
+def weights(config: dict, seed: int):
+    cfg = gpt_config(config)
+    return cfg, bf16_params(cfg, seed)
+
+
+def server(config: dict, cfg, params):
+    return common.entry_point(config)(params, cfg,
+                                      **config["entry_point"]["args"])
+
+
+def _reference_args(config: dict) -> dict:
+    return {"n_head": sizes(config)["H"], "gelu": "tanh",
+            "eps": config["model"]["layer_norm_epsilon"]}
+
+
+def served_margins(config: dict, params, prompt, served) -> np.ndarray:
+    from . import reference_gpt
+
+    return reference_gpt.served_margins(
+        params, prompt, served, pad_to=sizes(config)["T"],
+        **_reference_args(config))
+
+
+def train_step(config: dict, devices, seed: int) -> TrainStep:
+    """``entry_point.call`` (``build_gpt_train_step``) over a mesh whose
+    axes are the file's ``entry_point.mesh`` ({"dp": 1}; a product of the
+    cell's chips), with the optimizer it names at a constant rate."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from paddle_tpu import optimizer
+
+    ep = config["entry_point"]
+    rate = float(config["assumed"]["learning_rate"])
+    axes = ep["mesh"]
+    mesh = Mesh(np.array(devices).reshape(tuple(axes.values())), tuple(axes))
+    opt = getattr(optimizer, ep["optimizer"])(learning_rate=rate)
+    init_fn, step_fn, _ = common.entry_point(config)(
+        gpt_config(config), mesh, opt, **ep["args"])
+    return TrainStep(init_fn, step_fn,
+                     (jax.random.PRNGKey(seed), jnp.float32(rate)),
+                     int(ep["args"]["accum"]) * int(ep["micro_batch"]))
+
+
+def reference_loss(config: dict, seed: int, tokens) -> float:
+    import jax
+
+    from paddle_tpu.text import gpt
+
+    from . import reference_gpt
+
+    cfg = gpt_config(config)
+    params = jax.jit(lambda k: gpt.init_params(cfg, k))(
+        jax.random.PRNGKey(seed))
+    return reference_gpt.loss(params, tokens, **_reference_args(config))
 
 
 # --------------------------------------------------------------------------

@@ -1,6 +1,7 @@
-"""Training cell: whole steps of ``build_gpt_train_step`` dispatched back
-to back until ``--seconds`` have passed, the loss fetched every few steps
-as a trainer's log would.
+"""Training cell: whole steps of the family's train step
+(``families/<family>.train_step``) dispatched back to back until
+``--seconds`` have passed, the loss fetched every few steps as a trainer's
+log would.
 
 The rate is taken over all the work and all the time of the window: steps
 x sequences x seq_len / elapsed to a final ``block_until_ready``.  With
@@ -13,62 +14,50 @@ import time
 
 import numpy as np
 
-from .. import common, model, reference_gpt, trace
+from .. import common, trace
 from ..common import log
+from ..traffic_gen import token_stream
 
 
 def run(ctx: dict) -> None:
     import jax
     import jax.numpy as jnp
-    from jax.sharding import Mesh
 
-    from paddle_tpu import optimizer
     from paddle_tpu.framework import platform
-    from paddle_tpu.text import gpt, gpt_hybrid
 
     cell, args = ctx["cell"], ctx["args"]
     config, mix = cell["config"], cell["traffic"]
     devs = ctx["devices"]
     log(f"[setup] jax {jax.__version__}, compile cache at "
         f"{platform.init_compile_cache()}")
-    s = model.sizes(config)
-    ep = config["entry_point"]
+    fam = common.family(config)
+    s = fam.sizes(config)
     seq = int(mix["seq_len"])
-    accum = int(ep["args"]["accum"])
-    batch = accum * int(ep["micro_batch"])
-    lr = jnp.float32(config["assumed"]["learning_rate"])
     seed = common.jax_seed(args.seed)
 
     # ---- set-up: state from the seed on the device, warm-up steps --------
-    cfg = model.gpt_config(config)
-    axes = ep["mesh"]                      # {"dp": 1}; a product of chips
-    mesh = Mesh(np.array(devs).reshape(tuple(axes.values())), tuple(axes))
-    opt = getattr(optimizer, ep["optimizer"])(
-        learning_rate=float(config["assumed"]["learning_rate"]))
-    init_fn, step_fn, _ = gpt_hybrid.build_gpt_train_step(
-        cfg, mesh, opt, **ep["args"])
+    init_fn, step_fn, tail, batch = fam.train_step(config, devs, seed)
     t0 = time.perf_counter()
     state = init_fn(seed)
     jax.block_until_ready(state)
     t_state = time.perf_counter() - t0
     rng = np.random.default_rng(args.seed)
-    key = jax.random.PRNGKey(seed)
 
     def next_batch():
-        return model.token_stream(rng, batch, seq, s["V"])
+        return token_stream(rng, batch, seq, s["V"])
 
     first_batch = next_batch()
     losses = []
     t0 = time.perf_counter()
     toks = jnp.asarray(first_batch)
     for _ in range(int(mix["warmup_steps"])):
-        state, loss = step_fn(state, toks, key, lr)
+        state, loss = step_fn(state, toks, *tail)
         losses.append(float(loss))
         toks = jnp.asarray(next_batch())
     t_warm = time.perf_counter() - t0
     log(f"[setup] state from the seed {t_state:.2f}s; "
         f"{mix['warmup_steps']} warm-up steps (compile or cache load, then "
-        f"run) {t_warm:.2f}s; batch {batch} x {seq} tokens, accum {accum}")
+        f"run) {t_warm:.2f}s; batch {batch} x {seq} tokens")
 
     # ---- the window -------------------------------------------------------
     n0 = common.compile_count() + common.jit_entries(step_fn)
@@ -82,7 +71,7 @@ def run(ctx: dict) -> None:
     def one_step():
         nonlocal state, toks, pending, steps, step_s, losses
         with trace.annotate("bench.dispatch"):
-            state, loss = step_fn(state, toks, key, lr)
+            state, loss = step_fn(state, toks, *tail)
         pending.append(loss)
         steps += 1
         with trace.annotate("bench.next_batch"):
@@ -126,21 +115,18 @@ def run(ctx: dict) -> None:
     del state
     tol = config["correctness"]["first_loss_tol"]
     t0 = time.perf_counter()
-    params = jax.jit(lambda k: gpt.init_params(cfg, k))(
-        jax.random.PRNGKey(seed))
-    ref = reference_gpt.loss(
-        params, first_batch, n_head=s["H"],
-        eps=config["model"]["layer_norm_epsilon"], gelu="tanh")
-    finite = bool(np.isfinite(losses).all())
-    correct = (finite and abs(losses[0] - ref) <= tol
-               and losses[-1] < losses[0])
-    log(f"[correct] first step's loss {losses[0]:.5f} against "
-        f"reference_gpt {ref:.5f} on the same batch (tolerance {tol}, "
-        f"bf16 compute against float32) in {time.perf_counter() - t0:.1f}s; "
-        f"all {len(losses)} losses finite: {finite}; last below first: "
-        f"{losses[-1] < losses[0]}")
+    ref = fam.reference_loss(config, seed, first_batch)
+    nonfinite = int((~np.isfinite(losses)).sum())
+    log(f"[correct] first step's loss {losses[0]:.5f} against the "
+        f"{config['family']} family's reference {ref:.5f} on the same batch "
+        f"(tolerance {tol}, bf16 compute against float32) in "
+        f"{time.perf_counter() - t0:.1f}s; losses not finite: {nonfinite} "
+        f"of {len(losses)}; last less first: {losses[-1] - losses[0]:.5f}")
+    compared = {"first_loss_gap": (abs(losses[0] - ref), tol),
+                "losses_not_finite": (nonfinite, 0),
+                "last_loss_less_first": (losses[-1] - losses[0], 0.0)}
 
     common.emit(ctx, {"setup_s": setup_s, "train_tok_s": tok_s,
                       "compiles_in_window": compiles},
-                correct, steps, 0 if finite else 1, device,
+                compared, steps, 1 if nonfinite else 0, device,
                 {"trace": reduced, "sizes": s, "seq_len": seq})

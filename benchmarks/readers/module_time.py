@@ -1,10 +1,11 @@
 """Device time in ms of one execution of an XLA module: the median over
 its whole runs inside the traced slice.
 
-The module is chosen by ``module`` (a pattern on its name) and, because
-every step the program's Engine builds is called ``jit__lambda``, by a
-kernel that ran inside it: ``with_kernel``, where given, must match one of
-the run's custom calls."""
+The module is chosen by ``module`` (a pattern on its name: the train
+step, ``jit_step_fn``).  Every step the program's Engine builds is called
+``jit__lambda``; ``with_kernel``, where given, tells them apart by a custom
+call that ran inside.  No accepted metric does (a kernel renamed would
+blank it): a decode step is found by its scope, ``scope_time``."""
 import re
 
 from ..common import median

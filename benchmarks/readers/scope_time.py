@@ -6,8 +6,9 @@ of the ops that ``args`` select.
 The step's runs: ``step_scope`` (a pattern on the ``serving.<kind>`` scope
 in the paths of a run's ops: a decode step whatever its XLA module or its
 kernels are called) or ``module`` (a pattern on the XLA module's name: the
-train step, ``jit_step_fn``).  Its ops: ``parts`` (the innermost named
-part of the op's path is one of these), ``not_parts`` (is none of these:
+train step, ``jit_step_fn``).  Its ops, all of them where ``args`` select
+none (the whole step: the sum is its busy time): ``parts`` (the innermost
+named part of the op's path is one of these), ``not_parts`` (is none of these:
 what is left of the step, ops the program named no path for included),
 ``kernel`` (a pattern on the names in the op's path, where a Pallas
 kernel's ``name=`` is a scope of its own, and on the HLO op's name less its

@@ -4,11 +4,15 @@
     python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in BENCHMARK.json.  Everything that
-belongs to it is found by name: ``configs/<config>.json``,
+belongs to it is found by name: ``configs/<config>.json`` (whose ``family``
+names ``families/<family>.py``, the one place that knows the architecture,
+its plain reference and what a step of it must do, and whose
+``entry_point.call`` names what is driven in the program),
 ``traffic/<mix>.json`` (whose ``kind`` names ``generators/<kind>.py``) and,
 for a traced run, ``layer_metrics/<metric>.json`` (whose ``reader`` names
-``readers/<reader>.py``).  A new cell, mix, configuration or per-layer
-metric is new files and new entries; no file here needs an edit.
+``readers/<reader>.py``).  A new cell, mix, configuration, family or
+per-layer metric is new files and new entries; no file here needs an edit.
+A new end-to-end metric does: the generator kind computes it.
 
 The run needs a TPU and fails without one.  ``--rehearse`` asks for the CPU
 instead, at the tiny size in each file's ``rehearse`` block, for the tests:

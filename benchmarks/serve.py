@@ -15,14 +15,22 @@ the window; per-request statistics of that run use only requests due
 before the profiler started."""
 from __future__ import annotations
 
+import collections
 import os
 import time
 
 import numpy as np
 
-from . import common, model, reference_gpt, requests, trace
+from . import common, requests, trace
 from .common import log
 from .traffic_gen import warmup_buckets
+
+
+# what the driver notes after every tick(): its own stamp, the program's
+# two gauges, the turn of the driver's loop (a turn without a tick slept),
+# and the requests that held a slot in the tick, by their status
+Sample = collections.namedtuple(
+    "Sample", "t queue_depth slot_occupancy turn held")
 
 
 class Driver:
@@ -31,7 +39,8 @@ class Driver:
         self.records: dict = {}        # rid -> record (requests.py)
         self.open: set = set()         # rids not yet seen finished
         self.bad: dict = {}            # rid -> status other than ok
-        self.samples: list = []        # (t, queue_depth, slot_occupancy)
+        self.samples: list = []        # one Sample a tick()
+        self.turn = 0
 
     def submit_due(self, now: float) -> None:
         for req in self.source.due(now):
@@ -47,16 +56,17 @@ class Driver:
 
     def step(self, now: float) -> None:
         srv = self.srv
+        self.turn += 1
         self.submit_due(now)
         if srv.pending():
             with trace.annotate("bench.tick"):
                 srv.tick()
             with trace.annotate("bench.poll"):
                 t = time.perf_counter()
-                self.samples.append(
-                    (t, self.tl.gauge("serving.queue_depth").get(),
-                     self.tl.gauge("serving.slot_occupancy").get()))
-                self._poll(t)
+                self.samples.append(Sample(
+                    t, self.tl.gauge("serving.queue_depth").get(),
+                    self.tl.gauge("serving.slot_occupancy").get(),
+                    self.turn, self._poll(t)))
         else:
             nxt = self.source.next_due()
             wait = 0.02 if nxt is None else min(0.02, nxt - now)
@@ -64,15 +74,20 @@ class Driver:
                 with trace.annotate("bench.sleep"):
                     time.sleep(wait)
 
-    def _poll(self, now: float) -> None:
+    def _poll(self, now: float) -> int:
+        """Retire what the tick finished; the requests that held a slot in
+        it (active, or finished by it)."""
+        held = 0
         for rid in list(self.open):
             st = self.srv.status(rid)
+            held += st != "queued"
             if st in ("queued", "active"):
                 continue
             self.open.discard(rid)
             if st != "ok":
                 self.bad[rid] = st
             self.source.completed(rid, now)
+        return held
 
     def run_until(self, t_end: float, stop=None) -> None:
         while True:
@@ -82,18 +97,14 @@ class Driver:
             self.step(now)
 
 
-def build_server(config: dict, seed: int):
+def build_server(fam, config: dict, seed: int):
     import jax
 
-    from paddle_tpu.text.serving import DecodeServer
-
-    cfg = model.gpt_config(config)
     t0 = time.perf_counter()
-    params = jax.block_until_ready(
-        model.bf16_params(cfg, common.jax_seed(seed)))
+    cfg, params = fam.weights(config, common.jax_seed(seed))
+    jax.block_until_ready(params)
     t_weights = time.perf_counter() - t0
-    srv = DecodeServer(params, cfg, **config["entry_point"]["args"])
-    return params, srv, t_weights
+    return params, fam.server(config, cfg, params), t_weights
 
 
 def prime_block_copy(srv, vocab: int) -> None:
@@ -111,15 +122,13 @@ def prime_block_copy(srv, vocab: int) -> None:
             srv.tick()
 
 
-def check_served(params, config, sample) -> tuple:
-    """Teacher-force each (prompt, served) through the plain reference:
-    (worst margin below the reference's best logit, tokens checked)."""
-    s = model.sizes(config)
+def check_served(fam, params, config, sample) -> tuple:
+    """Teacher-force each (prompt, served) through the family's plain
+    reference: (worst margin below the reference's best logit, tokens
+    checked)."""
     worst, n = 0.0, 0
     for prompt, served in sample:
-        m = reference_gpt.served_margins(
-            params, prompt, served, n_head=s["H"], pad_to=s["T"],
-            eps=config["model"]["layer_norm_epsilon"], gelu="tanh")
+        m = fam.served_margins(config, params, prompt, served)
         if not np.isfinite(m).all():
             return float("inf"), n
         worst = max(worst, float(m.max()))
@@ -139,11 +148,12 @@ def run(ctx: dict, make_source) -> None:
     devs = ctx["devices"]
     log(f"[setup] jax {jax.__version__}, compile cache at "
         f"{platform.init_compile_cache()}")
-    s = model.sizes(config)
+    fam = common.family(config)
+    s = fam.sizes(config)
     seconds = float(args.seconds)
 
     # ---- set-up: weights, warm-up of this mix's shapes, ramp -------------
-    params, srv, t_weights = build_server(config, args.seed)
+    params, srv, t_weights = build_server(fam, config, args.seed)
     source = make_source(mix, args.seed, s, seconds, args.rehearse)
     t0 = time.perf_counter()
     buckets = warmup_buckets(source.prompt_lens())
@@ -214,15 +224,15 @@ def run(ctx: dict, make_source) -> None:
         "tpot_p50_ms": common.median(tpot) if tpot else None,
         "compiles_in_window": compiles,
     }
-    in_win = [row for row in drv.samples if t_win0 <= row[0] < t_win1]
-    qd = [q for _, q, _ in in_win]
+    in_win = [row for row in drv.samples if t_win0 <= row.t < t_win1]
+    qd = [row.queue_depth for row in in_win]
     quarter = max(1, len(qd) // 4)
     log(f"[window] {window:.2f}s; due {len(due)} requests "
         f"({len(due) / window:.2f}/s offered), completed {len(done)} "
         f"({len(done) / window:.2f}/s), failed {len(failed)}, statuses "
         f"other than ok {sorted(set(drv.bad.values()))}; generated "
         f"{(tok1 - tok0) / window:.1f} tokens/s; ticks {len(qd)}; slots "
-        f"occupied, mean {np.mean([o for _, _, o in in_win]) if in_win else None}")
+        f"occupied, mean {np.mean([row.slot_occupancy for row in in_win]) if in_win else None}")
     log(f"[window] samples: ttft n={len(ttft)} (requests due), tpot "
         f"n={len(tpot)} (requests retired); ttft p50/p95 {_pcts(ttft)} ms; "
         f"tpot p50/p95 {_pcts(tpot)} ms; generator lateness p50/p95/max "
@@ -246,21 +256,22 @@ def run(ctx: dict, make_source) -> None:
     pool = [r for r in done if r["rid"] not in drv.bad]
     picks = rng.permutation(len(pool))[:corr["sample_requests"]]
     sample = [(pool[i]["prompt"], srv.result(pool[i]["rid"])) for i in picks]
-    lengths_ok = all(len(out) == pool[i]["out_len"]
-                     for i, (_, out) in zip(picks, sample))
+    wrong_len = sum(len(out) != pool[i]["out_len"]
+                    for i, (_, out) in zip(picks, sample))
     srv.close()
     t0 = time.perf_counter()
-    worst, n_tok = check_served(params, config, sample)
-    correct = (bool(sample) and lengths_ok
-               and worst <= corr["logit_margin_tol"])
+    worst, n_tok = check_served(fam, params, config, sample)
     log(f"[correct] {len(sample)} served requests, {n_tok} tokens "
-        f"teacher-forced through reference_gpt in "
-        f"{time.perf_counter() - t0:.1f}s: worst margin below the "
+        f"teacher-forced through the {config['family']} family's reference "
+        f"in {time.perf_counter() - t0:.1f}s: worst margin below the "
         f"reference's best logit {worst:.4f} (tolerance "
-        f"{corr['logit_margin_tol']}); every output has its length: "
-        f"{lengths_ok}")
+        f"{corr['logit_margin_tol']}); outputs of another length than asked "
+        f"for: {wrong_len}")
+    compared = {"worst_logit_margin": (worst, corr["logit_margin_tol"]),
+                "outputs_of_wrong_length": (wrong_len, 0),
+                "no_request_to_sample": (0 if sample else 1, 0)}
 
-    common.emit(ctx, values, correct, len(due), len(failed), device, {
+    common.emit(ctx, values, compared, len(due), len(failed), device, {
         "joined": joined, "samples": drv.samples, "window": (t_win0, t_win1),
         "stats_window": (t_win0, t_stats1), "slice": (t_stats1, t_win1),
         "trace": reduced, "sizes": s})

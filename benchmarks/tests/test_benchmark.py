@@ -101,18 +101,39 @@ def _tree_digest(root):
     return out
 
 
-def test_fourth_cell_by_new_files_and_entries_only(tmp_path):
-    """A later PR adds a cell, a mix and a per-layer metric with new files
-    and new entries in BENCHMARK.json, and edits no file that is there.
-    (A new end-to-end metric is another matter: the generator kind has to
-    compute it, so it needs an edit to ``serve.py`` or ``train_steps.py``
-    by a benchmark PR.)"""
+def _copy(tmp_path) -> str:
+    """A copy of the benchmark beside the program, for a test to add to."""
     root = str(tmp_path / "copy")
     shutil.copytree(os.path.join(ROOT, "benchmarks"),
                     os.path.join(root, "benchmarks"),
                     ignore=shutil.ignore_patterns("_run", "__pycache__"))
     os.symlink(os.path.join(ROOT, "paddle_tpu"),
                os.path.join(root, "paddle_tpu"))
+    return root
+
+
+def _add(root, files: dict, man: dict) -> None:
+    """New files under benchmarks/ (a dict is written as JSON) and the
+    manifest beside it."""
+    for rel, body in files.items():
+        with open(os.path.join(root, "benchmarks", rel), "w") as f:
+            f.write(body if isinstance(body, str) else json.dumps(body))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(man, f)
+
+
+def _serve_cell(man: dict) -> dict:
+    return next(w for w in man["workloads"]
+                if common.cell(w["name"])["config"]["role"] == "serve")
+
+
+def test_fourth_cell_by_new_files_and_entries_only(tmp_path):
+    """A later PR adds a cell, a mix and a per-layer metric with new files
+    and new entries in BENCHMARK.json, and edits no file that is there.
+    (A new end-to-end metric is another matter: the generator kind has to
+    compute it, so it needs an edit to ``serve.py`` or ``train_steps.py``
+    by a benchmark PR.)"""
+    root = _copy(tmp_path)
     before = _tree_digest(root)
     chat = common.load_json("traffic", "chat-steady.json")
     new_files = {
@@ -128,12 +149,8 @@ def test_fourth_cell_by_new_files_and_entries_only(tmp_path):
         "layer_metrics/compiles_in_window.slow-test.json": {
             "reader": "value", "args": {"key": "compiles_in_window"}},
     }
-    for rel, body in new_files.items():
-        with open(os.path.join(root, "benchmarks", rel), "w") as f:
-            json.dump(body, f)
     man = json.loads(json.dumps(MANIFEST))
-    serve = next(w for w in man["workloads"]
-                 if common.cell(w["name"])["config"]["role"] == "serve")
+    serve = _serve_cell(man)
     name = "gpt1p3b-serve-chat-slow-test"
     man["workloads"].append(dict(serve, name=name, traffic="chat-slow-test"))
     e2e = [m for m in man["end_to_end"]
@@ -146,8 +163,7 @@ def test_fourth_cell_by_new_files_and_entries_only(tmp_path):
             "name": metric, "unit": unit, "better": "lower",
             "source": "device_trace", "layer": "model step",
             "moves": e2e[0]["name"], "workloads": [name]})
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(man, f)
+    _add(root, new_files, man)
     line, out = rehearse(root, name, 0)
     assert line["correct"] and line["failed"] == 0
     # round(4.0 requests/s x 3 s): the new mix's own rate, not chat's
@@ -164,6 +180,180 @@ def test_fourth_cell_by_new_files_and_entries_only(tmp_path):
     assert {k: after[k] for k in before} == before       # nothing edited
     assert set(after) - set(before) == {
         "benchmarks/" + rel for rel in new_files}
+
+
+TOY_FAMILY = '''"""A second family, for the test: its own key names in the configuration
+file and in its sizes, its own wrapper of the reference; gpt underneath."""
+from . import gpt
+
+
+def _as_gpt(config):
+    a = config["arch"]
+    return dict(config, assumed={"vocab_rows": a["rows"]}, model={
+        "n_embd": a["width"], "n_layer": a["depth"], "n_head": a["heads"],
+        "n_inner": a["ffn"], "n_positions": a["positions"],
+        "vocab_size": a["vocab"], "layer_norm_epsilon": a["eps"]})
+
+
+def sizes(config):
+    s = gpt.sizes(_as_gpt(config))
+    return {"width": s["D"], "T": s["T"], "V": s["V"],
+            "V_published": s["V_published"], "underneath": s}
+
+
+def weights(config, seed):
+    return gpt.weights(_as_gpt(config), seed)
+
+
+def server(config, cfg, params):
+    return gpt.server(config, cfg, params)
+
+
+def served_margins(config, params, prompt, served):
+    return gpt.served_margins(_as_gpt(config), params, prompt, served)
+
+
+def decode_step_cost(sizes, batch_rows, live_kv_tokens):
+    return gpt.decode_step_cost(sizes["underneath"], batch_rows,
+                                live_kv_tokens)
+'''
+
+# the timed path broken underneath: a token altered where the server hands
+# its answer over
+TOY_BROKEN = '''from . import toy
+from .toy import *  # noqa: F401,F403
+
+
+def server(config, cfg, params):
+    srv = toy.server(config, cfg, params)
+    result = srv.result
+
+    def altered(rid):
+        out = list(result(rid))
+        out[len(out) // 2] = (out[len(out) // 2] + 1) % config["arch"]["vocab"]
+        return out
+
+    srv.result = altered
+    return srv
+'''
+
+
+def _toy_config(family: str) -> dict:
+    chat = common.cell(_serve_cell(MANIFEST)["name"])["config"]
+    tiny = common.merged(chat, chat["rehearse"])
+    m = tiny["model"]
+    return {"family": family, "role": "serve", "dtype": tiny["dtype"],
+            "arch": {"width": m["n_embd"], "depth": m["n_layer"],
+                     "heads": m["n_head"], "ffn": m["n_inner"],
+                     "positions": m["n_positions"],
+                     "vocab": m["vocab_size"],
+                     "rows": tiny["assumed"]["vocab_rows"],
+                     "eps": m["layer_norm_epsilon"]},
+            "entry_point": tiny["entry_point"],
+            "correctness": tiny["correctness"]}
+
+
+def test_second_family_by_new_files_and_entries_only(tmp_path):
+    """A later ``model_config`` PR adds an architecture with new files and
+    new entries: a family module, its configuration file, a cell.  No file
+    that is there is edited, the rehearsal is correct in both modes, and
+    the same family with a token altered underneath is not."""
+    root = _copy(tmp_path)
+    before = _tree_digest(root)
+    new_files = {"families/toy.py": TOY_FAMILY,
+                 "families/toy_broken.py": TOY_BROKEN,
+                 "configs/toy-serve.json": _toy_config("toy"),
+                 "configs/toy-broken-serve.json": _toy_config("toy_broken")}
+    man = json.loads(json.dumps(MANIFEST))
+    serve = _serve_cell(man)
+    cells = {"toy-serve-chat": "toy-serve",
+             "toy-broken-serve-chat": "toy-broken-serve"}
+    for name, config in cells.items():
+        man["configs"].append({
+            "name": config, "source": "a test", "reduced": [], "why": "test",
+            "file": f"benchmarks/configs/{config}.json"})
+        man["workloads"].append(dict(serve, name=name, config=config))
+        for m in man["end_to_end"] + man["per_layer"]:
+            if serve["name"] in m.get("workloads", []):
+                m["workloads"].append(name)
+    _add(root, new_files, man)
+    chat = common.cell(serve["name"])
+    for trace_flag, want in ((0, chat["end_to_end"]), (1, chat["per_layer"])):
+        line, out = rehearse(root, "toy-serve-chat", trace_flag)
+        assert line["correct"] and line["failed"] == 0, out[-1500:]
+        got = check_line(line, [m["name"] for m in want])
+        if not trace_flag:
+            assert got == {m["name"] for m in want}
+        assert "the toy family's reference" in out
+        assert list(line)[-1] == "compared"
+        assert all(c["value"] <= c["limit"]
+                   for c in line["compared"].values())
+    line, out = rehearse(root, "toy-broken-serve-chat", 0)
+    assert line["correct"] is False, out[-1500:]
+    worst = line["compared"]["worst_logit_margin"]
+    assert worst["value"] > worst["limit"]
+    after = _tree_digest(root)
+    assert {k: after[k] for k in before} == before       # nothing edited
+    assert set(after) - set(before) == {
+        "benchmarks/" + rel for rel in new_files}
+
+
+@pytest.mark.parametrize("family, message", [
+    (None, 'states no "family"'),
+    ("nonesuch", "no module for family 'nonesuch'"),
+])
+def test_a_configuration_needs_a_family_that_has_a_module(tmp_path, family,
+                                                         message):
+    """No default architecture: the run ends with the benchmark's own
+    message and no result line."""
+    root = _copy(tmp_path)
+    config = _toy_config(family)
+    if family is None:
+        del config["family"]
+    man = json.loads(json.dumps(MANIFEST))
+    man["configs"].append({
+        "name": "anon", "source": "a test", "reduced": [], "why": "test",
+        "file": "benchmarks/configs/anon.json"})
+    man["workloads"].append(dict(_serve_cell(man), name="anon-chat",
+                                 config="anon"))
+    _add(root, {"configs/anon.json": config}, man)
+    p = subprocess.run(
+        [sys.executable, "benchmarks/run.py", "--workload", "anon-chat",
+         "--seed", "1", "--seconds", "1", "--trace", "0", "--rehearse"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0
+    assert "benchmark: " in p.stderr and message in p.stderr
+    assert not any(ln.startswith("{") for ln in p.stdout.splitlines())
+
+
+# what benchmarks/model.py gave at the parent of PR 28 (commit 427c384),
+# before it became families/gpt.py: (matmul_params, train_flops_per_token
+# at 2048, decode_step_cost at 20 rows holding 9500.5 tokens)
+GOLDEN = {
+    "cerebras-gpt-1.3b-serve": (1310982144, 9073852416.0, {
+        "bytes": 4498227200.0, "flops": 54307160064.0,
+        "weight_bytes": 2630352896, "kv_bytes": 1867874304.0}),
+    "cerebras-gpt-590m-train": (586874880, 4200726528.0, {
+        "bytes": 2230720512.0, "flops": 24525674496.0,
+        "weight_bytes": 1180041216, "kv_bytes": 1050679296.0}),
+}
+
+
+@pytest.mark.parametrize("entry", MANIFEST["configs"],
+                         ids=lambda c: c["name"])
+def test_gpt_family_counts_what_model_py_counted(entry):
+    """100% may not move with the file: the counts to the digit."""
+    with open(os.path.join(ROOT, entry["file"]), encoding="utf-8") as f:
+        config = json.load(f)
+    fam = common.family(config)
+    assert fam.__name__ == "benchmarks.families.gpt"
+    s = fam.sizes(config)
+    assert {"T", "V", "V_published"} <= set(s)
+    params, flops, step = GOLDEN[entry["name"]]
+    assert fam.matmul_params(s) == params
+    assert fam.train_flops_per_token(s, 2048) == flops
+    assert fam.decode_step_cost(s, 20, 9500.5) == step
 
 
 def test_schedule_is_the_mix_own_and_prompts_the_seed():

@@ -14,9 +14,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmarks import common  # noqa: E402
-from benchmarks.readers import (ring, scope_time, scope_trace,  # noqa: E402
-                                span_median, tick_host, token_gap)
+from benchmarks import common, trace  # noqa: E402
+from benchmarks.families import gpt  # noqa: E402
+from benchmarks.readers import (decode_mfu, module_time, ring,  # noqa: E402
+                                scope_time, scope_trace, span_median,
+                                tick_host, token_gap)
+from benchmarks.serve import Sample  # noqa: E402
 from benchmarks.tests.test_benchmark import check_line, rehearse  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
@@ -213,6 +216,55 @@ def test_recorded_decode_step_is_covered_by_its_three_parts(recorded,
         assert got[name] == pytest.approx(want, rel=1e-6), name
 
 
+def _module_reader_s_view(ex: dict) -> dict:
+    """A ``scope_trace`` extract as ``trace.reduce`` reads one."""
+    lo, dur = ex["slice"][0]
+    return trace.reduce({"host": [[trace.SLICE, lo, dur]], "devices": [
+        {"name": d["name"], "lines": {
+            trace.MODULE_LINE: d["modules"],
+            trace.OP_LINE: [[f"{n} {code}", a, s]
+                            for n, code, a, s in d["ops"]]}}
+        for d in ex["devices"]]})
+
+
+def test_recorded_decode_step_by_scope_is_the_module_s_time(recorded,
+                                                            monkeypatch):
+    """``decode_step_dev_ms`` finds the step by its ``serving.<kind>``
+    scope: within 0.1% of the module's run time on the same runs (how it
+    was found until PR 28), and the same whatever the kernel is called,
+    where the module-and-kernel reader finds nothing."""
+    by_kernel = {"module": "^jit__lambda$",
+                 "with_kernel": "^paged_decode_attention"}
+    got = {}
+    for kernel in ("paged_decode_attention", "walked_blocks_attn"):
+        # as a program with another kernel name would have written it: in
+        # the ops' names and in the op_name paths
+        chat = json.loads(json.dumps(recorded["chat"]).replace(
+            "paged_decode_attention", kernel))
+        assert kernel in json.dumps(chat["scopes"])
+        red = scope_trace.reduce(chat["extract"], chat["scopes"])
+        monkeypatch.setattr(scope_trace, "load", lambda run: red)
+        got[kernel] = (
+            scope_time.read({}, args_of("decode_step_dev_ms")),
+            sum(scope_time.read({}, args_of(m)) for m in (
+                "decode_attn_dev_ms", "decode_kv_relayout_dev_ms",
+                "decode_dense_dev_ms")),
+            module_time.read(
+                {"trace": _module_reader_s_view(chat["extract"])}, by_kernel))
+    by_scope, parts, by_module = got["paged_decode_attention"]
+    assert by_scope == pytest.approx(parts, rel=1e-9)
+    assert by_scope == pytest.approx(by_module, rel=1e-3)
+    assert by_scope <= by_module            # self times leave the gaps out
+    assert got["walked_blocks_attn"] == (by_scope, parts, None)
+    # no accepted metric names the module or the kernel any more
+    for m in common.manifest()["per_layer"]:
+        spec = json.dumps(common.load_json("layer_metrics",
+                                            m["name"] + ".json"))
+        if m["moves"] == "tpot_p50_ms":
+            assert "paged_decode_attention" not in spec
+            assert "jit__lambda" not in spec
+
+
 def test_recorded_train_step_names_its_flash_kernels(recorded, monkeypatch):
     train = recorded["train"]
     red = scope_trace.reduce(train["extract"], train["scopes"])
@@ -286,6 +338,89 @@ def test_an_older_program_reads_nothing(monkeypatch):
     assert token_gap.read(run, {"percentile": 99}) is None
     assert tick_host.read(run, {}) is None
     assert span_median.read(run, {"span": "serving.prefill"}) is None
+
+
+# -- the whole step's share of the peak, on the driver's own stamps ----------
+
+MFU_SIZES = {"D": 2048, "L": 24, "H": 16, "F": 8192, "T": 2048, "V": 50304,
+             "V_published": 50257}
+PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def mfu_run(samples, joined=None, window=(10.0, 20.0)) -> dict:
+    """Two requests hold a slot from t = 1 on, 100 and 300 prompt tokens,
+    a token every 0.25 s."""
+    if joined is None:
+        joined = [{"rid": i, "t_due": 0.0, "t_first": 1.0, "t_retire": 101.0,
+                   "tokens": 401, "prompt_len": p, "out_len": 401}
+                  for i, p in enumerate((100, 300))]
+    return {"peaks": PEAKS, "joined": joined, "samples": samples,
+            "stats_window": window, "sizes": MFU_SIZES, "family": gpt}
+
+
+def ticks(stamps, held=2, first_turn=1):
+    return [Sample(t, 0, held, first_turn + i, held)
+            for i, t in enumerate(stamps)]
+
+
+def test_decode_step_mfu_is_the_least_time_over_the_tick_period():
+    stamps = [10.0 + 0.25 * i for i in range(21)]        # 20 periods
+    run = mfu_run(ticks(stamps))
+    # each request has its prompt + 1 + (t - 1) / 0.25 tokens at t; the
+    # ticks that count are those after the window's first: 10.25 .. 15.0
+    counted = stamps[1:]
+    kv = sum((100 + 300) + 2 * (1 + (t - 1.0) / 0.25) for t in counted) \
+        / len(counted)
+    weights = 2 * (1310982144 + 2048 * 2048)
+    t_bytes = (weights + 2.0 * 24 * 2048 * 2 * kv) / 819e9
+    t_flops = (2.0 * 1310982144 * 2 + 4.0 * 24 * 2048 * kv) / 197e12
+    assert t_bytes > t_flops                              # memory-bound
+    args = args_of("decode_step_mfu")
+    assert decode_mfu.read(run, args) == pytest.approx(
+        100.0 * t_bytes / 0.25, rel=1e-9)
+    # the period is a median: one stalled tick moves nothing
+    slow = ticks(stamps[:10] + [t + 0.4 for t in stamps[10:]])
+    assert decode_mfu.read(mfu_run(slow), args) == pytest.approx(
+        100.0 * t_bytes / 0.25, rel=2e-2)
+
+
+def test_decode_step_mfu_counts_only_ticks_that_generated_tokens():
+    args = args_of("decode_step_mfu")
+    stamps = [10.0 + 0.25 * i for i in range(9)]
+    base = decode_mfu.read(mfu_run(ticks(stamps)), args)
+    # a sleep between two ticks (the loop turned without a tick): the gap
+    # over it is no step's time
+    gap = ticks(stamps[:4]) + ticks([t + 5.0 for t in stamps[4:]],
+                                    first_turn=30)
+    assert [round(p, 9) for _, p in decode_mfu.tick_periods(
+        gap, 10.0, 20.0)] == [0.25] * 7
+    # ticks in which no request held a slot (admission waiting) are left out
+    idle = ticks(stamps, held=0)
+    assert decode_mfu.tick_periods(idle, 10.0, 20.0) == []
+    assert decode_mfu.read(mfu_run(idle), args) is None
+    # ticks outside the statistics window, no peaks (a rehearsal), no
+    # requests joined: nothing to read, so nothing in the line
+    assert decode_mfu.read(mfu_run(ticks(stamps), window=(30.0, 40.0)),
+                           args) is None
+    assert decode_mfu.read(dict(mfu_run(ticks(stamps)), peaks=None),
+                           args) is None
+    assert decode_mfu.read(mfu_run(ticks(stamps), joined=[]), args) is None
+    assert 0.0 < base < 100.0
+
+
+def test_decode_step_mfu_cannot_pass_100_for_a_tick_no_shorter_than_the_least(
+        ):
+    args = args_of("decode_step_mfu")
+    cost = gpt.decode_step_cost(MFU_SIZES, 2, 400 + 2 * 40)
+    least = cost["bytes"] / PEAKS["hbm_bytes_per_s"]
+    for stretch in (1.0, 1.5, 50.0):
+        # every tick takes the least time its own live rows allow, or more
+        stamps = [10.0]
+        for _ in range(12):
+            stamps.append(stamps[-1] + stretch * least * 1.01)
+        got = decode_mfu.read(mfu_run(ticks(stamps)), args)
+        assert 0.0 < got <= 100.0
+        assert got == pytest.approx(100.0 / stretch, rel=0.03)
 
 
 # -- a rehearsal with the new entries present --------------------------------

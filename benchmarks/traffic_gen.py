@@ -1,5 +1,5 @@
 """The one general traffic generator: it reads a mix's parameter file and
-makes requests and arrival times from ``--seed``.
+makes requests and arrival times, or training batches, from ``--seed``.
 
 Lengths are the distribution's own quantiles and gaps the exponential's
 (stratified, no sampling noise).  Which length meets which, and which
@@ -65,3 +65,14 @@ Request = collections.namedtuple("Request", "prompt out_len t_due")
 def make_prompt(rng, n: int, vocab: int) -> np.ndarray:
     """Fresh random tokens of the published vocabulary: no shared prefix."""
     return rng.integers(0, vocab, (n,)).astype(np.int32)
+
+
+def token_stream(rng, B: int, T: int, vocab: int) -> np.ndarray:
+    """[B, T + 1] tokens of the deterministic stream next = (3 tok + 1)
+    mod 13, spread over the vocabulary (chip_smoke.token_stream)."""
+    t = rng.integers(0, 13, (B, 1))
+    rows = [t]
+    for _ in range(T):
+        t = (t * 3 + 1) % 13
+        rows.append(t)
+    return (np.concatenate(rows, 1) * (vocab // 13)).astype(np.int32)
