@@ -97,7 +97,6 @@ import re
 import threading
 import time
 import warnings
-import weakref
 from collections import deque
 
 from . import flags as _flags
@@ -959,7 +958,12 @@ def instrument_compile(name: str, key, flags_key, fn):
 
     wrapper._telemetry_inner = fn
     wrapper._telemetry_name = name
-    _instrumented.add(wrapper)
+    with _compile_lock:
+        # newest last; the oldest goes once the ring is full
+        _instrumented.pop((name, repr(key)), None)
+        _instrumented[(name, repr(key))] = wrapper
+        while len(_instrumented) > _INSTRUMENTED_KEPT:
+            _instrumented.pop(next(iter(_instrumented)))
     # the AOT surface of the jitted function, so an instrumented step can
     # still be lowered and compiled ahead of time (for a described chip)
     for attr in ("lower", "trace", "eval_shape"):
@@ -978,7 +982,14 @@ def instrument_compile(name: str, key, flags_key, fn):
 # trace reader asks the program, after the trace, for {HLO op name:
 # op_name path} of the executables that ran, and joins on the op's name.
 
-_instrumented: "weakref.WeakSet" = weakref.WeakSet()   # live wrappers
+# The wrappers executable_scopes() can still lower again, by (instrument
+# name, cfg/flags key).  Held strongly, a bounded ring of the newest: a
+# trace is read AFTER the serving that wrote it, often after the server
+# closed and the Engine dropped its executables, and a weak set emptied
+# whenever the cyclic collector happened to run in between (a wrapper
+# refers to itself), so the per-part metrics of a run came or went with it.
+_instrumented: dict = {}
+_INSTRUMENTED_KEPT = 64
 _HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$")
 _HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%([\w.\-]+) = ")
 _HLO_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
@@ -1046,7 +1057,7 @@ def executable_scopes(since: float | None = None) -> list:
     that is on).  Costs nothing until asked; an executable that cannot
     be lowered again is left out."""
     out = []
-    for w in list(_instrumented):
+    for w in list(_instrumented.values()):
         specs = getattr(w, "_telemetry_specs", None)
         if specs is None or (since is not None and
                              getattr(w, "_telemetry_last_call", 0.0) < since):

@@ -151,7 +151,7 @@ def _paged_adapter_step(params, cache, g, token, pos, cfg: gpt.GPTConfig):
 
         x, rows = jax.lax.scan(body, x, (merged, pool))
         x = gpt._norm(x, params, "ln_f", cfg)
-        logits = woq.logits(x, params, dt)[:, 0]
+        logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
         return logits[0].astype(jnp.float32), rows
 
     logits, rows = jax.vmap(one, in_axes=(0, 0, 0, 0),
@@ -228,7 +228,8 @@ def _paged_adapter_verify(params, cache, g, tokens, pos,
     dt = cfg.dtype
 
     def one(tok_k, p0, trow, gad):
-        x = woq.embed(params, tok_k[None], dt)            # [1, K, D]
+        x = woq.embed(params, tok_k[None], dt,
+                      cfg.embedding_multiplier)              # [1, K, D]
         if cfg.pos_embed == "learned":
             x = x + jax.lax.dynamic_slice(
                 params["wpe"], (p0, 0),
@@ -243,7 +244,8 @@ def _paged_adapter_verify(params, cache, g, tokens, pos,
 
         x, rows = jax.lax.scan(body, x, (merged, pool))
         x = gpt._norm(x, params, "ln_f", cfg)
-        logits = woq.logits(x, params, dt)[0]             # [K, V]
+        logits = woq.logits(x, params, dt,
+                            cfg.lm_head_multiplier)[0]      # [K, V]
         return logits.astype(jnp.float32), rows
 
     logits, rows = jax.vmap(one, in_axes=(0, 0, 0, 0),

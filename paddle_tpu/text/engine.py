@@ -187,6 +187,14 @@ def cfg_key(cfg):
             cfg.max_seq_len, cfg.ffn_ratio, str(cfg.dtype), cfg.use_flash,
             cfg.pos_embed, cfg.norm, cfg.activation,
             moe_key,
+            # explicit widths, the untied head, bias-free projections, the
+            # rope base, the forward multipliers and the parallel mixer
+            (cfg.head_dim, cfg.intermediate_size, cfg.tie_embeddings,
+             cfg.bias, cfg.rope_theta, cfg.embedding_multiplier,
+             cfg.lm_head_multiplier, cfg.attention_in_multiplier,
+             cfg.attention_out_multiplier, cfg.key_multiplier,
+             tuple(cfg.mlp_multipliers),
+             cfg.ssm.key() if cfg.ssm is not None else None),
             # trace-time env routing flags (flags.decode_jit_key): an
             # executable BAKES these in — W4 kernel gate (woq.mm), fused
             # LN (gpt._ln), cache donation (aliased vs copied buffers),
@@ -1511,6 +1519,11 @@ class Engine:
                 # bs floor itself.  Warm exactly that reachable set —
                 # log-many executables, no mid-serving compile
                 def _ladder(top):
+                    if srv._recurrent:
+                        # no prefix is ever adopted beside a recurrent
+                        # state: an admission prefills its whole prompt,
+                        # at that prompt's own bucket and no other
+                        return {min(max(top, srv._pool.bs), window)}
                     ws, p = {min(srv._pool.bs, window)}, 1
                     while p < top:
                         p *= 2

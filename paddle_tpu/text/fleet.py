@@ -441,6 +441,11 @@ class PrefillWorker:
         if lay not in ("contiguous", "paged"):
             raise ValueError(
                 f"layout {lay!r}: expected 'contiguous' or 'paged'")
+        if cfg.ssm is not None:
+            raise NotImplementedError(
+                "PrefillWorker: a config with an ssm mixer cannot hand a "
+                "prefill off yet — the handoff ships KV rows, and the "
+                "recurrent state the prompt leaves has no wire form")
         self.cfg = cfg
         self.max_len = int(max_len)
         self.name = name

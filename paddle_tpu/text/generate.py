@@ -81,6 +81,11 @@ def init_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
     if layout not in ("contiguous", None, ""):
         raise ValueError(
             f"layout {layout!r}: expected 'contiguous' or 'paged'")
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            "a config with an ssm mixer decodes through the paged cache "
+            "only (layout='paged'): the contiguous slab has no per-slot "
+            "recurrent state leaves")
     L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
     dt = _kv_store_dtype(cfg)
     shape = (L, batch, _round_cache_len(max_len), H, hd)
@@ -165,7 +170,8 @@ def _embed_step(params, token, pos, cfg: gpt.GPTConfig):
     """Embed one decode step's tokens [B] at position ``pos`` ->
     [B, 1, D] — the single embed+wpe shared by the contiguous decode
     step and the paged (kv_pool) routes."""
-    x = woq.embed(params, token, cfg.dtype)[:, None]
+    x = woq.embed(params, token, cfg.dtype,
+                  cfg.embedding_multiplier)[:, None]
     if cfg.pos_embed == "learned":
         x = x + jax.lax.dynamic_slice(
             params["wpe"], (pos, 0),
@@ -173,48 +179,62 @@ def _embed_step(params, token, pos, cfg: gpt.GPTConfig):
     return x
 
 
-def _block_pre_attn(x, p, pos, cfg: gpt.GPTConfig):
+def _block_pre_attn(x, p, pos, cfg: gpt.GPTConfig, h=None):
     """Pre-attention half of one decode block on a single position
     [B, 1, D]: ln1 -> qkv projection (the Hkv heads kept, never
     repeated) -> rope at ``pos`` -> storage-dtype rows.  Returns
     (q3, rows); every cached-decode route (contiguous AND paged kernel)
-    shares this, so the per-layer math can never drift between them."""
+    shares this, so the per-layer math can never drift between them.
+    ``h``: the block's normed input where the caller already has it (a
+    parallel mixer reads the same one)."""
     B = x.shape[0]
     hd = cfg.head_dim
-    h = gpt._norm(x, p, "ln1", cfg)
+    if h is None:
+        h = gpt._norm(x, p, "ln1", cfg)
     q3, k3, v3 = gpt._project_qkv(h, p, cfg, repeat_kv=False)
     if cfg.pos_embed == "rope":
         # rotate q and the NEW key row at this position; the cache holds
         # already-rotated keys (rope's relative-offset property makes
         # them valid forever)
         pos_arr = jnp.asarray(pos, jnp.int32).reshape(1)
-        q3 = gpt.apply_rope(q3, pos_arr)
-        k3 = gpt.apply_rope(k3, pos_arr)
+        q3 = gpt.apply_rope(q3, pos_arr, cfg.rope_theta)
+        k3 = gpt.apply_rope(k3, pos_arr, cfg.rope_theta)
     k_new = k3.reshape(B, -1, hd)   # Hkv rows under GQA, H otherwise
     v_new = v3.reshape(B, -1, hd)
     return q3, _store_rows(k_new, v_new, cfg)
 
 
 def _block_post_attn(x, attn, p, cfg: gpt.GPTConfig, valid=None,
-                     capacity=gpt._LEGACY, stats=None):
+                     capacity=gpt._LEGACY, stats=None, mix=None):
     """Post-attention half: output projection + residual + FFN tail
     (the other shared side of :func:`_block_pre_attn`).  The MoE serving
     step calls this ONCE for the whole batch (``valid``/``capacity``/
     ``stats`` forwarded to :func:`gpt._ffn_tail`) so the slot tokens
     route jointly under the configured capacity factor — the same layer
-    math as the dense route, a different token grouping."""
-    dt = cfg.dtype
-    a = woq.mm(attn, p, "proj_w", dt) + p["proj_b"].astype(dt)
+    math as the dense route, a different token grouping.  ``mix``: the
+    parallel ssm mixer's output on the same normed input, added to the
+    residual beside the attention's (h + attn + ssm)."""
+    a = gpt._attn_out(attn, p, cfg)
+    if mix is not None:
+        a = a + mix
     return gpt._ffn_tail(x + a, p, cfg, valid=valid, capacity=capacity,
                          stats=stats)
 
 
-def _cached_block(x, p, csl, pos, cfg: gpt.GPTConfig):
+def _cached_block(x, p, csl, pos, cfg: gpt.GPTConfig, state=None):
     """One block on a SINGLE position [B, 1, D] against one layer's cache
     slice ``csl`` (leaves k/v [B, T, Hkv, hd], plus scales for int8).
     Returns (x, rows): storage-dtype row leaves for the caller to write
-    at pos."""
-    q3, rows = _block_pre_attn(x, p, pos, cfg)
+    at pos.  ``state`` (a config with a parallel ssm mixer): this layer's
+    recurrent state, advanced by the position; the call then returns
+    (x, rows, new_state)."""
+    h = mix = None
+    if state is not None:
+        from . import ssm as _ssm
+
+        h = gpt._norm(x, p, "ln1", cfg)
+        mix, state = _ssm.mixer_step(h, p, cfg, state)
+    q3, rows = _block_pre_attn(x, p, pos, cfg, h=h)
     # attend over cache rows [B, max_len, Hkv, hd] with the fresh row at
     # pos — spliced in STORAGE form, so what this step attends is exactly
     # what later steps will read back (int8 included)
@@ -222,8 +242,9 @@ def _cached_block(x, p, csl, pos, cfg: gpt.GPTConfig):
                 csl[name], val[:, None],
                 (0, pos) + (0,) * (csl[name].ndim - 2))
             for name, val in rows.items()}
-    attn = _attend_cache(q3, full, pos, cfg)           # [B, 1, D]
-    return _block_post_attn(x, attn, p, cfg), rows
+    attn = _attend_cache(q3, full, pos, cfg)           # [B, 1, H * hd]
+    out = _block_post_attn(x, attn, p, cfg, mix=mix)
+    return (out, rows) if state is None else (out, rows, state)
 
 
 def _write_rows(cache: dict, rows: dict, pos) -> dict:
@@ -262,7 +283,7 @@ def decode_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     x, rows = jax.lax.scan(body, x, (params["blocks"], cache))
     new_cache = _write_rows(cache, rows, pos)
     x = gpt._norm(x, params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)[:, 0]
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
     return logits.astype(jnp.float32), new_cache
 
 
@@ -645,16 +666,16 @@ def _prefill_block(x, p, cfg: gpt.GPTConfig, valid=None):
     (x, rows) — storage-dtype row leaves for the caller to merge.
     ``valid`` [B, P]: pad mask forwarded to the MoE router (pads claim no
     expert capacity); dense models ignore it."""
-    B, P, D = x.shape
-    H, hd = cfg.num_heads, cfg.head_dim
+    B, P, _ = x.shape
+    H = cfg.num_heads
     dt = cfg.dtype
     h = gpt._norm(x, p, "ln1", cfg)
     # project ONCE (unrepeated); derive GQA attention copies by repeat
     q, k_rows, v_rows = gpt._project_qkv(h, p, cfg, repeat_kv=False)
     if cfg.pos_embed == "rope":
         pos_arr = jnp.arange(P)
-        q = gpt.apply_rope(q, pos_arr)
-        k_rows = gpt.apply_rope(k_rows, pos_arr)
+        q = gpt.apply_rope(q, pos_arr, cfg.rope_theta)
+        k_rows = gpt.apply_rope(k_rows, pos_arr, cfg.rope_theta)
     rows = _store_rows(k_rows, v_rows, cfg)
     # attend the STORAGE view of the fresh rows (the sibling sites'
     # attend-what-you-store invariant): under int8 the admission path
@@ -673,9 +694,10 @@ def _prefill_block(x, p, cfg: gpt.GPTConfig, valid=None):
     v = jnp.repeat(v_att, rep, axis=2) if rep > 1 else v_att
     from ..ops.attention import attention_array
 
-    attn = attention_array(q, k, v, is_causal=True).reshape(B, P, D)
-    a = woq.mm(attn, p, "proj_w", dt) + p["proj_b"].astype(dt)
-    return gpt._ffn_tail(x + a, p, cfg, valid=valid), rows
+    attn = attention_array(q, k, v, is_causal=True).reshape(
+        B, P, cfg.q_size)
+    return gpt._ffn_tail(x + gpt._attn_out(attn, p, cfg), p, cfg,
+                         valid=valid), rows
 
 
 def prefill_slot(params, cache, tokens, length, slot, cfg: gpt.GPTConfig):
@@ -695,7 +717,7 @@ def prefill_slot(params, cache, tokens, length, slot, cfg: gpt.GPTConfig):
     (tests/test_serving.py MoE prefill parity)."""
     dt = cfg.dtype
     P = tokens.shape[1]
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + params["wpe"][:P].astype(dt)[None]
     valid_mask = (jnp.arange(P) < length)[None, :]       # [1, P]
@@ -711,30 +733,32 @@ def prefill_slot(params, cache, tokens, length, slot, cfg: gpt.GPTConfig):
     last = jax.lax.dynamic_slice(x, (0, length - 1, 0),
                                  (1, 1, cfg.hidden_size))
     last = gpt._norm(last, params, "ln_f", cfg)
-    logits = woq.logits(last, params, dt)[0, 0]
+    logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0, 0]
     return logits.astype(jnp.float32), cache
 
 
-def _chunk_pre_attn(x, p, pos0, cfg: gpt.GPTConfig):
+def _chunk_pre_attn(x, p, pos0, cfg: gpt.GPTConfig, h=None):
     """Pre-attention half of one block on a K-token chunk [B, K, D] at
     positions [pos0, pos0+K): ln1 -> qkv projection (Hkv heads kept) ->
     rope over the chunk's positions -> storage-dtype rows.  Returns
     (q [B, K, H, hd], rows); :func:`_chunk_attend_block` and the batched
     kernel verify routes (here and kv_pool) all project through this
     one copy, so the chunk math can never drift between the einsum and
-    flash routes."""
+    flash routes.  ``h``: the block's normed input where the caller
+    already has it (a parallel mixer reads the same one)."""
     K = x.shape[1]
-    q, k_new, v_new = gpt._project_qkv(
-        gpt._norm(x, p, "ln1", cfg), p, cfg, repeat_kv=False)
+    if h is None:
+        h = gpt._norm(x, p, "ln1", cfg)
+    q, k_new, v_new = gpt._project_qkv(h, p, cfg, repeat_kv=False)
     if cfg.pos_embed == "rope":
         chunk_pos = pos0 + jnp.arange(K)
-        q = gpt.apply_rope(q, chunk_pos)
-        k_new = gpt.apply_rope(k_new, chunk_pos)
+        q = gpt.apply_rope(q, chunk_pos, cfg.rope_theta)
+        k_new = gpt.apply_rope(k_new, chunk_pos, cfg.rope_theta)
     return q, _store_rows(k_new, v_new, cfg)
 
 
 def _chunk_attend_block(x, p, csl, pos0, cfg: gpt.GPTConfig,
-                        valid=None):
+                        valid=None, state=None, length=None):
     """One transformer block over a K-token chunk at positions
     [pos0, pos0+K) against a per-layer cache slice ``csl`` (leaves k/v
     [B, T, Hkv, hd] + scales) whose rows [0, pos0) are already filled:
@@ -744,15 +768,25 @@ def _chunk_attend_block(x, p, csl, pos0, cfg: gpt.GPTConfig,
     start indices, so an overrunning window would silently write the
     chunk's rows at a shifted offset while the mask/positions still use
     pos0 (callers guarantee the bound; the serving walk overlaps its
-    last window instead of overrunning).  Returns (x_out, rows)."""
-    dt = cfg.dtype
-    q, rows = _chunk_pre_attn(x, p, pos0, cfg)
+    last window instead of overrunning).  Returns (x_out, rows).
+
+    ``state`` (a config with a parallel ssm mixer): this layer's recurrent
+    state of the chunk's sequence; the mixer continues it over the chunk's
+    first ``length`` positions (the rest is padding) and the call returns
+    (x_out, rows, new_state)."""
+    h = gpt._norm(x, p, "ln1", cfg)
+    q, rows = _chunk_pre_attn(x, p, pos0, cfg, h=h)
     full = {name: jax.lax.dynamic_update_slice(
                 csl[name], val, (0, pos0) + (0,) * (csl[name].ndim - 2))
             for name, val in rows.items()}
-    attn = _attend_cache(q, full, pos0, cfg)           # [B, K, D]
-    a = woq.mm(attn, p, "proj_w", dt) + p["proj_b"].astype(dt)
-    return gpt._ffn_tail(x + a, p, cfg, valid=valid), rows
+    attn = _attend_cache(q, full, pos0, cfg)           # [B, K, H * hd]
+    a = gpt._attn_out(attn, p, cfg)
+    if state is None:
+        return gpt._ffn_tail(x + a, p, cfg, valid=valid), rows
+    from . import ssm as _ssm
+
+    mix, state = _ssm.mixer_chunk(h, p, cfg, state, length=length)
+    return gpt._ffn_tail(x + a + mix, p, cfg, valid=valid), rows, state
 
 
 def _merge_slot_rows(cache, rows, slot, pos0, valid):
@@ -793,7 +827,7 @@ def prefill_slot_chunk(params, cache, tokens, pos0, length, slot,
     returns (logits at the chunk's last valid position [V], cache)."""
     dt = cfg.dtype
     P = tokens.shape[1]
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + jax.lax.dynamic_slice(
             params["wpe"], (pos0, 0), (P, cfg.hidden_size)).astype(dt)[None]
@@ -817,7 +851,7 @@ def prefill_slot_chunk(params, cache, tokens, pos0, length, slot,
     last = jax.lax.dynamic_slice(x, (0, length - 1, 0),
                                  (1, 1, cfg.hidden_size))
     last = gpt._norm(last, params, "ln_f", cfg)
-    logits = woq.logits(last, params, dt)[0, 0]
+    logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0, 0]
     return logits.astype(jnp.float32), cache
 
 
@@ -842,7 +876,7 @@ def verify_chunk(params, cache, tokens, pos0, cfg: gpt.GPTConfig):
     speculative_generate rejects MoE targets for exactly this reason."""
     dt = cfg.dtype
     B, K = tokens.shape
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + jax.lax.dynamic_slice(
             params["wpe"], (pos0, 0), (K, cfg.hidden_size)).astype(dt)[None]
@@ -855,7 +889,7 @@ def verify_chunk(params, cache, tokens, pos0, cfg: gpt.GPTConfig):
     x, rows = jax.lax.scan(body, x, (params["blocks"], cache))
     new_cache = _write_rows(cache, rows, pos0)
     x = gpt._norm(x, params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
     return logits.astype(jnp.float32), new_cache
 
 
@@ -902,7 +936,8 @@ def verify_chunk_batched(params, cache, tokens, pos, cfg: gpt.GPTConfig):
     H, hd = cfg.num_heads, cfg.head_dim
 
     def embed_one(tok_k, p0):
-        x = woq.embed(params, tok_k[None], dt)            # [1, K, D]
+        x = woq.embed(params, tok_k[None], dt,
+                      cfg.embedding_multiplier)              # [1, K, D]
         if cfg.pos_embed == "learned":
             x = x + jax.lax.dynamic_slice(
                 params["wpe"], (p0, 0),
@@ -944,7 +979,8 @@ def verify_chunk_batched(params, cache, tokens, pos, cfg: gpt.GPTConfig):
 
     def fin(xb):
         xb = gpt._norm(xb, params, "ln_f", cfg)
-        return woq.logits(xb, params, dt)[0]              # [K, V]
+        return woq.logits(xb, params, dt,
+                          cfg.lm_head_multiplier)[0]        # [K, V]
 
     logits = jax.vmap(fin)(x)
     return logits.astype(jnp.float32), new_cache
@@ -1027,8 +1063,8 @@ def _tree_pre_attn(x, p, pos0, depth, cfg: gpt.GPTConfig):
         gpt._norm(x, p, "ln1", cfg), p, cfg, repeat_kv=False)
     if cfg.pos_embed == "rope":
         node_pos = pos0 + depth
-        q = gpt.apply_rope(q, node_pos)
-        k_new = gpt.apply_rope(k_new, node_pos)
+        q = gpt.apply_rope(q, node_pos, cfg.rope_theta)
+        k_new = gpt.apply_rope(k_new, node_pos, cfg.rope_theta)
     return q, _store_rows(k_new, v_new, cfg)
 
 
@@ -1040,13 +1076,12 @@ def _tree_attend_block(x, p, csl, pos0, depth, tmask, cfg: gpt.GPTConfig):
     and paged tree verify routes — one copy of the tree math, the
     :func:`_chunk_attend_block` rule, same PRECONDITION pos0 + N <= T
     (dynamic_update_slice clamps; callers guarantee the bound)."""
-    dt = cfg.dtype
     q, rows = _tree_pre_attn(x, p, pos0, depth, cfg)
     full = {name: jax.lax.dynamic_update_slice(
                 csl[name], val, (0, pos0) + (0,) * (csl[name].ndim - 2))
             for name, val in rows.items()}
     attn = _attend_cache_tree(q, full, tmask, cfg)     # [B, N, D]
-    a = woq.mm(attn, p, "proj_w", dt) + p["proj_b"].astype(dt)
+    a = gpt._attn_out(attn, p, cfg)
     return gpt._ffn_tail(x + a, p, cfg), rows
 
 
@@ -1071,7 +1106,7 @@ def tree_verify_chunk(params, cache, tokens, amask, depth, pos0,
     dt = cfg.dtype
     B, N = tokens.shape
     T = cache["k"].shape[2]
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + jnp.take(params["wpe"], pos0 + depth[0],
                          axis=0).astype(dt)[None]
@@ -1088,7 +1123,7 @@ def tree_verify_chunk(params, cache, tokens, amask, depth, pos0,
     x, rows = jax.lax.scan(body, x, (params["blocks"], cache))
     new_cache = _write_rows(cache, rows, pos0)
     x = gpt._norm(x, params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
     return logits.astype(jnp.float32), new_cache
 
 

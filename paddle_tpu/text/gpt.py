@@ -76,8 +76,33 @@ class GPTConfig:
     pos_embed: str = "learned"
     norm: str = "layernorm"        # "layernorm" | "rmsnorm" (gain-only)
     activation: str = "gelu"       # "gelu" | "swiglu" (gated FFN)
+    # explicit widths: None keeps the classic derivations (head_dim =
+    # hidden_size // num_heads, intermediate_size = ffn_ratio *
+    # hidden_size); models whose heads do not tile the width (20 heads of
+    # 128 on 5120) or whose FFN is no whole multiple of it state them
+    head_dim: int | None = None
+    intermediate_size: int | None = None
+    tie_embeddings: bool = True    # False: a separate ``lm_head`` [V, D]
+    bias: bool = True              # False: projections carry no ``*_b`` leaf
+    rope_theta: float = 10000.0
+    # forward multipliers (muP-style scalars of the Falcon-H1 family),
+    # applied where the published forward applies them, never folded
+    # into the weights; 1.0 traces nothing
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: tuple = (1.0, 1.0)   # gate pre-activation, output
+    # ssm.SSMConfig: every block runs a Mamba-2 mixer IN PARALLEL with
+    # attention on the same normed input (h + attn + ssm), text/ssm.py
+    ssm: Any = None
 
     def __post_init__(self):
+        if self.head_dim is None:
+            self.head_dim = self.hidden_size // self.num_heads
+        if self.intermediate_size is None:
+            self.intermediate_size = self.ffn_ratio * self.hidden_size
         # the invariant lives on the config, not one entry point: every
         # consumer (count_params/shardings/init_cache/checkpoint-loaded
         # params) inherits the loud failure
@@ -98,10 +123,17 @@ class GPTConfig:
             raise ValueError(
                 "MoE experts use the gelu FFN; activation='swiglu' with "
                 "moe is not implemented")
+        if self.moe is not None and (self.ssm is not None or not self.bias):
+            raise ValueError(
+                "moe with an ssm mixer or bias-free projections is not "
+                "implemented (the expert FFN carries its own biases and "
+                "the joint-routing step knows no recurrent state)")
 
     @property
-    def head_dim(self):
-        return self.hidden_size // self.num_heads
+    def q_size(self):
+        """Width of the query projection: num_heads * head_dim (the
+        hidden size unless ``head_dim`` is stated)."""
+        return self.num_heads * self.head_dim
 
     @property
     def kv_heads(self):
@@ -110,7 +142,7 @@ class GPTConfig:
 
     @property
     def ffn_size(self):
-        return self.ffn_ratio * self.hidden_size
+        return self.intermediate_size
 
 
 def gpt_1p3b():
@@ -123,6 +155,10 @@ def gpt_13b():
                      max_seq_len=2048)
 
 
+_PROJECTION_BIASES = ("qkv_b", "q_b", "kv_b", "proj_b", "fc_b", "gate_b",
+                      "out_b")
+
+
 def init_params(cfg: GPTConfig, key) -> dict:
     """Stacked-block parameter pytree, fp32 master weights."""
     keys = jax.random.split(key, 10)
@@ -132,6 +168,7 @@ def init_params(cfg: GPTConfig, key) -> dict:
     def nrm(k, shape, std=s):
         return std * jax.random.normal(k, shape, jnp.float32)
 
+    Dq = cfg.q_size
     blk_keys = jax.random.split(keys[9], 6)
     # fold_in, NOT split(…, 7): widening the split would silently change
     # blk_keys[0..5] and with them every existing config's initial
@@ -141,7 +178,7 @@ def init_params(cfg: GPTConfig, key) -> dict:
     blocks = {
         "ln1_g": jnp.ones((L, D), jnp.float32),
         "ln2_g": jnp.ones((L, D), jnp.float32),
-        "proj_w": nrm(blk_keys[1], (L, D, D), std=s / math.sqrt(2 * L)),
+        "proj_w": nrm(blk_keys[1], (L, Dq, D), std=s / math.sqrt(2 * L)),
         "proj_b": jnp.zeros((L, D), jnp.float32),
     }
     if cfg.norm == "layernorm":   # rmsnorm is gain-only
@@ -150,15 +187,15 @@ def init_params(cfg: GPTConfig, key) -> dict:
     if cfg.num_kv_heads is not None:
         Dkv = cfg.kv_heads * cfg.head_dim
         # GQA: q keeps the full width; k/v project to Dkv
-        blocks["q_w"] = nrm(blk_keys[4], (L, D, D))
-        blocks["q_b"] = jnp.zeros((L, D), jnp.float32)
+        blocks["q_w"] = nrm(blk_keys[4], (L, D, Dq))
+        blocks["q_b"] = jnp.zeros((L, Dq), jnp.float32)
         blocks["kv_w"] = nrm(blk_keys[5], (L, 2, D, Dkv))
         blocks["kv_b"] = jnp.zeros((L, 2, Dkv), jnp.float32)
     else:
         # qkv stored as separate [3, D, D] mats (not one [D, 3D]) so the
         # output dim shards cleanly per-projection under tensor parallel
-        blocks["qkv_w"] = nrm(blk_keys[0], (L, 3, D, D))
-        blocks["qkv_b"] = jnp.zeros((L, 3, D), jnp.float32)
+        blocks["qkv_w"] = nrm(blk_keys[0], (L, 3, D, Dq))
+        blocks["qkv_b"] = jnp.zeros((L, 3, Dq), jnp.float32)
     if cfg.moe is None:
         blocks.update({
             "fc_w": nrm(blk_keys[2], (L, D, F)),
@@ -177,11 +214,21 @@ def init_params(cfg: GPTConfig, key) -> dict:
                      for k in jax.random.split(blk_keys[2], L)]
         blocks["moe"] = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *per_layer)
+    if cfg.ssm is not None:
+        from . import ssm as _ssm
+
+        blocks.update(_ssm.init_params(
+            cfg.ssm, D, L, jax.random.fold_in(keys[9], 7), std=s))
+    if not cfg.bias:   # bias-free projections: the leaves do not exist
+        blocks = {k: v for k, v in blocks.items()
+                  if k not in _PROJECTION_BIASES}
     params = {
         "wte": nrm(keys[0], (V, D)),
         "ln_f_g": jnp.ones((D,), jnp.float32),
         "blocks": blocks,
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = nrm(jax.random.fold_in(keys[0], 1), (V, D))
     if cfg.pos_embed == "learned":   # rope has no position table
         params["wpe"] = nrm(keys[1], (T, D))
     if cfg.norm == "layernorm":
@@ -193,6 +240,11 @@ def param_shardings(cfg: GPTConfig, dp="dp", mp="mp", pp=None, ep="ep") -> dict:
     """Megatron-style PartitionSpecs (reference mp_layers.py Column/RowParallel
     + VocabParallelEmbedding; ZeRO/pp compose by adding axes).  With MoE the
     expert dim shards over ``ep`` (expert parallelism)."""
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            "param_shardings: the ssm mixer has no tensor-parallel layout "
+            "yet (its heads, groups and conv channels would have to split "
+            "together)")
     l = pp  # leading stacked-layer axis shards over pipeline stages if set
     blocks = {
         "ln1_g": P(l, None),
@@ -229,11 +281,16 @@ def param_shardings(cfg: GPTConfig, dp="dp", mp="mp", pp=None, ep="ep") -> dict:
         blocks["moe"] = {
             k: P(l, *v) for k, v in moe_param_shardings(ep=ep, mp=mp).items()
         }
+    if not cfg.bias:
+        blocks = {k: v for k, v in blocks.items()
+                  if k not in _PROJECTION_BIASES}
     out = {
         "wte": P(mp, None),          # vocab-parallel embedding
         "ln_f_g": P(None),
         "blocks": blocks,
     }
+    if not cfg.tie_embeddings:
+        out["lm_head"] = P(mp, None)
     if cfg.pos_embed == "learned":
         out["wpe"] = P(None, None)
     if cfg.norm == "layernorm":
@@ -278,7 +335,7 @@ def apply_rope(x, positions, base: float = 10000.0):
     store rotated keys once and never re-rotate them."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    freqs = float(base) ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None] * freqs      # [T, half]
     cos = jnp.cos(ang)[:, None, :]                            # [T, 1, half]
     sin = jnp.sin(ang)[:, None, :]
@@ -344,10 +401,13 @@ def _gqa_qkv(h, p, cfg: GPTConfig, repeat_kv: bool = True,
     Hkv = Hkv if Hkv is not None else cfg.kv_heads
     hd = cfg.head_dim
     dt = cfg.dtype
-    q = (woq.mm(h, p, "q_w", dt) + p["q_b"].astype(dt)).reshape(B, T, H, hd)
-    kv = woq.mm_stacked(h, p, "kv_w", dt) \
-        + p["kv_b"].astype(dt)[:, None, None]
+    q = _add_bias(woq.mm(h, p, "q_w", dt), p, "q_b").reshape(B, T, H, hd)
+    kv = woq.mm_stacked(h, p, "kv_w", dt)
+    if "kv_b" in p:
+        kv = kv + p["kv_b"].astype(dt)[:, None, None]
     k = kv[0].reshape(B, T, Hkv, hd)
+    if cfg.key_multiplier != 1.0:
+        k = k * jnp.asarray(cfg.key_multiplier, dt)
     v = kv[1].reshape(B, T, Hkv, hd)
     rep = H // Hkv
     if repeat_kv and rep > 1:
@@ -363,14 +423,35 @@ def _project_qkv(h, p, cfg: GPTConfig, repeat_kv: bool = True):
     decode-path block (generate.py: cached/prefill/verify) project
     through."""
     B, T, _ = h.shape
+    dt = cfg.dtype
+    if cfg.attention_in_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.attention_in_multiplier, dt)
     if cfg.num_kv_heads is not None:
         return _gqa_qkv(h, p, cfg, repeat_kv=repeat_kv)
-    dt = cfg.dtype
     H, hd = cfg.num_heads, cfg.head_dim
-    qkv = woq.mm_stacked(h, p, "qkv_w", dt) \
-        + p["qkv_b"].astype(dt)[:, None, None]
-    return (qkv[0].reshape(B, T, H, hd), qkv[1].reshape(B, T, H, hd),
-            qkv[2].reshape(B, T, H, hd))
+    qkv = woq.mm_stacked(h, p, "qkv_w", dt)
+    if "qkv_b" in p:
+        qkv = qkv + p["qkv_b"].astype(dt)[:, None, None]
+    q, k, v = (qkv[i].reshape(B, T, H, hd) for i in range(3))
+    if cfg.key_multiplier != 1.0:
+        k = k * jnp.asarray(cfg.key_multiplier, dt)
+    return q, k, v
+
+
+def _add_bias(y, p, name: str):
+    """``y`` plus the bias leaf ``name``, where the tree has one (a
+    bias-free config's tree has none)."""
+    return y + p[name].astype(y.dtype) if name in p else y
+
+
+def _attn_out(attn, p, cfg: GPTConfig):
+    """The attention output projection on [.., H * hd] -> [.., D]: THE
+    one copy the train block and every decode-path block project
+    through."""
+    a = _add_bias(woq.mm(attn, p, "proj_w", cfg.dtype), p, "proj_b")
+    if cfg.attention_out_multiplier != 1.0:
+        a = a * jnp.asarray(cfg.attention_out_multiplier, cfg.dtype)
+    return a
 
 
 def _ffn_body(h, p, cfg: GPTConfig):
@@ -379,15 +460,19 @@ def _ffn_body(h, p, cfg: GPTConfig):
     and every decode-path block share."""
     dt = cfg.dtype
     with jax.named_scope("mlp"):
+        m_gate, m_out = cfg.mlp_multipliers
         if cfg.activation == "swiglu":
-            gate = jax.nn.silu(woq.mm(h, p, "gate_w", dt)
-                               + p["gate_b"].astype(dt))
-            up = woq.mm(h, p, "fc_w", dt) + p["fc_b"].astype(dt)
-            h = gate * up
+            gate = _add_bias(woq.mm(h, p, "gate_w", dt), p, "gate_b")
+            if m_gate != 1.0:
+                gate = gate * jnp.asarray(m_gate, dt)
+            up = _add_bias(woq.mm(h, p, "fc_w", dt), p, "fc_b")
+            h = jax.nn.silu(gate) * up
         else:
-            h = jax.nn.gelu(woq.mm(h, p, "fc_w", dt)
-                            + p["fc_b"].astype(dt))
-        return woq.mm(h, p, "out_w", dt) + p["out_b"].astype(dt)
+            h = jax.nn.gelu(_add_bias(woq.mm(h, p, "fc_w", dt), p, "fc_b"))
+        out = _add_bias(woq.mm(h, p, "out_w", dt), p, "out_b")
+        if m_out != 1.0:
+            out = out * jnp.asarray(m_out, dt)
+        return out
 
 
 def _ffn_dense(x, p, cfg: GPTConfig):
@@ -449,18 +534,26 @@ def _ffn_tail(x, p, cfg: GPTConfig, valid=None, capacity=_LEGACY,
 
 def _block(x, p, cfg: GPTConfig, dropout_key=None):
     """One transformer block on [B, T, D] activations (compute dtype)."""
-    B, T, D = x.shape
-    H, hd = cfg.num_heads, cfg.head_dim
+    B, T, _ = x.shape
     dt = cfg.dtype
     drop = cfg.dropout > 0.0 and dropout_key is not None
     h = _norm(x, p, "ln1", cfg)
     q, k, v = _project_qkv(h, p, cfg)
     if cfg.pos_embed == "rope":
         pos = jnp.arange(T)
-        q, k = apply_rope(q, pos), apply_rope(k, pos)
+        q = apply_rope(q, pos, cfg.rope_theta)
+        k = apply_rope(k, pos, cfg.rope_theta)
     attn = attention_array(q, k, v, is_causal=True)
-    attn = attn.reshape(B, T, D)
-    a = woq.mm(attn, p, "proj_w", dt) + p["proj_b"].astype(dt)
+    attn = attn.reshape(B, T, cfg.q_size)
+    a = _attn_out(attn, p, cfg)
+    if cfg.ssm is not None:
+        # the parallel mixer reads the same normed input, from the zero
+        # state a sequence starts in; its final state is not kept here
+        from . import ssm as _ssm
+
+        mix, _ = _ssm.mixer_chunk(h, p, cfg,
+                                  _ssm.zero_state(cfg.ssm, B, dt))
+        a = a + mix
     if drop:
         a = _dropout(a, cfg.dropout, jax.random.fold_in(dropout_key, 0))
     x = x + a
@@ -491,7 +584,7 @@ def forward_with_aux(params: dict, tokens, cfg: GPTConfig, act_sharding=None,
     key: PRNG key enabling dropout (cfg.dropout > 0); None = eval mode."""
     B, T = tokens.shape
     dt = cfg.dtype
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + params["wpe"][:T].astype(dt)[None]
     if act_sharding is not None:
@@ -525,7 +618,7 @@ def forward_with_aux(params: dict, tokens, cfg: GPTConfig, act_sharding=None,
 
         x, aux = jax.lax.scan(scan_body, x, params["blocks"])
     x = _norm(x, params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
     return logits, jnp.sum(aux)
 
 
@@ -559,17 +652,23 @@ def loss_fn(params: dict, tokens, cfg: GPTConfig, act_sharding=None, key=None):
 def count_params(cfg: GPTConfig) -> int:
     D, F, L, V, T = (cfg.hidden_size, cfg.ffn_size, cfg.num_layers, cfg.vocab_size,
                      cfg.max_seq_len)
-    Dkv = cfg.kv_heads * cfg.head_dim
-    qkv = (D * D + D + 2 * D * Dkv + 2 * Dkv
-           if cfg.num_kv_heads is not None else 3 * D * D + 3 * D)
+    Dq, Dkv = cfg.q_size, cfg.kv_heads * cfg.head_dim
+    b = 1 if cfg.bias else 0          # a projection's bias row, or none
+    qkv = (D * Dq + b * Dq + 2 * D * Dkv + b * 2 * Dkv
+           if cfg.num_kv_heads is not None else 3 * (D * Dq + b * Dq))
     norms = 4 * D if cfg.norm == "layernorm" else 2 * D  # 2 gains (+2 biases)
-    ffn = D * F + F + F * D + D
+    ffn = D * F + b * F + F * D + b * D
     if cfg.activation == "swiglu":
-        ffn += D * F + F                                  # gate matmul
-    per_block = norms + qkv + D * D + D + ffn
+        ffn += D * F + b * F                              # gate matmul
+    per_block = norms + qkv + Dq * D + b * D + ffn
+    if cfg.ssm is not None:
+        from . import ssm as _ssm
+
+        per_block += _ssm.count_params(cfg.ssm, D)
     final_norm = 2 * D if cfg.norm == "layernorm" else D
     pos = T * D if cfg.pos_embed == "learned" else 0
-    return V * D + pos + final_norm + L * per_block
+    head = 0 if cfg.tie_embeddings else V * D
+    return V * D + head + pos + final_norm + L * per_block
 
 
 def flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
@@ -582,10 +681,10 @@ def flops_per_token(cfg: GPTConfig, seq_len: int) -> float:
     scores: QK^T + AV = 12 L D T training flops/token (full, non-causal
     accounting — the conservative standard for MFU)."""
     D, F, L, V = cfg.hidden_size, cfg.ffn_size, cfg.num_layers, cfg.vocab_size
-    Dkv = cfg.kv_heads * cfg.head_dim
-    qkv_w = (D * D + 2 * D * Dkv if cfg.num_kv_heads is not None
-             else 3 * D * D)
+    Dq, Dkv = cfg.q_size, cfg.kv_heads * cfg.head_dim
+    qkv_w = (D * Dq + 2 * D * Dkv if cfg.num_kv_heads is not None
+             else 3 * D * Dq)
     ffn_w = (3 if cfg.activation == "swiglu" else 2) * D * F
-    n_matmul = L * (qkv_w + D * D + ffn_w) + V * D
-    attn = 12 * L * D * seq_len
+    n_matmul = L * (qkv_w + Dq * D + ffn_w) + V * D
+    attn = 12 * L * Dq * seq_len
     return 6 * n_matmul + attn

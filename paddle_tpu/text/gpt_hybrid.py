@@ -586,9 +586,17 @@ def build_gpt_train_step(cfg: gpt.GPTConfig, mesh: Mesh, optimizer,
     dp = axes.get("dp", 1)
     sp = axes.get("sp", 1)
     ep = axes.get("ep", 1)
+    if cfg.ssm is not None:
+        raise NotImplementedError(
+            "training a config with an ssm mixer is not supported yet: the "
+            "chunked scan's backward has no reference to be held to, and "
+            "the mixer no sharded layout (serve it: DecodeServer("
+            "layout='paged'))")
     if (pp > 1 or sp > 1) and (cfg.pos_embed != "learned"
                                or cfg.norm != "layernorm"
-                               or cfg.activation != "gelu"):
+                               or cfg.activation != "gelu"
+                               or not cfg.bias or not cfg.tie_embeddings
+                               or cfg.q_size != cfg.hidden_size):
         # early twin of _pipeline_parts' shared guard (which also covers
         # the public make_pipeline_* entry points): refuse before any
         # sharding work rather than silently training a DIFFERENT

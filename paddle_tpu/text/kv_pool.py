@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import generate, gpt, woq
+from . import ssm as _ssm
 from .. import flags as _flags
 from .. import telemetry as _telemetry
 
@@ -62,6 +63,11 @@ __all__ = [
 
 # the value/scale leaves of a pooled cache (everything except "tables")
 POOL_LEAVES = ("k", "v", "k_s", "v_s")
+# a recurrent mixer's per-slot leaves [L, batch, ...] (no block table maps
+# them) and the [batch] bool leaf that says which slots a decode step
+# advances; held beside the pool when the config has an ssm mixer
+STATE_LEAVES = _ssm.STATE_LEAVES
+LIVE = "live"
 
 
 class PoolExhausted(RuntimeError):
@@ -118,7 +124,36 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
     if dt == jnp.int8:
         cache["k_s"] = jnp.zeros(shape[:-1], jnp.float32)
         cache["v_s"] = jnp.zeros(shape[:-1], jnp.float32)
+    if cfg.ssm is not None:
+        # two kinds of state, one pytree: the mixer's state is per SLOT
+        # and of fixed size, so it is provisioned for every slot and
+        # donated and returned with the pool
+        cache.update(_ssm.init_state(cfg.ssm, L, batch, cfg.dtype))
+        cache[LIVE] = jnp.zeros((batch,), bool)
     return cache
+
+
+def _per_slot(mask, leaf, axis: int):
+    """``mask`` [batch] shaped to broadcast against ``leaf``, whose batch
+    axis is ``axis``."""
+    return mask.reshape((1,) * axis + (-1,)
+                        + (1,) * (leaf.ndim - axis - 1))
+
+
+def _from_zero(state: dict, pos, axis: int) -> dict:
+    """The state a step continues: zero where the slot feeds a sequence's
+    first position (so a slot reused after retirement never sees the last
+    tenant's state), else what the cache holds."""
+    return {n: jnp.where(_per_slot(pos == 0, v, axis),
+                         jnp.zeros((), v.dtype), v)
+            for n, v in state.items()}
+
+
+def _keep_idle(new: dict, old: dict, live, axis: int) -> dict:
+    """A slot that does not decode in this step (free, admitting, between
+    the chunks of a prefill) keeps its state bit for bit."""
+    return {n: jnp.where(_per_slot(live, old[n], axis), new[n], old[n])
+            for n in new}
 
 
 def _geometry(cache: dict):
@@ -183,24 +218,40 @@ def paged_decode_step_batched(params, cache, token, pos,
 
     tables = cache["tables"]
     pool = {n: cache[n] for n in POOL_LEAVES if n in cache}
+    state = None
+    if STATE_LEAVES[0] in cache:
+        with jax.named_scope("ssm"):
+            state = _from_zero({n: cache[n] for n in STATE_LEAVES}, pos, 1)
 
-    def one(tok_b, pos_b, trow):
+    def one(tok_b, pos_b, trow, st_b):
         dt = cfg.dtype
         x = generate._embed_step(params, tok_b[None], pos_b, cfg)
 
         def body(x, layer):
-            p, pl = layer
+            p, pl, st = layer
             csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
-            x, rows = generate._cached_block(x, p, csl, pos_b, cfg)
-            return x, rows
+            if st is None:
+                x, rows = generate._cached_block(x, p, csl, pos_b, cfg)
+                return x, (rows, None)
+            x, rows, st = generate._cached_block(
+                x, p, csl, pos_b, cfg,
+                state={n: v[None] for n, v in st.items()})
+            return x, (rows, {n: v[0] for n, v in st.items()})
 
-        x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
+        x, (rows, st_b) = jax.lax.scan(body, x,
+                                       (params["blocks"], pool, st_b))
         x = gpt._norm(x, params, "ln_f", cfg)
-        logits = woq.logits(x, params, dt)[:, 0]
-        return logits[0].astype(jnp.float32), rows
+        logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
+        return logits[0].astype(jnp.float32), rows, st_b
 
-    logits, rows = jax.vmap(one, in_axes=(0, 0, 0),
-                            out_axes=(0, 0))(token, pos, tables)
+    logits, rows, new_state = jax.vmap(
+        one, in_axes=(0, 0, 0, 1), out_axes=(0, 0, 1))(
+        token, pos, tables, state)
+    if new_state is not None:
+        with jax.named_scope("ssm"):
+            cache = dict(cache, **_keep_idle(
+                new_state, {n: cache[n] for n in STATE_LEAVES},
+                cache[LIVE], 1))
     # rows leaves [B, L, 1, Hkv(, hd)] -> [L, B, Hkv(, hd)]; physical row
     # per slot through the table (unmapped -> out of bounds -> dropped,
     # the slab path's clamp-into-masked-rows equivalent)
@@ -226,6 +277,10 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
     tb = tables[jnp.arange(B), pos // bs]
     phys = jnp.where(tb >= 0, tb * bs + pos % bs, N * bs)
     pool = {n: cache[n] for n in POOL_LEAVES if n in cache}
+    # the recurrent state rides the carry like the pool (written in place,
+    # a layer at a time), never the scan's stacked outputs
+    state = ({n: cache[n] for n in STATE_LEAVES}
+             if STATE_LEAVES[0] in cache else None)
     L = cache["k"].shape[0]
 
     def embed_one(tok_b, pos_b):
@@ -234,13 +289,27 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
     x = jax.vmap(embed_one)(token, pos)                  # [B, 1, 1, D]
 
     def body(carry, layer):
-        x, pool = carry
+        x, pool, state = carry
         p, li = layer
+        h = mix = None
+        if state is not None:
+            h = jax.vmap(lambda xb: gpt._norm(xb, p, "ln1", cfg))(x)
+            # the state's reads, selects and write-back are the mixer's
+            # work too: under its scope, so that its metrics hold them
+            with jax.named_scope("ssm"):
+                old = {n: v[li] for n, v in state.items()}
+                start = _from_zero(old, pos, 0)
+            mix, new = _ssm.mixer_step(h[:, 0], p, cfg, start)
+            with jax.named_scope("ssm"):
+                new = _keep_idle(new, old, cache[LIVE], 0)
+                state = {n: jax.lax.dynamic_update_index_in_dim(
+                             state[n], new[n], li, 0) for n in state}
+            mix = mix[:, None]                           # [B, 1, 1, D]
 
-        def pre(xb, pos_b):
-            return generate._block_pre_attn(xb, p, pos_b, cfg)
+        def pre(xb, pos_b, hb):
+            return generate._block_pre_attn(xb, p, pos_b, cfg, h=hb)
 
-        q3, rows = jax.vmap(pre)(x, pos)     # q3 [B,1,1,H,hd]
+        q3, rows = jax.vmap(pre)(x, pos, h)  # q3 [B,1,1,H,hd]
         # scatter the fresh rows into layer li BEFORE attending: the
         # kernel then reads exactly what later steps will read back
         # (scatter-then-attend == the slab path's splice-then-write)
@@ -262,21 +331,21 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
                 k_scale=layer_kv.get("k_s"), v_scale=layer_kv.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, 1, cfg.num_heads * hd)
 
-        def post(xb, ab):
-            return generate._block_post_attn(xb, ab, p, cfg)
+        def post(xb, ab, mb):
+            return generate._block_post_attn(xb, ab, p, cfg, mix=mb)
 
-        x = jax.vmap(post)(x, attn)
-        return (x, pool), None
+        x = jax.vmap(post)(x, attn, mix)
+        return (x, pool, state), None
 
-    (x, pool), _ = jax.lax.scan(
-        body, (x, pool), (params["blocks"], jnp.arange(L)))
+    (x, pool, state), _ = jax.lax.scan(
+        body, (x, pool, state), (params["blocks"], jnp.arange(L)))
 
     def fin(xb):
         xb = gpt._norm(xb, params, "ln_f", cfg)
-        return woq.logits(xb, params, dt)[0, 0]
+        return woq.logits(xb, params, dt, cfg.lm_head_multiplier)[0, 0]
 
     logits = jax.vmap(fin)(x)
-    return logits.astype(jnp.float32), dict(cache, **pool)
+    return logits.astype(jnp.float32), dict(cache, **pool, **(state or {}))
 
 
 def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
@@ -299,20 +368,41 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
     pool = {n: cache[n] for n in POOL_LEAVES if n in cache}
     dt = cfg.dtype
     C = tokens.shape[1]
-    x = woq.embed(params, tokens, dt)
+    x = woq.embed(params, tokens, dt, cfg.embedding_multiplier)
     if cfg.pos_embed == "learned":
         x = x + jax.lax.dynamic_slice(
             params["wpe"], (pos0, 0), (C, cfg.hidden_size)).astype(dt)[None]
     valid_mask = (jnp.arange(C) < length)[None, :]        # [1, C]
+    # the slot's recurrent state [L, 1, ...]: zero where the chunk opens
+    # the sequence (an admission starts from the zero state), else what
+    # the chunk before left; pads advance nothing (ssm.mixer_chunk)
+    state = None
+    if STATE_LEAVES[0] in cache:
+        with jax.named_scope("ssm"):
+            state = {n: jnp.where(
+                         pos0 == 0, jnp.zeros((), cache[n].dtype),
+                         jax.lax.dynamic_slice_in_dim(cache[n], slot, 1, 1))
+                     for n in STATE_LEAVES}
 
     def body(x, layer):
-        p, pl = layer
+        p, pl, st = layer
         csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
-        x, rows = generate._chunk_attend_block(x, p, csl, pos0, cfg,
-                                               valid=valid_mask)
-        return x, rows
+        if st is None:
+            x, rows = generate._chunk_attend_block(x, p, csl, pos0, cfg,
+                                                   valid=valid_mask)
+            return x, (rows, None)
+        x, rows, st = generate._chunk_attend_block(
+            x, p, csl, pos0, cfg, valid=valid_mask, state=st, length=length)
+        return x, (rows, st)
 
-    x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
+    x, (rows, state) = jax.lax.scan(body, x,
+                                    (params["blocks"], pool, state))
+    if state is not None:
+        with jax.named_scope("ssm"):
+            cache = dict(cache, **{
+                n: jax.lax.dynamic_update_slice_in_dim(cache[n], state[n],
+                                                       slot, 1)
+                for n in STATE_LEAVES})
     logi = pos0 + jnp.arange(C)
     tb = trow[jnp.clip(logi // bs, 0, nmax - 1)]
     phys = jnp.where((jnp.arange(C) < length) & (tb >= 0)
@@ -322,7 +412,7 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
     last = jax.lax.dynamic_slice(x, (0, length - 1, 0),
                                  (1, 1, cfg.hidden_size))
     last = gpt._norm(last, params, "ln_f", cfg)
-    logits = woq.logits(last, params, dt)[0, 0]
+    logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0, 0]
     return logits.astype(jnp.float32), cache
 
 
@@ -357,7 +447,8 @@ def paged_verify_chunk_batched(params, cache, tokens, pos, cfg):
     dt = cfg.dtype
 
     def one(tok_k, p0, trow):
-        x = woq.embed(params, tok_k[None], dt)            # [1, K, D]
+        x = woq.embed(params, tok_k[None], dt,
+                      cfg.embedding_multiplier)              # [1, K, D]
         if cfg.pos_embed == "learned":
             x = x + jax.lax.dynamic_slice(
                 params["wpe"], (p0, 0),
@@ -371,7 +462,8 @@ def paged_verify_chunk_batched(params, cache, tokens, pos, cfg):
 
         x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
         x = gpt._norm(x, params, "ln_f", cfg)
-        logits = woq.logits(x, params, dt)[0]             # [K, V]
+        logits = woq.logits(x, params, dt,
+                            cfg.lm_head_multiplier)[0]      # [K, V]
         return logits.astype(jnp.float32), rows
 
     logits, rows = jax.vmap(one, in_axes=(0, 0, 0),
@@ -416,7 +508,8 @@ def paged_tree_verify_chunk_batched(params, cache, tokens, amask, depth,
     T = nmax * bs
 
     def one(tok_k, am, dp, p0, trow):
-        x = woq.embed(params, tok_k[None], dt)            # [1, K, D]
+        x = woq.embed(params, tok_k[None], dt,
+                      cfg.embedding_multiplier)              # [1, K, D]
         if cfg.pos_embed == "learned":
             x = x + jnp.take(params["wpe"], p0 + dp,
                              axis=0).astype(dt)[None]
@@ -433,7 +526,8 @@ def paged_tree_verify_chunk_batched(params, cache, tokens, amask, depth,
 
         x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
         x = gpt._norm(x, params, "ln_f", cfg)
-        logits = woq.logits(x, params, dt)[0]             # [K, V]
+        logits = woq.logits(x, params, dt,
+                            cfg.lm_head_multiplier)[0]      # [K, V]
         return logits.astype(jnp.float32), rows
 
     logits, rows = jax.vmap(one, in_axes=(0, 0, 0, 0, 0),
@@ -511,7 +605,8 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
                      tb * bs + logi % bs, N * bs).reshape(B * K)
 
     def embed_one(tok_k, p0):
-        x = woq.embed(params, tok_k[None], dt)            # [1, K, D]
+        x = woq.embed(params, tok_k[None], dt,
+                      cfg.embedding_multiplier)              # [1, K, D]
         if cfg.pos_embed == "learned":
             x = x + jax.lax.dynamic_slice(
                 params["wpe"], (p0, 0),
@@ -557,7 +652,8 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
 
     def fin(xb):
         xb = gpt._norm(xb, params, "ln_f", cfg)
-        return woq.logits(xb, params, dt)[0]              # [K, V]
+        return woq.logits(xb, params, dt,
+                          cfg.lm_head_multiplier)[0]        # [K, V]
 
     logits = jax.vmap(fin)(x)
     return logits.astype(jnp.float32), dict(cache, **pool)

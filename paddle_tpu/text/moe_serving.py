@@ -253,7 +253,7 @@ def moe_decode_step_batched(params, cache, token, pos, act, stats,
     # rows leaves [L, B, 1, Hkv(, hd)] -> per-slot frontier write
     new_cache = generate._write_rows_batched(cache, rows, pos)
     x = gpt._norm(x[:, 0], params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)[:, 0]
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
     return logits.astype(jnp.float32), new_cache, stats
 
 
@@ -308,7 +308,7 @@ def _moe_paged_step_batched(params, cache, token, pos, act, stats,
     new_cache = kv_pool._scatter_rows(
         cache, {n: v[:, :, 0] for n, v in rows.items()}, phys)
     x = gpt._norm(x[:, 0], params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)[:, 0]
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
     return logits.astype(jnp.float32), new_cache, stats
 
 
@@ -406,7 +406,7 @@ def dense_eval_decode_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     x, rows = jax.lax.scan(body, x, (params["blocks"], cache))
     new_cache = generate._write_rows(cache, rows, pos)
     x = gpt._norm(x, params, "ln_f", cfg)
-    logits = woq.logits(x, params, dt)[:, 0]
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
     return logits.astype(jnp.float32), new_cache
 
 

@@ -587,6 +587,18 @@ class DecodeServer:
             raise ValueError(
                 f"layout {lay!r}: expected 'contiguous' or 'paged'")
         self._paged = lay == "paged"
+        # a recurrent mixer beside attention (cfg.ssm): per-slot state
+        # leaves ride the paged cache pytree (kv_pool.STATE_LEAVES) and a
+        # ``live`` leaf tells each decode step which slots it advances.
+        # A KV row written by a slot that is not really decoding is
+        # overwritten later; a recurrent state cannot be, so whatever
+        # relies on rewriting or rolling back rows is refused here, by
+        # name, before anything is built.
+        self._recurrent = cfg.ssm is not None
+        if self._recurrent:
+            self._refuse_recurrent(
+                lay, mesh, draft_cfg, spec_k, spec_tree, adapter_pool,
+                prefill_chunk, prefill_budget, max_len)
         if self._paged:
             from . import kv_pool as _kv
 
@@ -599,6 +611,9 @@ class DecodeServer:
             self._pool = _kv.PagedAllocator(
                 self.cache["k"].shape[1], self.cache["k"].shape[2],
                 self.cache["tables"].shape[1], max_batch)
+            if self._recurrent and self._tel:
+                _telemetry.gauge("kv_pool.state_bytes").set(sum(
+                    self.cache[n].nbytes for n in _kv.STATE_LEAVES))
         else:
             self._pool = None
             self.cache = generate.init_cache(cfg, max_batch, max_len)
@@ -953,6 +968,106 @@ class DecodeServer:
                     "them before attaching an adapter_pool (the pool's "
                     "gathered delta would stack on top of them)")
 
+    def _refuse_recurrent(self, lay, mesh, draft_cfg, spec_k, spec_tree,
+                          adapter_pool, prefill_chunk, prefill_budget,
+                          max_len):
+        """What cannot work with a recurrent state yet raises at
+        construction, naming the reason (as MoE speculation does)."""
+        cfg = self.cfg
+
+        def no(what, why):
+            raise NotImplementedError(
+                f"{what} with an ssm mixer (cfg.ssm) is not supported "
+                f"yet: {why}")
+
+        if lay != "paged":
+            no("layout='contiguous'", "the recurrent state leaves live in "
+               "the paged cache pytree only; pass layout='paged'")
+        if mesh is not None:
+            no("mesh=", "the mixer has no tensor-parallel layout "
+               "(gpt.param_shardings)")
+        if draft_cfg is not None or (spec_k or 0) > 0 \
+                or (spec_tree or 0) > 0 \
+                or (spec_k is None and spec_tree is None
+                    and (_flags.spec_k() or _flags.spec_tree())):
+            no("speculation (spec_k / spec_tree / draft_cfg)",
+               "a rejected draft token has advanced the state, and a "
+               "state cannot be rolled back as rows are")
+        if adapter_pool is not None:
+            no("adapter_pool", "the adapter step kinds know no state leaves")
+        if _flags.kv_spill_mb():
+            no("the host spill tier (PADDLE_TPU_KV_SPILL_MB)",
+               "it restores prefix rows by inject_rows, and there is no "
+               "state snapshot to restore beside them")
+        if "PADDLE_TPU_KV_RADIX" in _os.environ and _flags.kv_radix():
+            no("prefix reuse asked for by PADDLE_TPU_KV_RADIX",
+               "adopted rows would need the state the shared prefix left, "
+               "and no snapshot of it is kept; the index is off for this "
+               "configuration (kv_pool.prefix_skipped_recurrent counts)")
+        window = min(max_len, cfg.max_seq_len)
+        budget = (prefill_budget if prefill_budget is not None
+                  else _flags.prefill_budget())
+        for name, width in (("prefill_chunk", prefill_chunk),
+                            ("prefill_budget", budget)):
+            if width and window % int(width):
+                # chunks of a state's prefill cannot overlap (the last
+                # window of the row walk re-runs rows; a state would
+                # advance twice), so they tile the window exactly
+                raise ValueError(
+                    f"{name}={width} must divide the serving window "
+                    f"{window} for a config with an ssm mixer (its "
+                    f"prefill chunks cannot overlap)")
+
+    def _refuse_handoff(self, what: str):
+        if self._recurrent:
+            raise NotImplementedError(
+                f"{what} with an ssm mixer (cfg.ssm) is not supported "
+                f"yet: the prefill handoff ships KV rows (inject_rows), "
+                f"and the recurrent state the prompt leaves has no wire "
+                f"form")
+
+    def _chunk_starts(self, shared: int, n: int, width: int,
+                      window: int) -> list:
+        """Where a prompt's prefill chunks of ``width`` start, after
+        ``shared`` adopted rows.  Row walks end on a window that overlaps
+        the one before (overlapped rows recompute to identical values)
+        rather than overrun the cache; a recurrent state would advance
+        twice there, so its chunks tile [0, n) and only the last is
+        short (``width`` divides the window: construction checked)."""
+        if self._recurrent:
+            return list(range(0, n, width))
+        if n - shared <= width:
+            return [shared if shared + width <= window
+                    else max(0, n - width)]
+        return list(range(shared, n - width, width)) + [n - width]
+
+    def _adopt_prefix(self, slot: int, req) -> int:
+        """Rows of the longest indexed prefix adopted into ``slot``'s
+        table (0 where sharing is off: no prefill, an adapter's rows, or
+        a recurrent state, which no snapshot could restore beside them)."""
+        if self._recurrent:
+            if self._tel:
+                _telemetry.count("kv_pool.prefix_skipped_recurrent")
+            return 0
+        if not self._prefill_on or req.get("adapter"):
+            return 0
+        return self._pool.adopt_prefix(slot, req["prompt"])
+
+    def _push_live(self):
+        """Tell the next decode step which slots it advances: the slots
+        that decode (or feed their prompt token by token), not the free
+        ones and not those mid-admission, whose state the prefill chunks
+        own.  A no-op without a recurrent state."""
+        if not self._recurrent:
+            return
+        from . import kv_pool as _kv
+
+        # the joint-routing step's occupancy mask is this one too
+        leaf = jnp.asarray(self._moe_act())
+        if self._device is not None:
+            leaf = jax.device_put(leaf, self._device)
+        self.cache = dict(self.cache, **{_kv.LIVE: leaf})
+
     # -- request lifecycle --------------------------------------------------
 
     def submit(self, prompt, max_new_tokens: int = 32,
@@ -1108,6 +1223,7 @@ class DecodeServer:
         seeds the first token from ``logits`` with the exact sampling/
         telemetry/NaN-guard semantics of local prefill — decode then
         proceeds bit-identically to a locally prefilled request."""
+        self._refuse_handoff("submit_prefilled")
         req = self._build_request(prompt, max_new_tokens, stop,
                                   temperature, top_k, top_p, ttl_s,
                                   priority)
@@ -1152,6 +1268,8 @@ class DecodeServer:
         :meth:`drain_queue` / ``_build_request`` — it is trusted, not
         re-validated (but the window is re-checked: replicas may be
         heterogeneous)."""
+        if "prefilled" in req or req.get("stream"):
+            self._refuse_handoff("adopt_request of a prefilled request")
         total = len(req["prompt"]) + req["max_new"] \
             - len(req.get("carry", ()))
         if total > min(self.max_len, self.cfg.max_seq_len):
@@ -1601,14 +1719,9 @@ class DecodeServer:
                 # delta (or the base), so adoption would serve wrong
                 # attention state.  Base (adapter 0) traffic shares as
                 # before.
-                shared = alloc.adopt_prefix(slot, prompt) \
-                    if self._prefill_on and not req.get("adapter") else 0
+                shared = self._adopt_prefix(slot, req)
                 self._drain_restores()
-                if n - shared <= W:
-                    starts = [shared if shared + W <= window
-                              else max(0, n - W)]
-                else:
-                    starts = list(range(shared, n - W, W)) + [n - W]
+                starts = self._chunk_starts(shared, n, W, window)
                 while True:
                     try:
                         alloc.ensure_rows(slot, min(starts), n)
@@ -1770,9 +1883,12 @@ class DecodeServer:
         st.pop("admitting", None)
         st.pop("admit_starts", None)
         st.pop("admit_i", None)
-        if self._paged and self._prefill_on and not st.get("adapter"):
+        if self._paged and self._prefill_on and not st.get("adapter") \
+                and not self._recurrent:
             # adapter rows never index for sharing (see _claim_admitting)
             self._pool.register_prefix(slot, prompt)
+        if self._recurrent and self._tel:
+            _telemetry.count("kv_pool.state_resets")
         if self._spec_on and self.draft_cfg is not None:
             # draft chunks advanced in lockstep (see _advance_admitting);
             # without a draft cache the catch-up feeds from 0
@@ -1823,6 +1939,7 @@ class DecodeServer:
         QUEUED buffer host-side and replay at claim — admission order
         is unchanged.  Decoded output is bit-identical to
         :meth:`submit_prefilled` with the same rows and logits."""
+        self._refuse_handoff("stream_prefilled_begin")
         req = self._build_request(prompt, max_new_tokens, stop,
                                   temperature, top_k, top_p, ttl_s,
                                   priority)
@@ -2225,6 +2342,7 @@ class DecodeServer:
             self._pool.ensure_rows(slot, st["pos"],
                                    min(st["pos"] + steps, cap))
         self._apply_pool_ops()
+        self._push_live()
 
     def _paged_prefill_slot(self, req, slot):
         """Paged admission: adopt the longest indexed prompt prefix into
@@ -2242,21 +2360,16 @@ class DecodeServer:
         # adapter≠0 prompts bypass the prefix cache entirely: adopted
         # rows carry a different (or no) weight delta, and registering
         # adapter rows would poison future base/other-adapter admissions
-        shared = alloc.adopt_prefix(slot, prompt) \
-            if self._prefill_on and not req.get("adapter") else 0
+        shared = self._adopt_prefix(slot, req)
         self._drain_restores()
         window = min(self.max_len, self.cfg.max_seq_len)
         if self._chunk:
+            # one chunk covering the suffix starts AT the adopted prefix
+            # (recomputing shared rows would COW every adopted block and
+            # forfeit the reuse), backing off only when the window bound
+            # forces an overlap
             C = min(self._chunk, window)
-            if n - shared <= C:
-                # one chunk covers the suffix: start AT the adopted
-                # prefix (recomputing shared rows would COW every
-                # adopted block and forfeit the reuse), backing off only
-                # when the window bound forces an overlap
-                starts = [shared if shared + C <= window
-                          else max(0, n - C)]
-            else:
-                starts = list(range(shared, n - C, C)) + [n - C]
+            starts = self._chunk_starts(shared, n, C, window)
         else:
             # bucketed suffix: one power-of-two chunk per admission,
             # floored at the block size — suffixes after a prefix hit
@@ -2325,8 +2438,12 @@ class DecodeServer:
             # rows actually prefilled — the repeated-prefix FLOPs saving
             # is (prompt length - this) per request
             _telemetry.count("kv_pool.prefill_rows", rows_done)
-        if not req.get("adapter"):
+        if not req.get("adapter") and not self._recurrent:
             alloc.register_prefix(slot, prompt)
+        if self._recurrent and self._tel:
+            # every admission starts from the zero state (the chunk at
+            # position 0 reads none)
+            _telemetry.count("kv_pool.state_resets")
         return name, len(starts), logits
 
     def _inject_prefilled(self, req, slot):

@@ -259,25 +259,33 @@ def mm_stacked(h, p: dict, name: str, dt):
     return jnp.einsum("...d,kde->k...e", h, w(p, name, dt))
 
 
-def embed(params: dict, token, dt):
-    """wte[token] in compute dtype, dequantizing per-row scales if int8.
+def embed(params: dict, token, dt, mult: float = 1.0):
+    """wte[token] in compute dtype, dequantizing per-row scales if int8,
+    times the config's ``embedding_multiplier`` (1.0 traces nothing).
     Every path embeds through here, so the ``embed`` scope is here too."""
     with jax.named_scope("embed"):
         e = params["wte"][token].astype(dt)
         if params["wte"].dtype == jnp.int8:
             e = e * params["wte_s"][token].astype(dt)
+        if mult != 1.0:
+            e = e * jnp.asarray(mult, dt)
         return e
 
 
-def logits(x, params: dict, dt):
-    """Tied-head logits x @ wte.T; per-row wte scales factor out of the
-    contraction and apply on the [..., V] output (cheaper than scaling the
-    weight, exactly equal).  Every path's head, so the ``lm_head`` scope
-    is here."""
+def logits(x, params: dict, dt, mult: float = 1.0):
+    """Logits x @ head.T, the head being the tree's own ``lm_head`` leaf
+    where it has one (an untied head) and ``wte`` otherwise; per-row
+    scales of an int8 head factor out of the contraction and apply on the
+    [..., V] output (cheaper than scaling the weight, exactly equal);
+    times the config's ``lm_head_multiplier``.  Every path's head, so the
+    ``lm_head`` scope is here."""
+    name = "lm_head" if "lm_head" in params else "wte"
     with jax.named_scope("lm_head"):
-        y = x @ params["wte"].T.astype(dt)
-        if params["wte"].dtype == jnp.int8:
-            y = y * params["wte_s"].reshape(-1).astype(dt)
+        y = x @ params[name].T.astype(dt)
+        if params[name].dtype == jnp.int8:
+            y = y * params[name + "_s"].reshape(-1).astype(dt)
+        if mult != 1.0:
+            y = y * jnp.asarray(mult, dt)
         return y
 
 

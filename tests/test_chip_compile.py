@@ -256,6 +256,54 @@ def test_engine_decode_step(one_chip, no_persistent_cache, as_on_tpu,
                for p in paths)
 
 
+def test_hybrid_decode_step_and_prefill(one_chip, no_persistent_cache,
+                                        as_on_tpu, purge_engine):
+    """A Mamba-2 mixer beside grouped-query attention at the published
+    widths of the benchmark's hybrid configuration (two layers of it, an
+    eighth of the vocabulary): the paged kernel takes 20 query heads
+    over 4 KV heads of 128, the state leaves ride the layer scan's carry
+    and are aliased to the outputs (donated with the pool), and the
+    mixer's ops carry the ``ssm`` scope the per-layer metrics select."""
+    import json
+
+    from benchmarks.families import falcon_h1 as fam
+    from paddle_tpu.text import engine, generate
+
+    with open("benchmarks/configs/falcon-h1-34b-serve.json") as f:
+        config = json.load(f)
+    config.update(num_hidden_layers=2, vocab_size=32640)
+    cfg = fam.gpt_config(config)
+    purge_engine(cfg)
+    params = _abstract(_param_shapes(cfg), one_chip, dtype=BF)
+    cache = _abstract(jax.eval_shape(lambda: generate.init_cache(
+        cfg, B, T, layout="paged")), one_chip)
+    assert cache["ssm"].shape == (2, B, 32, 128, 256)
+    assert cache["ssm"].dtype == F32 and cache["live"].shape == (B,)
+    tok = jax.ShapeDtypeStruct((B,), I32, sharding=one_chip)
+    step = engine.ENGINE.get("step", engine.StepSpec(cfg=cfg, paged=True))
+    compiled = step.lower(params, cache, tok, tok).compile()
+    text = compiled.as_text()
+    assert _names_a_kernel("paged_decode_attention", text)
+    paths = set(re.findall(r'op_name="([^"]*)"', text))
+    for scope in ("/ssm/ssm_update/", "/ssm/ssm_conv/", "/attn/", "(mlp)/"):
+        assert any(p.startswith("jit(<lambda>)/serving.step/")
+                   and scope in p for p in paths), scope
+    # the state is written in place: no second copy of it in the step
+    mem = compiled.memory_analysis()
+    state = sum(int(np.prod(cache[n].shape)) * cache[n].dtype.itemsize
+                for n in ("ssm", "conv"))
+    assert mem.alias_size_in_bytes >= state
+    assert mem.temp_size_in_bytes < state
+    scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+    pf = engine.ENGINE.get("paged_prefill",
+                           engine.StepSpec(cfg=cfg, bucket=256))
+    text = pf.lower(params, cache, jax.ShapeDtypeStruct(
+        (1, 256), I32, sharding=one_chip), scalar, scalar,
+        scalar).compile().as_text()
+    assert any("/ssm/ssm_scan/" in p for p in
+               re.findall(r'op_name="([^"]*)"', text))
+
+
 def _train_step(cfg, mesh, accum=1):
     from paddle_tpu.optimizer import AdamW
     from paddle_tpu.text import gpt_hybrid
