@@ -8,10 +8,11 @@ reads are optimized (PR 1/2).  This kernel streams the KV cache through
 VMEM in T-blocks with the same online-softmax recurrence as
 ``_flash_fwd_impl``, specialized for small T_q:
 
-* **split-KV grid** ``(B * Hkv, T // BT)``: each cell owns one (batch,
-  kv-head) pair and walks the KV blocks keeping a running max/denominator
-  in VMEM scratch — no [T] score row ever hits HBM, and blocks entirely
-  past the causal frontier (``base > pos + Tq - 1``) are skipped;
+* **split-KV grid** ``(B * Hkv, T // BT)`` (the contiguous kernel): each
+  cell owns one (batch, kv-head) pair and walks the KV blocks keeping a
+  running max/denominator in VMEM scratch — no [T] score row ever hits
+  HBM, and blocks entirely past the causal frontier
+  (``base > pos + Tq - 1``) are skipped;
 * **GQA-aware**: the q rows for one kv head are its whole query group
   ([Tq * G, hd], G = Hq // Hkv), so the kernel consumes the Hkv-head
   cache DIRECTLY (the ``repeat_kv=False`` layout ``_gqa_qkv`` already
@@ -19,7 +20,15 @@ VMEM in T-blocks with the same online-softmax recurrence as
   is the cache's true size, not G times it;
 * **int8 cache**: per-(position, head) scales (``quantize_kv``) dequantize
   inside the kernel right after the VMEM load — HBM reads a quarter of
-  the fp32 bytes, and no dequantized copy is ever written back.
+  the fp32 bytes, and no dequantized copy is ever written back;
+* **paged kernel: a grid cell a slot** (``_paged_call``).  The pool stays
+  in HBM; a cell loops over its slot's compute blocks only as far as the
+  causal frontier and the mapped table entries reach, copies each live
+  page by its physical number (all KV heads of the page in one copy, the
+  next block's copies in flight while this one is attended) and runs the
+  same recurrence a head at a time.  A free slot, an unmapped entry and
+  every entry past a frontier cost no copy and no loop turn: the cells
+  of a layer hold neither ``Hkv`` nor the table's width as a factor.
 
 Forward-only by design (decode is inference).  Routing follows
 ops/_pallas.py (platform + static shape gate, no probe); the flag is
@@ -182,12 +191,14 @@ def _rows_last(out, q_shape):
             .reshape(B, Tq, Hq, hd))
 
 
-def _scratch(Rp: int, hd: int):
+def _scratch(Rp: int, hd: int, heads: tuple = ()):
+    """Online-softmax state of one head's Rp rows (``heads``: a leading
+    dim for a cell that holds several heads at once)."""
     from jax.experimental.pallas import tpu as pltpu
 
-    return [pltpu.VMEM((Rp, 1), jnp.float32),     # running max
-            pltpu.VMEM((Rp, 1), jnp.float32),     # running denominator
-            pltpu.VMEM((Rp, hd), jnp.float32)]    # running numerator
+    return [pltpu.VMEM(heads + (Rp, 1), jnp.float32),     # running max
+            pltpu.VMEM(heads + (Rp, 1), jnp.float32),     # running denominator
+            pltpu.VMEM(heads + (Rp, hd), jnp.float32)]    # running numerator
 
 
 def _init_scratch(m_scr, l_scr, acc_scr):
@@ -196,10 +207,11 @@ def _init_scratch(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _finish(o_ref, l_scr, acc_scr):
+def _finish(l_scr, acc_scr, dtype):
+    """The attended rows; zeros where nothing was attended."""
     l = l_scr[...]
     l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
+    return (acc_scr[...] / l_safe).astype(dtype)
 
 
 def _head_col(s_blk, h):
@@ -209,18 +221,11 @@ def _head_col(s_blk, h):
     return jnp.sum(jnp.where(lane == h, s_blk, 0.0), axis=1, keepdims=True)
 
 
-def _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref, ks_ref,
-                  vs_ref, m_scr, l_scr, acc_scr):
+def _attend_block(p_b, base, G, scale, qb, kb, vb, m_scr, l_scr, acc_scr):
     """One KV block of the online-softmax recurrence, shared by the
-    contiguous and the paged kernel: q_ref [1, 1, Rp, hd], k/v_ref
-    [1, BT, hd] (this head's lane chunk), scales [1, BT, Hkv] or None;
-    ``base`` is the block's first LOGICAL cache row."""
-    qb = q_ref[0, 0].astype(jnp.float32)               # [Rp, hd]
-    kb = k_ref[0].astype(jnp.float32)                  # [BT, hd]
-    vb = v_ref[0].astype(jnp.float32)
-    if ks_ref is not None:
-        kb = kb * _head_col(ks_ref[0], h)
-        vb = vb * _head_col(vs_ref[0], h)
+    contiguous and the paged kernel: qb [Rp, hd] and kb / vb [BT, hd] in
+    float32 (an int8 block already times its scales), the state refs one
+    head's; ``base`` is the block's first LOGICAL cache row."""
     s = scale * jax.lax.dot_general(
         qb, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)            # [Rp, BT]
@@ -308,12 +313,17 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
         # skip KV blocks entirely past the causal frontier
         @pl.when(base <= p_b + Tq - 1)
         def _run():
-            _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref,
-                          ks_ref, vs_ref, *scr)
+            kb = k_ref[0].astype(jnp.float32)              # [BT, hd]
+            vb = v_ref[0].astype(jnp.float32)
+            if quant:
+                kb = kb * _head_col(ks_ref[0], h)
+                vb = vb * _head_col(vs_ref[0], h)
+            _attend_block(p_b, base, G, scale,
+                          q_ref[0, 0].astype(jnp.float32), kb, vb, *scr)
 
         @pl.when(ti == nt - 1)
         def _fin():
-            _finish(o_ref, *scr[1:])
+            o_ref[0, 0] = _finish(*scr[1:], o_ref.dtype)
 
     q_spec = pl.BlockSpec((1, 1, Rp, hd),
                           lambda i, t: (i // Hkv, i % Hkv, 0, 0))
@@ -355,8 +365,8 @@ def gather_paged_view(k_pool, tables):
     to block 0 — their rows sit past every causal frontier (the
     allocator maps blocks through the write position), so the garbage is
     masked exactly like a slab's unwritten rows.  THE oracle/fallback
-    materialization; the Pallas path resolves the same table per grid
-    cell instead."""
+    materialization; the Pallas path copies the same table's live pages
+    inside its grid cell instead."""
     idx = jnp.clip(tables, 0, k_pool.shape[0] - 1)          # [B, nmax]
     g = k_pool[idx]                                          # [B,nmax,bs,...]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
@@ -403,10 +413,12 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
     [N, bs, Hkv].  Shapes the static gate rejects take gather + the XLA
     reference; a shape it accepts compiles the kernel.
 
-    The grid cell resolves its T-block THROUGH the table via scalar
-    prefetch, so the HBM read is each slot's mapped blocks only — never
-    a materialized [B, T] gather — and causally-dead or unmapped blocks
-    are skipped."""
+    A grid cell is a slot: it walks the table entries up to its causal
+    frontier as far as they are mapped and copies those pages from the
+    pool in HBM by their physical number, so the HBM read is each slot's
+    live blocks only — never a materialized [B, T] gather — and a free
+    slot, or an entry that is unmapped or past the frontier, is neither
+    copied nor visited.  A slot that attends nothing gives zeros."""
     if not paged_supported(q.shape, k_pool.shape):
         return _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
                           scale)
@@ -414,6 +426,26 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos,
         lambda *a: _paged_call(*a, scale), k_pool.shape[2],
         (q, 2), (k_pool, 2), (v_pool, 2), (tables, None), (pos, None),
         (k_scale, 2), (v_scale, 2))
+
+
+_KV_ROWS = 128           # KV rows a compute block of the paged kernel holds, at least
+_KV_BUF_BYTES = 8 << 20  # of VMEM for a cell's K and V pages, both halves of each
+
+
+def _paged_geometry(bs: int, Hkv: int, hd: int, Rp: int, itemsize: int):
+    """(pages a compute block, KV heads a cell holds at once): enough
+    pages for ``_KV_ROWS`` rows, and every head unless their q rows or
+    their lanes of a compute block outgrow VMEM (then the cell walks its
+    pages once a chunk of heads)."""
+    P = -(-_KV_ROWS // bs)
+
+    def fits(h):
+        return (h * Rp <= _R_CAP
+                and 4 * P * bs * h * hd * itemsize <= _KV_BUF_BYTES)
+
+    Hb = max(h for h in range(1, Hkv + 1)
+             if Hkv % h == 0 and (h == 1 or fits(h)))
+    return P, Hb
 
 
 def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
@@ -429,73 +461,127 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
 
     qh = _rows_first(q, Hkv)
     Rp = qh.shape[2]
+    P, Hb = _paged_geometry(bs, Hkv, hd, Rp, k_pool.dtype.itemsize)
+    KB = P * bs             # KV rows of a compute block
     tab = tables.astype(jnp.int32)
     pos2 = pos.reshape(B).astype(jnp.int32)
 
-    def kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(tab_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref, *scr = rest
+            ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, \
+                *scr = rest
         else:
-            ks_ref = vs_ref = None
-            o_ref, *scr = rest
-        i = pl.program_id(0)
-        ti = pl.program_id(1)
-        b, h = i // Hkv, i % Hkv
+            o_ref, k_buf, v_buf, sem, *scr = rest
+        b = pl.program_id(0)
+        p_b = pos_ref[b]
+        # the table entries this slot walks: those up to its causal
+        # frontier, as far as they are mapped (the allocator maps every
+        # block through the write position; an unmapped entry holds
+        # another tenant's rows).  A free slot walks none.
+        n_front = jnp.clip(jax.lax.div(p_b + Tq + bs - 1, bs), 0, nmax)
+        n_live = jax.lax.fori_loop(
+            0, n_front,
+            lambda j, n: jnp.where((n == j) & (tab_ref[b, j] >= 0), j + 1, n),
+            0)
+        n_groups = jax.lax.div(n_live + P - 1, P)
 
-        @pl.when(ti == 0)
-        def _init():
+        def copies(g, half, c, go):
+            """``go`` on the copy of each live page of group ``g`` (all
+            of chunk ``c``'s heads of the page at once) into buffer
+            ``half``; a page past the last live one is not copied."""
+            lanes = pl.ds(c * Hb * hd, Hb * hd)
+            for j in range(P):
+                @pl.when(g * P + j < n_live)
+                def _page():
+                    page = tab_ref[b, g * P + j]
+                    pairs = [(k_hbm.at[page, :, lanes], k_buf.at[half, j]),
+                             (v_hbm.at[page, :, lanes], v_buf.at[half, j])]
+                    if quant:
+                        pairs += [(ks_hbm.at[page], ks_buf.at[half, j]),
+                                  (vs_hbm.at[page], vs_buf.at[half, j])]
+                    for src, dst in pairs:
+                        go(pltpu.make_async_copy(src, dst, sem.at[half]))
+
+        for c in range(Hkv // Hb):
             _init_scratch(*scr)
 
-        p_b = pos_ref[b]
-        base = ti * bs          # LOGICAL row base of this block
+            @pl.when(n_groups > 0)
+            def _first():
+                copies(0, 0, c, lambda d: d.start())
 
-        # skip blocks past the causal frontier AND unmapped table slots
-        # (an unmapped block holds another tenant's rows; the allocator
-        # maps every block through the write position, so a mapped-but-
-        # stale row is already behind the mask like a slab's)
-        @pl.when((base <= p_b + Tq - 1) & (tab_ref[b, ti] >= 0))
-        def _run():
-            _attend_block(p_b, base, h, G, scale, q_ref, k_ref, v_ref,
-                          ks_ref, vs_ref, *scr)
+            def group(g, carry):
+                half = jax.lax.rem(g, 2)
 
-        @pl.when(ti == nmax - 1)
-        def _fin():
-            _finish(o_ref, *scr[1:])
+                # the next group's copies fly while this one is attended
+                @pl.when(g + 1 < n_groups)
+                def _next():
+                    copies(g + 1, 1 - half, c, lambda d: d.start())
 
-    # the pool block for grid cell (i, t) is resolved THROUGH the
-    # prefetched table: physical block tab[b, t] (clamped — the kernel
-    # body skips the compute for unmapped entries, but the DMA engine
-    # still needs an in-bounds address)
-    def _kv_idx(i, t, tab_ref, pos_ref):
-        pb = jnp.clip(tab_ref[i // Hkv, t], 0, N - 1)
-        return (pb, 0, i % Hkv)
+                copies(g, half, c, lambda d: d.wait())
+                # the last group's pages past the last live one hold what
+                # the buffer held before: their weights are zero, and
+                # zero times that must be zero
+                for j in range(P):
+                    @pl.when(g * P + j >= n_live)
+                    def _stale():
+                        v_buf[half, j] = jnp.zeros(v_buf.shape[2:],
+                                                   v_buf.dtype)
+                        if quant:
+                            vs_buf[half, j] = jnp.zeros(vs_buf.shape[2:],
+                                                        vs_buf.dtype)
+                if quant:
+                    ks = ks_buf[half].reshape(KB, ks_buf.shape[-1])
+                    vs = vs_buf[half].reshape(KB, vs_buf.shape[-1])
+                for h in range(Hb):
+                    hh = c * Hb + h
+                    kb = k_buf[half, :, :, h * hd:(h + 1) * hd].astype(
+                        jnp.float32).reshape(KB, hd)
+                    vb = v_buf[half, :, :, h * hd:(h + 1) * hd].astype(
+                        jnp.float32).reshape(KB, hd)
+                    if quant:
+                        kb = kb * ks[:, hh:hh + 1]
+                        vb = vb * vs[:, hh:hh + 1]
+                    _attend_block(p_b, g * KB, G, scale,
+                                  q_ref[0, hh].astype(jnp.float32), kb, vb,
+                                  *(r.at[h] for r in scr))
+                return carry
 
-    def _ks_idx(i, t, tab_ref, pos_ref):
-        pb = jnp.clip(tab_ref[i // Hkv, t], 0, N - 1)
-        return (pb, 0, 0)
+            jax.lax.fori_loop(0, n_groups, group, 0)
+            for h in range(Hb):
+                o_ref[0, c * Hb + h] = _finish(scr[1].at[h], scr[2].at[h],
+                                               o_ref.dtype)
 
-    q_spec = pl.BlockSpec(
-        (1, 1, Rp, hd),
-        lambda i, t, tab_ref, pos_ref: (i // Hkv, i % Hkv, 0, 0))
-    in_specs = [q_spec, pl.BlockSpec((1, bs, hd), _kv_idx),
-                pl.BlockSpec((1, bs, hd), _kv_idx)]
+    q_spec = pl.BlockSpec((1, Hkv, Rp, hd),
+                          lambda b, tab_ref, pos_ref: (b, 0, 0, 0))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, in_hbm, in_hbm]
     # the kernel's view of the layer's pool, heads folded into the lane
-    # dimension: on the chip a relayout copy of the whole slice, so it is
-    # counted with the pool's gathers, not with the attention
+    # dimension (a page of it is contiguous): on the chip a relayout copy
+    # of the whole slice, so it is counted with the pool's gathers, not
+    # with the attention
     with jax.named_scope("kv_gather"):
         args = [qh, k_pool.reshape(N, bs, Hkv * hd),
                 v_pool.reshape(N, bs, Hkv * hd)]
+    bufs = [pltpu.VMEM((2, P, bs, Hb * hd), k_pool.dtype),
+            pltpu.VMEM((2, P, bs, Hb * hd), v_pool.dtype)]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, Hkv), _ks_idx),
-                     pl.BlockSpec((1, bs, Hkv), _ks_idx)]
-        args += [k_scale, v_scale]
+        # a page is copied whole lanes at a time: the scales' head axis
+        # is padded up to a lane tile (the chip's compiler refuses to
+        # slice a page off an operand whose rows are narrower)
+        with jax.named_scope("kv_gather"):
+            lane_pad = ((0, 0), (0, 0), (0, -Hkv % 128))
+            scales = [jnp.pad(k_scale, lane_pad), jnp.pad(v_scale, lane_pad)]
+        in_specs += [in_hbm, in_hbm]
+        args += scales
+        bufs += [pltpu.VMEM((2, P) + x.shape[1:], x.dtype) for x in scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B * Hkv, nmax),
+        grid=(B,),
         in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=_scratch(Rp, hd),
+        scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2,))]
+        + _scratch(Rp, hd, (Hb,)),
     )
     out = pl.pallas_call(
         kernel,

@@ -36,8 +36,8 @@ Device math lives here too: :func:`paged_decode_step_batched` is the
 pooled twin of ``serving.decode_step_batched`` (einsum fallback =
 per-slot ``generate._cached_block`` on a gathered view — bit-identical
 to the slab path holding the same rows; kernel route =
-``ops/decode_attention.paged_decode_attention``, which resolves each
-T-block through the table inside the grid), and
+``ops/decode_attention.paged_decode_attention``, whose grid cell walks
+one slot's live blocks through the table), and
 :func:`paged_prefill_chunk` is the pooled ``generate.prefill_slot_chunk``.
 The contiguous layout stays the default (``PADDLE_TPU_KV_LAYOUT``).
 """
@@ -203,8 +203,8 @@ def paged_decode_step_batched(params, cache, token, pos,
     bit-identical to a slab holding the same rows.  Kernel route (TPU /
     interpret, ``PADDLE_TPU_FLASH_DECODE``): fresh rows scatter into the
     pool first, then ``ops/decode_attention.paged_decode_attention``
-    streams each slot's mapped blocks through the grid — no [B, T]
-    gather is ever materialized."""
+    copies each slot's live blocks inside that slot's grid cell — no
+    [B, T] gather is ever materialized."""
     from ..ops import decode_attention as da
 
     N, bs, nmax = _geometry(cache)
@@ -264,7 +264,8 @@ def paged_decode_step_batched(params, cache, token, pos,
 def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
     """Kernel route of :func:`paged_decode_step_batched` — the layer
     loop runs at top level so the paged kernel sees the whole batch
-    (grid ``(B*Hkv, nmax)``); the per-slot pre/post math stays vmapped
+    (a grid cell a slot, which walks that slot's live blocks); the
+    per-slot pre/post math stays vmapped
     (norm/projections/rope/MoE routing at the contiguous step's B=1
     shapes)."""
     from ..ops import decode_attention as da

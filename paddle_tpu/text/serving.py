@@ -3969,6 +3969,15 @@ class DecodeServer:
             _telemetry.set_gauge("kv_pool.blocks_in_use", used)
             _telemetry.set_gauge("serving.kv_utilization",
                                  used / max(1, self._pool.N))
+            # the table entries the paged decode kernel walks this step
+            # (each occupied slot's blocks up to its write position),
+            # over all that the tables hold
+            bs = self._pool.bs
+            _telemetry.set_gauge(
+                "kv_pool.walk_share",
+                sum(-(-(st["pos"] + 1) // bs)
+                    for st in self._slots.values())
+                / (self.max_batch * self._pool.nmax))
             _telemetry.set_gauge("kv_pool.host_spill_bytes",
                                  self._pool.host_spill_bytes)
             seen = self._pool.prefix_hits + self._pool.prefix_misses

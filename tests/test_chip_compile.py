@@ -171,21 +171,32 @@ def test_decode_kernel(shape, Hkv, kv, Tq):
     assert n == 1
 
 
+# (slots, query heads, KV heads, pool blocks, rows a block, table entries):
+# this file's widths at both block sizes, then the two chat cells' own
+PAGED_SHAPES = {
+    "bs16": (B, H, H, B * T // 16, 16, T // 16),
+    "bs128": (B, H, H, B * T // 128, 128, T // 128),
+    "gpt1p3b-serve-chat": (32, 16, 16, 3072, 16, 128),
+    "falconh1-serve-chat": (64, 20, 4, 8192, 16, 128),
+}
+
+
 @pytest.mark.parametrize("Tq", [1, 4])
-@pytest.mark.parametrize("bs", [16, 128])
+@pytest.mark.parametrize("pool_shape", list(PAGED_SHAPES))
 @pytest.mark.parametrize("kv", ["bf16", "int8"])
-def test_paged_decode_kernel(shape, kv, bs, Tq):
+def test_paged_decode_kernel(shape, kv, pool_shape, Tq):
     from paddle_tpu.ops import decode_attention as da
 
-    nmax = T // bs
-    q = shape((B, Tq, H, HD), BF)
-    pool = shape((B * nmax, bs, H, HD), BF if kv == "bf16" else I8)
-    sc = shape((B * nmax, bs, H), F32) if kv == "int8" else None
+    slots, Hq, Hkv, N, bs, nmax = PAGED_SHAPES[pool_shape]
+    q = shape((slots, Tq, Hq, HD), BF)
+    pool = shape((N, bs, Hkv, HD), BF if kv == "bf16" else I8)
+    sc = shape((N, bs, Hkv), F32) if kv == "int8" else None
     assert da.paged_supported(q.shape, pool.shape)
     n = kernels_in(
         lambda q, k, v, t, p, a, b: da._paged_call(q, k, v, t, p, a, b,
                                                    None),
-        q, pool, pool, shape((B, nmax), I32), shape((B,), I32), sc, sc)
+        q, pool, pool, shape((slots, nmax), I32), shape((slots,), I32),
+        sc, sc, names=("paged_decode_attention",))
     assert n == 1
 
 

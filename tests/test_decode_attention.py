@@ -638,6 +638,161 @@ def test_spec_serving_flash_verify_greedy_parity(interpret, kv_env):
 
 
 # ---------------------------------------------------------------------------
+# the paged kernel walks each slot's live blocks: a grid cell a slot, the
+# pages of a compute block fetched by their physical number, all KV heads of
+# a page in one copy
+# ---------------------------------------------------------------------------
+
+
+def _paged_case(Hkv, G_, Tq, bs, kv, hd=128, seed=0):
+    """Operands in which every slot is one way a table can look.  The
+    pages of a compute block are ``P``; ``nmax`` is no multiple of it."""
+    P, _ = da._paged_geometry(bs, Hkv, hd, 8, 2)
+    nmax = 2 * P + max(1, P // 2)
+    T = nmax * bs
+    pos = [0,                    # a frontier at row 0
+           bs + 5,               # a frontier inside a page
+           P * bs - Tq,          # the last row of a compute block
+           P * bs,               # the first row of the next one
+           7,                    # a free slot: nothing is mapped
+           T - Tq]               # the whole window, the tail group short
+    B = len(pos)
+    N = B * nmax + 3
+    rng = np.random.default_rng(seed)
+    phys = [int(x) for x in rng.permutation(np.arange(1, N))]  # out of order
+    tables = np.full((B, nmax), -1, np.int32)
+    for b, p in enumerate(pos):
+        if b == 4:
+            continue
+        live = (p + Tq - 1) // bs + 1
+        # some slots map blocks past their frontier, as a slot does that
+        # was given room for its next rows
+        for j in range(min(nmax, live + b % 2)):
+            tables[b, j] = phys.pop()
+    tables[5, 0] = tables[1, 0]       # one block shared by two slots
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (B, Tq, Hkv * G_, hd), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (N, bs, Hkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (N, bs, Hkv, hd), jnp.float32)
+    ksc = vsc = None
+    if kv == "int8":
+        kp, ksc = da.quantize_kv(kp)
+        vp, vsc = da.quantize_kv(vp)
+    else:
+        kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    return (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos, jnp.int32),
+            ksc, vsc)
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("bs", [16, 128])
+@pytest.mark.parametrize("Tq", [1, 4])
+@pytest.mark.parametrize("Hkv,G_", [(16, 1), (4, 5), (1, 2)])
+def test_paged_kernel_matches_oracle(interpret, Hkv, G_, Tq, bs, kv):
+    args = _paged_case(Hkv, G_, Tq, bs, kv)
+    assert da.paged_supported(args[0].shape, args[1].shape)
+    out = np.asarray(da._paged_call(*args, None), np.float32)
+    ref = np.asarray(da._xla_paged(*args, None), np.float32)
+    live = [0, 1, 2, 3, 5]
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-2, rtol=2e-2)
+    # the oracle reads block 0 where nothing is mapped; the kernel
+    # attends nothing there and gives zeros
+    assert (out[4] == 0).all()
+
+
+@pytest.mark.parametrize("Tq", [1, 4])
+def test_paged_kernel_reads_no_block_it_need_not(interpret, Tq):
+    """Blocks that no table maps, and blocks mapped past their slot's
+    frontier, are never copied: filled with NaN they change nothing."""
+    q, kp, vp, tables, pos, _, _ = _paged_case(4, 5, Tq, 16, "bf16")
+    bs = kp.shape[1]
+    t = np.asarray(tables)
+    needed = {int(t[b, j]) for b in range(t.shape[0])
+              for j in range(t.shape[1])
+              if t[b, j] >= 0 and j * bs <= int(pos[b]) + Tq - 1}
+    dead = np.asarray([n for n in range(kp.shape[0]) if n not in needed])
+    assert 0 in dead and len(set(t[t >= 0]) & set(dead)) >= 2
+    clean = np.asarray(da._paged_call(q, kp, vp, tables, pos, None, None,
+                                      None), np.float32)
+    got = np.asarray(da._paged_call(
+        q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan), tables, pos,
+        None, None, None), np.float32)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every pallas_call in ``fn``'s jaxpr."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield tuple(eqn.params["grid_mapping"].grid)
+            for v in eqn.params.values():
+                inner = getattr(v, "jaxpr", v)
+                if hasattr(inner, "eqns"):
+                    yield from walk(inner)
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+def test_paged_grid_is_a_cell_a_slot(kv):
+    """The cells of a layer hold neither the KV heads nor the table's
+    width as a factor (the parent's grid was ``(B * Hkv, nmax)``)."""
+    S = jax.ShapeDtypeStruct
+
+    def grid(B, Hkv, G_, nmax, bs):
+        pool = S((B * nmax, bs, Hkv, 128),
+                 jnp.int8 if kv == "int8" else jnp.bfloat16)
+        sc = S((B * nmax, bs, Hkv), jnp.float32) if kv == "int8" else None
+        (g,) = _pallas_grids(
+            lambda q, k, v, t, p, a, b: da._paged_call(q, k, v, t, p, a, b,
+                                                       None),
+            S((B, 1, Hkv * G_, 128), jnp.bfloat16), pool, pool,
+            S((B, nmax), jnp.int32), S((B,), jnp.int32), sc, sc)
+        return g
+
+    cells = {grid(32, Hkv, G_, nmax, bs)
+             for Hkv, G_ in ((1, 2), (4, 5), (16, 1))
+             for nmax, bs in ((16, 128), (128, 16), (256, 8))}
+    assert cells == {(32,)}
+    assert grid(64, 4, 5, 128, 16) == (64,)
+
+
+def test_paged_geometry_follows_the_shapes():
+    """Pages a compute block: 128 KV rows at least.  Heads a cell holds
+    at once: all of them, until their q rows or their pages outgrow the
+    cell."""
+    assert da._paged_geometry(16, 16, 128, 8, 2) == (8, 16)
+    assert da._paged_geometry(16, 4, 128, 8, 2) == (8, 4)
+    assert da._paged_geometry(128, 16, 128, 8, 1) == (1, 16)
+    assert da._paged_geometry(8, 2, 256, 32, 4) == (16, 2)
+    assert da._paged_geometry(16, 16, 128, 512, 2) == (8, 2)
+    assert da._paged_geometry(16, 16, 128, 1024, 2) == (8, 1)
+    # 256 float32 rows of 32 heads are 4 MB a buffer: half the heads
+    assert da._paged_geometry(256, 32, 128, 8, 4) == (1, 16)
+
+
+def test_paged_kernel_loops_head_chunks_at_many_q_rows(interpret):
+    """Where all heads at once would outgrow the cell, it walks its pages
+    once a chunk of heads: the same answers."""
+    Hkv, G_, Tq, bs = 4, 8, 64, 16            # 512 q rows a KV head
+    assert da._paged_geometry(bs, Hkv, 128, Tq * G_, 4)[1] == 2
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(ks[0], (2, Tq, Hkv * G_, 128), jnp.float32)
+    kp = jax.random.normal(ks[1], (24, bs, Hkv, 128), jnp.float32)
+    vp = jax.random.normal(ks[2], (24, bs, Hkv, 128), jnp.float32)
+    tables = jnp.asarray(np.random.default_rng(3).permutation(24)
+                         .reshape(2, 12), jnp.int32)
+    pos = jnp.asarray([5, 100], jnp.int32)
+    assert da.paged_supported(q.shape, kp.shape)
+    np.testing.assert_allclose(
+        np.asarray(da._paged_call(q, kp, vp, tables, pos, None, None, None)),
+        np.asarray(da._xla_paged(q, kp, vp, tables, pos, None, None, None)),
+        atol=1e-4, rtol=1e-4)
+
+
+# ---------------------------------------------------------------------------
 # no quiet fallback: a refusal reaches the caller, a shape gate picks XLA
 # ---------------------------------------------------------------------------
 

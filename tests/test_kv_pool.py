@@ -445,6 +445,12 @@ def test_kv_utilization_gauge_true_occupancy(markov_gpt):
     used = srv._pool.blocks_in_use
     assert g["serving.kv_utilization"] == pytest.approx(used / 8)
     assert g["kv_pool.blocks_in_use"] == used
+    # the table entries the paged kernel walks: one slot's blocks up to
+    # its write position, of 2 slots x 3 entries
+    (st,) = srv._slots.values()
+    assert g["kv_pool.walk_share"] == pytest.approx(
+        -(-(st["pos"] + 1) // 8) / (2 * 3))
+    assert 0 < g["kv_pool.walk_share"] < 1
     srv.close()
     # contiguous: rows denominator is the rounded allocation (24), not
     # max_len (20)
