@@ -4,8 +4,8 @@ Reference capability: the reference hardens its allocator/executor stack
 with retry-on-OOM chains and nan/inf guards but (like most production
 frameworks) tests them with hand-built failure drills; this module makes
 the drills a first-class, deterministic runtime feature so the chaos
-suite (tests/test_resilience.py) and the CI bench smoke can assert the
-recovery paths instead of hoping.
+suite (tests/test_resilience.py) can assert the recovery paths instead
+of hoping.
 
 Spec grammar (``PADDLE_TPU_FAULTS``)::
 

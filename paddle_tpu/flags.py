@@ -321,8 +321,8 @@ def kv_radix() -> bool:
     under an extra refcount; the adopter's first write copies it through
     the normal COW drain) so admission adopts the longest *token*
     prefix.  ``PADDLE_TPU_KV_RADIX=0`` restores the whole-block
-    matching — the A/B baseline ``bench.py --config prefix`` measures
-    against.  Host-side index bookkeeping only — adoption depth changes
+    matching (tests/test_kv_pool.py compares the two hit rates).
+    Host-side index bookkeeping only — adoption depth changes
     which rows prefill recomputes, never the compiled programs, so this
     is NOT part of any jit-cache key."""
     v = os.environ.get("PADDLE_TPU_KV_RADIX", "1").strip().lower()
@@ -406,8 +406,7 @@ def fleet_tick_block() -> int:
     """Decode steps per replica tick in the fleet router's serve loop
     (``PADDLE_TPU_FLEET_TICK_BLOCK``, default 1): >1 routes each
     replica's tick through ``tick_block(k)`` — fewer host round trips
-    per token at block-granular retirement, the bench's serving
-    lever.  Host scheduling only."""
+    per token at block-granular retirement.  Host scheduling only."""
     try:
         return max(1, int(os.environ.get("PADDLE_TPU_FLEET_TICK_BLOCK",
                                          "1")))

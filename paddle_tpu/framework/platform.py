@@ -10,9 +10,9 @@ _COUNT_FLAG = re.compile(r"--xla_force_host_platform_device_count=(\d+)")
 
 # Per-chip peaks keyed on a ``device_kind`` substring (lowercased match):
 # (dense-MXU bf16 peak FLOPs/s, HBM bandwidth bytes/s).  The single source
-# of truth for every MFU / roofline computation — bench.py and
-# telemetry's device feed both read it, so a headline MFU and the live
-# gauge can never disagree about what "peak" means.  There is
+# of truth for every MFU / roofline computation inside the program
+# (telemetry's device feed; the benchmark keeps a copy with its source
+# as benchmarks/peaks.json).  There is
 # deliberately NO catch-all TPU entry: a TPU whose kind is not listed is
 # an error wherever an MFU or a roofline is computed, not a guess.
 DEVICE_PEAKS: dict = {

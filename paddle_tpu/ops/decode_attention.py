@@ -102,14 +102,14 @@ def dequantize_kv(q, s, dt):
 def random_filled_cache(cache: dict, key, amp: float = 1.0) -> dict:
     """A ``generate.init_cache`` tree filled with synthetic normal K/V
     (scaled by ``amp``), quantizing through the real format when the
-    cache carries scale planes — THE cache-format-aware fill the bench
-    and on-device certification share (one copy; a format change edits
+    cache carries scale planes — THE cache-format-aware fill the tests
+    share (one copy; a format change edits
     exactly here).
 
     Paged caches (``text/kv_pool.py`` trees with a ``tables`` leaf) fill
     the whole [L, N, bs, Hkv, hd] pool and, when the tables are still
     unmapped (-1), lay slots out identity-style (slot b owns blocks
-    [b*nmax, (b+1)*nmax)) so the kernel-parity oracle and bench arms
+    [b*nmax, (b+1)*nmax)) so the kernel-parity oracle and the tests
     exercise real block-table gathers without a host allocator."""
     ks = jax.random.split(key, 2)
     kf = jax.random.normal(ks[0], cache["k"].shape) * amp

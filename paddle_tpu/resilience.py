@@ -6,8 +6,8 @@ fallbacks (auto-growth best-fit -> garbage collect -> synchronous free ->
 retry, PAPER.md §L1) instead of killing the process, and error-clip /
 check_nan_inf guard training from one bad batch.  This module is the
 TPU-native equivalent at RUNTIME granularity: the schedulers and loops
-that sit above XLA (DecodeServer ticks, Model.fit steps, the probe/bench
-infra) get one shared vocabulary of
+that sit above XLA (DecodeServer ticks, Model.fit steps) get one shared
+vocabulary of
 
 * :func:`retry` — bounded attempts with capped exponential backoff and
   DETERMINISTIC jitter (seeded, so chaos tests can assert the exact

@@ -455,8 +455,8 @@ def admission_snapshot() -> dict:
 
 def reset() -> None:
     """Drop every histogram/gauge/event/compile record and this module's
-    counters (tests; bench arms isolate their snapshots with this).  The
-    rest of the monitor registry is left alone."""
+    counters (tests and the benchmark isolate their windows with this).
+    The rest of the monitor registry is left alone."""
     global _log_fh, _log_path
     with _lock:
         _hists.clear()
@@ -1335,8 +1335,7 @@ def snapshot() -> dict:
 
 def latency_summary(prefix: str = "serving.") -> dict:
     """Compact {short_name: {count, p50, p99}} over histograms under
-    ``prefix`` — the ``telemetry`` block bench arms embed in their JSON
-    lines, so BENCH_*.json captures latency distributions, not means."""
+    ``prefix``: distributions, not means."""
     with _lock:
         hists = sorted(_hists.items())
     out = {}

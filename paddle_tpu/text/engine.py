@@ -1156,8 +1156,8 @@ class Engine:
         self._steps = _LRU(
             int(_os.environ.get("PADDLE_TPU_STEP_CACHE_SIZE", "64")))
         # generous defaults: eviction only matters for servers cycling
-        # many model configs; a tournament of bench rungs stays far
-        # under the bound
+        # many model configs; one served model stays far under the
+        # bound
         self._gen = _LRU(
             int(_os.environ.get("PADDLE_TPU_GEN_CACHE_SIZE", "64")))
 

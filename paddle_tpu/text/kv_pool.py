@@ -812,7 +812,7 @@ class PagedAllocator:
         self.rss_limit_bytes = _flags.kv_spill_rss_mb() << 20
         self._spilled: dict = {}   # full chain tokens -> (host rows, nbytes)
         self._pending_restores: list = []    # [(slot, start, rows, block)]
-        # host mirrors of the telemetry counters (tests/bench read these
+        # host mirrors of the telemetry counters (tests read these
         # without the registry)
         self.prefix_hits = 0
         self.prefix_misses = 0

@@ -672,7 +672,7 @@ class DecodeServer:
         self._self_draft = self._spec_on and draft_cfg is None
         self._min_accept = _flags.spec_min_accept()
         # server-level speculation accounting (load_stats / the
-        # acceptance-rate gauge / bench's target-passes-per-token)
+        # acceptance-rate gauge / the tests' target passes per token)
         self._spec_prop = 0         # proposals scored by the target
         self._spec_acc = 0          # ... of those, accepted
         self._spec_rounds = 0       # batched verify dispatches
@@ -2838,7 +2838,7 @@ class DecodeServer:
         batched draft steps), ONE batched target verify over every
         slot, host-side acceptance, retire.  The verify is the round's
         only target pass — up to K tokens per slot for one pass, the
-        multiplier the spec bench arm measures.  Rejected verify rows
+        multiplier the tests count as target passes.  Rejected verify rows
         land at/past each slot's new position pointer where the
         stale-row invariant already hides them (the same rule as
         warmup garbage and slot reuse), so acceptance needs no masked

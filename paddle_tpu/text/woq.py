@@ -227,9 +227,10 @@ def mm(h, p: dict, name: str, dt):
     """``h @ w(p, name, dt)`` with a fused-kernel fast path.
 
     When ``name`` resolves to a nibble-packed int4 2-D weight, the env
-    flag ``PADDLE_TPU_W4_KERNEL=1`` is set (the bench flips it on only
-    under fresh on-device certification — a compiling-but-wrong kernel
-    must never serve tokens), and no LoRA adapter is attached, the
+    flag ``PADDLE_TPU_W4_KERNEL=1`` is set (off by default: only
+    ``chip_smoke.py``'s kernels phase has held it to its oracle on the
+    chip — a compiling-but-wrong kernel must never serve tokens), and no
+    LoRA adapter is attached, the
     matmul runs through the Pallas W4 kernel (ops/woq_matmul.py): the
     packed bytes stream through VMEM and no dequantized bf16 copy is
     ever written to HBM.  Every other case — float weights, per-channel

@@ -502,6 +502,50 @@ def test_warmup_covers_adapter_and_constraint_paths():
     assert final == before
 
 
+def test_two_adapters_and_a_json_constraint_on_one_warm_server():
+    """The tier-1 round of the three properties above on ONE server (the
+    per-property tests are marked slow): after ``warmup(sample=True,
+    constrained=True)`` a batch of two adapters and one JSON-schema
+    constrained request gives each adapter slot its merged-tree solo
+    tokens, parseable JSON of the schema's shape, and no new step-cache
+    key."""
+    cfg = _cfg()
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    ads = {"prod-a": _mk_adapter(params, cfg, jax.random.PRNGKey(1),
+                                 scale=0.3),
+           "prod-b": _mk_adapter(params, cfg, jax.random.PRNGKey(2),
+                                 scale=0.3)}
+    pool = A.AdapterPool(params, cfg, rank=4, max_adapters=2)
+    for name, ad in ads.items():
+        pool.register(name, ad)
+    rng = np.random.default_rng(7)
+    prompts = {name: [int(x) for x in rng.integers(1, 30, 5)]
+               for name in ads}
+    spec = A.JsonSchemaConstraint(
+        {"type": "object", "properties": {"ok": {"type": "boolean"}}},
+        _VOCAB)
+
+    srv = serving.DecodeServer(params, cfg, max_batch=3, max_len=64,
+                               adapter_pool=pool)
+    srv.warmup(sample=True, constrained=True)
+    before = set(serving._STEP_CACHE.keys())
+    rids = {name: srv.submit(prompts[name], max_new_tokens=10,
+                             adapter=name) for name in ads}
+    rid_c = srv.submit([int(x) for x in rng.integers(1, 30, 4)],
+                       max_new_tokens=20, constraint=spec)
+    while srv.pending():
+        srv.tick()
+    got = {name: srv.result(r) for name, r in rids.items()}
+    text = "".join(_VOCAB[t] for t in srv.result(rid_c))
+    added = set(serving._STEP_CACHE.keys()) - before   # before close()
+    srv.close()
+    for name, ad in ads.items():
+        assert got[name] == _greedy_reference(
+            lora.join_lora(params, ad), cfg, prompts[name], 10), name
+    assert isinstance(json.loads(text)["ok"], bool), text
+    assert added == set()
+
+
 def test_load_stats_reports_tenant_shape():
     cfg = _cfg()
     params = gpt.init_params(cfg, jax.random.PRNGKey(0))

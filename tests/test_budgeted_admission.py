@@ -102,7 +102,8 @@ def test_budgeted_matches_monolithic(cfg_params, layout, mode, budget):
     got = _serve(params, cfg, prompts, budget, mode=mode, **kw)
     assert got == ref
     assert _count("serving.admitting_claims") >= 1
-    assert _count("serving.prefill_chunks_interleaved") >= 2
+    # the 40-token prompt walks ceil(40 / budget) chunks, each counted
+    assert _count("serving.prefill_chunks_interleaved") >= -(-40 // budget)
 
 
 def test_budget_wider_than_prompt_stays_monolithic(cfg_params):
@@ -392,9 +393,9 @@ def test_below_threshold_long_coschedules_locally(cfg_params):
 
 def test_fleet_mixed_gap_bounded_without_workers(cfg_params):
     """The mixed-workload gap bound with workers ABSENT, stated as the
-    schedule property that produces it (wall-clock bounds live in
-    ``bench.py --config mixed``, which asserts the measured >=5x):
-    while the long prompt is admitting on a budgeted no-worker fleet,
+    schedule property that produces it (a wall-clock bound on the CPU
+    is no result, and the chip has no mixed-queue cell yet: ROADMAP
+    R-W2): while the long prompt is admitting on a budgeted no-worker fleet,
     the co-scheduled short request KEEPS GENERATING — with monolithic
     admission, zero tokens can land during the prefill by construction
     (the whole walk runs inside one replica tick)."""

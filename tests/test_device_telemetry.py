@@ -2,10 +2,9 @@
 analysis captured at ``telemetry.instrument_compile`` time, live MFU /
 roofline gauges, HBM sampling on the serving/fit hot paths (zero extra
 device syncs — the PR-2/PR-4 pins re-asserted), the /healthz and
-POST /profile endpoints, the bench provenance block schema, and the
-``tools/check_instrumented.py`` watchtower.
+POST /profile endpoints, and the ``tools/check_instrumented.py``
+watchtower.
 """
-import importlib.util
 import json
 import os
 import urllib.request
@@ -23,8 +22,6 @@ from paddle_tpu.framework import monitor, platform as fw_platform
 from paddle_tpu.hapi import Model
 from paddle_tpu.hapi import model as hapi_model
 from paddle_tpu.text import gpt, serving
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -378,42 +375,3 @@ class TestEndpoints:
         assert not (tmp_path / "attacker").exists()  # dir param ignored
         assert any(fs for _, _, fs in os.walk(resp["trace_dir"]))
 
-
-class TestProvenance:
-    @pytest.fixture()
-    def bench(self):
-        spec = importlib.util.spec_from_file_location(
-            "bench_prov_test", os.path.join(REPO, "bench.py"))
-        m = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(m)
-        return m
-
-    def test_provenance_schema(self, bench):
-        prov = bench._provenance(jax.devices()[0])
-        assert sorted(prov) == sorted(bench._PROVENANCE_KEYS)
-        assert prov["platform"] == "cpu"  # conftest pins CPU
-        assert prov["jax"] == jax.__version__
-        for gone in ("fallback_reason", "probe_wedge",
-                     "certified_families"):
-            assert gone not in prov
-        assert isinstance(prov["flags"], dict)
-        json.dumps(prov)  # must be JSON-line safe
-
-    def test_stamp_keeps_an_existing_block(self, bench):
-        rec = {"metric": "m", "provenance": {"platform": "tpu"}}
-        bench._stamp_provenance(rec, jax.devices()[0])
-        assert rec["provenance"] == {"platform": "tpu"}
-        fresh = bench._stamp_provenance({"metric": "m"}, jax.devices()[0])
-        assert fresh["provenance"]["platform"] == "cpu"
-
-    def test_unknown_device_kind_is_an_error_off_tpu_null(self, bench):
-        class _D:
-            platform = "tpu"
-            device_kind = "TPU vNext prototype"
-        with pytest.raises(ValueError, match="DEVICE_PEAKS"):
-            bench._peak_flops(_D())
-        assert bench._peak_flops(jax.devices()[0]) is None  # the CPU
-        assert bench._mfu_fields(None) == {"mfu": None,
-                                           "vs_baseline": 0.0}
-        f = bench._mfu_fields(0.45)
-        assert f["mfu"] == 0.45 and f["vs_baseline"] == 1.0

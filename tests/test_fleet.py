@@ -78,7 +78,8 @@ def _drive(router, prompts, max_new=6, timeout_s=120.0):
     deadline = time.time() + timeout_s
     while router.pending() and time.time() < deadline:
         router.tick()
-        if not any(r._slots or r._queue for r in router.replicas):
+        if not any(r._slots or r._queue for r in router.replicas
+                   if r is not None):
             # nothing decoding: the fleet is waiting on a prefill
             # worker thread — don't spin the tick loop dry
             time.sleep(0.002)
@@ -1099,7 +1100,8 @@ def test_autoscale_drill_out_then_in(fleet_env, cfg_params):
     """The telemetry-driven scaling loop end to end: sustained
     admission rung >= threshold attaches the registered spare
     (fleet.scale_outs), sustained idle drains it back to the pool
-    (fleet.scale_ins) — debounced, never flapping on one hot tick."""
+    (fleet.scale_ins) — debounced, never flapping on one hot tick; what
+    is left serves the same tokens as one server."""
     fleet_env(PADDLE_TPU_FLEET_AUTOSCALE="1",
               PADDLE_TPU_FLEET_SCALE_RUNG="2",
               PADDLE_TPU_FLEET_SCALE_OUT_TICKS="2",
@@ -1128,7 +1130,11 @@ def test_autoscale_drill_out_then_in(fleet_env, cfg_params):
     assert _count("fleet.scale_ins") == 1
     assert router._spares == [spare]     # drained back to the pool
     assert int(tl.gauge("fleet.replicas").get()) == 1
+    # the drilled fleet still serves bit for bit
+    prompts = _prompts(seed=31)
+    got = _drive(router, prompts)        # a removed replica is a None
     router.close()
+    assert got == _single(params, cfg, prompts)
 
 
 def test_chain_migration_follows_the_prompt(fleet_env):

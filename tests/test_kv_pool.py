@@ -247,8 +247,8 @@ def test_round_len_whole_blocks():
 
 
 def _serve(params, cfg, prompts, layout, max_new=6, tick="tick",
-           async_=False, **kw):
-    srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=32,
+           async_=False, max_len=32, **kw):
+    srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=max_len,
                                layout=layout, async_dispatch=async_, **kw)
     rids = [srv.submit(p, max_new_tokens=max_new) for p in prompts]
     while srv.pending():
@@ -344,6 +344,28 @@ def test_cow_on_fully_shared_prompt(markov_gpt):
     assert stats["cow_copies"] >= 1
     ref, _ = _serve(params, cfg, [prompt, prompt], "contiguous")
     assert out == ref
+
+
+def test_paged_peak_resident_blocks_at_most_half_the_slab():
+    """What the layout is for: the slab provisions ``max_len`` rows for
+    EVERY slot, the pool maps blocks as rows are written.  A mixed batch
+    of 9-13-token prompts behind a shared 8-token prefix, 6 tokens
+    generated each, on two slots of 64 rows: same tokens as the slab, a
+    prefix hit, and never more than half the slab's blocks mapped."""
+    cfg = _cfg(vocab_size=128, hidden_size=64)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(2)
+    shared = [int(x) for x in rng.integers(1, 100, 8)]
+    prompts = [shared + [int(x) for x in rng.integers(1, 100, n)]
+               for n in (3, 5, 1)]
+
+    kw = dict(tick="block", max_len=64, block_size=8)
+    cont, _ = _serve(params, cfg, prompts, "contiguous", **kw)
+    paged, stats = _serve(params, cfg, prompts, "paged", **kw)
+    assert paged == cont
+    assert stats["prefix_hits"] >= 1
+    slab_blocks = 2 * (kv_pool.round_len(64, 8) // 8)
+    assert stats["peak_blocks_in_use"] <= slab_blocks // 2, stats
 
 
 def test_pool_exhaustion_queues_until_blocks_free(markov_gpt):
@@ -751,6 +773,81 @@ def test_spill_restore_bit_parity(kv_env, kv, mode, markov_gpt):
     assert warm == cold == ref[0]
     assert stats["restored_blocks"] >= 2
     assert saved >= 0.9 * (len(prompt) - 1)
+
+
+def test_radix_beats_block_matching_and_spill_cycles_add_no_executable(
+        kv_env):
+    """A 20-token preamble over 8-token blocks diverges MID-BLOCK: whole
+    blocks (``PADDLE_TPU_KV_RADIX=0``) can share 16 tokens, the radix
+    split all 20, so its prefix hit rate is strictly higher, with tokens
+    equal to the slab's in both arms.  Then two spill -> restore cycles
+    of one prompt on one server: the same tokens each time, 90% of the
+    re-prefill rows adopted from restored blocks, and the second cycle
+    adds no step-cache key (restoring goes through executables the first
+    cycle already built)."""
+    cfg = _cfg(vocab_size=128, hidden_size=64)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    rng = np.random.default_rng(11)
+    pre = [int(x) for x in rng.integers(1, 100, 20)]
+    prompts = [pre + [int(x) for x in rng.integers(1, 100, 4)]
+               for _ in range(3)]
+
+    def serve(layout, radix):
+        kv_env(PADDLE_TPU_KV_RADIX=radix, PADDLE_TPU_KV_SPILL_MB=None)
+        srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=40,
+                                   layout=layout, block_size=8)
+        toks = []
+        for p in prompts:                # one at a time: later ones adopt
+            rid = srv.submit(p, max_new_tokens=6)
+            while srv.pending():
+                srv.tick()
+            toks.append(srv.result(rid))
+        stats = srv._pool.stats() if srv._pool is not None else None
+        srv.close()
+        return toks, stats
+
+    def rate(st):
+        return st["prefix_hits"] / max(
+            1, st["prefix_hits"] + st["prefix_misses"])
+
+    cont, _ = serve("contiguous", "1")
+    tok_radix, s_radix = serve("paged", "1")
+    tok_block, s_block = serve("paged", "0")
+    assert tok_radix == cont and tok_block == cont
+    assert s_radix["radix_splits"] >= 1
+    assert rate(s_radix) > rate(s_block), (s_radix, s_block)
+
+    kv_env(PADDLE_TPU_KV_RADIX="1", PADDLE_TPU_KV_SPILL_MB="4")
+    srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=40,
+                               layout="paged", block_size=8)
+    pool, prompt = srv._pool, prompts[0]
+
+    def cycle():
+        rid = srv.submit(prompt, max_new_tokens=6)
+        while srv.pending():
+            srv.tick()
+        first = srv.result(rid)
+        for _ in range(16):              # demote the whole cold chain
+            if not pool._interned:
+                break
+            srv._evict_or_spill(8)
+        hits0 = pool.prefix_hits
+        rid = srv.submit(prompt, max_new_tokens=6)
+        while srv.pending():
+            srv.tick()
+        return first, srv.result(rid), pool.prefix_hits - hits0
+
+    first, again, saved = cycle()
+    assert first == cont[0] and again == first
+    st = pool.stats()
+    assert st["spilled_blocks"] >= 1 and st["restored_blocks"] >= 1, st
+    assert saved >= 0.9 * (len(prompt) - 1)
+    keys0 = set(serving._STEP_CACHE.keys())
+    first2, again2, _ = cycle()
+    added = set(serving._STEP_CACHE.keys()) - keys0   # before close()
+    srv.close()
+    assert first2 == first and again2 == first
+    assert added == set()
 
 
 def test_oom_fault_spills_cold_prefix_with_parity(kv_env, markov_gpt):

@@ -854,17 +854,31 @@ def test_resilience_lint_repo_clean():
 
 
 # ---------------------------------------------------------------------------
-# bench smoke round (the CI wiring itself)
+# one injected-fault round: the OOM chain and a deadline shed on one model
 # ---------------------------------------------------------------------------
 
-def test_bench_resilience_smoke():
-    import sys
+def test_injected_oom_and_deadline_round():
+    """One OOM injected on a serving tick: the retry chain engages AND
+    the requests finish with tokens bit-identical to a fault-free pass;
+    then an impossible TTL on a request queued behind two saturated
+    slots: the next tick sheds it with the timeout status while the
+    active requests keep decoding.  Both engaged counters are read."""
+    cfg = _cfg(vocab_size=128, hidden_size=64)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(1).integers(1, 100, (2, 5))
 
-    sys.path.insert(0, str(__import__("pathlib").Path(
-        __file__).resolve().parents[1]))
-    import bench
+    clean = _serve(cfg, params, prompts)
+    tl.reset()
+    faulted = _serve(cfg, params, prompts, spec="oom:tick:2")
+    assert faulted == clean
+    assert _count("resilience.oom_retries") >= 1
 
-    rec = bench._resilience_smoke()
-    assert rec["ok"]
-    assert rec["oom_retries"] >= 1
-    assert rec["deadline_sheds"] >= 1
+    srv = serving.DecodeServer(params, cfg, max_batch=2, max_len=32)
+    for p in prompts:
+        srv.submit(p, max_new_tokens=8)
+    rid = srv.submit(prompts[0], max_new_tokens=4, ttl_s=0.001)
+    time.sleep(0.01)
+    while srv.pending():
+        srv.tick()
+    assert srv.status(rid) == "timeout"
+    assert _count("resilience.deadline_sheds") >= 1

@@ -293,6 +293,44 @@ class TestFitPrefetch:
         assert len(hist) <= 20
 
 
+class TestFitAllThree:
+    def test_accum_async_prefetch_fit_matches_sync_loop(self):
+        """In-jit accumulation, device-resident losses and prefetch AT
+        ONCE, through ``Model.prepare(grad_accum=)`` on a token LM (int
+        inputs through an Embedding): after two epochs the parameters
+        equal the synchronous ``grad_accum=1`` loop's and every epoch's
+        loss is finite.  The tests above turn one of the three on at a
+        time."""
+        vocab, hidden, T = 64, 32, 16
+        toks = np.random.default_rng(0).integers(0, vocab, (24, T + 1))
+        X, Y = toks[:, :-1].astype(np.int32), toks[:, 1:].astype(np.int64)
+
+        def run(grad_accum, async_, prefetch):
+            paddle.seed(0)
+            net = nn.Sequential(nn.Embedding(vocab, hidden),
+                                nn.Linear(hidden, hidden), nn.GELU(),
+                                nn.LayerNorm(hidden),
+                                nn.Linear(hidden, vocab))
+            m = Model(net)
+            m.prepare(AdamW(learning_rate=1e-3,
+                            parameters=net.parameters()),
+                      F.cross_entropy, grad_accum=grad_accum,
+                      async_metrics=async_)
+            hist = m.fit((X, Y), batch_size=8, epochs=2, verbose=0,
+                         shuffle=False,
+                         prefetch_factor=4 if prefetch else 0)
+            return hist, {k: np.asarray(p.value)
+                          for k, p in net.named_parameters()}
+
+        _, sync_p = run(1, async_=False, prefetch=False)
+        over_hist, over_p = run(2, async_=True, prefetch=True)
+        assert len(over_hist) == 2
+        assert all(np.isfinite(h["loss"]) for h in over_hist), over_hist
+        for k in sync_p:
+            np.testing.assert_allclose(over_p[k], sync_p[k], rtol=1e-4,
+                                       atol=1e-5, err_msg=k)
+
+
 class TestBucketedApply:
     def _tree(self, seed=0):
         rng = np.random.default_rng(seed)

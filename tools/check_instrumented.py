@@ -36,8 +36,8 @@ accept/propose/fallback path in ``text/serving.py`` (name contains
 ``spec_accept``/``spec_propose``/``spec_fallback``) must count a
 ``spec.*`` telemetry counter or delegate to another marker-named
 callable — the acceptance rate IS the signal that decides whether
-speculation pays for itself (the fallback knob, the bench arm, the
-router gauge), so an uncounted accept/reject path silently skews it.
+speculation pays for itself (the fallback knob, the router gauge),
+so an uncounted accept/reject path silently skews it.
 
 Usage: ``python tools/check_instrumented.py [repo_root]`` — exits 1 and
 lists ``file:line`` for every unrouted site.  ``tests/
@@ -64,7 +64,6 @@ SCAN = (
 # resilience lint scope: everywhere retry loops / shed sites live
 RESIL_SCAN = (
     "paddle_tpu",
-    "bench.py",
     "tools",
 )
 
@@ -105,13 +104,13 @@ TRACE_MARKERS = ("handoff", "migrate", "adopt", "reroute", "drain")
 # Speculative-decoding lint (round 11, same rule family): every spec
 # accept/propose/fallback path in text/serving.py must count a spec.*
 # telemetry counter (directly, or by delegating to another marker-named
-# callable) — the acceptance rate drives the fallback knob, the bench
-# arm's passes-per-token, and the router's per-replica gauge, so a
-# silent accept/reject path skews the very signal that decides whether
-# speculation pays for itself.  Round 17 extends the marker family to
-# the tree round: every tree propose/accept and constrained branch-
-# prune path must count (spec.tree_nodes_proposed / tree_nodes_accepted
-# / tree_pruned_constrained) — the accepted-path-length gauge and the
+# callable) — the acceptance rate drives the fallback knob and the
+# router's per-replica gauge, so a silent accept/reject path skews the
+# very signal that decides whether speculation pays for itself.
+# Round 17 extends the marker family to the tree round: every tree
+# propose/accept and constrained branch-prune path must count
+# (spec.tree_nodes_proposed / tree_nodes_accepted /
+# tree_pruned_constrained) — the accepted-path-length gauge and the
 # fallbacks==0 contract for constrained workloads hang off exactly
 # these sites.
 SPEC_FILE = os.path.join("paddle_tpu", "text", "serving.py")
