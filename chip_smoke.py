@@ -251,29 +251,36 @@ def phase_kernels(sz: Sizes, seed: int):
                 name, da._decode_call(q, kc, vc, pos, ksc, vsc, None),
                 da._xla_decode(q, kc, vc, pos, ksc, vsc, None), 2e-2)
 
-    # paged decode attention through shuffled block tables
+    # paged decode attention through shuffled block tables: the kernel is
+    # handed the pool's whole leaf [L, N, bs, H*hd] and a layer's number
+    # (the larger block size reads layer 1), the reference that layer alone
     for kv in ("bf16", "int8"):
-        for bs in (16, 128):
+        for li, bs in enumerate((16, 128)):
             if T % bs:
                 continue
             nmax = T // bs
             nblk = B * nmax
             ks = jax.random.split(jax.random.fold_in(key, 20 + bs), 3)
             q = jax.random.normal(ks[0], (B, 1, H, hd), bf)
-            kp = jax.random.normal(ks[1], (nblk, bs, H, hd), bf)
-            vp = jax.random.normal(ks[2], (nblk, bs, H, hd), bf)
-            ksc = vsc = None
+            kp = jax.random.normal(ks[1], (2, nblk, bs, H, hd), bf)
+            vp = jax.random.normal(ks[2], (2, nblk, bs, H, hd), bf)
+            ksc = vsc = ks1 = vs1 = None
             if kv == "int8":
                 kp, ksc = da.quantize_kv(kp)
                 vp, vsc = da.quantize_kv(vp)
+                ks1, vs1 = ksc[li:li + 1], vsc[li:li + 1]
+            kp, vp = (x.reshape(2, nblk, bs, H * hd) for x in (kp, vp))
             tables = jnp.asarray(rng.permutation(nblk).reshape(B, nmax),
                                  jnp.int32)
             pos = jnp.asarray(np.linspace(T // 2, T - 1, B), jnp.int32)
             assert da.paged_supported(q.shape, kp.shape)
             name = f"paged {kv} bs{bs}"
             errs[name] = _close(
-                name, da._paged_call(q, kp, vp, tables, pos, ksc, vsc, None),
-                da._xla_paged(q, kp, vp, tables, pos, ksc, vsc, None), 2e-2)
+                name, da._paged_call(q, kp, vp, tables, pos,
+                                     jnp.asarray(li, jnp.int32), ksc, vsc,
+                                     None),
+                da._xla_paged(q, kp[li:li + 1], vp[li:li + 1], tables, pos,
+                              0, ks1, vs1, None), 2e-2)
 
     for name, e in errs.items():
         log(f"[kernels] {name}: max abs err {e:.3g}")

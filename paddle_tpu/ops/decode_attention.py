@@ -21,11 +21,17 @@ VMEM in T-blocks with the same online-softmax recurrence as
 * **int8 cache**: per-(position, head) scales (``quantize_kv``) dequantize
   inside the kernel right after the VMEM load — HBM reads a quarter of
   the fp32 bytes, and no dequantized copy is ever written back;
-* **paged kernel: a grid cell a slot** (``_paged_call``).  The pool stays
-  in HBM; a cell loops over its slot's compute blocks only as far as the
-  causal frontier and the mapped table entries reach, copies each live
-  page by its physical number (all KV heads of the page in one copy, the
-  next block's copies in flight while this one is attended) and runs the
+* **paged kernel: a grid cell a slot** (``_paged_call``).  Its operand is
+  the pool's whole K (or V) leaf ``[L, N, bs, Hkv*hd]`` (every layer's
+  pages, the heads of a row side by side in the lanes: a page is one
+  contiguous ``[bs, Hkv*hd]`` tile run) and a layer number that rides the
+  scalar prefetch beside the tables and the positions.  The leaf stays
+  in HBM and is addressed by (layer, page): no layer's slice of it is
+  cut out, copied or re-laid-out for the kernel.  A cell loops over its
+  slot's compute blocks only as far as the causal frontier and the
+  mapped table entries reach, copies each live page by its layer and
+  physical number (all KV heads of the page in one copy, the next
+  block's copies in flight while this one is attended) and runs the
   same recurrence a head at a time.  A free slot, an unmapped entry and
   every entry past a frontier cost no copy and no loop turn: the cells
   of a layer hold neither ``Hkv`` nor the table's width as a factor.
@@ -107,7 +113,9 @@ def random_filled_cache(cache: dict, key, amp: float = 1.0) -> dict:
     exactly here).
 
     Paged caches (``text/kv_pool.py`` trees with a ``tables`` leaf) fill
-    the whole [L, N, bs, Hkv, hd] pool and, when the tables are still
+    the whole [L, N, bs, Hkv*hd] pool (a scale of the int8 format is one
+    head's: the leaf is quantized through its per-head view) and, when
+    the tables are still
     unmapped (-1), lay slots out identity-style (slot b owns blocks
     [b*nmax, (b+1)*nmax)) so the kernel-parity oracle and the tests
     exercise real block-table gathers without a host allocator."""
@@ -115,9 +123,11 @@ def random_filled_cache(cache: dict, key, amp: float = 1.0) -> dict:
     kf = jax.random.normal(ks[0], cache["k"].shape) * amp
     vf = jax.random.normal(ks[1], cache["v"].shape) * amp
     if "k_s" in cache:
-        k, k_s = quantize_kv(kf)
-        v, v_s = quantize_kv(vf)
-        out = dict(cache, k=k, v=v, k_s=k_s, v_s=v_s)
+        per_head = cache["k_s"].shape + (-1,)
+        k, k_s = quantize_kv(kf.reshape(per_head))
+        v, v_s = quantize_kv(vf.reshape(per_head))
+        out = dict(cache, k=k.reshape(kf.shape), v=v.reshape(vf.shape),
+                   k_s=k_s, v_s=v_s)
     else:
         out = dict(cache, k=kf.astype(cache["k"].dtype),
                    v=vf.astype(cache["v"].dtype))
@@ -333,7 +343,10 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
                      memory_space=pltpu.SMEM),
         q_spec, kv_spec, kv_spec,
     ]
-    with jax.named_scope("kv_gather"):   # see _paged_call
+    # the kernel's view of the slab, heads folded into the lane dimension:
+    # on the chip a relayout copy of the cache, so it is counted with the
+    # cache's gathers, not with the attention
+    with jax.named_scope("kv_gather"):
         args = [pos3, qh, k.reshape(B, T, Hkv * hd),
                 v.reshape(B, T, Hkv * hd)]
     if quant:
@@ -359,25 +372,29 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, scale):
 # ---------------------------------------------------------------------------
 
 
-def gather_paged_view(k_pool, tables):
-    """Per-slot contiguous view of a pooled leaf: k_pool [N, bs, ...] +
-    tables [B, nmax] -> [B, nmax*bs, ...].  Unmapped entries (-1) clamp
-    to block 0 — their rows sit past every causal frontier (the
-    allocator maps blocks through the write position), so the garbage is
-    masked exactly like a slab's unwritten rows.  THE oracle/fallback
+def gather_paged_view(leaf, layer, tables):
+    """Per-slot contiguous view of one layer of a pooled leaf: leaf
+    [L, N, bs, ...] + layer (int32 scalar) + tables [B, nmax] ->
+    [B, nmax*bs, ...], gathered from the whole leaf by (layer, page): no
+    layer's slice is cut out first.  Unmapped entries (-1) clamp to
+    block 0 — their rows sit past every causal frontier (the allocator
+    maps blocks through the write position), so the garbage is masked
+    exactly like a slab's unwritten rows.  THE oracle/fallback
     materialization; the Pallas path copies the same table's live pages
     inside its grid cell instead."""
-    idx = jnp.clip(tables, 0, k_pool.shape[0] - 1)          # [B, nmax]
-    g = k_pool[idx]                                          # [B,nmax,bs,...]
+    idx = jnp.clip(tables, 0, leaf.shape[1] - 1)             # [B, nmax]
+    g = leaf[layer, idx]                                     # [B,nmax,bs,...]
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
 def paged_supported(q_shape, pool_shape) -> bool:
     """Static shape gate for the paged kernel: q [B, Tq, Hq, hd] against
-    a pool [N, bs, Hkv, hd] (the KV block is the pool's own block)."""
+    a K/V leaf [L, N, bs, Hkv*hd] (the KV block is the pool's own
+    block)."""
     B, Tq, Hq, hd = q_shape
-    N, bs, Hkv = pool_shape[0], pool_shape[1], pool_shape[2]
-    return (hd in (128, 256) and Hq % Hkv == 0
+    bs, lanes = pool_shape[2], pool_shape[3]
+    Hkv = lanes // hd
+    return (hd in (128, 256) and lanes % hd == 0 and Hq % Hkv == 0
             and Tq * (Hq // Hkv) <= _R_CAP
             and bs >= 8 and bs % 8 == 0)
 
@@ -391,41 +408,52 @@ def paged_available(q_shape, pool_shape) -> bool:
             and (_INTERPRET or _pallas.on_tpu()))
 
 
-def _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
-    """Oracle/fallback: gather the per-slot views through the tables and
-    run the contiguous XLA reference — bit-identical values to a slab
-    holding the same rows (the gather only relocates blocks)."""
-    k = gather_paged_view(k_pool, tables)
-    v = gather_paged_view(v_pool, tables)
-    ks = gather_paged_view(k_scale, tables) if k_scale is not None else None
-    vs = gather_paged_view(v_scale, tables) if v_scale is not None else None
+def _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
+               scale):
+    """Oracle/fallback: gather the per-slot views of the layer through
+    the tables and run the contiguous XLA reference — bit-identical
+    values to a slab holding the same rows (the gather only relocates
+    blocks)."""
+    B, hd = q.shape[0], q.shape[3]
+    per_head = (B, -1, k_pool.shape[3] // hd, hd)
+    k = gather_paged_view(k_pool, layer, tables).reshape(per_head)
+    v = gather_paged_view(v_pool, layer, tables).reshape(per_head)
+    ks = vs = None
+    if k_scale is not None:
+        ks = gather_paged_view(k_scale, layer, tables)
+        vs = gather_paged_view(v_scale, layer, tables)
     return _xla_decode(q, k, v, pos, ks, vs, scale)
 
 
-def paged_decode_attention(q, k_pool, v_pool, tables, pos,
+def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer,
                            k_scale=None, v_scale=None, scale=None):
-    """Block-table decode attention: q [B, Tq, Hq, hd] against a pooled
-    cache k/v [N, bs, Hkv, hd] addressed through ``tables`` [B, nmax]
-    int32 (physical block per logical block; -1 = unmapped) ->
-    [B, Tq, Hq, hd] (q.dtype).  ``pos`` [B] as in :func:`decode_attention`
-    — logical row t of slot b is table[b, t // bs] row t % bs, and rows
-    t <= pos[b] + i are attended.  int8 pools pass per-row scales
-    [N, bs, Hkv].  Shapes the static gate rejects take gather + the XLA
-    reference; a shape it accepts compiles the kernel.
+    """Block-table decode attention over one layer of the pool: q
+    [B, Tq, Hq, hd] against the pool's K/V leaves [L, N, bs, Hkv*hd]
+    read at ``layer`` (int32 scalar; a caller that holds one layer's
+    pool passes ``pool[None]`` and 0, which copies nothing) and
+    addressed through ``tables`` [B, nmax] int32 (physical block per
+    logical block; -1 = unmapped) -> [B, Tq, Hq, hd] (q.dtype).  ``pos``
+    [B] as in :func:`decode_attention` — logical row t of slot b is
+    table[b, t // bs] row t % bs, and rows t <= pos[b] + i are attended.
+    int8 pools pass their per-row scale leaves [L, N, bs, Hkv].  Shapes
+    the static gate rejects take gather + the XLA reference; a shape it
+    accepts compiles the kernel.
 
     A grid cell is a slot: it walks the table entries up to its causal
     frontier as far as they are mapped and copies those pages from the
-    pool in HBM by their physical number, so the HBM read is each slot's
-    live blocks only — never a materialized [B, T] gather — and a free
-    slot, or an entry that is unmapped or past the frontier, is neither
-    copied nor visited.  A slot that attends nothing gives zeros."""
+    leaf in HBM by (layer, physical number), so the HBM read is each
+    slot's live blocks only — never a materialized [B, T] gather, never
+    a layer's slice of the pool — and a free slot, or an entry that is
+    unmapped or past the frontier, is neither copied nor visited.  A
+    slot that attends nothing gives zeros."""
+    layer = jnp.asarray(layer, jnp.int32)
     if not paged_supported(q.shape, k_pool.shape):
-        return _xla_paged(q, k_pool, v_pool, tables, pos, k_scale, v_scale,
-                          scale)
+        return _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale,
+                          v_scale, scale)
     return _per_head_shard(
-        lambda *a: _paged_call(*a, scale), k_pool.shape[2],
-        (q, 2), (k_pool, 2), (v_pool, 2), (tables, None), (pos, None),
-        (k_scale, 2), (v_scale, 2))
+        lambda *a: _paged_call(*a, scale), k_pool.shape[3] // q.shape[3],
+        (q, 2), (k_pool, 3), (v_pool, 3), (tables, None), (pos, None),
+        (layer, None), (k_scale, 3), (v_scale, 3))
 
 
 _KV_ROWS = 128           # KV rows a compute block of the paged kernel holds, at least
@@ -448,12 +476,13 @@ def _paged_geometry(bs: int, Hkv: int, hd: int, Rp: int, itemsize: int):
     return P, Hb
 
 
-def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
+def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
+                scale):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, Tq, Hq, hd = q.shape
-    N, bs, Hkv = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    bs, Hkv = k_pool.shape[2], k_pool.shape[3] // hd
     G = Hq // Hkv
     nmax = tables.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
@@ -465,8 +494,9 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
     KB = P * bs             # KV rows of a compute block
     tab = tables.astype(jnp.int32)
     pos2 = pos.reshape(B).astype(jnp.int32)
+    lay = layer.reshape(1).astype(jnp.int32)
 
-    def kernel(tab_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest):
+    def kernel(tab_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, *rest):
         if quant:
             ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, \
                 *scr = rest
@@ -474,6 +504,7 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
             o_ref, k_buf, v_buf, sem, *scr = rest
         b = pl.program_id(0)
         p_b = pos_ref[b]
+        li = lay_ref[0]
         # the table entries this slot walks: those up to its causal
         # frontier, as far as they are mapped (the allocator maps every
         # block through the write position; an unmapped entry holds
@@ -494,8 +525,9 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
                 @pl.when(g * P + j < n_live)
                 def _page():
                     page = tab_ref[b, g * P + j]
-                    pairs = [(k_hbm.at[page, :, lanes], k_buf.at[half, j]),
-                             (v_hbm.at[page, :, lanes], v_buf.at[half, j])]
+                    pairs = [
+                        (k_hbm.at[li, page, :, lanes], k_buf.at[half, j]),
+                        (v_hbm.at[li, page, :, lanes], v_buf.at[half, j])]
                     if quant:
                         pairs += [(ks_hbm.at[page], ks_buf.at[half, j]),
                                   (vs_hbm.at[page], vs_buf.at[half, j])]
@@ -552,31 +584,29 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
                                                o_ref.dtype)
 
     q_spec = pl.BlockSpec((1, Hkv, Rp, hd),
-                          lambda b, tab_ref, pos_ref: (b, 0, 0, 0))
+                          lambda b, tab_ref, pos_ref, lay_ref: (b, 0, 0, 0))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, in_hbm, in_hbm]
-    # the kernel's view of the layer's pool, heads folded into the lane
-    # dimension (a page of it is contiguous): on the chip a relayout copy
-    # of the whole slice, so it is counted with the pool's gathers, not
-    # with the attention
-    with jax.named_scope("kv_gather"):
-        args = [qh, k_pool.reshape(N, bs, Hkv * hd),
-                v_pool.reshape(N, bs, Hkv * hd)]
+    # the K and V operands are the pool's leaves as they are stored
+    args = [qh, k_pool, v_pool]
     bufs = [pltpu.VMEM((2, P, bs, Hb * hd), k_pool.dtype),
             pltpu.VMEM((2, P, bs, Hb * hd), v_pool.dtype)]
     if quant:
         # a page is copied whole lanes at a time: the scales' head axis
         # is padded up to a lane tile (the chip's compiler refuses to
-        # slice a page off an operand whose rows are narrower)
+        # slice a page off an operand whose rows are narrower).  The
+        # scale planes alone are still sliced by layer for that: 4 bytes
+        # a head of a row beside its hd bytes of int8
         with jax.named_scope("kv_gather"):
             lane_pad = ((0, 0), (0, 0), (0, -Hkv % 128))
-            scales = [jnp.pad(k_scale, lane_pad), jnp.pad(v_scale, lane_pad)]
+            scales = [jnp.pad(x[layer], lane_pad)
+                      for x in (k_scale, v_scale)]
         in_specs += [in_hbm, in_hbm]
         args += scales
         bufs += [pltpu.VMEM((2, P) + x.shape[1:], x.dtype) for x in scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=in_specs,
         out_specs=q_spec,
@@ -589,5 +619,5 @@ def _paged_call(q, k_pool, v_pool, tables, pos, k_scale, v_scale, scale):
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=_INTERPRET,
         name="paged_decode_attention",
-    )(tab, pos2, *args)
+    )(tab, pos2, lay, *args)
     return _rows_last(out, q.shape)

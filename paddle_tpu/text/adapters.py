@@ -144,12 +144,13 @@ def _paged_adapter_step(params, cache, g, token, pos, cfg: gpt.GPTConfig):
         merged = dict(params["blocks"], **gad)
 
         def body(x, layer):
-            p, pl = layer
-            csl = {n: kv_pool._gather_slot(v, trow) for n, v in pl.items()}
+            p, li = layer
+            csl = kv_pool._gather_slot(pool, li, trow, cfg)
             x, rows = generate._cached_block(x, p, csl, pos_b, cfg)
             return x, rows
 
-        x, rows = jax.lax.scan(body, x, (merged, pool))
+        x, rows = jax.lax.scan(
+            body, x, (merged, jnp.arange(cfg.num_layers)))
         x = gpt._norm(x, params, "ln_f", cfg)
         logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
         return logits[0].astype(jnp.float32), rows
@@ -237,12 +238,13 @@ def _paged_adapter_verify(params, cache, g, tokens, pos,
         merged = dict(params["blocks"], **gad)
 
         def body(x, layer):
-            p, pl = layer
-            csl = {n: kv_pool._gather_slot(v, trow) for n, v in pl.items()}
+            p, li = layer
+            csl = kv_pool._gather_slot(pool, li, trow, cfg)
             x, rows = generate._chunk_attend_block(x, p, csl, p0, cfg)
             return x, rows
 
-        x, rows = jax.lax.scan(body, x, (merged, pool))
+        x, rows = jax.lax.scan(
+            body, x, (merged, jnp.arange(cfg.num_layers)))
         x = gpt._norm(x, params, "ln_f", cfg)
         logits = woq.logits(x, params, dt,
                             cfg.lm_head_multiplier)[0]      # [K, V]

@@ -482,7 +482,9 @@ class PrefillWorker:
     def prefill(self, prompt, trace=None):
         """Run one prompt's admission prefill; returns ``(rows,
         logits)``: rows are host arrays ``[L, 1, n, Hkv(, hd)]`` per
-        cache leaf (int8 scale planes included) in the storage dtype,
+        cache leaf (a paged worker's K/V rows as its pool stores them,
+        ``[L, 1, n, Hkv*hd]``; int8 scale planes included) in the
+        storage dtype,
         logits the fp32 ``[V]`` admission logits — exactly what
         ``DecodeServer.submit_prefilled`` expects."""
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
@@ -511,15 +513,10 @@ class PrefillWorker:
                 self.params, self.cache, jnp.asarray(padded),
                 jnp.asarray(0), jnp.asarray(n), jnp.asarray(0))
             tb = self._pool.tables[0]
-            phys = [int(tb[i // bs]) * bs + i % bs for i in range(n)]
-            rows = {}
-            for name, arr in self.cache.items():
-                if name == "tables":
-                    continue
-                flat = np.asarray(arr).reshape(
-                    (arr.shape[0], arr.shape[1] * arr.shape[2])
-                    + arr.shape[3:])
-                rows[name] = flat[:, phys][:, None]
+            logi = np.arange(n)
+            blk, off = tb[logi // bs], logi % bs
+            rows = {name: np.asarray(arr)[:, blk, off][:, None]
+                    for name, arr in self.cache.items() if name != "tables"}
             self._pool.free_slot(0)
         else:
             bucket = serving._pow2_bucket(n, window)
@@ -613,13 +610,10 @@ class PrefillWorker:
                 if name == "tables":
                     continue
                 if self._paged:
-                    flat = arr.reshape(
-                        (arr.shape[0], arr.shape[1] * arr.shape[2])
-                        + arr.shape[3:])
-                    phys = jnp.asarray(
-                        [int(tb[i // bs]) * bs + i % bs
-                         for i in range(lo, hi)], jnp.int32)
-                    out[name] = jnp.take(flat, phys, axis=1)[:, None]
+                    logi = np.arange(lo, hi)
+                    phys = tb[logi // bs] * bs + logi % bs
+                    out[name] = _kv.take_rows(
+                        arr, jnp.asarray(phys, jnp.int32))[:, None]
                 else:
                     out[name] = arr[:, 0:1, lo:hi]
             return out

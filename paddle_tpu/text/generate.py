@@ -517,11 +517,13 @@ def _decode_param_specs(params, cfg: gpt.GPTConfig, mp: str):
 def sharded_cache_specs(cfg: gpt.GPTConfig, cache: dict, mesh,
                         mp: str = "mp") -> dict:
     """PartitionSpec per cache leaf for tensor-parallel decode — ONE
-    rule for both layouts: the Hkv axis (axis 3 of the contiguous slab
-    ``[L, B, T, Hkv(, hd)]`` AND of the paged pool
-    ``[L, N, bs, Hkv(, hd)]``, scale planes included) shards over ``mp``
-    when divisible, everything else replicates; the paged ``tables``
-    leaf (host-scheduler state, int32 indices) always replicates."""
+    rule for both layouts: the heads' axis shards over ``mp`` when
+    divisible, everything else replicates.  It is axis 3 of the
+    contiguous slab ``[L, B, T, Hkv(, hd)]`` AND of the paged pool, whose
+    K/V leaves ``[L, N, bs, Hkv*hd]`` hold a row's heads side by side (a
+    shard of that axis is a contiguous range of whole heads) and whose
+    scale planes are ``[L, N, bs, Hkv]``; the paged ``tables`` leaf
+    (host-scheduler state, int32 indices) always replicates."""
     from jax.sharding import PartitionSpec as P
 
     mp_size = mesh.shape[mp]

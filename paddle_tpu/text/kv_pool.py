@@ -8,9 +8,14 @@ stack (auto-growth best-fit chunks, retry-on-OOM chains) at KV-cache
 granularity, in the mold of vLLM's PagedAttention and SGLang's
 RadixAttention:
 
-* **block pool** — device leaves ``[L, num_blocks, block_size, Hkv, hd]``
-  (int8 scale planes ``[L, N, bs, Hkv]`` ride along exactly as in the
-  contiguous layout), shared by every slot;
+* **block pool** — device leaves ``[L, num_blocks, block_size, Hkv*hd]``:
+  a row's heads side by side in the last axis (head ``h`` in lanes
+  ``[h*hd, (h+1)*hd)``), so that a page ``leaf[layer, page]`` is stored
+  the way the paged kernel copies it and every reader — the kernel, the
+  prefill's slot gather, the row scatter — addresses the whole leaf by
+  (layer, page): no step cuts a layer's slice out of it or re-lays it
+  out (int8 scale planes ``[L, N, bs, Hkv]`` ride along exactly as in
+  the contiguous layout), shared by every slot;
 * **block tables** — an int32 ``[max_batch, nmax]`` leaf mapping each
   slot's logical block to a physical pool block (-1 = unmapped), carried
   in the cache pytree so the jitted steps stay pure pytree-in/pytree-out
@@ -99,7 +104,7 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
                      block_size: int | None = None,
                      num_blocks: int | None = None) -> dict:
     """The pooled cache pytree (``generate.init_cache(layout="paged")``):
-    value leaves ``[L, N, bs, Hkv, hd]`` (+ int8 scale planes
+    value leaves ``[L, N, bs, Hkv*hd]`` (+ int8 scale planes
     ``[L, N, bs, Hkv]``) and an int32 ``tables`` leaf ``[batch, nmax]``
     initialized unmapped (-1).  ``num_blocks`` defaults to full
     provisioning (``batch * nmax`` — slab-equivalent capacity, the
@@ -118,12 +123,12 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
         raise ValueError(f"num_blocks must be >= 1, got {N}")
     L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
     dt = generate._kv_store_dtype(cfg)
-    shape = (L, N, bs, H, hd)
+    shape = (L, N, bs, H * hd)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
              "tables": jnp.full((batch, nmax), -1, jnp.int32)}
     if dt == jnp.int8:
-        cache["k_s"] = jnp.zeros(shape[:-1], jnp.float32)
-        cache["v_s"] = jnp.zeros(shape[:-1], jnp.float32)
+        cache["k_s"] = jnp.zeros((L, N, bs, H), jnp.float32)
+        cache["v_s"] = jnp.zeros((L, N, bs, H), jnp.float32)
     if cfg.ssm is not None:
         # two kinds of state, one pytree: the mixer's state is per SLOT
         # and of fixed size, so it is provisioned for every slot and
@@ -162,32 +167,70 @@ def _geometry(cache: dict):
     return N, bs, cache["tables"].shape[1]
 
 
-def _gather_slot(pool_leaf, trow):
-    """One slot's contiguous view of a per-layer pool leaf:
-    ``pool_leaf`` [N, bs, ...] + table row [nmax] -> [1, nmax*bs, ...].
+def _gather_slot(pool: dict, li, trow, cfg: gpt.GPTConfig) -> dict:
+    """One slot's contiguous view of layer ``li`` of every pool leaf:
+    leaves [L, N, bs, ...] + table row [nmax] -> {"k", "v":
+    [1, nmax*bs, Hkv, hd], scales: [1, nmax*bs, Hkv]}, gathered from the
+    whole leaf by (layer, page) and given its heads after the gather.
     Delegates to the kernel module's batched gather — ONE copy of the
     unmapped-entry (clamp-to-block-0, causally-masked) semantics shared
     with the oracle/fallback paths."""
     from ..ops import decode_attention as da
 
     with jax.named_scope("kv_gather"):
-        return da.gather_paged_view(pool_leaf, trow[None])
+        view = {n: da.gather_paged_view(v, li, trow[None])
+                for n, v in pool.items()}
+        for n in ("k", "v"):
+            view[n] = view[n].reshape(view[n].shape[:2]
+                                      + (cfg.kv_heads, cfg.head_dim))
+    return view
+
+
+def _row_index(arr, layers, phys):
+    """The (layer, block, row) index of physical row numbers ``phys`` [R]
+    in a pool leaf ``arr`` [L, N, bs, ...] as it is stored, for
+    ``layers``: one layer's number (-> [R] rows) or None for every layer
+    (-> [L, R]).  Every one of the three is an index of the gather or
+    scatter and a row its window: a window over the layers makes the
+    chip's compiler copy the whole leaf into a layout with the layers
+    inside a tile, and back."""
+    bs = arr.shape[2]
+    if layers is None:
+        layers = jnp.arange(arr.shape[0])[:, None]
+    return layers, phys // bs, phys % bs
+
+
+def take_rows(arr, phys):
+    """Rows ``phys`` [R] (physical row numbers, in bounds) of every layer
+    of a pool leaf [L, N, bs, ...] -> [L, R, ...]."""
+    return arr[_row_index(arr, None, phys)]
+
+
+def _put_rows(arr, layers, phys, val):
+    """``arr`` [L, N, bs, ...] with the rows ``val`` written at physical
+    row numbers ``phys`` [R] (int32, out-of-bounds = dropped — the
+    overrun/unmapped sink) of ``layers``: one layer's number (``val``
+    [R, Hkv(, hd)]) or None for every layer (``val`` [L, R, Hkv(, hd)]).
+    The rows take the leaf's own row shape (a K/V row's heads side by
+    side) and the leaf is indexed as it is stored: in place under
+    donation, no reshape and no other layout of the pool."""
+    idx = _row_index(arr, layers, phys)
+    lead = jnp.broadcast_shapes(jnp.shape(idx[0]), phys.shape)
+    val = val.reshape(lead + arr.shape[3:]).astype(arr.dtype)
+    return arr.at[idx].set(val, mode="drop")
 
 
 def _scatter_rows(cache: dict, rows: dict, phys) -> dict:
     """Write per-layer row leaves into the pool at physical row indices
     ``phys`` (int32, out-of-bounds = dropped — the overrun/unmapped
-    sink).  ``rows`` leaves [L, R, Hkv(, hd)] against pool leaves
-    [L, N, bs, Hkv(, hd)]; the single row-write every paged decode/
-    prefill path funnels through (the ``generate._write_rows`` twin)."""
+    sink).  ``rows`` leaves [L, R, Hkv(, hd)] (or already in the pool's
+    row shape, [L, R, Hkv*hd]) against pool leaves [L, N, bs, ...]; the
+    single row-write every paged decode/prefill path funnels through
+    (the ``generate._write_rows`` twin)."""
     out = dict(cache)
     with jax.named_scope("kv_gather"):
         for name, val in rows.items():
-            arr = cache[name]
-            L, NR = arr.shape[0], arr.shape[1] * arr.shape[2]
-            flat = arr.reshape((L, NR) + arr.shape[3:])
-            flat = flat.at[:, phys].set(val.astype(arr.dtype), mode="drop")
-            out[name] = flat.reshape(arr.shape)
+            out[name] = _put_rows(cache[name], None, phys, val)
     return out
 
 
@@ -211,8 +254,7 @@ def paged_decode_step_batched(params, cache, token, pos,
     B = token.shape[0]
     H, hd = cfg.num_heads, cfg.head_dim
     use_kernel = (_flags.flash_decode()
-                  and da.paged_available((B, 1, H, hd),
-                                         cache["k"].shape[1:]))
+                  and da.paged_available((B, 1, H, hd), cache["k"].shape))
     if use_kernel:
         return _paged_step_kernel(params, cache, token, pos, cfg)
 
@@ -228,8 +270,8 @@ def paged_decode_step_batched(params, cache, token, pos,
         x = generate._embed_step(params, tok_b[None], pos_b, cfg)
 
         def body(x, layer):
-            p, pl, st = layer
-            csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
+            p, li, st = layer
+            csl = _gather_slot(pool, li, trow, cfg)
             if st is None:
                 x, rows = generate._cached_block(x, p, csl, pos_b, cfg)
                 return x, (rows, None)
@@ -238,8 +280,8 @@ def paged_decode_step_batched(params, cache, token, pos,
                 state={n: v[None] for n, v in st.items()})
             return x, (rows, {n: v[0] for n, v in st.items()})
 
-        x, (rows, st_b) = jax.lax.scan(body, x,
-                                       (params["blocks"], pool, st_b))
+        x, (rows, st_b) = jax.lax.scan(
+            body, x, (params["blocks"], jnp.arange(cfg.num_layers), st_b))
         x = gpt._norm(x, params, "ln_f", cfg)
         logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)[:, 0]
         return logits[0].astype(jnp.float32), rows, st_b
@@ -282,7 +324,6 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
     # a layer at a time), never the scan's stacked outputs
     state = ({n: cache[n] for n in STATE_LEAVES}
              if STATE_LEAVES[0] in cache else None)
-    L = cache["k"].shape[0]
 
     def embed_one(tok_b, pos_b):
         return generate._embed_step(params, tok_b[None], pos_b, cfg)
@@ -313,23 +354,17 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
         q3, rows = jax.vmap(pre)(x, pos, h)  # q3 [B,1,1,H,hd]
         # scatter the fresh rows into layer li BEFORE attending: the
         # kernel then reads exactly what later steps will read back
-        # (scatter-then-attend == the slab path's splice-then-write)
+        # (scatter-then-attend == the slab path's splice-then-write).
+        # The kernel is handed the whole leaves and the layer's number:
+        # nothing here has a layer's slice of the pool as its result
         with jax.named_scope("kv_gather"):
-            new_pool = {}
-            for n, val in rows.items():
-                arr = pool[n]
-                NR = arr.shape[1] * arr.shape[2]
-                flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
-                flat = flat.at[li, phys].set(val[:, 0].astype(arr.dtype),
-                                             mode="drop")
-                new_pool[n] = flat.reshape(arr.shape)
-            pool = new_pool
-            layer_kv = {n: v[li] for n, v in pool.items()}
+            pool = dict(pool, **{n: _put_rows(pool[n], li, phys, val[:, 0])
+                                 for n, val in rows.items()})
         q = q3.reshape(B, 1, cfg.num_heads, hd)
         with jax.named_scope("attn"):
             attn = da.paged_decode_attention(
-                q, layer_kv["k"], layer_kv["v"], tables, pos,
-                k_scale=layer_kv.get("k_s"), v_scale=layer_kv.get("v_s"))
+                q, pool["k"], pool["v"], tables, pos, li,
+                k_scale=pool.get("k_s"), v_scale=pool.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, 1, cfg.num_heads * hd)
 
         def post(xb, ab, mb):
@@ -339,7 +374,8 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
         return (x, pool, state), None
 
     (x, pool, state), _ = jax.lax.scan(
-        body, (x, pool, state), (params["blocks"], jnp.arange(L)))
+        body, (x, pool, state),
+        (params["blocks"], jnp.arange(cfg.num_layers)))
 
     def fin(xb):
         xb = gpt._norm(xb, params, "ln_f", cfg)
@@ -386,8 +422,8 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
                      for n in STATE_LEAVES}
 
     def body(x, layer):
-        p, pl, st = layer
-        csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
+        p, li, st = layer
+        csl = _gather_slot(pool, li, trow, cfg)
         if st is None:
             x, rows = generate._chunk_attend_block(x, p, csl, pos0, cfg,
                                                    valid=valid_mask)
@@ -396,8 +432,8 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
             x, p, csl, pos0, cfg, valid=valid_mask, state=st, length=length)
         return x, (rows, st)
 
-    x, (rows, state) = jax.lax.scan(body, x,
-                                    (params["blocks"], pool, state))
+    x, (rows, state) = jax.lax.scan(
+        body, x, (params["blocks"], jnp.arange(cfg.num_layers), state))
     if state is not None:
         with jax.named_scope("ssm"):
             cache = dict(cache, **{
@@ -441,7 +477,7 @@ def paged_verify_chunk_batched(params, cache, tokens, pos, cfg):
     B, K = tokens.shape
     if (_flags.flash_decode()
             and da.paged_available((B, K, cfg.num_heads, cfg.head_dim),
-                                   cache["k"].shape[1:])):
+                                   cache["k"].shape)):
         return _paged_verify_kernel(params, cache, tokens, pos, cfg)
     tables = cache["tables"]
     pool = {n: cache[n] for n in POOL_LEAVES if n in cache}
@@ -456,12 +492,13 @@ def paged_verify_chunk_batched(params, cache, tokens, pos, cfg):
                 (K, cfg.hidden_size)).astype(dt)[None]
 
         def body(x, layer):
-            p, pl = layer
-            csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
+            p, li = layer
+            csl = _gather_slot(pool, li, trow, cfg)
             x, rows = generate._chunk_attend_block(x, p, csl, p0, cfg)
             return x, rows
 
-        x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
+        x, rows = jax.lax.scan(
+            body, x, (params["blocks"], jnp.arange(cfg.num_layers)))
         x = gpt._norm(x, params, "ln_f", cfg)
         logits = woq.logits(x, params, dt,
                             cfg.lm_head_multiplier)[0]      # [K, V]
@@ -519,13 +556,14 @@ def paged_tree_verify_chunk_batched(params, cache, tokens, amask, depth,
         tmask = jax.lax.dynamic_update_slice(tmask, am[None], (0, 0, p0))
 
         def body(x, layer):
-            p, pl = layer
-            csl = {n: _gather_slot(v, trow) for n, v in pl.items()}
+            p, li = layer
+            csl = _gather_slot(pool, li, trow, cfg)
             x, rows = generate._tree_attend_block(x, p, csl, p0, dp,
                                                   tmask, cfg)
             return x, rows
 
-        x, rows = jax.lax.scan(body, x, (params["blocks"], pool))
+        x, rows = jax.lax.scan(
+            body, x, (params["blocks"], jnp.arange(cfg.num_layers)))
         x = gpt._norm(x, params, "ln_f", cfg)
         logits = woq.logits(x, params, dt,
                             cfg.lm_head_multiplier)[0]      # [K, V]
@@ -568,16 +606,12 @@ def paged_tree_commit(cache, src, pos):
     src_p = phys_of(pos[:, None] + src).reshape(B * M)
     dst_p = phys_of(pos[:, None] + 1
                     + jnp.arange(M)[None, :]).reshape(B * M)
+    src_p = jnp.clip(src_p, 0, N * bs - 1)
     out = dict(cache)
     for name in POOL_LEAVES:
-        if name not in cache:
-            continue
-        arr = cache[name]
-        L, NR = arr.shape[0], arr.shape[1] * arr.shape[2]
-        flat = arr.reshape((L, NR) + arr.shape[3:])
-        rows = flat[:, jnp.clip(src_p, 0, NR - 1)]
-        flat = flat.at[:, dst_p].set(rows, mode="drop")
-        out[name] = flat.reshape(arr.shape)
+        if name in cache:
+            out[name] = _put_rows(cache[name], None, dst_p,
+                                  take_rows(cache[name], src_p))
     return out
 
 
@@ -598,7 +632,6 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
     H, hd = cfg.num_heads, cfg.head_dim
     tables = cache["tables"]
     pool = {n: cache[n] for n in POOL_LEAVES if n in cache}
-    L = cache["k"].shape[0]
     logi = pos[:, None] + jnp.arange(K)[None, :]          # [B, K]
     tb = jnp.take_along_axis(tables, jnp.clip(logi // bs, 0, nmax - 1),
                              axis=1)
@@ -625,22 +658,12 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
 
         q3, rows = jax.vmap(pre)(x, pos)  # q3 [B, 1, K, H, hd]
         with jax.named_scope("kv_gather"):
-            new_pool = {}
-            for n, val in rows.items():
-                arr = pool[n]
-                NR = arr.shape[1] * arr.shape[2]
-                flat = arr.reshape((arr.shape[0], NR) + arr.shape[3:])
-                v = val[:, 0].reshape((B * K,) + val.shape[3:])
-                flat = flat.at[li, phys].set(v.astype(arr.dtype),
-                                             mode="drop")
-                new_pool[n] = flat.reshape(arr.shape)
-            pool = new_pool
-            layer_kv = {n: v[li] for n, v in pool.items()}
+            pool = dict(pool, **{n: _put_rows(pool[n], li, phys, val[:, 0])
+                                 for n, val in rows.items()})
         with jax.named_scope("attn"):
             attn = da.paged_decode_attention(
-                q3.reshape(B, K, H, hd), layer_kv["k"], layer_kv["v"],
-                tables, pos,
-                k_scale=layer_kv.get("k_s"), v_scale=layer_kv.get("v_s"))
+                q3.reshape(B, K, H, hd), pool["k"], pool["v"], tables, pos,
+                li, k_scale=pool.get("k_s"), v_scale=pool.get("v_s"))
         attn = attn.astype(dt).reshape(B, 1, K, H * hd)
 
         def post(xb, ab):
@@ -649,7 +672,7 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
         return (jax.vmap(post)(x, attn), pool), None
 
     (x, pool), _ = jax.lax.scan(
-        body, (x, pool), (params["blocks"], jnp.arange(L)))
+        body, (x, pool), (params["blocks"], jnp.arange(cfg.num_layers)))
 
     def fin(xb):
         xb = gpt._norm(xb, params, "ln_f", cfg)
@@ -662,7 +685,8 @@ def _paged_verify_kernel(params, cache, tokens, pos, cfg: gpt.GPTConfig):
 
 def inject_rows(cache: dict, rows: dict, start, length, slot) -> dict:
     """Write externally computed cache rows (a prefill worker's output —
-    leaves ``[L, 1, C, Hkv(, hd)]``, valid through ``length``) into one
+    leaves ``[L, 1, C, Hkv(, hd)]`` or, from a paged worker or the spill
+    tier, ``[L, 1, C, Hkv*hd]``; valid through ``length``) into one
     slot's rows [start, length) through its block table — the paged
     half of the fleet's prefill/decode handoff
     (``generate._merge_slot_rows`` is the contiguous twin).  ``start``

@@ -281,11 +281,10 @@ def _moe_paged_step_batched(params, cache, token, pos, act, stats,
 
     def body(carry, layer):
         x, stats = carry
-        p, pl = layer           # pl leaves [N, bs, Hkv(, hd)]
+        p, li = layer
 
         def pre(xb, pos_b, trow):
-            csl = {n: kv_pool._gather_slot(v, trow)
-                   for n, v in pl.items()}               # [1, T, ...]
+            csl = kv_pool._gather_slot(pool, li, trow, cfg)  # [1, T, ...]
             q3, rows = generate._block_pre_attn(xb, p, pos_b, cfg)
             full = {n: jax.lax.dynamic_update_slice(
                         csl[n], v[:, None],
@@ -299,8 +298,8 @@ def _moe_paged_step_batched(params, cache, token, pos, act, stats,
             stats=stats)
         return (x2[:, None], stats), rows
 
-    (x, stats), rows = jax.lax.scan(body, (x, stats),
-                                    (params["blocks"], pool))
+    (x, stats), rows = jax.lax.scan(
+        body, (x, stats), (params["blocks"], jnp.arange(cfg.num_layers)))
     # rows leaves [L, B, 1, Hkv(, hd)]; physical row per slot through the
     # table (unmapped -> out of bounds -> dropped, the slab clamp twin)
     tb = tables[jnp.arange(B), pos // bs]

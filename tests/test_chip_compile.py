@@ -151,7 +151,8 @@ def test_w4_matmul(shape):
 
 # --------------------------------------------------------------------------
 # decode kernels: refused on the parent commit for their block shapes
-# (pos block (1, 1) of (8, 1); KV block (1, bs, 1, 128) of [N, bs, 16, 128])
+# (pos block (1, 1) of (8, 1); KV block (1, bs, 1, 128) of [N, bs, 16, 128]:
+# PR 21's pool; since PR 32 a K/V leaf is [L, N, bs, Hkv*hd])
 # --------------------------------------------------------------------------
 
 
@@ -189,14 +190,16 @@ def test_paged_decode_kernel(shape, kv, pool_shape, Tq):
 
     slots, Hq, Hkv, N, bs, nmax = PAGED_SHAPES[pool_shape]
     q = shape((slots, Tq, Hq, HD), BF)
-    pool = shape((N, bs, Hkv, HD), BF if kv == "bf16" else I8)
-    sc = shape((N, bs, Hkv), F32) if kv == "int8" else None
+    # the pool's leaf as it is stored: two layers, a row's heads side by
+    # side, read at a layer the kernel is told at run time
+    pool = shape((2, N, bs, Hkv * HD), BF if kv == "bf16" else I8)
+    sc = shape((2, N, bs, Hkv), F32) if kv == "int8" else None
     assert da.paged_supported(q.shape, pool.shape)
     n = kernels_in(
-        lambda q, k, v, t, p, a, b: da._paged_call(q, k, v, t, p, a, b,
-                                                   None),
+        lambda q, k, v, t, p, li, a, b: da._paged_call(q, k, v, t, p, li, a,
+                                                       b, None),
         q, pool, pool, shape((slots, nmax), I32), shape((slots,), I32),
-        sc, sc, names=("paged_decode_attention",))
+        shape((), I32), sc, sc, names=("paged_decode_attention",))
     assert n == 1
 
 
@@ -313,6 +316,122 @@ def test_hybrid_decode_step_and_prefill(one_chip, no_persistent_cache,
         scalar).compile().as_text()
     assert any("/ssm/ssm_scan/" in p for p in
                re.findall(r'op_name="([^"]*)"', text))
+
+
+# the two chat cells' servers (slots, pool blocks; 16 rows a block, a
+# window of 2048), and ``peak_memory_in_bytes`` of their decode step as the
+# parent of PR 32 (122b50b) compiled it here, at full depth
+POOL_CELLS = {"gpt1p3b": (32, 3072), "falconh1": (64, 8192)}
+PARENT_STEP_PEAK = {"gpt1p3b": 12_906_021_376, "falconh1": 14_352_944_640}
+_HLO_BYTES = {"bf16": 2, "f16": 2, "f32": 4, "s8": 1, "u8": 1, "s32": 4,
+              "u32": 4, "pred": 1}
+
+
+def _computations(text) -> dict:
+    """{computation's name: its instructions' lines} of a compiled module."""
+    out, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _holds_pool_slices(text, slice_elems: int, dtype: str) -> list:
+    """(name, opcode, shape) of every op whose result holds a whole number
+    of layer slices of a K/V leaf (``slice_elems`` elements of ``dtype``
+    each), one at least, and is not the leaf passed on as it is: a
+    parameter, a tuple or its element, the loop, a bitcast, or the row
+    scatter writing into the leaf it was given."""
+    comps = _computations(text)
+    passed_on = {"parameter", "tuple", "get-tuple-element", "while",
+                 "bitcast", "scatter"}
+    found = []
+    for lines in comps.values():
+        for line in lines:
+            m = re.match(r"^\s+(?:ROOT )?%([\w.\-]+) = (.*?)\s"
+                         r"([a-z][a-z\-]*)\((.*)$", line)
+            if m is None:
+                continue
+            name, shape, opcode, rest = m.groups()
+            sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dt, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]",
+                                                shape) if dt == dtype]
+            if not any(n >= slice_elems and n % slice_elems == 0
+                       for n in sizes) or opcode in passed_on:
+                continue
+            called = re.search(r"calls=%([\w.\-]+)", rest)
+            body = comps.get(called.group(1), ()) if called else ()
+            if opcode == "fusion" and any(" scatter(" in ln for ln in body):
+                continue
+            found.append((name, opcode, shape.split("{")[0]))
+    return found
+
+
+@pytest.mark.parametrize("program", ["gpt1p3b-decode", "gpt1p3b-prefill256",
+                                     "falconh1-decode"])
+def test_paged_step_holds_no_slice_of_the_pool(one_chip, no_persistent_cache,
+                                               as_on_tpu, purge_engine,
+                                               program):
+    """The pool is addressed by (layer, page): at the chat cells' own
+    sizes and depths, no op of the compiled decode step or prefill has a
+    layer's slice of a K/V leaf as its result (a copy, a dynamic slice, a
+    reshape or bitcast-convert fusion: the parent's step held four a
+    layer, ``constant_dynamic-slice_fusion`` and ``squeeze..._reshape``
+    twice each, 87% of the 1.3B cell's step on the chip), the row scatter
+    writes in place, and the decode step is not larger than the
+    parent's."""
+    import json
+
+    from benchmarks.families import falcon_h1 as fam
+    from paddle_tpu.text import engine, generate
+
+    cell, kind = program.split("-")
+    if cell == "gpt1p3b":
+        cfg = _gpt(24)
+    else:
+        with open("benchmarks/configs/falcon-h1-34b-serve.json") as f:
+            cfg = fam.gpt_config(json.load(f))
+    purge_engine(cfg)
+    slots, blocks = POOL_CELLS[cell]
+    params = _abstract(_param_shapes(cfg), one_chip, dtype=BF)
+    cache = _abstract(jax.eval_shape(lambda: generate.init_cache(
+        cfg, slots, T, layout="paged", block_size=16, num_blocks=blocks)),
+        one_chip)
+    slice_elems = blocks * 16 * cfg.kv_heads * cfg.head_dim
+    assert int(np.prod(cache["k"].shape)) == cfg.num_layers * slice_elems
+    assert cache["k"].dtype == BF
+    if kind == "decode":
+        tok = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("step", engine.StepSpec(cfg=cfg, paged=True))
+        args = (params, cache, tok, tok)
+    else:
+        scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("paged_prefill",
+                               engine.StepSpec(cfg=cfg, bucket=256))
+        args = (params, cache, jax.ShapeDtypeStruct(
+            (1, 256), I32, sharding=one_chip), scalar, scalar, scalar)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    if kind == "decode":
+        assert _names_a_kernel("paged_decode_attention", text)
+    assert _holds_pool_slices(text, slice_elems, "bf16") == []
+    mem = compiled.memory_analysis()
+    # both leaves are donated and written where they are
+    assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * slice_elems * 2
+    if cell == "gpt1p3b":
+        # nothing of a slice's size among the temporaries (the parent:
+        # 604,726,272 B in the step, 253,104,640 B in this prefill)
+        assert mem.temp_size_in_bytes < slice_elems * 2
+    if kind == "decode":
+        # the hybrid step's peak is its mixer's state temporaries on both
+        # sides (two float32 slices of 268 MB): within 1 KB of the parent's
+        assert mem.peak_memory_in_bytes <= PARENT_STEP_PEAK[cell] + (1 << 20)
+        if cell == "gpt1p3b":
+            assert mem.peak_memory_in_bytes < PARENT_STEP_PEAK[cell] - 5e8
 
 
 def _train_step(cfg, mesh, accum=1):

@@ -572,7 +572,7 @@ def test_paged_verify_kernel_vs_gather_einsum(interpret, kv_env):
 
     kv_env(PADDLE_TPU_FLASH_DECODE="1")
     assert da.paged_available((B, K, cfg.num_heads, cfg.head_dim),
-                              fresh()["k"].shape[1:])
+                              fresh()["k"].shape)
     calls = {"n": 0}
     orig = da._paged_call
 
@@ -639,14 +639,20 @@ def test_spec_serving_flash_verify_greedy_parity(interpret, kv_env):
 
 # ---------------------------------------------------------------------------
 # the paged kernel walks each slot's live blocks: a grid cell a slot, the
-# pages of a compute block fetched by their physical number, all KV heads of
-# a page in one copy
+# pages of a compute block fetched by their layer and physical number from
+# the pool's whole leaf, all KV heads of a page in one copy
 # ---------------------------------------------------------------------------
 
 
-def _paged_case(Hkv, G_, Tq, bs, kv, hd=128, seed=0):
-    """Operands in which every slot is one way a table can look.  The
-    pages of a compute block are ``P``; ``nmax`` is no multiple of it."""
+def _leaf(pool):
+    """[..., Hkv, hd] rows as the pool stores them: [..., Hkv*hd]."""
+    return pool.reshape(pool.shape[:-2] + (-1,))
+
+
+def _paged_case(Hkv, G_, Tq, bs, kv, hd=128, seed=0, layers=1, layer=0):
+    """Operands in which every slot is one way a table can look, the
+    K/V leaves of ``layers`` layers to be read at ``layer``.  The pages
+    of a compute block are ``P``; ``nmax`` is no multiple of it."""
     P, _ = da._paged_geometry(bs, Hkv, hd, 8, 2)
     nmax = 2 * P + max(1, P // 2)
     T = nmax * bs
@@ -672,15 +678,16 @@ def _paged_case(Hkv, G_, Tq, bs, kv, hd=128, seed=0):
     tables[5, 0] = tables[1, 0]       # one block shared by two slots
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     q = jax.random.normal(ks[0], (B, Tq, Hkv * G_, hd), jnp.bfloat16)
-    kp = jax.random.normal(ks[1], (N, bs, Hkv, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, bs, Hkv, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (layers, N, bs, Hkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (layers, N, bs, Hkv, hd), jnp.float32)
     ksc = vsc = None
     if kv == "int8":
         kp, ksc = da.quantize_kv(kp)
         vp, vsc = da.quantize_kv(vp)
     else:
         kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
-    return (q, kp, vp, jnp.asarray(tables), jnp.asarray(pos, jnp.int32),
+    return (q, _leaf(kp), _leaf(vp), jnp.asarray(tables),
+            jnp.asarray(pos, jnp.int32), jnp.asarray(layer, jnp.int32),
             ksc, vsc)
 
 
@@ -700,22 +707,52 @@ def test_paged_kernel_matches_oracle(interpret, Hkv, G_, Tq, bs, kv):
     assert (out[4] == 0).all()
 
 
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("Tq", [1, 4])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_paged_kernel_reads_its_layer_of_the_leaf(interpret, layer, Tq, kv):
+    """A leaf of three layers read at each: what the oracle gives on
+    that layer alone (handed as a leaf of one layer, read at 0), through
+    the public entry and under ``jit`` with the layer a traced value, as
+    the step's scan hands it over."""
+    q, kp, vp, tables, pos, li, ksc, vsc = _paged_case(
+        4, 5, Tq, 16, kv, layers=3, layer=layer)
+    assert kp.shape[0] == 3 and da.paged_supported(q.shape, kp.shape)
+    sl = slice(layer, layer + 1)
+    ref = np.asarray(da._xla_paged(
+        q, kp[sl], vp[sl], tables, pos, 0,
+        None if ksc is None else ksc[sl], None if vsc is None else vsc[sl],
+        None), np.float32)
+    out = np.asarray(jax.jit(da.paged_decode_attention)(
+        q, kp, vp, tables, pos, li, ksc, vsc), np.float32)
+    live = [0, 1, 2, 3, 5]
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-2, rtol=2e-2)
+    assert (out[4] == 0).all()
+    # and the oracle itself reads the leaf by its layer
+    np.testing.assert_array_equal(
+        np.asarray(da._xla_paged(q, kp, vp, tables, pos, li, ksc, vsc,
+                                 None), np.float32), ref)
+
+
 @pytest.mark.parametrize("Tq", [1, 4])
 def test_paged_kernel_reads_no_block_it_need_not(interpret, Tq):
     """Blocks that no table maps, and blocks mapped past their slot's
-    frontier, are never copied: filled with NaN they change nothing."""
-    q, kp, vp, tables, pos, _, _ = _paged_case(4, 5, Tq, 16, "bf16")
-    bs = kp.shape[1]
+    frontier, are never copied, and no page of another layer is: filled
+    with NaN they change nothing."""
+    q, kp, vp, tables, pos, li, _, _ = _paged_case(4, 5, Tq, 16, "bf16",
+                                                   layers=2, layer=1)
+    bs = kp.shape[2]
     t = np.asarray(tables)
     needed = {int(t[b, j]) for b in range(t.shape[0])
               for j in range(t.shape[1])
               if t[b, j] >= 0 and j * bs <= int(pos[b]) + Tq - 1}
-    dead = np.asarray([n for n in range(kp.shape[0]) if n not in needed])
+    dead = np.asarray([n for n in range(kp.shape[1]) if n not in needed])
     assert 0 in dead and len(set(t[t >= 0]) & set(dead)) >= 2
-    clean = np.asarray(da._paged_call(q, kp, vp, tables, pos, None, None,
-                                      None), np.float32)
+    clean = np.asarray(da._paged_call(q, kp, vp, tables, pos, li, None,
+                                      None, None), np.float32)
     got = np.asarray(da._paged_call(
-        q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan), tables, pos,
+        q, kp.at[1, dead].set(jnp.nan).at[0].set(jnp.nan),
+        vp.at[1, dead].set(jnp.nan).at[0].set(jnp.nan), tables, pos, li,
         None, None, None), np.float32)
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got, clean)
@@ -742,14 +779,16 @@ def test_paged_grid_is_a_cell_a_slot(kv):
     S = jax.ShapeDtypeStruct
 
     def grid(B, Hkv, G_, nmax, bs):
-        pool = S((B * nmax, bs, Hkv, 128),
+        pool = S((2, B * nmax, bs, Hkv * 128),
                  jnp.int8 if kv == "int8" else jnp.bfloat16)
-        sc = S((B * nmax, bs, Hkv), jnp.float32) if kv == "int8" else None
+        sc = (S((2, B * nmax, bs, Hkv), jnp.float32) if kv == "int8"
+              else None)
         (g,) = _pallas_grids(
-            lambda q, k, v, t, p, a, b: da._paged_call(q, k, v, t, p, a, b,
-                                                       None),
+            lambda q, k, v, t, p, li, a, b: da._paged_call(
+                q, k, v, t, p, li, a, b, None),
             S((B, 1, Hkv * G_, 128), jnp.bfloat16), pool, pool,
-            S((B, nmax), jnp.int32), S((B,), jnp.int32), sc, sc)
+            S((B, nmax), jnp.int32), S((B,), jnp.int32), S((), jnp.int32),
+            sc, sc)
         return g
 
     cells = {grid(32, Hkv, G_, nmax, bs)
@@ -780,15 +819,18 @@ def test_paged_kernel_loops_head_chunks_at_many_q_rows(interpret):
     assert da._paged_geometry(bs, Hkv, 128, Tq * G_, 4)[1] == 2
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (2, Tq, Hkv * G_, 128), jnp.float32)
-    kp = jax.random.normal(ks[1], (24, bs, Hkv, 128), jnp.float32)
-    vp = jax.random.normal(ks[2], (24, bs, Hkv, 128), jnp.float32)
+    kp = jax.random.normal(ks[1], (1, 24, bs, Hkv * 128), jnp.float32)
+    vp = jax.random.normal(ks[2], (1, 24, bs, Hkv * 128), jnp.float32)
     tables = jnp.asarray(np.random.default_rng(3).permutation(24)
                          .reshape(2, 12), jnp.int32)
     pos = jnp.asarray([5, 100], jnp.int32)
+    li = jnp.asarray(0, jnp.int32)
     assert da.paged_supported(q.shape, kp.shape)
     np.testing.assert_allclose(
-        np.asarray(da._paged_call(q, kp, vp, tables, pos, None, None, None)),
-        np.asarray(da._xla_paged(q, kp, vp, tables, pos, None, None, None)),
+        np.asarray(da._paged_call(q, kp, vp, tables, pos, li, None, None,
+                                  None)),
+        np.asarray(da._xla_paged(q, kp, vp, tables, pos, li, None, None,
+                                 None)),
         atol=1e-4, rtol=1e-4)
 
 
@@ -819,8 +861,9 @@ def test_refusal_in_the_kernel_build_propagates(interpret, monkeypatch,
     with pytest.raises(ValueError, match="Mosaic refused"):
         if paged:
             tables = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
-            da.paged_decode_attention(q, k.reshape(8, 16, 4, 128),
-                                      v.reshape(8, 16, 4, 128), tables, pos)
+            da.paged_decode_attention(q, k.reshape(1, 8, 16, 4 * 128),
+                                      v.reshape(1, 8, 16, 4 * 128), tables,
+                                      pos, 0)
         else:
             da.decode_attention(q, k, v, pos)
     assert not hasattr(da, "_probe") and not hasattr(da, "_paged_probe")
@@ -841,11 +884,11 @@ def test_failed_shape_gate_still_picks_xla(interpret, monkeypatch):
         np.asarray(da.decode_attention(q, k, v, pos)),
         np.asarray(da._xla_decode(q, k, v, pos, None, None, None)),
         atol=1e-6)
-    kp, vp = k.reshape(8, 16, 4, 64), v.reshape(8, 16, 4, 64)
+    kp, vp = k.reshape(1, 8, 16, 4 * 64), v.reshape(1, 8, 16, 4 * 64)
     tables = jnp.arange(8, dtype=jnp.int32).reshape(2, 4)
     assert not da.paged_supported(q.shape, kp.shape)
     np.testing.assert_allclose(
-        np.asarray(da.paged_decode_attention(q, kp, vp, tables, pos)),
+        np.asarray(da.paged_decode_attention(q, kp, vp, tables, pos, 0)),
         np.asarray(da._xla_decode(q, k, v, pos, None, None, None)),
         atol=1e-6)
 
@@ -855,4 +898,4 @@ def test_off_a_tpu_the_gate_is_closed_without_interpret():
     assert da._INTERPRET is False
     assert da.supported((1, 1, 4, 128), (1, 64, 4, 128))
     assert not da.available((1, 1, 4, 128), (1, 64, 4, 128))
-    assert not da.paged_available((1, 1, 4, 128), (8, 16, 4, 128))
+    assert not da.paged_available((1, 1, 4, 128), (1, 8, 16, 4 * 128))

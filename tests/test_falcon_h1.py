@@ -252,7 +252,8 @@ def test_kernel_route_equals_reference():
     old = da._INTERPRET
     da._INTERPRET = True
     try:
-        assert da.paged_available((3, 1, 2, 128), (T // 16 * 3, 16, 1, 128))
+        assert da.paged_available((3, 1, 2, 128),
+                                  (2, T // 16 * 3, 16, 1 * 128))
         calls = []
         real = da._paged_call
         da._paged_call = lambda *a, **k: calls.append(1) or real(*a, **k)
@@ -274,13 +275,16 @@ def test_kernel_route_equals_reference():
 def test_idle_slot_keeps_its_state_bit_for_bit(interpret):
     """A decode step advances the live slots alone: a free slot, and one
     between the chunks of its prefill, read the same state after it, to
-    the bit; a slot fed position 0 starts from zero whatever it held."""
+    the bit; a slot fed position 0 starts from zero whatever it held.
+    The pool beside the state is addressed by (layer, page): each layer's
+    K/V leaf takes the step's four rows and keeps every other row, of
+    that layer and of the other, to the bit."""
     cfg = make_cfg(model=KERNEL_MODEL)
     p = gpt.init_params(cfg, jax.random.PRNGKey(4))
     cache = paged_cache(cfg, 4)
     junk = {n: jax.random.normal(jax.random.PRNGKey(i), cache[n].shape,
                                  jnp.float32).astype(cache[n].dtype)
-            for i, n in enumerate(ssm.STATE_LEAVES)}
+            for i, n in enumerate(ssm.STATE_LEAVES + ("k", "v"))}
     cache = dict(cache, **junk, live=jnp.asarray([True, False, True, False]))
     tok = jnp.asarray([5, 6, 7, 8], jnp.int32)
     pos = jnp.asarray([9, 4, 0, 0], jnp.int32)
@@ -296,6 +300,16 @@ def test_idle_slot_keeps_its_state_bit_for_bit(interpret):
             np.testing.assert_array_equal(np.asarray(new[n][:, slot]),
                                           np.asarray(junk[n][:, slot]))
         assert (np.asarray(new[n][:, 0]) != np.asarray(junk[n][:, 0])).any()
+    assert cache["k"].shape == (2, 4 * T // 16, 16, 1 * 128)
+    written = np.zeros(cache["k"].shape[1:3], bool)
+    for slot, at in enumerate(np.asarray(pos)):
+        written[np.asarray(cache["tables"])[slot, at // 16], at % 16] = True
+    for n in ("k", "v"):
+        got, was = np.asarray(new[n]), np.asarray(junk[n])
+        np.testing.assert_array_equal(got[:, ~written], was[:, ~written])
+        for layer in range(2):
+            assert (got[layer][written] != was[layer][written]).any()
+        assert (got[0][written] != got[1][written]).any()
     # slot 2 at position 0: what a zero state gives, not the junk's
     zero = dict(cache, **{n: jnp.zeros_like(cache[n])
                           for n in ssm.STATE_LEAVES})

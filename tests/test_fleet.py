@@ -550,8 +550,10 @@ def test_tp_decode_server_token_parity(markov_gpt, layout):
         srv.tick()
     got = [srv.result(r) for r in rids]
     k = srv.cache["k"]
-    hkv_axis = 3                      # slab [L,B,T,Hkv,hd] / pool [L,N,bs,Hkv,hd]
-    assert k.sharding.shard_shape(k.shape)[hkv_axis] == cfg.kv_heads // 2
+    # the heads' axis is 3 in both: slab [L,B,T,Hkv,hd], pool
+    # [L,N,bs,Hkv*hd] (a shard holds whole heads, side by side)
+    per_head = 1 if layout == "contiguous" else cfg.head_dim
+    assert k.sharding.shard_shape(k.shape)[3] == cfg.kv_heads // 2 * per_head
     if layout == "paged":
         t = srv.cache["tables"]
         assert t.sharding.shard_shape(t.shape) == t.shape  # replicated
@@ -601,7 +603,7 @@ def test_build_sharded_decode_paged_pool(markov_gpt):
         params, cfg, _mesh(2), layout="paged", block_size=8)
     cache_s = make_cache(2, 16)
     assert cache_s["k"].sharding.shard_shape(
-        cache_s["k"].shape)[3] == cfg.kv_heads // 2
+        cache_s["k"].shape)[3] == cfg.kv_heads // 2 * cfg.head_dim
     assert cache_s["tables"].sharding.shard_shape(
         cache_s["tables"].shape) == cache_s["tables"].shape
     cache_r = generate.init_cache(cfg, 2, 16, layout="paged",

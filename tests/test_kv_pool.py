@@ -216,7 +216,8 @@ def test_init_paged_cache_shapes(kv_env):
     cfg = _cfg(num_kv_heads=2)
     c = G.init_cache(cfg, 3, 20, layout="paged", block_size=8)
     # rows round to 24 -> nmax 3; full provisioning 3*3 blocks
-    assert c["k"].shape == (2, 9, 8, 2, 8)
+    # a row's heads side by side: 2 KV heads of 8
+    assert c["k"].shape == c["v"].shape == (2, 9, 8, 2 * 8)
     assert c["tables"].shape == (3, 3)
     assert int(c["tables"].min()) == -1
     kv_env(PADDLE_TPU_KV_DTYPE="int8")
@@ -518,34 +519,162 @@ def test_paged_kernel_matches_gathered_oracle(interpret, kv):
     bs, nmax, N = 8, 4, 10
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, 1, Hkv * G_, hd), jnp.float32)
-    kp = jax.random.normal(ks[1], (N, bs, Hkv, hd), jnp.float32)
-    vp = jax.random.normal(ks[2], (N, bs, Hkv, hd), jnp.float32)
+    kp = jax.random.normal(ks[1], (1, N, bs, Hkv, hd), jnp.float32)
+    vp = jax.random.normal(ks[2], (1, N, bs, Hkv, hd), jnp.float32)
     tables = jnp.asarray([[3, 5, 1, -1], [0, 7, -1, -1]], jnp.int32)
     pos = jnp.asarray([17, 9], jnp.int32)
     ksc = vsc = None
     if kv == "int8":
         kp, ksc = da.quantize_kv(kp)
         vp, vsc = da.quantize_kv(vp)
-    out = da.paged_decode_attention(q, kp, vp, tables, pos,
+    kp, vp = (x.reshape(1, N, bs, Hkv * hd) for x in (kp, vp))
+    out = da.paged_decode_attention(q, kp, vp, tables, pos, 0,
                                     k_scale=ksc, v_scale=vsc)
-    ref = da._xla_paged(q, kp, vp, tables, pos, ksc, vsc, None)
+    ref = da._xla_paged(q, kp, vp, tables, pos, 0, ksc, vsc, None)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
-def test_paged_kernel_route_greedy_tokens(interpret, kv_env):
-    """Through the server: the paged KERNEL route (scatter-then-gather
-    through the grid) yields the same greedy tokens as the contiguous
-    kernel route."""
-    # head_dim 64 (the kernel's smallest tile) at the smallest width
-    cfg = _cfg(hidden_size=128, num_heads=2, vocab_size=16)
+@pytest.mark.parametrize("scenario",
+                         ["hd64", "decode", "verify", "prefix", "cow", "mp2"])
+def test_paged_kernel_route_greedy_tokens(interpret, kv_env, monkeypatch,
+                                          scenario):
+    """Through the server: the paged KERNEL route (rows scattered into the
+    whole leaf, then the kernel reading it at the layer's number) yields
+    the same greedy tokens as the slab's kernel route — through plain
+    decode, speculation's verify step (Tq = K), an adopted prefix and a
+    copy-on-write, three layers deep.  ``hd64`` is off the kernel's gate:
+    the same servers through the gather-einsum route.  ``mp2`` shards the
+    pool's heads over two devices: each shard's kernel is handed its own
+    contiguous half of every row's lanes."""
+    hd = 64 if scenario == "hd64" else 128
+    cfg = _cfg(hidden_size=2 * hd, num_heads=2, vocab_size=16, num_layers=3)
     params = gpt.init_params(cfg, jax.random.PRNGKey(1))
+    calls = []
+    real = da._paged_call
+    monkeypatch.setattr(
+        da, "_paged_call",
+        lambda *a, **k: calls.append(a[1].shape) or real(*a, **k))
     rng = np.random.default_rng(6)
     prompts = [list(rng.integers(1, 15, 10)), list(rng.integers(1, 15, 5))]
-    ref, _ = _serve(params, cfg, prompts, "contiguous", max_new=5)
-    got, _ = _serve(params, cfg, prompts, "paged", max_new=5,
-                    block_size=8)
+    kw = {}
+    if scenario == "verify":
+        kw = {"spec_k": 3}
+    elif scenario == "prefix":
+        shared = list(rng.integers(1, 15, 8))
+        prompts = [shared + [1, 5], shared + [2], prompts[1]]
+    elif scenario == "cow":
+        prompts = [list(rng.integers(1, 15, 16))] * 2
+    ref, _ = _serve(params, cfg, prompts, "contiguous", max_new=5, **kw)
+    if scenario == "mp2":
+        from jax.sharding import Mesh
+
+        kw = {"mesh": Mesh(np.array(jax.devices()[:2]), ("mp",))}
+    got, stats = _serve(params, cfg, prompts, "paged", max_new=5,
+                        block_size=8, **kw)
     assert got == ref
+    assert bool(calls) == (scenario != "hd64")
+    if calls:
+        lanes = cfg.kv_heads * hd // (2 if scenario == "mp2" else 1)
+        assert set(calls) == {(3, 2 * 4, 8, lanes)}
+    if scenario == "prefix":
+        assert stats["prefix_hits"] > 0
+    if scenario == "cow":
+        assert stats["cow_copies"] >= 1
+
+
+def _written_rows(tables, pos, n, bs):
+    """[N, bs] bool: the physical rows a step writes, slot b's ``n`` rows
+    from ``pos[b]`` on through its table row."""
+    mask = np.zeros((int(tables.max()) + 1, bs), bool)
+    for b, p0 in enumerate(pos):
+        for t in range(int(p0), int(p0) + n):
+            mask[tables[b, t // bs], t % bs] = True
+    return mask
+
+
+@pytest.mark.parametrize("step,route,kv", [
+    ("decode", "einsum", "fp32"), ("decode", "kernel", "fp32"),
+    ("decode", "einsum", "int8"), ("decode", "kernel", "int8"),
+    ("verify", "einsum", "fp32"), ("verify", "kernel", "fp32"),
+    ("verify", "kernel", "int8"), ("tree", "einsum", "fp32"),
+    ("prefill", "einsum", "fp32"), ("prefill", "einsum", "int8")])
+def test_paged_step_writes_its_rows_and_no_other(interpret, kv_env, step,
+                                                 route, kv):
+    """One step over a pool of three layers: in every layer the rows the
+    step writes are new, each layer's its own, and every other row of
+    every leaf — the other pages of the layer, the same pages of the
+    other layers — reads as before, to the bit.  The kernel route writes
+    what the gather-einsum route writes."""
+    kv_env(PADDLE_TPU_KV_DTYPE=None if kv == "fp32" else kv)
+    cfg = _cfg(hidden_size=256, num_heads=2, num_layers=3, vocab_size=32)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(2))
+    B, bs, K = 2, 8, 4
+    old = da.random_filled_cache(
+        G.init_cache(cfg, B, 64, layout="paged", block_size=bs),
+        jax.random.PRNGKey(3), amp=0.3)
+    assert old["k"].shape == (3, B * 8, bs, 2 * 128)
+    pos = jnp.asarray([19, 42], jnp.int32)
+    tok = jnp.asarray([[3, 7, 1, 9], [5, 2, 8, 4]], jnp.int32)
+
+    def run():
+        if step == "decode":
+            return kv_pool.paged_decode_step_batched(params, old, tok[:, 0],
+                                                     pos, cfg)
+        if step == "verify":
+            return kv_pool.paged_verify_chunk_batched(params, old, tok, pos,
+                                                      cfg)
+        if step == "tree":
+            chain = jnp.broadcast_to(jnp.tril(jnp.ones((K, K), bool)),
+                                     (B, K, K))
+            depth = jnp.broadcast_to(jnp.arange(K), (B, K))
+            return kv_pool.paged_tree_verify_chunk_batched(
+                params, old, tok, chain, depth, pos, cfg)
+        return kv_pool.paged_prefill_chunk(
+            params, old, tok[1:], pos[1], jnp.asarray(K), jnp.asarray(1),
+            cfg)
+
+    calls = []
+    real = da._paged_call
+    da._paged_call = lambda *a, **k: calls.append(1) or real(*a, **k)
+    try:
+        kv_env(PADDLE_TPU_FLASH_DECODE="1" if route == "kernel" else "0")
+        logits, new = run()
+        assert bool(calls) == (route == "kernel")
+        if route == "kernel":
+            kv_env(PADDLE_TPU_FLASH_DECODE="0")
+            want_logits, want = run()
+    finally:
+        da._paged_call = real
+    tables = np.asarray(old["tables"])
+    if step == "prefill":
+        mask = _written_rows(tables[1:], np.asarray(pos[1:]), K, bs)
+    else:
+        mask = _written_rows(tables, np.asarray(pos),
+                             1 if step == "decode" else K, bs)
+    mask = np.pad(mask, ((0, old["k"].shape[1] - mask.shape[0]), (0, 0)))
+    for name in kv_pool.POOL_LEAVES:
+        if name not in old:
+            continue
+        before, after = np.asarray(old[name]), np.asarray(new[name])
+        np.testing.assert_array_equal(after[:, ~mask], before[:, ~mask])
+        for li in range(3):
+            assert (after[li][mask] != before[li][mask]).any(), (name, li)
+        assert (after[0][mask] != after[1][mask]).any()
+        assert (after[1][mask] != after[2][mask]).any()
+        if route == "kernel":
+            # the first layer's rows see no attention: the same to the
+            # bit; deeper ones to the storage dtype's rounding
+            got, ref = (np.asarray(x).astype(np.float32)
+                        for x in (after, want[name]))
+            np.testing.assert_array_equal(got[0], ref[0])
+            np.testing.assert_allclose(
+                got, ref,
+                atol=2 if name in ("k", "v") and kv == "int8" else 2e-2)
+    if route == "kernel":
+        np.testing.assert_allclose(np.asarray(logits),
+                                   np.asarray(want_logits), atol=3e-2,
+                                   rtol=3e-2)
 
 
 # ---------------------------------------------------------------------------
