@@ -164,7 +164,7 @@ def _xla_decode(q, k, v, pos, k_scale, v_scale, scale):
     s = jnp.where(mask, s, _NEG)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgit,btkd->bikgd", w, vf)
-    return out.reshape(B, Tq, Hq, hd).astype(q.dtype)
+    return out.reshape(B, Tq, Hq, vf.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -194,16 +194,17 @@ def _rows_first(q, Hkv: int):
 
 
 def _rows_last(out, q_shape):
-    B, Tq, Hq, hd = q_shape
-    Hkv = out.shape[1]
+    B, Tq, Hq, _ = q_shape
+    Hkv, hd = out.shape[1], out.shape[3]
     G = Hq // Hkv
     return (out[:, :, :Tq * G].reshape(B, Hkv, Tq, G, hd).swapaxes(1, 2)
             .reshape(B, Tq, Hq, hd))
 
 
 def _scratch(Rp: int, hd: int, heads: tuple = ()):
-    """Online-softmax state of one head's Rp rows (``heads``: a leading
-    dim for a cell that holds several heads at once)."""
+    """Online-softmax state of one head's Rp rows of ``hd`` value lanes
+    (``heads``: a leading dim for a cell that holds several heads at
+    once)."""
     from jax.experimental.pallas import tpu as pltpu
 
     return [pltpu.VMEM(heads + (Rp, 1), jnp.float32),     # running max
@@ -387,29 +388,36 @@ def gather_paged_view(leaf, layer, tables):
     return g.reshape((g.shape[0], g.shape[1] * g.shape[2]) + g.shape[3:])
 
 
-def paged_supported(q_shape, pool_shape) -> bool:
+def paged_supported(q_shape, pool_shape, v_width: int | None = None) -> bool:
     """Static shape gate for the paged kernel: q [B, Tq, Hq, hd] against
     a K/V leaf [L, N, bs, Hkv*hd] (the KV block is the pool's own
-    block)."""
+    block).  ``v_width``: the pool holds one shared row a token (a latent
+    row: the leaf's lanes are the score width ``hd``, the row's first
+    ``v_width`` lanes the value); whole lane tiles both."""
     B, Tq, Hq, hd = q_shape
     bs, lanes = pool_shape[2], pool_shape[3]
     Hkv = lanes // hd
-    return (hd in (128, 256) and lanes % hd == 0 and Hq % Hkv == 0
+    if v_width is not None:
+        widths = (lanes == hd and hd % 128 == 0 and 0 < v_width <= hd
+                  and v_width % 128 == 0)
+    else:
+        widths = hd in (128, 256)
+    return (widths and lanes % hd == 0 and Hq % Hkv == 0
             and Tq * (Hq // Hkv) <= _R_CAP
             and bs >= 8 and bs % 8 == 0)
 
 
-def paged_available(q_shape, pool_shape) -> bool:
+def paged_available(q_shape, pool_shape, v_width: int | None = None) -> bool:
     """paged_supported + a backend that runs the kernel (a TPU, or
     interpret mode when a test flipped ``_INTERPRET``) — the trace-time
     routing check text/kv_pool.py consults before leaving the
     gather-einsum path."""
-    return (paged_supported(q_shape, pool_shape)
+    return (paged_supported(q_shape, pool_shape, v_width)
             and (_INTERPRET or _pallas.on_tpu()))
 
 
 def _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
-               scale):
+               scale, v_width=None):
     """Oracle/fallback: gather the per-slot views of the layer through
     the tables and run the contiguous XLA reference — bit-identical
     values to a slab holding the same rows (the gather only relocates
@@ -417,7 +425,10 @@ def _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
     B, hd = q.shape[0], q.shape[3]
     per_head = (B, -1, k_pool.shape[3] // hd, hd)
     k = gather_paged_view(k_pool, layer, tables).reshape(per_head)
-    v = gather_paged_view(v_pool, layer, tables).reshape(per_head)
+    if v_pool is None:                      # the row's first lanes
+        v = k[..., :v_width]
+    else:
+        v = gather_paged_view(v_pool, layer, tables).reshape(per_head)
     ks = vs = None
     if k_scale is not None:
         ks = gather_paged_view(k_scale, layer, tables)
@@ -426,7 +437,8 @@ def _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer,
-                           k_scale=None, v_scale=None, scale=None):
+                           k_scale=None, v_scale=None, scale=None,
+                           v_width: int | None = None):
     """Block-table decode attention over one layer of the pool: q
     [B, Tq, Hq, hd] against the pool's K/V leaves [L, N, bs, Hkv*hd]
     read at ``layer`` (int32 scalar; a caller that holds one layer's
@@ -439,6 +451,11 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer,
     the static gate rejects take gather + the XLA reference; a shape it
     accepts compiles the kernel.
 
+    ``v_pool=None`` with ``v_width``: a pool of one shared row a token
+    (latent attention, absorbed: ``k_pool`` [L, N, bs, hd] holds the row
+    all ``Hq`` heads score against, and its first ``v_width`` lanes are
+    the value) -> [B, Tq, Hq, v_width].  A page is then copied once.
+
     A grid cell is a slot: it walks the table entries up to its causal
     frontier as far as they are mapped and copies those pages from the
     leaf in HBM by (layer, physical number), so the HBM read is each
@@ -447,11 +464,14 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, layer,
     unmapped or past the frontier, is neither copied nor visited.  A
     slot that attends nothing gives zeros."""
     layer = jnp.asarray(layer, jnp.int32)
-    if not paged_supported(q.shape, k_pool.shape):
+    if (v_pool is None) != (v_width is not None):
+        raise ValueError("v_pool=None and v_width come together")
+    if not paged_supported(q.shape, k_pool.shape, v_width):
         return _xla_paged(q, k_pool, v_pool, tables, pos, layer, k_scale,
-                          v_scale, scale)
+                          v_scale, scale, v_width)
     return _per_head_shard(
-        lambda *a: _paged_call(*a, scale), k_pool.shape[3] // q.shape[3],
+        lambda *a: _paged_call(*a, scale, v_width),
+        k_pool.shape[3] // q.shape[3],
         (q, 2), (k_pool, 3), (v_pool, 3), (tables, None), (pos, None),
         (layer, None), (k_scale, 3), (v_scale, 3))
 
@@ -477,7 +497,7 @@ def _paged_geometry(bs: int, Hkv: int, hd: int, Rp: int, itemsize: int):
 
 
 def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
-                scale):
+                scale, v_width=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -487,6 +507,10 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
     nmax = tables.shape[1]
     scale = scale if scale is not None else 1.0 / (hd ** 0.5)
     quant = k_scale is not None
+    # one shared row a token: the value is the key page's first lanes,
+    # so a page is copied once and there is no V operand or buffer
+    shared = v_pool is None
+    vd = v_width if shared else hd
 
     qh = _rows_first(q, Hkv)
     Rp = qh.shape[2]
@@ -496,12 +520,15 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
     pos2 = pos.reshape(B).astype(jnp.int32)
     lay = layer.reshape(1).astype(jnp.int32)
 
-    def kernel(tab_ref, pos_ref, lay_ref, q_ref, k_hbm, v_hbm, *rest):
-        if quant:
-            ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem, \
-                *scr = rest
+    def kernel(tab_ref, pos_ref, lay_ref, q_ref, k_hbm, *rest):
+        if shared:
+            o_ref, k_buf, sem, *scr = rest
+            v_hbm, v_buf = None, k_buf
+        elif quant:
+            v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, \
+                sem, *scr = rest
         else:
-            o_ref, k_buf, v_buf, sem, *scr = rest
+            v_hbm, o_ref, k_buf, v_buf, sem, *scr = rest
         b = pl.program_id(0)
         p_b = pos_ref[b]
         li = lay_ref[0]
@@ -526,8 +553,10 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
                 def _page():
                     page = tab_ref[b, g * P + j]
                     pairs = [
-                        (k_hbm.at[li, page, :, lanes], k_buf.at[half, j]),
-                        (v_hbm.at[li, page, :, lanes], v_buf.at[half, j])]
+                        (k_hbm.at[li, page, :, lanes], k_buf.at[half, j])]
+                    if not shared:
+                        pairs.append((v_hbm.at[li, page, :, lanes],
+                                      v_buf.at[half, j]))
                     if quant:
                         pairs += [(ks_hbm.at[page], ks_buf.at[half, j]),
                                   (vs_hbm.at[page], vs_buf.at[half, j])]
@@ -568,8 +597,8 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
                     hh = c * Hb + h
                     kb = k_buf[half, :, :, h * hd:(h + 1) * hd].astype(
                         jnp.float32).reshape(KB, hd)
-                    vb = v_buf[half, :, :, h * hd:(h + 1) * hd].astype(
-                        jnp.float32).reshape(KB, hd)
+                    vb = v_buf[half, :, :, h * hd:h * hd + vd].astype(
+                        jnp.float32).reshape(KB, vd)
                     if quant:
                         kb = kb * ks[:, hh:hh + 1]
                         vb = vb * vs[:, hh:hh + 1]
@@ -583,14 +612,17 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
                 o_ref[0, c * Hb + h] = _finish(scr[1].at[h], scr[2].at[h],
                                                o_ref.dtype)
 
-    q_spec = pl.BlockSpec((1, Hkv, Rp, hd),
-                          lambda b, tab_ref, pos_ref, lay_ref: (b, 0, 0, 0))
+    def cell(b, tab_ref, pos_ref, lay_ref):
+        return (b, 0, 0, 0)
+
+    q_spec = pl.BlockSpec((1, Hkv, Rp, hd), cell)
+    o_spec = pl.BlockSpec((1, Hkv, Rp, vd), cell)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [q_spec, in_hbm, in_hbm]
     # the K and V operands are the pool's leaves as they are stored
-    args = [qh, k_pool, v_pool]
-    bufs = [pltpu.VMEM((2, P, bs, Hb * hd), k_pool.dtype),
-            pltpu.VMEM((2, P, bs, Hb * hd), v_pool.dtype)]
+    pools = [k_pool] if shared else [k_pool, v_pool]
+    in_specs = [q_spec] + [in_hbm] * len(pools)
+    args = [qh] + pools
+    bufs = [pltpu.VMEM((2, P, bs, Hb * hd), x.dtype) for x in pools]
     if quant:
         # a page is copied whole lanes at a time: the scales' head axis
         # is padded up to a lane tile (the chip's compiler refuses to
@@ -609,14 +641,14 @@ def _paged_call(q, k_pool, v_pool, tables, pos, layer, k_scale, v_scale,
         num_scalar_prefetch=3,
         grid=(B,),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=o_spec,
         scratch_shapes=bufs + [pltpu.SemaphoreType.DMA((2,))]
-        + _scratch(Rp, hd, (Hb,)),
+        + _scratch(Rp, vd, (Hb,)),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qh.shape[:3] + (vd,), q.dtype),
         interpret=_INTERPRET,
         name="paged_decode_attention",
     )(tab, pos2, lay, *args)

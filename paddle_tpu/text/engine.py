@@ -194,7 +194,9 @@ def cfg_key(cfg):
              cfg.lm_head_multiplier, cfg.attention_in_multiplier,
              cfg.attention_out_multiplier, cfg.key_multiplier,
              tuple(cfg.mlp_multipliers),
-             cfg.ssm.key() if cfg.ssm is not None else None),
+             cfg.ssm.key() if cfg.ssm is not None else None,
+             cfg.mla.key() if cfg.mla is not None else None,
+             cfg.experts.key() if cfg.experts is not None else None),
             # trace-time env routing flags (flags.decode_jit_key): an
             # executable BAKES these in — W4 kernel gate (woq.mm), fused
             # LN (gpt._ln), cache donation (aliased vs copied buffers),

@@ -446,6 +446,11 @@ class PrefillWorker:
                 "PrefillWorker: a config with an ssm mixer cannot hand a "
                 "prefill off yet — the handoff ships KV rows, and the "
                 "recurrent state the prompt leaves has no wire form")
+        if cfg.mla is not None:
+            raise NotImplementedError(
+                "PrefillWorker: a latent-attention config cannot hand a "
+                "prefill off yet — the handoff ships K/V rows, and a "
+                "latent row has no wire form")
         self.cfg = cfg
         self.max_len = int(max_len)
         self.name = name

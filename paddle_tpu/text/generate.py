@@ -86,6 +86,11 @@ def init_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
             "a config with an ssm mixer decodes through the paged cache "
             "only (layout='paged'): the contiguous slab has no per-slot "
             "recurrent state leaves")
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "a latent-attention config decodes through the paged cache "
+            "only (layout='paged'): the contiguous slab has no latent "
+            "row format")
     L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
     dt = _kv_store_dtype(cfg)
     shape = (L, batch, _round_cache_len(max_len), H, hd)

@@ -97,6 +97,14 @@ class GPTConfig:
     # ssm.SSMConfig: every block runs a Mamba-2 mixer IN PARALLEL with
     # attention on the same normed input (h + attn + ssm), text/ssm.py
     ssm: Any = None
+    # mla.MLAConfig + moe.ExpertShareConfig, together: the layer is the
+    # shortcut-connected latent block (:func:`latent_block`): two latent
+    # attention sublayers, two dense SwiGLU FFNs, and one chip's share of
+    # a routed expert layer whose result joins after the second FFN.
+    # ``num_heads`` are the latent heads, ``intermediate_size`` the dense
+    # FFNs' width; the cache holds a latent row a token a sublayer
+    mla: Any = None
+    experts: Any = None
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -119,6 +127,20 @@ class GPTConfig:
             raise ValueError(f"unknown activation {self.activation!r}")
         if self.pos_embed == "rope" and self.head_dim % 2:
             raise ValueError("rope needs an even head_dim")
+        if (self.mla is None) != (self.experts is None):
+            raise ValueError(
+                "mla and experts come together (the latent block); a "
+                "plain block with latent attention, or with an expert "
+                "share, is not implemented")
+        if self.mla is not None and (
+                self.pos_embed != "rope" or self.norm != "rmsnorm"
+                or self.activation != "swiglu" or self.bias
+                or self.tie_embeddings or self.moe is not None
+                or self.ssm is not None or self.num_kv_heads is not None):
+            raise ValueError(
+                "the latent block is rope, RMSNorm, SwiGLU, bias-free, an "
+                "untied head, and neither cfg.moe (the GShard layer), an "
+                "ssm mixer nor num_kv_heads beside it")
         if self.moe is not None and self.activation != "gelu":
             raise ValueError(
                 "MoE experts use the gelu FFN; activation='swiglu' with "
@@ -167,6 +189,38 @@ def init_params(cfg: GPTConfig, key) -> dict:
 
     def nrm(k, shape, std=s):
         return std * jax.random.normal(k, shape, jnp.float32)
+
+    if cfg.mla is not None:
+        from . import mla as _mla
+        from .moe import init_expert_share
+
+        fk = jax.random.split(keys[9], 6)
+        ak = jax.random.split(keys[2], _mla.SUBLAYERS)
+        blocks = {
+            # the four norms of a layer: before each attention sublayer
+            # (0, 2) and each dense FFN (1, 3); the expert layer reads
+            # norm 1's output
+            "ln_g": jnp.ones((L, 4, D), jnp.float32),
+            "moe": init_expert_share(keys[3], D, cfg.experts, L, std=s),
+        }
+        # a sublayer's weights are leaves of their own, [L, ...]: a layer
+        # step reads each where it is stored (a leaf [L, 2, ...] would be
+        # copied out a layer at a time to be cut in two)
+        for i in range(_mla.SUBLAYERS):
+            blocks[f"attn{i}"] = _mla.init_params(
+                cfg.mla, D, cfg.num_heads, L, ak[i], std=s)
+            blocks[f"ffn{i}"] = {
+                "gate_w": nrm(fk[3 * i], (L, D, F)),
+                "fc_w": nrm(fk[3 * i + 1], (L, D, F)),
+                "out_w": nrm(fk[3 * i + 2], (L, F, D),
+                             std=s / math.sqrt(4 * L)),
+            }
+        return {
+            "wte": nrm(keys[0], (V, D)),
+            "lm_head": nrm(jax.random.fold_in(keys[0], 1), (V, D)),
+            "ln_f_g": jnp.ones((D,), jnp.float32),
+            "blocks": blocks,
+        }
 
     Dq = cfg.q_size
     blk_keys = jax.random.split(keys[9], 6)
@@ -245,6 +299,11 @@ def param_shardings(cfg: GPTConfig, dp="dp", mp="mp", pp=None, ep="ep") -> dict:
             "param_shardings: the ssm mixer has no tensor-parallel layout "
             "yet (its heads, groups and conv channels would have to split "
             "together)")
+    if cfg.mla is not None:
+        raise NotImplementedError(
+            "param_shardings: the latent block has no layout across chips "
+            "yet (no ep exchange is written: one chip runs its own share "
+            "of the experts)")
     l = pp  # leading stacked-layer axis shards over pipeline stages if set
     blocks = {
         "ln1_g": P(l, None),
@@ -532,6 +591,59 @@ def _ffn_tail(x, p, cfg: GPTConfig, valid=None, capacity=_LEGACY,
     return x + y, stats
 
 
+def latent_block(x, p, cfg: GPTConfig, attend, valid=None):
+    """The shortcut-connected latent layer on rows ``x`` [T, D] (a
+    sequence's positions, or a decode step's slots):
+
+        h1 = x  + MLA_0(norm_0(x))
+        m  = norm_1(h1);  S = experts(m);  h2 = h1 + FFN_0(m)
+        h3 = h2 + MLA_1(norm_2(h2))
+        out = h3 + FFN_1(norm_3(h3)) + S
+
+    The residual stream is float32 where the caller hands it in so (the
+    serving paths and ``forward`` do): every sublayer reads a normed copy
+    in the compute dtype and adds its output to the unrounded stream.
+    ``attend(i, n, p_i)`` is sublayer i's attention on the normed rows
+    ``n`` with its weights ``p_i``, out-projected: the full forward, the
+    prefill chunk and the decode step differ in nothing else.  ``valid``
+    [T]: rows that select experts (see ``moe.route_share``).  Returns
+    (out, the expert layer's counts)."""
+    from .moe import expert_share
+
+    def norm(h, j):
+        return _norm(h, {"ln_g": p["ln_g"][j]}, "ln", cfg)
+
+    h1 = x + attend(0, norm(x, 0), p["attn0"])
+    m = norm(h1, 1)
+    S, counts = expert_share(m, p["moe"], cfg.experts, cfg.dtype, valid)
+    h2 = h1 + _ffn_body(m, p["ffn0"], cfg)
+    h3 = h2 + attend(1, norm(h2, 2), p["attn1"])
+    return h3 + _ffn_body(norm(h3, 3), p["ffn1"], cfg) + S, counts
+
+
+def _latent_forward_block(x, p, cfg: GPTConfig):
+    """:func:`latent_block` over whole sequences [B, T, D]: the rows of
+    all sequences go through the layer together (the expert layer
+    takes rows, no batch axis); a sublayer's queries attend
+    their own sequence's rows, up-projected."""
+    from . import mla as _mla
+
+    B, T, D = x.shape
+    pos = jnp.arange(T)
+
+    def attend(i, n, p_i):
+        def one(nb):
+            q_nope, q_rope, rows = _mla.project(nb, p_i, cfg, pos)
+            with jax.named_scope("attn"):
+                return _mla.attend_chunk(q_nope, q_rope, rows, 0, p_i, cfg)
+
+        attn = jax.vmap(one)(n.reshape(B, T, D))
+        return _mla.out_proj(attn.reshape(B * T, -1), p_i, cfg)
+
+    out, _ = latent_block(x.reshape(B * T, D), p, cfg, attend)
+    return out.reshape(B, T, D)
+
+
 def _block(x, p, cfg: GPTConfig, dropout_key=None):
     """One transformer block on [B, T, D] activations (compute dtype)."""
     B, T, _ = x.shape
@@ -590,6 +702,19 @@ def forward_with_aux(params: dict, tokens, cfg: GPTConfig, act_sharding=None,
     if act_sharding is not None:
         x = jax.lax.with_sharding_constraint(x, act_sharding)
 
+    if cfg.mla is not None:
+        if key is not None:
+            raise NotImplementedError(
+                "the latent block has no training forward (dropout, "
+                "router noise): pass key=None")
+        from .moe import layer_of
+
+        x = x.astype(jnp.float32)   # the residual stream (latent_block)
+        for li in range(cfg.num_layers):  # its experts' leaves are a layer's
+            x = _latent_forward_block(x, layer_of(params["blocks"], li), cfg)
+        x = _norm(x, params, "ln_f", cfg)
+        return woq.logits(x, params, dt, cfg.lm_head_multiplier), \
+            jnp.zeros((), jnp.float32)
     blk = functools.partial(_block, cfg=cfg)
     if cfg.remat:  # see _remat_policy for the policy names
         # prevent_cse=False: inside lax.scan the loop structure already
@@ -652,6 +777,15 @@ def loss_fn(params: dict, tokens, cfg: GPTConfig, act_sharding=None, key=None):
 def count_params(cfg: GPTConfig) -> int:
     D, F, L, V, T = (cfg.hidden_size, cfg.ffn_size, cfg.num_layers, cfg.vocab_size,
                      cfg.max_seq_len)
+    if cfg.mla is not None:
+        from . import mla as _mla
+        from .moe import count_expert_share
+
+        router, expert = count_expert_share(cfg.experts, D)
+        outside = (2 * _mla.count_params(cfg.mla, D, cfg.num_heads)
+                   + 2 * 3 * D * F + 4 * D + router)
+        return (L * (outside + cfg.experts.n_held * expert)
+                + 2 * V * D + D)
     Dq, Dkv = cfg.q_size, cfg.kv_heads * cfg.head_dim
     b = 1 if cfg.bias else 0          # a projection's bias row, or none
     qkv = (D * Dq + b * Dq + 2 * D * Dkv + b * 2 * Dkv

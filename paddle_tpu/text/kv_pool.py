@@ -48,6 +48,7 @@ The contiguous layout stays the default (``PADDLE_TPU_KV_LAYOUT``).
 """
 from __future__ import annotations
 
+import math
 import zlib
 
 import jax
@@ -55,6 +56,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import generate, gpt, woq
+from . import mla as _mla
+from . import moe as _moe
 from . import ssm as _ssm
 from .. import flags as _flags
 from .. import telemetry as _telemetry
@@ -66,8 +69,15 @@ __all__ = [
     "paged_tree_commit", "copy_blocks", "inject_rows",
 ]
 
-# the value/scale leaves of a pooled cache (everything except "tables")
-POOL_LEAVES = ("k", "v", "k_s", "v_s")
+# a latent-attention config's pool: ONE leaf of latent rows
+# [2 * L, N, bs, lanes] in place of "k" and "v" (an attention sublayer,
+# not a layer, indexes its first axis; a row is [c | kr], zero-padded to
+# whole lane tiles), and the expert layer's device-side counts
+# (moe.SHARE_COUNTS) carried with the cache
+LATENT = "latent"
+COUNTS = "moe_counts"
+# the value/scale leaves of a pooled cache: what block tables map
+POOL_LEAVES = ("k", "v", "k_s", "v_s", LATENT)
 # a recurrent mixer's per-slot leaves [L, batch, ...] (no block table maps
 # them) and the [batch] bool leaf that says which slots a decode step
 # advances; held beside the pool when the config has an ssm mixer
@@ -123,6 +133,16 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
         raise ValueError(f"num_blocks must be >= 1, got {N}")
     L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
     dt = generate._kv_store_dtype(cfg)
+    if cfg.mla is not None:
+        if dt == jnp.int8:
+            raise NotImplementedError(
+                "an int8 pool of latent rows is not supported yet: the "
+                "scale planes are per head, and a latent row has none")
+        return {LATENT: jnp.zeros((_mla.SUBLAYERS * L, N, bs,
+                                   latent_lanes(cfg)), dt),
+                "tables": jnp.full((batch, nmax), -1, jnp.int32),
+                LIVE: jnp.zeros((batch,), bool),
+                COUNTS: jnp.zeros((len(_moe.SHARE_COUNTS),), jnp.int32)}
     shape = (L, N, bs, H * hd)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
              "tables": jnp.full((batch, nmax), -1, jnp.int32)}
@@ -136,6 +156,13 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
         cache.update(_ssm.init_state(cfg.ssm, L, batch, cfg.dtype))
         cache[LIVE] = jnp.zeros((batch,), bool)
     return cache
+
+
+def latent_lanes(cfg: gpt.GPTConfig) -> int:
+    """Lanes a stored latent row takes: ``[c | kr]`` and zeros up to a
+    whole lane tile (the chip's layout pads the last axis to 128 whatever
+    is asked for, and the paged kernel copies whole tiles)."""
+    return -(-cfg.mla.row_width // 128) * 128
 
 
 def _per_slot(mask, leaf, axis: int):
@@ -163,8 +190,8 @@ def _keep_idle(new: dict, old: dict, live, axis: int) -> dict:
 
 def _geometry(cache: dict):
     """(num_blocks, block_size, nmax) of a pooled cache pytree."""
-    N, bs = cache["k"].shape[1], cache["k"].shape[2]
-    return N, bs, cache["tables"].shape[1]
+    leaf = cache["k"] if "k" in cache else cache[LATENT]
+    return leaf.shape[1], leaf.shape[2], cache["tables"].shape[1]
 
 
 def _gather_slot(pool: dict, li, trow, cfg: gpt.GPTConfig) -> dict:
@@ -250,6 +277,8 @@ def paged_decode_step_batched(params, cache, token, pos,
     [B, T] gather is ever materialized."""
     from ..ops import decode_attention as da
 
+    if LATENT in cache:
+        return _latent_step(params, cache, token, pos, cfg)
     N, bs, nmax = _geometry(cache)
     B = token.shape[0]
     H, hd = cfg.num_heads, cfg.head_dim
@@ -385,6 +414,116 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
     return logits.astype(jnp.float32), dict(cache, **pool, **(state or {}))
 
 
+def _pad_lanes(x, lanes: int):
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - x.shape[-1])])
+
+
+def _latent_step(params, cache, token, pos, cfg: gpt.GPTConfig):
+    """:func:`paged_decode_step_batched` for a latent-attention config:
+    the whole batch a layer at a time ([B, D] rows through
+    ``gpt.latent_block``), so that the expert layer routes the step's
+    tokens together.  An attention sublayer writes its fresh latent rows
+    into the pool by (sublayer, page) and attends in the absorbed form:
+    the paged kernel, each slot's live pages and nothing else, where it
+    runs; the per-slot whole view through the tables elsewhere.  Slots
+    the ``live`` leaf does not name select no expert and count nowhere."""
+    from ..ops import decode_attention as da
+
+    N, bs, nmax = _geometry(cache)
+    B = token.shape[0]
+    m, dt, H = cfg.mla, cfg.dtype, cfg.num_heads
+    tables, live = cache["tables"], cache[LIVE]
+    tb = tables[jnp.arange(B), pos // bs]
+    phys = jnp.where(tb >= 0, tb * bs + pos % bs, N * bs)
+    lanes = cache[LATENT].shape[3]
+    kernel = (_flags.flash_decode() and da.paged_available(
+        (B, 1, H, lanes), cache[LATENT].shape, m.kv_lora_rank))
+    attend_pool = da.paged_decode_attention if kernel else da._xla_paged
+    # the residual stream in float32 (the sublayers' inputs are normed
+    # into the compute dtype): [B, D]
+    x = woq.embed(params, token, dt,
+                  cfg.embedding_multiplier).astype(jnp.float32)
+
+    # the layers in a Python loop, not a scan: a layer's weights are then
+    # read where they are stored (see moe.init_expert_share)
+    pool, counts = cache[LATENT], cache[COUNTS]
+    for li in range(cfg.num_layers):
+        box = [pool]
+
+        def attend(i, n, p_i, li=li):
+            sub = _mla.SUBLAYERS * li + i
+            q_nope, q_rope, rows = _mla.project(n, p_i, cfg, pos)
+            # scatter-then-attend, as the K/V kernel route does
+            with jax.named_scope("kv_gather"):
+                box[0] = _put_rows(box[0], sub, phys,
+                                   _pad_lanes(rows, lanes))
+            with jax.named_scope("attn"):
+                q_lat = _pad_lanes(_mla.absorb_q(q_nope, q_rope, p_i, cfg),
+                                   lanes)
+                lat = attend_pool(
+                    q_lat[:, None], box[0], None, tables, pos,
+                    jnp.asarray(sub, jnp.int32), None, None,
+                    1.0 / math.sqrt(m.qk_head_dim), m.kv_lora_rank)
+                out = _mla.absorb_out(lat[:, 0], p_i, cfg)
+            return _mla.out_proj(out, p_i, cfg)
+
+        x, c = gpt.latent_block(x, _moe.layer_of(params["blocks"], li),
+                                cfg, attend, valid=live)
+        pool, counts = box[0], counts + c
+    x = gpt._norm(x, params, "ln_f", cfg)
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
+    return logits.astype(jnp.float32), dict(
+        cache, **{LATENT: pool, COUNTS: counts})
+
+
+def _latent_prefill_chunk(params, cache, tokens, pos0, length, slot,
+                          cfg: gpt.GPTConfig):
+    """:func:`paged_prefill_chunk` for a latent-attention config: the
+    chunk's queries attend the slot's table-gathered latent rows [0, pos0)
+    and the chunk's own, up-projected (``mla.attend_chunk``); rows
+    [pos0, pos0 + length) are written through the table, a sublayer at a
+    time.  Padded positions select no expert and write no row."""
+    from ..ops import decode_attention as da
+
+    N, bs, nmax = _geometry(cache)
+    trow = cache["tables"][slot]                          # [nmax]
+    dt, C = cfg.dtype, tokens.shape[1]
+    lanes = cache[LATENT].shape[3]
+    x = woq.embed(params, tokens[0], dt,
+                  cfg.embedding_multiplier).astype(jnp.float32)    # [C, D]
+    valid = jnp.arange(C) < length
+    logi = pos0 + jnp.arange(C)
+    tb = trow[jnp.clip(logi // bs, 0, nmax - 1)]
+    phys = jnp.where(valid & (tb >= 0) & (logi // bs < nmax),
+                     tb * bs + logi % bs, N * bs)
+
+    pool = cache[LATENT]
+    for li in range(cfg.num_layers):
+        box = [pool]
+
+        def attend(i, n, p_i, li=li):
+            sub = _mla.SUBLAYERS * li + i
+            q_nope, q_rope, rows = _mla.project(n, p_i, cfg, logi)
+            rows = _pad_lanes(rows, lanes).astype(pool.dtype)
+            with jax.named_scope("kv_gather"):
+                view = da.gather_paged_view(box[0], sub, trow[None])[0]
+                full = jax.lax.dynamic_update_slice(view, rows, (pos0, 0))
+                box[0] = _put_rows(box[0], sub, phys, rows)
+            with jax.named_scope("attn"):
+                a = _mla.attend_chunk(q_nope, q_rope,
+                                      full[:, :cfg.mla.row_width], pos0,
+                                      p_i, cfg)
+            return _mla.out_proj(a, p_i, cfg)
+
+        x, _ = gpt.latent_block(x, _moe.layer_of(params["blocks"], li),
+                                cfg, attend, valid=valid)
+        pool = box[0]
+    last = jax.lax.dynamic_slice(x, (length - 1, 0), (1, cfg.hidden_size))
+    last = gpt._norm(last, params, "ln_f", cfg)
+    logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0]
+    return logits.astype(jnp.float32), dict(cache, **{LATENT: pool})
+
+
 def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
                         cfg: gpt.GPTConfig):
     """``generate.prefill_slot_chunk`` on the pooled layout: one chunk of
@@ -399,6 +538,9 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
     first unshared row — the shared blocks are ATTENDED through the
     gather but never recomputed, which is where the prefix cache's
     prefill FLOPs saving comes from."""
+    if LATENT in cache:
+        return _latent_prefill_chunk(params, cache, tokens, pos0, length,
+                                     slot, cfg)
     N, bs, nmax = _geometry(cache)
     tables = cache["tables"]
     trow = tables[slot]                                   # [nmax]

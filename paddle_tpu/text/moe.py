@@ -226,3 +226,153 @@ def moe_ffn(params: dict, x, cfg: MoEConfig, key=None, activation=jax.nn.gelu,
     if with_stats:
         return y.reshape(orig_shape), aux, delta
     return y.reshape(orig_shape), aux
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of a routed expert layer with zero-compute experts
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class ExpertShareConfig:
+    """A routed expert layer as one chip of an expert-parallel deployment
+    sees it: the router scores all ``n_routed`` SwiGLU experts and the
+    ``n_zero`` zero-compute (identity) experts, a token takes its
+    ``top_k`` best, and this chip holds the routed experts ``held`` =
+    [lo, hi).  The layer computes the held experts' part of the result
+    and the identity part; what the absent experts would add is left out
+    (their chips add it in a deployment: no ``ep`` exchange is written)."""
+    n_routed: int                 # routed experts the router scores
+    n_zero: int                   # identity experts after them
+    top_k: int
+    expert_size: int              # a routed expert's SwiGLU width
+    scaling: float = 1.0          # routed_scaling_factor on the whole sum
+    held: tuple = (0, 0)          # [lo, hi) of the routed experts held here
+
+    def __post_init__(self):
+        lo, hi = self.held
+        if not 0 <= lo < hi <= self.n_routed:
+            raise ValueError(f"held {self.held} is no range of the "
+                             f"{self.n_routed} routed experts")
+        if self.top_k > self.n_routed + self.n_zero:
+            raise ValueError("top_k exceeds the router's width")
+
+    @property
+    def n_held(self) -> int:
+        return self.held[1] - self.held[0]
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed + self.n_zero
+
+    def key(self) -> tuple:
+        return dataclasses.astuple(self)
+
+
+# what expert_share counts of a call's token-expert selections, in order:
+# to a held expert, to an identity expert, to an expert another chip
+# holds; then the distinct held experts hit, and whether any row selected
+# at all (the calls counted: a call whose rows are all free slots is none)
+SHARE_COUNTS = ("pairs_held", "pairs_zero", "pairs_absent", "experts_hit",
+                "calls")
+
+
+def init_expert_share(key, d_model: int, ex: ExpertShareConfig,
+                      layers: int, std: float = 0.02) -> dict:
+    """The router over all experts (``[L, ...]`` leaves, its selection
+    bias beside it) and the held experts' three matrices, each a TUPLE
+    of a leaf a layer ``[E, ...]``: a layer's experts cut out of a
+    stacked leaf were copied (600 MB a layer at the published widths)
+    before every use."""
+    k = jax.random.split(key, 4)
+    E, Fe = ex.n_held, ex.expert_size
+
+    def nrm(kk, shape, s=std):
+        return s * jax.random.normal(kk, (layers,) + shape, jnp.float32)
+
+    def per_layer(kk, shape, s=std):
+        return tuple(s * jax.random.normal(kl, shape, jnp.float32)
+                     for kl in jax.random.split(kk, layers))
+
+    return {
+        "router_w": nrm(k[0], (d_model, ex.router_width)),
+        "router_b": jnp.zeros((layers, ex.router_width), jnp.float32),
+        "gate_w": per_layer(k[1], (E, d_model, Fe)),
+        "up_w": per_layer(k[2], (E, d_model, Fe)),
+        "down_w": per_layer(k[3], (E, Fe, d_model),
+                            std / math.sqrt(2 * layers)),
+    }
+
+
+def layer_of(blocks: dict, li: int) -> dict:
+    """Layer ``li``'s weights of a latent config's ``blocks``: a stacked
+    leaf's static slice, a per-layer tuple's member."""
+    return jax.tree_util.tree_map(
+        lambda v: v[li], blocks, is_leaf=lambda v: isinstance(v, tuple))
+
+
+def count_expert_share(ex: ExpertShareConfig, d_model: int) -> tuple:
+    """(router + selection bias, one routed expert)."""
+    return ((d_model + 1) * ex.router_width, 3 * d_model * ex.expert_size)
+
+
+def route_share(m, p, ex: ExpertShareConfig, valid=None):
+    """The router on rows ``m`` [T, D], in float32: (idx [T, k] the
+    selected experts, w [T, k] their softmax scores, not renormalised).
+    The selection bias chooses only.  ``valid`` [T]: a row that is
+    padding, or a slot that holds no request, selects no expert (its
+    scores are zero and its selections count nowhere)."""
+    with jax.named_scope("moe_route"):
+        logits = m.astype(jnp.float32) @ p["router_w"].astype(jnp.float32)
+        s = jax.nn.softmax(logits, axis=-1)
+        _, idx = jax.lax.top_k(s + p["router_b"].astype(jnp.float32),
+                               ex.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if valid is not None:
+            w = jnp.where(valid[:, None], w, 0.0)
+        return idx, w
+
+
+def expert_share(m, p, ex: ExpertShareConfig, dt, valid=None):
+    """``scaling * (sum over a token's selected HELD experts of s_e *
+    expert_e(m) + sum over its selected identity experts of s_e * m)`` on
+    rows ``m`` [T, D] -> ([T, D] float32, for the caller's residual stream;
+    counts int32 [5] as ``SHARE_COUNTS``).
+
+    No token is dropped whatever the routing, and neither a shape nor the
+    work depends on it: every held expert runs on every row (three
+    batched matmuls, an expert a batch entry: at a step's few rows an
+    expert's weights are what a matmul costs, and they are read once),
+    and a row's score for an expert it did not select is zero.  The
+    scores go onto the hidden activations, so the down projection sums
+    over the experts in its float32 accumulator."""
+    T, D = m.shape
+    k, E = ex.top_k, ex.n_held
+    lo, hi = ex.held
+    with jax.named_scope("moe"):
+        idx, w = route_share(m, p, ex, valid)
+        live = (jnp.ones((T, 1), bool) if valid is None
+                else valid[:, None])
+        held = (idx >= lo) & (idx < hi) & live
+        zero = (idx >= ex.n_routed) & live
+        with jax.named_scope("moe_zero"):
+            z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1, keepdims=True)
+            out = z * m.astype(jnp.float32)
+        with jax.named_scope("moe_experts"):
+            # sel [T, k, E]: selection j of token t is held expert e
+            sel = held[:, :, None] & (
+                (idx - lo)[:, :, None] == jnp.arange(E)[None, None, :])
+            score = jnp.sum(jnp.where(sel, w[:, :, None], 0.0), axis=1)
+            sizes = jnp.sum(sel, axis=(0, 1), dtype=jnp.int32)
+            xe = jnp.broadcast_to(m.astype(dt), (E, T, D))
+            g = jnp.einsum("etd,edf->etf", xe, woq.w(p, "gate_w", dt))
+            u = jnp.einsum("etd,edf->etf", xe, woq.w(p, "up_w", dt))
+            h = (jax.nn.silu(g) * u).astype(jnp.float32) * score.T[:, :, None]
+            out = out + jnp.einsum(
+                "etf,efd->td", h.astype(dt), woq.w(p, "down_w", dt),
+                preferred_element_type=jnp.float32)
+        n_held, n_zero = jnp.sum(held), jnp.sum(zero)
+        counts = jnp.stack([
+            n_held, n_zero, jnp.sum(live) * k - n_held - n_zero,
+            jnp.sum(sizes > 0), jnp.any(live)]).astype(jnp.int32)
+        return ex.scaling * out, counts

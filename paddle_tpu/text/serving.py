@@ -34,6 +34,7 @@ from . import admission as _admission
 from . import engine as _engine
 from . import generate, gpt
 from .engine import StepSpec as _Spec
+from .moe import SHARE_COUNTS as _SHARE_COUNTS
 from .. import faults as _faults
 from .. import flags as _flags
 from .. import resilience as _resilience
@@ -513,6 +514,16 @@ def validate_request(prompt, max_new_tokens, stop, temperature, top_k,
     return prompt, stop, ttl, min(int(top_k), vocab_size)
 
 
+def _speculation_asked(draft_cfg, spec_k, spec_tree) -> bool:
+    """Would a server built with these arguments speculate (a draft, a
+    linear or tree budget, or the environment's where neither is
+    stated)?  What a configuration that cannot speculate refuses on."""
+    return (draft_cfg is not None or (spec_k or 0) > 0
+            or (spec_tree or 0) > 0
+            or (spec_k is None and spec_tree is None
+                and bool(_flags.spec_k() or _flags.spec_tree())))
+
+
 class DecodeServer:
     """Host-side slot scheduler around one jitted batched decode step.
 
@@ -599,6 +610,24 @@ class DecodeServer:
             self._refuse_recurrent(
                 lay, mesh, draft_cfg, spec_k, spec_tree, adapter_pool,
                 prefill_chunk, prefill_budget, max_len)
+        # latent attention with an expert share (cfg.mla, cfg.experts):
+        # the pool holds one leaf of latent rows, the ``live`` leaf tells
+        # the step which slots select experts, and the expert layer's
+        # counts ride the cache (kv_pool.COUNTS, drained by load_stats
+        # and close)
+        self._latent = cfg.mla is not None
+        self._share_counts = np.zeros((len(_SHARE_COUNTS),), np.int64)
+        # the device-side counts are int32 and a step adds at most
+        # max_batch * top_k * layers to one: tick() drains them long
+        # before one can wrap (every 13 minutes of steps at the cell's
+        # size: one fetch that waits for the step in flight)
+        self._share_ticks = 0
+        self._share_drain_every = (
+            (1 << 28) // (max_batch * cfg.experts.top_k * cfg.num_layers)
+            if cfg.mla is not None else 0)
+        if self._latent:
+            self._refuse_latent(lay, mesh, draft_cfg, spec_k, spec_tree,
+                                adapter_pool)
         if self._paged:
             from . import kv_pool as _kv
 
@@ -608,12 +637,15 @@ class DecodeServer:
             self.cache = generate.init_cache(
                 cfg, max_batch, max_len, layout="paged",
                 block_size=block_size, num_blocks=num_blocks)
-            self._pool = _kv.PagedAllocator(
-                self.cache["k"].shape[1], self.cache["k"].shape[2],
-                self.cache["tables"].shape[1], max_batch)
+            self._pool = _kv.PagedAllocator(*_kv._geometry(self.cache),
+                                            max_batch)
             if self._recurrent and self._tel:
                 _telemetry.gauge("kv_pool.state_bytes").set(sum(
                     self.cache[n].nbytes for n in _kv.STATE_LEAVES))
+            if self._latent and self._tel:
+                leaf = self.cache[_kv.LATENT]
+                _telemetry.gauge("kv_pool.latent_row_bytes").set(
+                    leaf.shape[0] * leaf.shape[3] * leaf.dtype.itemsize)
         else:
             self._pool = None
             self.cache = generate.init_cache(cfg, max_batch, max_len)
@@ -986,10 +1018,7 @@ class DecodeServer:
         if mesh is not None:
             no("mesh=", "the mixer has no tensor-parallel layout "
                "(gpt.param_shardings)")
-        if draft_cfg is not None or (spec_k or 0) > 0 \
-                or (spec_tree or 0) > 0 \
-                or (spec_k is None and spec_tree is None
-                    and (_flags.spec_k() or _flags.spec_tree())):
+        if _speculation_asked(draft_cfg, spec_k, spec_tree):
             no("speculation (spec_k / spec_tree / draft_cfg)",
                "a rejected draft token has advanced the state, and a "
                "state cannot be rolled back as rows are")
@@ -1018,7 +1047,42 @@ class DecodeServer:
                     f"{window} for a config with an ssm mixer (its "
                     f"prefill chunks cannot overlap)")
 
+    def _refuse_latent(self, lay, mesh, draft_cfg, spec_k, spec_tree,
+                       adapter_pool):
+        """What cannot work with latent rows and an expert share yet
+        raises at construction, naming the reason."""
+        def no(what, why):
+            raise NotImplementedError(
+                f"{what} with latent attention and an expert share "
+                f"(cfg.mla, cfg.experts) is not supported yet: {why}")
+
+        if lay != "paged":
+            no("layout='contiguous'", "the contiguous slab has no latent "
+               "row format; pass layout='paged'")
+        if mesh is not None:
+            no("mesh=", "no ep exchange is written: one chip runs its own "
+               "share of the experts, and nothing stands in for the "
+               "absent chips")
+        if _speculation_asked(draft_cfg, spec_k, spec_tree):
+            no("speculation (spec_k / spec_tree / draft_cfg)",
+               "the verify chunks know no latent row format, and a "
+               "chunk's tokens would select experts for rejected drafts")
+        if adapter_pool is not None:
+            no("adapter_pool", "the adapter step kinds know no latent rows")
+        if _flags.kv_spill_mb():
+            no("the host spill tier (PADDLE_TPU_KV_SPILL_MB)",
+               "it restores rows by inject_rows, and a latent row has no "
+               "wire form")
+        if _flags.kv_cache_dtype() == "int8":
+            no("an int8 pool (PADDLE_TPU_KV_DTYPE=int8)",
+               "the scale planes are per head, and a latent row has none")
+
     def _refuse_handoff(self, what: str):
+        if self._latent:
+            raise NotImplementedError(
+                f"{what} with latent attention (cfg.mla) is not supported "
+                f"yet: the prefill handoff ships K/V rows (inject_rows), "
+                f"and a latent row has no wire form")
         if self._recurrent:
             raise NotImplementedError(
                 f"{what} with an ssm mixer (cfg.ssm) is not supported "
@@ -1057,8 +1121,8 @@ class DecodeServer:
         """Tell the next decode step which slots it advances: the slots
         that decode (or feed their prompt token by token), not the free
         ones and not those mid-admission, whose state the prefill chunks
-        own.  A no-op without a recurrent state."""
-        if not self._recurrent:
+        own.  A no-op for a cache without the leaf."""
+        if not (self._recurrent or self._latent):
             return
         from . import kv_pool as _kv
 
@@ -3407,11 +3471,14 @@ class DecodeServer:
         if self._wedged:
             self._wedged = False
             _telemetry.clear_runtime_wedge()
-        if self._moe_stats is not None:
+        if self._moe_stats is not None or self._latent:
             # publish the final routing totals before the accumulator
             # (and its device buffer) is dropped with the executables
             try:
-                self._moe_snapshot()
+                if self._latent:
+                    self._drain_share_counts()
+                else:
+                    self._moe_snapshot()
             except Exception:
                 pass    # a wedged device must not block shutdown
             self._moe_stats = None
@@ -3604,6 +3671,9 @@ class DecodeServer:
             **(dict(zip(("moe_dropped_tokens", "moe_expert_load"),
                         self._moe_snapshot()))
                if self._moe_stats is not None else {}),
+            # an expert share's selections by where they went, since the
+            # server was built (the drain publishes the moe.* telemetry)
+            **(self._drain_share_counts() if self._latent else {}),
             # fleet tracing: spans ride the stats collection when asked
             **(dict(zip(("spans", "span_drops"), self.drain_spans()))
                if include_spans else {}),
@@ -3762,6 +3832,39 @@ class DecodeServer:
             self._moe_stats, counted=self._moe_counted, tel=self._tel)
         self._moe_counted = dropped
         return dropped, load
+
+    def _drain_share_counts(self) -> dict:
+        """Fetch the expert layer's device-side counts
+        (``kv_pool.COUNTS``, added up by every decode step over its live
+        slots), zero the leaf, and publish what came since the last
+        drain: counters ``moe.pairs_held`` / ``pairs_zero`` /
+        ``pairs_absent`` (token-expert selections to an expert held
+        here, to an identity expert, to another chip's) and the gauge
+        ``moe.experts_hit`` (distinct held experts hit a layer a step,
+        mean over what this server has run).  The fetch waits for the
+        step in flight; no tick pays it.  Returns the totals."""
+        from . import kv_pool as _kv
+
+        self._share_ticks = 0
+        if self.cache is not None:
+            new = np.asarray(jax.device_get(self.cache[_kv.COUNTS]),
+                             np.int64)
+            zeros = jnp.zeros_like(self.cache[_kv.COUNTS])
+            if self._device is not None:
+                zeros = jax.device_put(zeros, self._device)
+            self.cache = dict(self.cache, **{_kv.COUNTS: zeros})
+            self._share_counts = self._share_counts + new
+            if self._tel:
+                for name, n in zip(_SHARE_COUNTS[:3], new[:3]):
+                    if n:
+                        _telemetry.count("moe." + name, int(n))
+        held, zero, absent, hit, calls = (int(v) for v in
+                                          self._share_counts)
+        if self._tel and calls:
+            _telemetry.set_gauge("moe.experts_hit", hit / calls)
+        return {"moe_pairs_held": held, "moe_pairs_zero": zero,
+                "moe_pairs_absent": absent,
+                "moe_experts_hit": hit / calls if calls else 0.0}
 
     # -- multi-tenant serving: adapter gather + constraint masks ------------
 
@@ -4333,6 +4436,10 @@ class DecodeServer:
             self._adm.control_tick(
                 idle=not self._slots and not self._queue)
         self._rss_guard()
+        if self._latent:
+            self._share_ticks += 1
+            if self._share_ticks >= self._share_drain_every:
+                self._drain_share_counts()
         with self._tick_scope():
             self._guarded(self._tick_impl)
 

@@ -434,6 +434,64 @@ def test_paged_step_holds_no_slice_of_the_pool(one_chip, no_persistent_cache,
             assert mem.peak_memory_in_bytes < PARENT_STEP_PEAK[cell] - 5e8
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill256"])
+def test_latent_paged_step_holds_no_slice_and_no_whole_view(
+        one_chip, no_persistent_cache, as_on_tpu, purge_engine, program):
+    """The latent cell at its own size (256 slots, 20,480 blocks of 16
+    rows of 640 lanes, 4 layers of 16 held experts): the decode step takes
+    the paged kernel on the shared row (eight calls, a sublayer each), no
+    op has a sublayer's slice of the pool as its result, the row scatters
+    write in place, and the temporaries hold neither a per-slot whole
+    view of a sublayer (256 x 4096 rows: 1.3 GB) nor a copy of a layer's
+    experts (0.6 GB: the batched matmuls read each layer's own leaf).
+    The prefill gathers one slot's view (5 MB a sublayer), nothing more."""
+    import json
+
+    from benchmarks.families import longcat_flash as fam
+    from paddle_tpu.text import engine, generate, kv_pool
+
+    with open("benchmarks/configs/longcat-flash-omni-serve.json") as f:
+        config = json.load(f)
+    cfg = fam.gpt_config(config)
+    purge_engine(cfg)
+    a = config["entry_point"]["args"]
+    slots, blocks = a["max_batch"], a["num_blocks"]
+    params = _abstract(_param_shapes(cfg), one_chip, dtype=BF)
+    cache = _abstract(jax.eval_shape(lambda: generate.init_cache(
+        cfg, slots, a["max_len"], layout="paged", block_size=16,
+        num_blocks=blocks)), one_chip)
+    leaf = cache[kv_pool.LATENT]
+    slice_elems = blocks * 16 * 640
+    assert leaf.shape == (8, blocks, 16, 640) and leaf.dtype == BF
+    if program == "decode":
+        tok = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("step", engine.StepSpec(cfg=cfg, paged=True))
+        args = (params, cache, tok, tok)
+    else:
+        scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("paged_prefill",
+                               engine.StepSpec(cfg=cfg, bucket=256))
+        args = (params, cache, jax.ShapeDtypeStruct(
+            (1, 256), I32, sharding=one_chip), scalar, scalar, scalar)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _holds_pool_slices(text, slice_elems, "bf16") == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8 * slice_elems * 2
+    if program == "decode":
+        assert _kernel_calls("paged_decode_attention", text) == 8
+        assert mem.temp_size_in_bytes < 128 << 20
+        # the experts' matmuls keep the program's scope on the chip's
+        # compiler: decode_moe_dev_ms finds them by it, not by an op's name
+        for dot, n in (("etd,edf->etf", 2), ("etf,efd->td", 1)):
+            assert text.count(f"moe/moe_experts/{dot}/dot_general") \
+                >= n * cfg.num_layers
+    else:
+        assert mem.temp_size_in_bytes < 1 << 30
+    # fits the chip beside what else the process holds
+    assert mem.peak_memory_in_bytes < 15.0e9
+
+
 def _train_step(cfg, mesh, accum=1):
     from paddle_tpu.optimizer import AdamW
     from paddle_tpu.text import gpt_hybrid
