@@ -335,9 +335,7 @@ def _generate_impl(params, prompt, key, temperature, *, cfg,
         if top_k > 0 or top_p < 1.0:
             logits = _filter_logits(logits, temperature, top_k, top_p)
         else:
-            logits = jnp.where(jnp.asarray(temperature) > 0.0,
-                               logits / jnp.maximum(temperature, 1e-6),
-                               logits)
+            logits = _scale_logits(logits, temperature)
         nxt = jax.lax.cond(
             jnp.asarray(temperature) > 0.0,
             lambda: jax.random.categorical(sub, logits),
@@ -1201,6 +1199,20 @@ def _key_seed(key):
         return np.asarray(key).ravel()
 
 
+def _bc(a, dt, lead, xp):
+    """A scalar or per-row sampling parameter against [..., V] logits."""
+    return xp.broadcast_to(xp.asarray(a, dt), lead)[..., None]
+
+
+def _scale_logits(logits, temperature, xp=jnp):
+    """Logits over their temperature; temperature == 0 leaves them
+    unscaled (greedy callers take the argmax).  The first stage of
+    ``_filter_logits`` and, alone, the law of a sampler with both
+    filters off: a plain sampled step sorts nothing."""
+    t = _bc(temperature, xp.float32, logits.shape[:-1], xp)
+    return xp.where(t > 0, logits / xp.maximum(t, 1e-6), logits)
+
+
 def _filter_logits(logits, temperature, top_k, top_p, xp=jnp):
     """THE temperature → top-k → nucleus filter over [..., V] logits —
     the single source of truth for every sampler: ``_generate_impl``
@@ -1213,26 +1225,26 @@ def _filter_logits(logits, temperature, top_k, top_p, xp=jnp):
     temperature/top_k/top_p broadcast over the leading dims; top_k == 0
     and top_p == 1 disable their stages; temperature == 0 leaves logits
     unscaled (greedy callers take the argmax, which every stage
-    preserves — the top token always survives)."""
+    preserves — the top token always survives).
+
+    The vocabulary is sorted ONCE: what top-k masks is a suffix of the
+    descending order, so the nucleus stage reads the same sorted row
+    with that suffix at the mask value (``adapters.NEG_INF``, the floor
+    of any masked logit) instead of sorting the masked logits again."""
     V = logits.shape[-1]
     lead = logits.shape[:-1]
-
-    def bc(a, dt):
-        return xp.broadcast_to(xp.asarray(a, dt), lead)[..., None]
-
-    t = bc(temperature, xp.float32)
-    tk = bc(top_k, xp.int32)
-    tp = bc(top_p, xp.float32)
-    x = xp.where(t > 0, logits / xp.maximum(t, 1e-6), logits)
+    tk = _bc(top_k, xp.int32, lead, xp)
+    tp = _bc(top_p, xp.float32, lead, xp)
+    x = _scale_logits(logits, temperature, xp)
     srt = xp.sort(x, axis=-1)[..., ::-1]               # descending
     kth = xp.take_along_axis(srt, xp.clip(tk - 1, 0, V - 1), axis=-1)
     x = xp.where((tk > 0) & (x < kth), -1e30, x)
-    srt2 = xp.sort(x, axis=-1)[..., ::-1]
-    e = xp.exp(srt2 - srt2[..., :1])
+    srt = xp.where((tk > 0) & (srt < kth), -1e30, srt)
+    e = xp.exp(srt - srt[..., :1])
     probs = e / xp.sum(e, axis=-1, keepdims=True)
     keep = xp.cumsum(probs, axis=-1) - probs < tp  # mass BEFORE the token
     kth_idx = xp.sum(keep, axis=-1, keepdims=True) - 1
-    cutoff = xp.take_along_axis(srt2, kth_idx, axis=-1)
+    cutoff = xp.take_along_axis(srt, kth_idx, axis=-1)
     return xp.where((tp < 1.0) & (x < cutoff), -1e30, x)
 
 

@@ -80,6 +80,15 @@ def _sample_batched(logits, key, temp, topk, topp, mask=None):
     greedy path).  The filter math lives in generate._filter_logits —
     the single shared implementation.
 
+    The step does what its batch asks for, chosen on the device from
+    these same arrays (``lax.cond`` on a scalar of the step's inputs:
+    one executable, the same under ``lax.scan`` and under a mesh): an
+    all-greedy batch takes the argmax and nothing else; a batch whose
+    sampled slots ask for no filter scales and draws; only a sampled
+    slot with top-k or top-p on sorts the vocabulary, once.  A row's
+    token is the same whichever branch its batch-mates select
+    (``_asked`` is the choice; ``_count_sample_steps`` counts it).
+
     ``mask``: optional additive constraint mask [B, V] float32
     (0 = allowed, ``adapters.NEG_INF`` = banned — see
     text/adapters.mask_logits), applied BEFORE both branches so greedy
@@ -89,11 +98,43 @@ def _sample_batched(logits, key, temp, topk, topp, mask=None):
     with jax.named_scope("sample"):
         if mask is not None:
             logits = logits + mask
-        scaled = generate._filter_logits(logits, temp, topk, topp)
-        sampled = jax.random.categorical(key, scaled,
-                                         axis=-1).astype(jnp.int32)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return jnp.where(temp > 0.0, sampled, greedy)
+        on, draws, filters = _asked(temp, topk, topp)
+
+        def greedy():
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+        def draw(scaled):
+            sampled = jax.random.categorical(key, scaled,
+                                             axis=-1).astype(jnp.int32)
+            return jnp.where(on, sampled, greedy())
+
+        def sampled():
+            return jax.lax.cond(
+                filters,
+                lambda: draw(generate._filter_logits(logits, temp, topk,
+                                                     topp)),
+                lambda: draw(generate._scale_logits(logits, temp)))
+
+        return jax.lax.cond(draws, sampled, greedy)
+
+
+def _asked(temp, topk, topp):
+    """What a batch's sampling arrays ask of the sampler: (the slots that
+    sample, whether any does, whether any of those has top-k or top-p
+    on).  One expression for the device's arrays, where the two scalars
+    select ``_sample_batched``'s branch, and for the host's numpy copy
+    of them, where ``_count_sample_steps`` counts it."""
+    on = temp > 0.0
+    return on, on.any(), (on & ((topk > 0) | (topp < 1.0))).any()
+
+
+def _count_sample_steps(temp, tk, tp, steps: int = 1):
+    """``serving.sample_steps_greedy`` / ``_sampled`` (drawn, no sort) /
+    ``_filtered`` (sorted): which of ``_sample_batched``'s outcomes the
+    dispatched steps take, from the host's arrays (no device read)."""
+    _, draws, filters = _asked(temp, tk, tp)
+    kind = "filtered" if filters else "sampled" if draws else "greedy"
+    _telemetry.count(f"serving.sample_steps_{kind}", steps)
 
 
 def sample_step_batched(params, cache, tok, pos, key, temp, topk, topp,
@@ -4570,6 +4611,7 @@ class DecodeServer:
         # a failed call (real or injected OOM) leaves host state exactly
         # as before the tick, so the guard's retry is bit-exact
         self._step_no = n + 1
+        _count_sample_steps(temp, tk, tp)
         # NaN guard on the tick logits (greedy path only — the sampled
         # path fetches tokens, not logits).  The full-logits fetch is
         # extra host traffic, so it only engages when a fault targets
@@ -4733,6 +4775,7 @@ class DecodeServer:
         except Exception:
             self._rollback_dispatch(snap, n)
             raise
+        _count_sample_steps(temp, tk, tp)
         self._inflight = {"kind": "step", "toks": nxt, "feed": nxt,
                           "fn": fname, "step_no0": n,
                           "snap": snap, "t_disp": time.perf_counter()}
@@ -4770,6 +4813,7 @@ class DecodeServer:
         except Exception:
             self._rollback_dispatch(snap, n)
             raise
+        _count_sample_steps(temp, tk, tp, block)
         self._inflight = {"kind": "block", "toks": toks, "feed": feed,
                           "fn": fname, "snap": snap, "block": block,
                           "step_no0": n, "t_disp": time.perf_counter()}
@@ -5094,6 +5138,7 @@ class DecodeServer:
                     self.params, self.cache, jnp.asarray(tok),
                     jnp.asarray(pos))
         self._step_no = n + block   # after the call: see _tick_impl
+        _count_sample_steps(temp, tk, tp, block)
         with self._phase("wait"):
             toks = np.asarray(toks)  # the block's one device->host fetch
         done = []

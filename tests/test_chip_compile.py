@@ -434,6 +434,122 @@ def test_paged_step_holds_no_slice_of_the_pool(one_chip, no_persistent_cache,
             assert mem.peak_memory_in_bytes < PARENT_STEP_PEAK[cell] - 5e8
 
 
+def _branches_of(comps) -> list:
+    """[(the computation a ``conditional`` sits in, [its branch
+    computations, false first])] of ``_computations(text)``."""
+    return [(comp, re.findall(r"%([\w.\-]+)", m.group(1)))
+            for comp, lines in comps.items() for line in lines
+            for m in [re.search(r"\sconditional\(.*branch_computations="
+                                r"\{([^}]*)\}", line)] if m]
+
+
+def _reached_from(comps, comp) -> set:
+    """``comp`` and every computation its instructions call, to any
+    depth (a fusion's body, a sort's comparator, a nested branch)."""
+    seen, todo = set(), [comp]
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)="
+                               r"%([\w.\-]+)", line)
+            for group in re.findall(r"branch_computations=\{([^}]*)\}",
+                                    line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+def _sampler_work(comps, names) -> list:
+    """What only a sampled step needs among the instructions of the
+    computations ``names``: ``"sort"`` for a sort of the vocabulary,
+    ``"bits"`` for an op that makes random bits (jax's threefry is plain
+    integer ops on the chip, told by its op_name path; the hardware
+    generator by its opcode)."""
+    found = []
+    for c in names:
+        for line in comps[c]:
+            if re.search(r"\ssort\(", line):
+                found.append("sort")
+            elif re.search(r"\srng[\w\-]*\(|op_name=\"[^\"]*(?:threefry|"
+                           r"random_bits|_uniform|_gumbel)", line):
+                found.append("bits")
+    return found
+
+
+@pytest.mark.parametrize("cell", ["gpt1p3b", "falconh1"])
+def test_async_step_sorts_nothing_for_a_greedy_batch(
+        one_chip, no_persistent_cache, as_on_tpu, purge_engine, cell):
+    """The paged ``async`` step, the one every serve cell runs, at the chat
+    cells' own slots, pools and vocabularies (two layers: the sampler sees
+    ``[slots, V]`` logits whatever the depth): the sampler's work is chosen
+    on the device.  XLA keeps both ``lax.cond``s as ``conditional`` ops
+    (flattened into a ``select`` every batch would pay for the sorted
+    branch again); no sort and no random-bit op is in the entry
+    computation, outside the conditionals, or in the all-greedy branch;
+    the drawn-without-a-filter branch draws and sorts nothing; the whole
+    module holds one sort where the parent's held two; and the ops inside
+    the branches keep the ``serving.async_step/sample`` path the
+    per-layer metrics select them by."""
+    import json
+
+    from benchmarks.families import falcon_h1 as fam
+    from paddle_tpu import telemetry
+    from paddle_tpu.text import engine, generate
+
+    if cell == "gpt1p3b":
+        cfg = _gpt(2)
+    else:
+        with open("benchmarks/configs/falcon-h1-34b-serve.json") as f:
+            config = json.load(f)
+        config.update(num_hidden_layers=2)
+        cfg = fam.gpt_config(config)
+    purge_engine(cfg)
+    slots, blocks = POOL_CELLS[cell]
+    params = _abstract(_param_shapes(cfg), one_chip, dtype=BF)
+    cache = _abstract(jax.eval_shape(lambda: generate.init_cache(
+        cfg, slots, T, layout="paged", block_size=16, num_blocks=blocks)),
+        one_chip)
+
+    def arr(dt, dims=(slots,)):
+        return jax.ShapeDtypeStruct(dims, dt, sharding=one_chip)
+
+    fn = engine.ENGINE.get("async", engine.StepSpec(cfg=cfg, paged=True))
+    text = fn.lower(params, cache, arr(I32), arr(jnp.bool_), arr(I32),
+                    arr(I32), arr(jnp.uint32, (2,)), arr(F32), arr(I32),
+                    arr(F32)).compile().as_text()
+    comps = _computations(text)
+    conds = _branches_of(comps)
+    assert len(conds) == 2, conds
+    entry = next(c for c in comps if re.search(
+        rf"^ENTRY %{re.escape(c)} ", text, re.M))
+    (_, (greedy, sampled)), = [c for c in conds if c[0] == entry]
+    (inner_in, (drawn, filtered)), = [c for c in conds if c[0] != entry]
+    in_greedy, in_sampled, in_drawn, in_filtered = (
+        _reached_from(comps, c) for c in (greedy, sampled, drawn, filtered))
+    assert inner_in in in_sampled
+    assert _sampler_work(comps, set(comps) - in_greedy - in_sampled) == []
+    assert _sampler_work(comps, in_greedy) == []
+    work = _sampler_work(comps, in_drawn)
+    assert "bits" in work and "sort" not in work, set(work)
+    work = _sampler_work(comps, in_filtered)
+    assert "bits" in work and work.count("sort") == 1, set(work)
+    assert len(re.findall(r"\ssort\(", text)) == 1
+    scopes = telemetry.hlo_op_scopes(text)
+    for branch in (greedy, drawn, filtered):
+        paths = [scopes[m.group(1)] for line in comps[branch]
+                 for m in [re.match(r"\s+(?:ROOT )?%([\w.\-]+) = ", line)]
+                 if m and m.group(1) in scopes]
+        # the nucleus' cumsum is expanded by XLA into ops named
+        # ``reduce_window_sum`` and nothing else, as in the parent
+        lost = [p for p in paths if p != "reduce_window_sum" and not (
+            p.startswith("jit(<lambda>)/serving.async_step/")
+            and "/sample/" in p)]
+        assert paths and not lost, (branch, lost[:3])
+        assert branch is filtered or "reduce_window_sum" not in paths
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill256"])
 def test_latent_paged_step_holds_no_slice_and_no_whole_view(
         one_chip, no_persistent_cache, as_on_tpu, purge_engine, program):

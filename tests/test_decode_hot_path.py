@@ -170,14 +170,17 @@ def test_async_tick_block_matches_sync(small_model):
     assert _serve(params, cfg, reqs, True, block=4, warm=True) == want
 
 
-def test_async_sampled_matches_sync(small_model):
+@pytest.mark.parametrize("asked", [dict(top_k=7), dict(top_p=0.8), {}],
+                         ids=["top_k", "top_p", "no_filter"])
+def test_async_sampled_matches_sync(small_model, asked):
     """Sampled serving: the async scheduler consumes the same fold_in
     step counters as the sync one, so draws are byte-identical (no
     queueing: admission shifts change WHICH steps a queued slot
-    occupies — the documented batched-serving schedule dependence)."""
+    occupies — the documented batched-serving schedule dependence),
+    whichever of the sampler's branches the requests select."""
     cfg, params = small_model
     reqs = _staggered_reqs(3)
-    kw = dict(temperature=0.8, top_k=7)
+    kw = dict(temperature=0.8, **asked)
     want = _serve(params, cfg, reqs, False, max_batch=4, **kw)
     assert want != _serve(params, cfg, reqs, False, max_batch=4,
                           temperature=1.3)  # sampling actually engaged

@@ -552,9 +552,12 @@ def _chi2_counts(counts, law, n):
     return float(((o - e) ** 2 / e).sum()), int(keep.sum())
 
 
-def test_sampled_tick_matches_sampled_tick_block():
+@pytest.mark.parametrize("asked", [dict(top_p=0.95), dict(top_k=4), {}],
+                         ids=["top_p", "top_k", "no_filter"])
+def test_sampled_tick_matches_sampled_tick_block(asked):
     """Same seed, same step counters: per-token ticks and block ticks
-    draw identical samples (the fold_in(base, step) schedule)."""
+    draw identical samples (the fold_in(base, step) schedule), through
+    the sampler's sorted branch and through its plain draw."""
     cfg = _cfg(vocab_size=12)
     params = gpt.init_params(cfg, jax.random.PRNGKey(8))
     rng = np.random.default_rng(3)
@@ -564,7 +567,7 @@ def test_sampled_tick_matches_sampled_tick_block():
         srv = serving.DecodeServer(params, cfg, max_batch=3, max_len=32,
                                    seed=11)
         rids = [srv.submit(p, max_new_tokens=9, temperature=1.2,
-                           top_p=0.95) for p in prompts]
+                           **asked) for p in prompts]
         while srv.pending():
             srv.tick_block(block) if block else srv.tick()
         return [srv.result(r) for r in rids]
@@ -640,3 +643,196 @@ def test_sampled_admission_follows_target_law_prefill_path():
     stat, df = _chi2_counts(counts, law, n)
     assert stat < 3 * max(df, 1) + 10, stat
     assert counts[law == 0].sum() == 0
+
+
+# ---------------------------------------------------------------------------
+# the sampler does what the batch asks for (PR 34): an all-greedy step takes
+# an argmax, a sampled step with no filter asked scales and draws, a filtered
+# step sorts once.  The reference below is the formula as it stood: two
+# sorts, every batch.
+# ---------------------------------------------------------------------------
+
+
+def _two_sort_filter(logits, temperature, top_k, top_p, xp=jnp):
+    V = logits.shape[-1]
+    lead = logits.shape[:-1]
+
+    def bc(a, dt):
+        return xp.broadcast_to(xp.asarray(a, dt), lead)[..., None]
+
+    t = bc(temperature, xp.float32)
+    tk = bc(top_k, xp.int32)
+    tp = bc(top_p, xp.float32)
+    x = xp.where(t > 0, logits / xp.maximum(t, 1e-6), logits)
+    srt = xp.sort(x, axis=-1)[..., ::-1]
+    kth = xp.take_along_axis(srt, xp.clip(tk - 1, 0, V - 1), axis=-1)
+    x = xp.where((tk > 0) & (x < kth), -1e30, x)
+    srt2 = xp.sort(x, axis=-1)[..., ::-1]
+    e = xp.exp(srt2 - srt2[..., :1])
+    probs = e / xp.sum(e, axis=-1, keepdims=True)
+    keep = xp.cumsum(probs, axis=-1) - probs < tp
+    kth_idx = xp.sum(keep, axis=-1, keepdims=True) - 1
+    cutoff = xp.take_along_axis(srt2, kth_idx, axis=-1)
+    return xp.where((tp < 1.0) & (x < cutoff), -1e30, x)
+
+
+def _two_sort_sample(logits, key, temp, topk, topp, mask=None):
+    if mask is not None:
+        logits = logits + mask
+    scaled = _two_sort_filter(logits, temp, topk, topp)
+    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(temp > 0.0, sampled, greedy)
+
+
+_SLOTS, _VOCAB = 6, 40
+# per-slot (temp, topk, topp), and whether the batch carries a constraint mask
+_BATCHES = {
+    "all_greedy": ([0, 0, 0, 0, 0, 0], [0, 3, 0, 0, 0, 0],
+                   [1, 1, .5, 1, 1, 1], False),
+    "mixed": ([0, 1.2, 0, .7, 0, 2.], [0, 5, 3, 0, 0, 0],
+              [1, 1, .5, .9, 1, 1], False),
+    "no_filter": ([1, 1.2, 0, .7, 0, 2.], [0, 0, 4, 0, 0, 0],
+                  [1, 1, 1, 1, .6, 1], False),
+    "top_k": ([1, 1.2, .3, .7, 1, 2.], [1, 5, 3, 40, 90, 0],
+              [1, 1, 1, 1, 1, 1], False),
+    "top_p": ([1, 1.2, .3, .7, 1, 2.], [0, 0, 0, 0, 0, 0],
+              [.9, .5, .05, 1, .99, .7], False),
+    "both": ([1, 1.2, .3, .7, 1, 2.], [2, 5, 0, 7, 40, 3],
+             [.9, .5, .8, 1, .3, .7], False),
+    "masked": ([0, 1.2, 0, .7, 1, 2.], [0, 5, 0, 0, 2, 0],
+               [1, 1, 1, .9, 1, 1], True),
+}
+
+
+def _batch_arrays(name):
+    from paddle_tpu.text import adapters
+
+    temp, topk, topp, masked = _BATCHES[name]
+    rng = np.random.default_rng(len(name))
+    logits = (rng.normal(size=(_SLOTS, _VOCAB)) * 3).astype(np.float32)
+    mask = None
+    if masked:
+        mask = np.where(rng.random((_SLOTS, _VOCAB)) < 0.3, 0.0,
+                        adapters.NEG_INF).astype(np.float32)
+        mask[0] = 0.0                         # an unconstrained row
+        mask[1, :] = adapters.NEG_INF
+        mask[1, [4, 9, 30]] = 0.0             # fewer allowed than top_k asks
+    return (jnp.asarray(logits), jnp.asarray(temp, jnp.float32),
+            jnp.asarray(topk, jnp.int32), jnp.asarray(topp, jnp.float32),
+            None if mask is None else jnp.asarray(mask))
+
+
+@pytest.mark.parametrize("batch", list(_BATCHES))
+def test_sample_batched_draws_the_tokens_of_the_two_sort_formula(batch):
+    """Whatever branch the batch selects on the device, a fixed key gives
+    exactly the tokens the unconditional two-sort sampler gave."""
+    logits, temp, topk, topp, mask = _batch_arrays(batch)
+    new = jax.jit(serving._sample_batched)
+    old = jax.jit(_two_sort_sample)
+    drawn = set()
+    for seed in range(24):
+        key = jax.random.fold_in(jax.random.PRNGKey(7), seed)
+        got = np.asarray(new(logits, key, temp, topk, topp, mask=mask))
+        want = np.asarray(old(logits, key, temp, topk, topp, mask=mask))
+        np.testing.assert_array_equal(got, want)
+        drawn.add(tuple(got))
+    greedy = np.asarray(jnp.argmax(
+        logits if mask is None else logits + mask, axis=-1))
+    np.testing.assert_array_equal(got[np.asarray(temp) == 0],
+                                  greedy[np.asarray(temp) == 0])
+    assert (len(drawn) > 1) == bool(np.asarray(temp).any())
+    if mask is not None:
+        assert (np.asarray(mask)[np.arange(_SLOTS), got] == 0).all()
+
+
+def _filter_rows(case):
+    """(logits [R, V], temperature, top_k, top_p), rows and parameters
+    chosen to sit on the edges of the one-sort argument."""
+    from paddle_tpu.text import adapters
+
+    rng = np.random.default_rng(5)
+    x = (rng.normal(size=(5, 24)) * 2).astype(np.float32)
+    temp = np.asarray([1.0, 0.5, 2.0, 0.0, 1.3], np.float32)
+    topk = np.asarray([4, 4, 4, 4, 4], np.int32)
+    topp = np.asarray([1.0, 0.9, 0.5, 0.7, 0.2], np.float32)
+    if case == "ties_at_kth":
+        x[:, 3:9] = x[:, 3:4]           # six equal values around rank 4
+        x[0, :] = 1.25                  # a whole row of one value
+    elif case == "top_k_over_unmasked":
+        x[:, 3:] = adapters.NEG_INF     # three tokens allowed, four asked
+        topk[:] = [4, 7, 24, 100, 3]
+    elif case == "neg_inf_rows":
+        x[:, ::2] = adapters.NEG_INF    # half the row banned
+        x[2, 1:] = adapters.NEG_INF     # one token left
+        topk[:] = [0, 4, 2, 12, 13]
+    elif case == "filters_off":
+        topk[:] = 0
+        topp[:] = 1.0
+    else:
+        assert case == "plain", case
+    return x, temp, topk, topp
+
+
+@pytest.mark.parametrize("case", ["plain", "ties_at_kth",
+                                  "top_k_over_unmasked", "neg_inf_rows",
+                                  "filters_off"])
+@pytest.mark.parametrize("xp", [jnp, np], ids=["jnp", "numpy"])
+def test_filter_logits_one_sort_is_the_two_sort_formula_bit_for_bit(xp,
+                                                                    case):
+    x, temp, topk, topp = _filter_rows(case)
+    if xp is np:
+        x = x.astype(np.float64)        # the host mirror's precision
+    whole = np.asarray(G._filter_logits(xp.asarray(x), temp, topk, topp,
+                                        xp=xp))
+    want = np.asarray(_two_sort_filter(xp.asarray(x), temp, topk, topp,
+                                       xp=xp))
+    assert whole.dtype == want.dtype
+    np.testing.assert_array_equal(whole, want)
+    # scalar parameters, the offline sampler's and the host mirror's form
+    got = np.asarray(G._filter_logits(xp.asarray(x[1]), 0.5, 4, 0.9, xp=xp))
+    want = np.asarray(_two_sort_filter(xp.asarray(x[1]), 0.5, 4, 0.9,
+                                       xp=xp))
+    np.testing.assert_array_equal(got, want)
+    if case == "filters_off":       # what a plainly sampled step draws from
+        np.testing.assert_array_equal(
+            np.asarray(G._scale_logits(xp.asarray(x), temp, xp=xp)), whole)
+
+
+def _sample_step_counts():
+    from paddle_tpu.framework import monitor
+
+    return {k: int(monitor.get_stat(f"serving.sample_steps_{k}").get())
+            for k in ("greedy", "sampled", "filtered")}
+
+
+@pytest.mark.parametrize("mode", ["tick", "tick_block", "async",
+                                  "async_block"])
+def test_sample_step_counters_add_up_to_the_steps_dispatched(mode):
+    """Greedy, plainly sampled and filtered requests one after another on
+    a one-slot server: every dispatched step is counted once, under the
+    branch its arrays select on the device."""
+    cfg = _cfg(vocab_size=12)
+    params = gpt.init_params(cfg, jax.random.PRNGKey(8))
+    srv = serving.DecodeServer(params, cfg, max_batch=1, max_len=32,
+                               seed=3, prefill=False,
+                               async_dispatch=mode.startswith("async"))
+    before, step0 = _sample_step_counts(), srv._step_no
+    grew = {}
+    for kind, asked in (("greedy", {}), ("sampled", dict(temperature=1.1)),
+                        ("filtered", dict(temperature=1.1, top_k=3))):
+        was = _sample_step_counts()
+        rid = srv.submit([3, 5, 7], max_new_tokens=6, **asked)
+        while srv.pending():
+            srv.tick_block(2) if mode.endswith("block") else srv.tick()
+        assert len(srv.result(rid)) == 6
+        now = _sample_step_counts()
+        grew[kind] = {k: now[k] - was[k] for k in now}
+        # the prompt's feeding steps keep nothing and ask for nothing
+        assert grew[kind][kind] >= 4 or kind == "greedy", grew
+        assert all(v == 0 for k, v in grew[kind].items()
+                   if k not in (kind, "greedy")), grew
+    assert grew["greedy"]["greedy"] >= 6
+    after = _sample_step_counts()
+    assert sum(after.values()) - sum(before.values()) \
+        == srv._step_no - step0 > 0
