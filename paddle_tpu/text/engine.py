@@ -196,7 +196,10 @@ def cfg_key(cfg):
              tuple(cfg.mlp_multipliers),
              cfg.ssm.key() if cfg.ssm is not None else None,
              cfg.mla.key() if cfg.mla is not None else None,
-             cfg.experts.key() if cfg.experts is not None else None),
+             cfg.experts.key() if cfg.experts is not None else None,
+             # the layer pattern and its two forward scalars
+             cfg.layer_types, cfg.attention_multiplier,
+             cfg.residual_multiplier),
             # trace-time env routing flags (flags.decode_jit_key): an
             # executable BAKES these in — W4 kernel gate (woq.mm), fused
             # LN (gpt._ln), cache donation (aliased vs copied buffers),

@@ -91,6 +91,11 @@ def init_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
             "a latent-attention config decodes through the paged cache "
             "only (layout='paged'): the contiguous slab has no latent "
             "row format")
+    if cfg.layer_types is not None:
+        raise NotImplementedError(
+            "a layer pattern decodes through the paged cache only "
+            "(layout='paged'): the contiguous slab's leaves are as deep "
+            "as the model, not as a kind of layer")
     L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
     dt = _kv_store_dtype(cfg)
     shape = (L, batch, _round_cache_len(max_len), H, hd)
@@ -146,7 +151,8 @@ def _attend_cache(q, full, pos, cfg: gpt.GPTConfig):
 
             pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
             out = da.decode_attention(q, k_all, v_all, pos_b,
-                                      k_scale=ks, v_scale=vs)
+                                      k_scale=ks, v_scale=vs,
+                                      scale=cfg.attention_multiplier)
             return out.astype(dt).reshape(B, Tq, H * hd)
         if ks is not None:
             from ..ops import decode_attention as da
@@ -162,8 +168,12 @@ def _attend_cache(q, full, pos, cfg: gpt.GPTConfig):
         Hkv = k_all.shape[2]
         g = H // Hkv
         qg = q.reshape(B, Tq, Hkv, g, hd)
-        scores = jnp.einsum("bikgd,btkd->bkgit", qg, k_all) / jnp.sqrt(
-            jnp.asarray(hd, jnp.float32)).astype(dt)
+        scores = jnp.einsum("bikgd,btkd->bkgit", qg, k_all)
+        if cfg.attention_multiplier is None:
+            scores = scores / jnp.sqrt(
+                jnp.asarray(hd, jnp.float32)).astype(dt)
+        else:       # a stated softmax scale (a pattern config's)
+            scores = scores * jnp.asarray(cfg.attention_multiplier, dt)
         mask = (jnp.arange(T)[None, :]
                 <= pos + jnp.arange(Tq)[:, None])[None, None, None]
         scores = jnp.where(mask, scores.astype(jnp.float32), -1e30)

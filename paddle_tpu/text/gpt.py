@@ -72,7 +72,9 @@ class GPTConfig:
     # other and of GQA — num_kv_heads + the three below give the
     # Llama/Mistral shape on the same GPT machinery):
     # "learned" = trained wpe table; "rope" = rotary embeddings applied
-    # to q/k (no position table; the decode cache stores ROTATED keys)
+    # to q/k (no position table; the decode cache stores ROTATED keys);
+    # "none" = no position anywhere (neither a table nor a rotation: the
+    # causal mask, and any recurrent mixer, carry the order)
     pos_embed: str = "learned"
     norm: str = "layernorm"        # "layernorm" | "rmsnorm" (gain-only)
     activation: str = "gelu"       # "gelu" | "swiglu" (gated FFN)
@@ -105,6 +107,19 @@ class GPTConfig:
     # FFNs' width; the cache holds a latent row a token a sublayer
     mla: Any = None
     experts: Any = None
+    # a LAYER PATTERN, one entry a layer, "mamba" or "attention": layer l
+    # runs ONE mixer (the ssm mixer or grouped-query attention, not both)
+    # and then the expert layer ``experts`` (:func:`pattern_block`).
+    # Stated once, here: the block, the cache's leaves (state for the
+    # mamba layers only, K/V rows for the attention layers only:
+    # :attr:`layer_slots`), the decode step and the prefill chunk read it.
+    # With it two forward scalars of the Granite-4.0-H family:
+    # ``attention_multiplier`` is the softmax scale as stated (None:
+    # 1 / sqrt(head_dim)); ``residual_multiplier`` scales both branches
+    # before they join the residual stream
+    layer_types: Any = None
+    attention_multiplier: float | None = None
+    residual_multiplier: float = 1.0
 
     def __post_init__(self):
         if self.head_dim is None:
@@ -119,7 +134,7 @@ class GPTConfig:
             raise ValueError(
                 f"num_kv_heads {self.num_kv_heads} must divide num_heads "
                 f"{self.num_heads}")
-        if self.pos_embed not in ("learned", "rope"):
+        if self.pos_embed not in ("learned", "rope", "none"):
             raise ValueError(f"unknown pos_embed {self.pos_embed!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(f"unknown norm {self.norm!r}")
@@ -127,11 +142,19 @@ class GPTConfig:
             raise ValueError(f"unknown activation {self.activation!r}")
         if self.pos_embed == "rope" and self.head_dim % 2:
             raise ValueError("rope needs an even head_dim")
-        if (self.mla is None) != (self.experts is None):
+        if self.layer_types is not None:
+            self._check_pattern()
+        elif (self.mla is None) != (self.experts is None):
             raise ValueError(
                 "mla and experts come together (the latent block); a "
                 "plain block with latent attention, or with an expert "
-                "share, is not implemented")
+                "share and no layer pattern (layer_types), is not "
+                "implemented")
+        elif (self.attention_multiplier is not None
+              or self.residual_multiplier != 1.0):
+            raise ValueError(
+                "attention_multiplier and residual_multiplier are applied "
+                "by the pattern block only (layer_types)")
         if self.mla is not None and (
                 self.pos_embed != "rope" or self.norm != "rmsnorm"
                 or self.activation != "swiglu" or self.bias
@@ -150,6 +173,55 @@ class GPTConfig:
                 "moe with an ssm mixer or bias-free projections is not "
                 "implemented (the expert FFN carries its own biases and "
                 "the joint-routing step knows no recurrent state)")
+
+    def _check_pattern(self):
+        """A stated layer pattern: what the pattern block is."""
+        self.layer_types = tuple(self.layer_types)
+        if (len(self.layer_types) != self.num_layers
+                or set(self.layer_types) - {"mamba", "attention"}):
+            raise ValueError(
+                f"layer_types states one of 'mamba' / 'attention' a "
+                f"layer ({self.num_layers} layers), got {self.layer_types}")
+        if self.experts is None or self.mla is not None or (
+                self.ssm is None and "mamba" in self.layer_types):
+            raise ValueError(
+                "a layer pattern runs one mixer a layer (ssm for its "
+                "mamba layers) and then an expert layer (experts), "
+                "without latent attention (mla)")
+        if (self.pos_embed != "none" or self.norm != "rmsnorm"
+                or self.activation != "swiglu" or self.bias
+                or self.moe is not None or self.num_kv_heads is None):
+            raise ValueError(
+                "the pattern block is position-free (pos_embed='none'), "
+                "RMSNorm, SwiGLU, bias-free, grouped-query (num_kv_heads "
+                "stated) and without cfg.moe (the GShard layer)")
+
+    @property
+    def layer_slots(self) -> tuple:
+        """A pattern's static map from a layer to its index among the
+        layers of its own kind: ((kind, index), ...).  The index is the
+        layer's place in the params' ``mamba`` / ``attn`` leaves and in
+        the cache's state / K/V leaves, which are as deep as their kind
+        has layers."""
+        seen = {"mamba": 0, "attention": 0}
+        out = []
+        for kind in self.layer_types:
+            out.append((kind, seen[kind]))
+            seen[kind] += 1
+        return tuple(out)
+
+    def layers_of(self, kind: str) -> int:
+        """Layers of ``kind`` ("mamba" / "attention"): every layer counts
+        as both where no pattern is stated (the parallel hybrid block)."""
+        if self.layer_types is None:
+            return self.num_layers
+        return sum(t == kind for t in self.layer_types)
+
+    @property
+    def softmax_scale(self) -> float:
+        return (self.attention_multiplier
+                if self.attention_multiplier is not None
+                else 1.0 / math.sqrt(self.head_dim))
 
     @property
     def q_size(self):
@@ -190,6 +262,8 @@ def init_params(cfg: GPTConfig, key) -> dict:
     def nrm(k, shape, std=s):
         return std * jax.random.normal(k, shape, jnp.float32)
 
+    if cfg.layer_types is not None:
+        return _init_pattern_params(cfg, keys, nrm)
     if cfg.mla is not None:
         from . import mla as _mla
         from .moe import init_expert_share
@@ -290,6 +364,54 @@ def init_params(cfg: GPTConfig, key) -> dict:
     return params
 
 
+def _init_pattern_params(cfg: GPTConfig, keys, nrm) -> dict:
+    """The tree of a stated layer pattern: the two norms and the expert
+    layer of every layer ``[L, ...]`` (the routed experts a tuple of a
+    leaf a layer, ``moe.init_expert_share``), and each kind's mixer
+    leaves as deep as that kind has layers: ``mamba`` (``ssm.init_params``'
+    leaves, ``[Lm, ...]``) and ``attn`` (``q_w``, ``kv_w``, ``proj_w``,
+    ``[La, ...]``).  :func:`pattern_layer` cuts one layer's out."""
+    from . import ssm as _ssm
+    from .moe import init_expert_share
+
+    D, L, V = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
+    Dq, Dkv = cfg.q_size, cfg.kv_heads * cfg.head_dim
+    Lm, La = cfg.layers_of("mamba"), cfg.layers_of("attention")
+    ak = jax.random.split(keys[2], 3)
+    blocks = {
+        "ln1_g": jnp.ones((L, D), jnp.float32),
+        "ln2_g": jnp.ones((L, D), jnp.float32),
+        "moe": init_expert_share(keys[3], D, cfg.experts, L),
+    }
+    if Lm:
+        blocks["mamba"] = _ssm.init_params(cfg.ssm, D, Lm, keys[9])
+    if La:
+        blocks["attn"] = {
+            "q_w": nrm(ak[0], (La, D, Dq)),
+            "kv_w": nrm(ak[1], (La, 2, D, Dkv)),
+            "proj_w": nrm(ak[2], (La, Dq, D), std=0.02 / math.sqrt(2 * L)),
+        }
+    params = {"wte": nrm(keys[0], (V, D)),
+              "ln_f_g": jnp.ones((D,), jnp.float32), "blocks": blocks}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = nrm(jax.random.fold_in(keys[0], 1), (V, D))
+    return params
+
+
+def pattern_layer(blocks: dict, cfg: GPTConfig, li: int) -> dict:
+    """Layer ``li``'s weights of a pattern config's ``blocks``: its norms
+    and expert layer by the layer's number, its mixer's leaves by its
+    index among the layers of its kind (``cfg.layer_slots``); static
+    slices of the stacked leaves, a per-layer tuple's member."""
+    from .moe import layer_of
+
+    kind, i = cfg.layer_slots[li]
+    mixer = blocks["mamba" if kind == "mamba" else "attn"]
+    return {"ln1_g": blocks["ln1_g"][li], "ln2_g": blocks["ln2_g"][li],
+            "moe": layer_of(blocks["moe"], li),
+            **{k: v[i] for k, v in mixer.items()}}
+
+
 def param_shardings(cfg: GPTConfig, dp="dp", mp="mp", pp=None, ep="ep") -> dict:
     """Megatron-style PartitionSpecs (reference mp_layers.py Column/RowParallel
     + VocabParallelEmbedding; ZeRO/pp compose by adding axes).  With MoE the
@@ -299,9 +421,9 @@ def param_shardings(cfg: GPTConfig, dp="dp", mp="mp", pp=None, ep="ep") -> dict:
             "param_shardings: the ssm mixer has no tensor-parallel layout "
             "yet (its heads, groups and conv channels would have to split "
             "together)")
-    if cfg.mla is not None:
+    if cfg.experts is not None:
         raise NotImplementedError(
-            "param_shardings: the latent block has no layout across chips "
+            "param_shardings: an expert share has no layout across chips "
             "yet (no ep exchange is written: one chip runs its own share "
             "of the experts)")
     l = pp  # leading stacked-layer axis shards over pipeline stages if set
@@ -644,6 +766,58 @@ def _latent_forward_block(x, p, cfg: GPTConfig):
     return out.reshape(B, T, D)
 
 
+def pattern_block(x, p, cfg: GPTConfig, mixer, valid=None):
+    """One layer of a stated pattern (``cfg.layer_types``) on rows ``x``
+    [T, D] (a sequence's positions, or a decode step's slots):
+
+        h1  = x  + residual_multiplier * mixer(norm_1(x))
+        out = h1 + residual_multiplier * experts(norm_2(h1))
+
+    ``mixer(n)`` is the layer's ONE mixer on the normed rows, the ssm
+    mixer or attention by the layer's kind: the full forward, the prefill
+    chunk and the decode step differ in nothing else.  The residual
+    stream is float32 where the caller hands it in so (as
+    :func:`latent_block`'s).  ``valid`` [T]: rows that select experts.
+    Returns (out, the expert layer's counts)."""
+    from .moe import expert_share
+
+    def scaled(y):
+        y = y.astype(x.dtype)
+        if cfg.residual_multiplier != 1.0:
+            y = y * jnp.asarray(cfg.residual_multiplier, x.dtype)
+        return y
+
+    h1 = x + scaled(mixer(_norm(x, p, "ln1", cfg)))
+    y, counts = expert_share(_norm(h1, p, "ln2", cfg), p["moe"],
+                             cfg.experts, cfg.dtype, valid)
+    return h1 + scaled(y), counts
+
+
+def _pattern_forward_block(x, p, cfg: GPTConfig, kind: str):
+    """:func:`pattern_block` over whole sequences [B, T, D]: the rows of
+    all sequences go through the layer together; a mamba layer scans each
+    sequence from the zero state, an attention layer's queries attend
+    their own sequence (no position applied to q or k)."""
+    B, T, D = x.shape
+
+    def mixer(n):
+        n = n.reshape(B, T, D)
+        if kind == "mamba":
+            from . import ssm as _ssm
+
+            out, _ = _ssm.mixer_chunk(
+                n, p, cfg, _ssm.zero_state(cfg.ssm, B, cfg.dtype))
+        else:
+            q, k, v = _project_qkv(n, p, cfg)
+            attn = attention_array(q, k, v, is_causal=True,
+                                   scale=cfg.softmax_scale)
+            out = _attn_out(attn.reshape(B, T, cfg.q_size), p, cfg)
+        return out.reshape(B * T, D)
+
+    out, _ = pattern_block(x.reshape(B * T, D), p, cfg, mixer)
+    return out.reshape(B, T, D)
+
+
 def _block(x, p, cfg: GPTConfig, dropout_key=None):
     """One transformer block on [B, T, D] activations (compute dtype)."""
     B, T, _ = x.shape
@@ -702,16 +876,22 @@ def forward_with_aux(params: dict, tokens, cfg: GPTConfig, act_sharding=None,
     if act_sharding is not None:
         x = jax.lax.with_sharding_constraint(x, act_sharding)
 
-    if cfg.mla is not None:
+    if cfg.experts is not None:
         if key is not None:
             raise NotImplementedError(
-                "the latent block has no training forward (dropout, "
+                "an expert share has no training forward (dropout, "
                 "router noise): pass key=None")
         from .moe import layer_of
 
         x = x.astype(jnp.float32)   # the residual stream (latent_block)
         for li in range(cfg.num_layers):  # its experts' leaves are a layer's
-            x = _latent_forward_block(x, layer_of(params["blocks"], li), cfg)
+            if cfg.layer_types is not None:
+                x = _pattern_forward_block(
+                    x, pattern_layer(params["blocks"], cfg, li), cfg,
+                    cfg.layer_types[li])
+            else:
+                x = _latent_forward_block(
+                    x, layer_of(params["blocks"], li), cfg)
         x = _norm(x, params, "ln_f", cfg)
         return woq.logits(x, params, dt, cfg.lm_head_multiplier), \
             jnp.zeros((), jnp.float32)
@@ -777,6 +957,17 @@ def loss_fn(params: dict, tokens, cfg: GPTConfig, act_sharding=None, key=None):
 def count_params(cfg: GPTConfig) -> int:
     D, F, L, V, T = (cfg.hidden_size, cfg.ffn_size, cfg.num_layers, cfg.vocab_size,
                      cfg.max_seq_len)
+    if cfg.layer_types is not None:
+        from . import ssm as _ssm
+        from .moe import count_expert_share
+
+        outside, expert = count_expert_share(cfg.experts, D)
+        attn = 2 * D * cfg.q_size + 2 * D * cfg.kv_heads * cfg.head_dim
+        mamba = _ssm.count_params(cfg.ssm, D) if cfg.ssm is not None else 0
+        return (L * (2 * D + outside + cfg.experts.n_held * expert)
+                + cfg.layers_of("mamba") * mamba
+                + cfg.layers_of("attention") * attn
+                + V * D * (1 if cfg.tie_embeddings else 2) + D)
     if cfg.mla is not None:
         from . import mla as _mla
         from .moe import count_expert_share

@@ -72,7 +72,7 @@ __all__ = [
 # a latent-attention config's pool: ONE leaf of latent rows
 # [2 * L, N, bs, lanes] in place of "k" and "v" (an attention sublayer,
 # not a layer, indexes its first axis; a row is [c | kr], zero-padded to
-# whole lane tiles), and the expert layer's device-side counts
+# whole lane tiles), and an expert share's device-side counts
 # (moe.SHARE_COUNTS) carried with the cache
 LATENT = "latent"
 COUNTS = "moe_counts"
@@ -80,7 +80,11 @@ COUNTS = "moe_counts"
 POOL_LEAVES = ("k", "v", "k_s", "v_s", LATENT)
 # a recurrent mixer's per-slot leaves [L, batch, ...] (no block table maps
 # them) and the [batch] bool leaf that says which slots a decode step
-# advances; held beside the pool when the config has an ssm mixer
+# advances; held beside the pool when the config has an ssm mixer.  Under
+# a stated layer pattern (cfg.layer_types) the two kinds of leaf differ in
+# depth: "k" / "v" hold the attention layers only and the state leaves
+# the mamba layers only, each indexed by a layer's place among its own
+# kind (cfg.layer_slots)
 STATE_LEAVES = _ssm.STATE_LEAVES
 LIVE = "live"
 
@@ -143,17 +147,32 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
                 "tables": jnp.full((batch, nmax), -1, jnp.int32),
                 LIVE: jnp.zeros((batch,), bool),
                 COUNTS: jnp.zeros((len(_moe.SHARE_COUNTS),), jnp.int32)}
-    shape = (L, N, bs, H * hd)
+    if cfg.layer_types is not None:
+        if dt == jnp.int8:
+            raise NotImplementedError(
+                "an int8 pool under a layer pattern is not supported yet: "
+                "the pattern's prefill chunk and step were never held to "
+                "the scale planes")
+        if not cfg.layers_of("attention"):
+            raise NotImplementedError(
+                "a layer pattern without an attention layer has no K/V "
+                "leaf for the block tables to map")
+    La = cfg.layers_of("attention")
+    shape = (La, N, bs, H * hd)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
              "tables": jnp.full((batch, nmax), -1, jnp.int32)}
     if dt == jnp.int8:
-        cache["k_s"] = jnp.zeros((L, N, bs, H), jnp.float32)
-        cache["v_s"] = jnp.zeros((L, N, bs, H), jnp.float32)
+        cache["k_s"] = jnp.zeros((La, N, bs, H), jnp.float32)
+        cache["v_s"] = jnp.zeros((La, N, bs, H), jnp.float32)
     if cfg.ssm is not None:
         # two kinds of state, one pytree: the mixer's state is per SLOT
         # and of fixed size, so it is provisioned for every slot and
         # donated and returned with the pool
-        cache.update(_ssm.init_state(cfg.ssm, L, batch, cfg.dtype))
+        cache.update(_ssm.init_state(cfg.ssm, cfg.layers_of("mamba"),
+                                     batch, cfg.dtype))
+    if cfg.experts is not None:
+        cache[COUNTS] = jnp.zeros((len(_moe.SHARE_COUNTS),), jnp.int32)
+    if cfg.ssm is not None or cfg.experts is not None:
         cache[LIVE] = jnp.zeros((batch,), bool)
     return cache
 
@@ -279,6 +298,8 @@ def paged_decode_step_batched(params, cache, token, pos,
 
     if LATENT in cache:
         return _latent_step(params, cache, token, pos, cfg)
+    if cfg.layer_types is not None:
+        return _pattern_step(params, cache, token, pos, cfg)
     N, bs, nmax = _geometry(cache)
     B = token.shape[0]
     H, hd = cfg.num_heads, cfg.head_dim
@@ -524,6 +545,147 @@ def _latent_prefill_chunk(params, cache, tokens, pos0, length, slot,
     return logits.astype(jnp.float32), dict(cache, **{LATENT: pool})
 
 
+def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
+    """:func:`paged_decode_step_batched` for a stated layer pattern
+    (``cfg.layer_types``): the whole batch a layer at a time ([B, D] rows
+    through ``gpt.pattern_block``), so that the expert layer routes the
+    step's tokens together.  A mamba layer advances its own leaf of the
+    state (``state[n][i]``, i the layer's place among the mamba layers): a
+    slot that feeds a first position starts from zero, an idle slot keeps
+    its state bit for bit.  An attention layer writes its fresh rows into
+    its own leaf of the pool (``pool[n][j]``, j its place among the
+    attention layers) and attends through the tables, no position applied
+    to q or k: the paged kernel, each slot's live pages, where it runs;
+    the gathered view elsewhere.  Slots the ``live`` leaf does not name
+    select no expert and count nowhere."""
+    from ..ops import decode_attention as da
+
+    N, bs, nmax = _geometry(cache)
+    B = token.shape[0]
+    dt, H, hd = cfg.dtype, cfg.num_heads, cfg.head_dim
+    tables, live = cache["tables"], cache[LIVE]
+    tb = tables[jnp.arange(B), pos // bs]
+    phys = jnp.where(tb >= 0, tb * bs + pos % bs, N * bs)
+    kernel = (_flags.flash_decode()
+              and da.paged_available((B, 1, H, hd), cache["k"].shape))
+    attend_pool = da.paged_decode_attention if kernel else da._xla_paged
+    x = woq.embed(params, token, dt,
+                  cfg.embedding_multiplier).astype(jnp.float32)    # [B, D]
+
+    # the layers in a Python loop: a layer's weights, its leaf of the pool
+    # and its leaf of the state are read and written where they are stored
+    box = {"pool": {n: cache[n] for n in ("k", "v")},
+           "state": {n: cache[n] for n in STATE_LEAVES if n in cache}}
+    counts = cache[COUNTS]
+    for li, (kind, i) in enumerate(cfg.layer_slots):
+        p = gpt.pattern_layer(params["blocks"], cfg, li)
+
+        def mamba(n, p=p, i=i):
+            # the state's reads, selects and write-back are the mixer's
+            # work too: under its scope, so that its metrics hold them
+            with jax.named_scope("ssm"):
+                old = {k: v[i] for k, v in box["state"].items()}
+                start = _from_zero(old, pos, 0)
+            out, new = _ssm.mixer_step(n[:, None], p, cfg, start)
+            with jax.named_scope("ssm"):
+                new = _keep_idle(new, old, live, 0)
+                box["state"] = {
+                    k: jax.lax.dynamic_update_index_in_dim(v, new[k], i, 0)
+                    for k, v in box["state"].items()}
+            return out[:, 0]
+
+        def attention(n, p=p, i=i):
+            q, k, v = gpt._project_qkv(n[:, None], p, cfg, repeat_kv=False)
+            rows = generate._store_rows(k[:, 0], v[:, 0], cfg)
+            # scatter-then-attend, as the K/V kernel route does
+            with jax.named_scope("kv_gather"):
+                box["pool"] = {k_: _put_rows(box["pool"][k_], i, phys, val)
+                               for k_, val in rows.items()}
+            with jax.named_scope("attn"):
+                attn = attend_pool(
+                    q, box["pool"]["k"], box["pool"]["v"], tables, pos,
+                    jnp.asarray(i, jnp.int32), None, None,
+                    cfg.softmax_scale)
+            return gpt._attn_out(attn.astype(dt).reshape(B, H * hd), p, cfg)
+
+        x, c = gpt.pattern_block(
+            x, p, cfg, mamba if kind == "mamba" else attention, valid=live)
+        counts = counts + c
+    x = gpt._norm(x, params, "ln_f", cfg)
+    logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
+    return logits.astype(jnp.float32), dict(
+        cache, **box["pool"], **box["state"], **{COUNTS: counts})
+
+
+def _pattern_prefill_chunk(params, cache, tokens, pos0, length, slot,
+                           cfg: gpt.GPTConfig):
+    """:func:`paged_prefill_chunk` for a stated layer pattern: the chunk's
+    rows [C, D] a layer at a time.  A mamba layer continues the slot's
+    state over the chunk's first ``length`` positions by the chunked scan
+    (from zero where the chunk opens the sequence) and writes it back; an
+    attention layer's queries attend the slot's table-gathered rows
+    [0, pos0) and the chunk's own, and rows [pos0, pos0 + length) are
+    written through the table.  Padded positions advance no state, write
+    no row and select no expert."""
+    N, bs, nmax = _geometry(cache)
+    trow = cache["tables"][slot]                          # [nmax]
+    dt, C = cfg.dtype, tokens.shape[1]
+    x = woq.embed(params, tokens[0], dt,
+                  cfg.embedding_multiplier).astype(jnp.float32)    # [C, D]
+    valid = jnp.arange(C) < length
+    logi = pos0 + jnp.arange(C)
+    tb = trow[jnp.clip(logi // bs, 0, nmax - 1)]
+    phys = jnp.where(valid & (tb >= 0) & (logi // bs < nmax),
+                     tb * bs + logi % bs, N * bs)
+
+    box = {"pool": {n: cache[n] for n in ("k", "v")},
+           "state": {n: cache[n] for n in STATE_LEAVES if n in cache}}
+    for li, (kind, i) in enumerate(cfg.layer_slots):
+        p = gpt.pattern_layer(params["blocks"], cfg, li)
+
+        def mamba(n, p=p, i=i):
+            with jax.named_scope("ssm"):
+                # the slot's state of this layer, cut from the leaf as it
+                # is stored (a layer's slice first would be copied whole)
+                start = {k: jnp.where(
+                             pos0 == 0, jnp.zeros((), v.dtype),
+                             jax.lax.dynamic_slice(
+                                 v, (i, slot) + (0,) * (v.ndim - 2),
+                                 (1, 1) + v.shape[2:])[0])
+                         for k, v in box["state"].items()}
+            out, new = _ssm.mixer_chunk(n[None], p, cfg, start,
+                                        length=length)
+            with jax.named_scope("ssm"):
+                box["state"] = {
+                    k: jax.lax.dynamic_update_slice(
+                        v, new[k][None],
+                        (i, slot) + (0,) * (v.ndim - 2))
+                    for k, v in box["state"].items()}
+            return out[0]
+
+        def attention(n, p=p, i=i):
+            q, k, v = gpt._project_qkv(n[None], p, cfg, repeat_kv=False)
+            rows = generate._store_rows(k, v, cfg)        # [1, C, Hkv, hd]
+            csl = _gather_slot(box["pool"], i, trow, cfg)
+            full = {k_: jax.lax.dynamic_update_slice(
+                        csl[k_], val, (0, pos0, 0, 0))
+                    for k_, val in rows.items()}
+            with jax.named_scope("kv_gather"):
+                box["pool"] = {
+                    k_: _put_rows(box["pool"][k_], i, phys, val[0])
+                    for k_, val in rows.items()}
+            attn = generate._attend_cache(q, full, pos0, cfg)
+            return gpt._attn_out(attn, p, cfg)[0]
+
+        x, _ = gpt.pattern_block(
+            x, p, cfg, mamba if kind == "mamba" else attention, valid=valid)
+    last = jax.lax.dynamic_slice(x, (length - 1, 0), (1, cfg.hidden_size))
+    last = gpt._norm(last, params, "ln_f", cfg)
+    logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0]
+    return logits.astype(jnp.float32), dict(
+        cache, **box["pool"], **box["state"])
+
+
 def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
                         cfg: gpt.GPTConfig):
     """``generate.prefill_slot_chunk`` on the pooled layout: one chunk of
@@ -541,6 +703,9 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
     if LATENT in cache:
         return _latent_prefill_chunk(params, cache, tokens, pos0, length,
                                      slot, cfg)
+    if cfg.layer_types is not None:
+        return _pattern_prefill_chunk(params, cache, tokens, pos0, length,
+                                      slot, cfg)
     N, bs, nmax = _geometry(cache)
     tables = cache["tables"]
     trow = tables[slot]                                   # [nmax]

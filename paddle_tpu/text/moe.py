@@ -241,15 +241,28 @@ class ExpertShareConfig:
     ``top_k`` best, and this chip holds the routed experts ``held`` =
     [lo, hi).  The layer computes the held experts' part of the result
     and the identity part; what the absent experts would add is left out
-    (their chips add it in a deployment: no ``ep`` exchange is written)."""
+    (their chips add it in a deployment: no ``ep`` exchange is written).
+
+    Two published score forms: ``"softmax"`` scores are the softmax over
+    ALL the router's outputs, the ``top_k`` best taken (a selection bias
+    added for the choice only) and not renormalised; ``"topk_softmax"``
+    takes the ``top_k`` largest LOGITS and a softmax over those alone
+    (they sum to 1; no selection bias).  ``shared_size`` > 0: a dense
+    SwiGLU expert of that width on every row, beside the routed sum
+    (every chip of a deployment computes a token's once, on the token's
+    own chip)."""
     n_routed: int                 # routed experts the router scores
     n_zero: int                   # identity experts after them
     top_k: int
     expert_size: int              # a routed expert's SwiGLU width
     scaling: float = 1.0          # routed_scaling_factor on the whole sum
     held: tuple = (0, 0)          # [lo, hi) of the routed experts held here
+    score: str = "softmax"        # or "topk_softmax"
+    shared_size: int = 0          # the shared expert's SwiGLU width, or 0
 
     def __post_init__(self):
+        if self.score not in ("softmax", "topk_softmax"):
+            raise ValueError(f"unknown score form {self.score!r}")
         lo, hi = self.held
         if not 0 <= lo < hi <= self.n_routed:
             raise ValueError(f"held {self.held} is no range of the "
@@ -264,6 +277,11 @@ class ExpertShareConfig:
     @property
     def router_width(self) -> int:
         return self.n_routed + self.n_zero
+
+    @property
+    def selection_bias(self) -> bool:
+        """The ``router_b`` leaf: the softmax form's, added for the choice."""
+        return self.score == "softmax"
 
     def key(self) -> tuple:
         return dataclasses.astuple(self)
@@ -280,10 +298,11 @@ SHARE_COUNTS = ("pairs_held", "pairs_zero", "pairs_absent", "experts_hit",
 def init_expert_share(key, d_model: int, ex: ExpertShareConfig,
                       layers: int, std: float = 0.02) -> dict:
     """The router over all experts (``[L, ...]`` leaves, its selection
-    bias beside it) and the held experts' three matrices, each a TUPLE
-    of a leaf a layer ``[E, ...]``: a layer's experts cut out of a
-    stacked leaf were copied (600 MB a layer at the published widths)
-    before every use."""
+    bias beside it where the score form has one, the shared expert's
+    three matrices where the config has one) and the held experts' three
+    matrices, each a TUPLE of a leaf a layer ``[E, ...]``: a layer's
+    experts cut out of a stacked leaf were copied (600 MB a layer at the
+    published widths) before every use."""
     k = jax.random.split(key, 4)
     E, Fe = ex.n_held, ex.expert_size
 
@@ -294,14 +313,24 @@ def init_expert_share(key, d_model: int, ex: ExpertShareConfig,
         return tuple(s * jax.random.normal(kl, shape, jnp.float32)
                      for kl in jax.random.split(kk, layers))
 
-    return {
+    out = {
         "router_w": nrm(k[0], (d_model, ex.router_width)),
-        "router_b": jnp.zeros((layers, ex.router_width), jnp.float32),
         "gate_w": per_layer(k[1], (E, d_model, Fe)),
         "up_w": per_layer(k[2], (E, d_model, Fe)),
         "down_w": per_layer(k[3], (E, Fe, d_model),
                             std / math.sqrt(2 * layers)),
     }
+    if ex.selection_bias:
+        out["router_b"] = jnp.zeros((layers, ex.router_width), jnp.float32)
+    if ex.shared_size:
+        ks = jax.random.split(jax.random.fold_in(key, 4), 3)
+        Fs = ex.shared_size
+        out.update(
+            shared_gate_w=nrm(ks[0], (d_model, Fs)),
+            shared_up_w=nrm(ks[1], (d_model, Fs)),
+            shared_down_w=nrm(ks[2], (Fs, d_model),
+                              std / math.sqrt(2 * layers)))
+    return out
 
 
 def layer_of(blocks: dict, li: int) -> dict:
@@ -312,22 +341,31 @@ def layer_of(blocks: dict, li: int) -> dict:
 
 
 def count_expert_share(ex: ExpertShareConfig, d_model: int) -> tuple:
-    """(router + selection bias, one routed expert)."""
-    return ((d_model + 1) * ex.router_width, 3 * d_model * ex.expert_size)
+    """(what a layer holds outside its routed experts: the router, its
+    selection bias and the shared expert where there is one; one routed
+    expert)."""
+    return ((d_model + ex.selection_bias) * ex.router_width
+            + 3 * d_model * ex.shared_size, 3 * d_model * ex.expert_size)
 
 
 def route_share(m, p, ex: ExpertShareConfig, valid=None):
     """The router on rows ``m`` [T, D], in float32: (idx [T, k] the
-    selected experts, w [T, k] their softmax scores, not renormalised).
-    The selection bias chooses only.  ``valid`` [T]: a row that is
-    padding, or a slot that holds no request, selects no expert (its
-    scores are zero and its selections count nowhere)."""
+    selected experts, w [T, k] their scores in the config's score form:
+    the softmax over all outputs at the selected ones, not renormalised
+    (the selection bias chooses only), or the softmax over the selected
+    logits alone).  ``valid`` [T]: a row that is padding, or a slot that
+    holds no request, selects no expert (its scores are zero and its
+    selections count nowhere)."""
     with jax.named_scope("moe_route"):
         logits = m.astype(jnp.float32) @ p["router_w"].astype(jnp.float32)
-        s = jax.nn.softmax(logits, axis=-1)
-        _, idx = jax.lax.top_k(s + p["router_b"].astype(jnp.float32),
-                               ex.top_k)
-        w = jnp.take_along_axis(s, idx, axis=-1)
+        if ex.score == "topk_softmax":
+            top, idx = jax.lax.top_k(logits, ex.top_k)
+            w = jax.nn.softmax(top, axis=-1)
+        else:
+            s = jax.nn.softmax(logits, axis=-1)
+            _, idx = jax.lax.top_k(s + p["router_b"].astype(jnp.float32),
+                                   ex.top_k)
+            w = jnp.take_along_axis(s, idx, axis=-1)
         if valid is not None:
             w = jnp.where(valid[:, None], w, 0.0)
         return idx, w
@@ -335,8 +373,9 @@ def route_share(m, p, ex: ExpertShareConfig, valid=None):
 
 def expert_share(m, p, ex: ExpertShareConfig, dt, valid=None):
     """``scaling * (sum over a token's selected HELD experts of s_e *
-    expert_e(m) + sum over its selected identity experts of s_e * m)`` on
-    rows ``m`` [T, D] -> ([T, D] float32, for the caller's residual stream;
+    expert_e(m) + sum over its selected identity experts of s_e * m)``,
+    plus the shared expert's output where the config has one, on rows
+    ``m`` [T, D] -> ([T, D] float32, for the caller's residual stream;
     counts int32 [5] as ``SHARE_COUNTS``).
 
     No token is dropped whatever the routing, and neither a shape nor the
@@ -354,10 +393,12 @@ def expert_share(m, p, ex: ExpertShareConfig, dt, valid=None):
         live = (jnp.ones((T, 1), bool) if valid is None
                 else valid[:, None])
         held = (idx >= lo) & (idx < hi) & live
-        zero = (idx >= ex.n_routed) & live
-        with jax.named_scope("moe_zero"):
-            z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1, keepdims=True)
-            out = z * m.astype(jnp.float32)
+        out = zero = None
+        if ex.n_zero:
+            zero = (idx >= ex.n_routed) & live
+            with jax.named_scope("moe_zero"):
+                z = jnp.sum(jnp.where(zero, w, 0.0), axis=-1, keepdims=True)
+                out = z * m.astype(jnp.float32)
         with jax.named_scope("moe_experts"):
             # sel [T, k, E]: selection j of token t is held expert e
             sel = held[:, :, None] & (
@@ -368,11 +409,23 @@ def expert_share(m, p, ex: ExpertShareConfig, dt, valid=None):
             g = jnp.einsum("etd,edf->etf", xe, woq.w(p, "gate_w", dt))
             u = jnp.einsum("etd,edf->etf", xe, woq.w(p, "up_w", dt))
             h = (jax.nn.silu(g) * u).astype(jnp.float32) * score.T[:, :, None]
-            out = out + jnp.einsum(
+            routed = jnp.einsum(
                 "etf,efd->td", h.astype(dt), woq.w(p, "down_w", dt),
                 preferred_element_type=jnp.float32)
-        n_held, n_zero = jnp.sum(held), jnp.sum(zero)
+            out = routed if out is None else out + routed
+        n_held = jnp.sum(held)
+        n_zero = (jnp.sum(zero) if zero is not None
+                  else jnp.zeros((), jnp.int32))
         counts = jnp.stack([
             n_held, n_zero, jnp.sum(live) * k - n_held - n_zero,
             jnp.sum(sizes > 0), jnp.any(live)]).astype(jnp.int32)
-        return ex.scaling * out, counts
+        if ex.scaling != 1.0:
+            out = ex.scaling * out
+        if ex.shared_size:
+            with jax.named_scope("moe_shared"):
+                x = m.astype(dt)
+                hs = (jax.nn.silu(woq.mm(x, p, "shared_gate_w", dt))
+                      * woq.mm(x, p, "shared_up_w", dt))
+                out = out + jnp.dot(hs, woq.w(p, "shared_down_w", dt),
+                                    preferred_element_type=jnp.float32)
+        return out, counts

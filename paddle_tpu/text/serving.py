@@ -647,15 +647,21 @@ class DecodeServer:
         # relies on rewriting or rolling back rows is refused here, by
         # name, before anything is built.
         self._recurrent = cfg.ssm is not None
+        # one chip's share of a routed expert layer (cfg.experts): the
+        # ``live`` leaf tells the step which slots select experts, and
+        # the expert layer's counts ride the cache (kv_pool.COUNTS,
+        # drained by load_stats and close).  Its refusals name the absent
+        # chips, so they come first
+        self._experts = cfg.experts is not None
+        if self._experts:
+            self._refuse_share(mesh, draft_cfg, spec_k, spec_tree,
+                               adapter_pool)
         if self._recurrent:
             self._refuse_recurrent(
                 lay, mesh, draft_cfg, spec_k, spec_tree, adapter_pool,
                 prefill_chunk, prefill_budget, max_len)
-        # latent attention with an expert share (cfg.mla, cfg.experts):
-        # the pool holds one leaf of latent rows, the ``live`` leaf tells
-        # the step which slots select experts, and the expert layer's
-        # counts ride the cache (kv_pool.COUNTS, drained by load_stats
-        # and close)
+        # latent attention (cfg.mla): the pool holds one leaf of latent
+        # rows in place of K and V
         self._latent = cfg.mla is not None
         self._share_counts = np.zeros((len(_SHARE_COUNTS),), np.int64)
         # the device-side counts are int32 and a step adds at most
@@ -665,10 +671,9 @@ class DecodeServer:
         self._share_ticks = 0
         self._share_drain_every = (
             (1 << 28) // (max_batch * cfg.experts.top_k * cfg.num_layers)
-            if cfg.mla is not None else 0)
+            if self._experts else 0)
         if self._latent:
-            self._refuse_latent(lay, mesh, draft_cfg, spec_k, spec_tree,
-                                adapter_pool)
+            self._refuse_latent(lay)
         if self._paged:
             from . import kv_pool as _kv
 
@@ -683,6 +688,11 @@ class DecodeServer:
             if self._recurrent and self._tel:
                 _telemetry.gauge("kv_pool.state_bytes").set(sum(
                     self.cache[n].nbytes for n in _kv.STATE_LEAVES))
+                # how deep each kind of leaf is (a layer pattern's differ)
+                _telemetry.gauge("kv_pool.kv_layers").set(
+                    self.cache["k"].shape[0])
+                _telemetry.gauge("kv_pool.state_layers").set(
+                    self.cache[_kv.STATE_LEAVES[0]].shape[0])
             if self._latent and self._tel:
                 leaf = self.cache[_kv.LATENT]
                 _telemetry.gauge("kv_pool.latent_row_bytes").set(
@@ -1088,28 +1098,39 @@ class DecodeServer:
                     f"{window} for a config with an ssm mixer (its "
                     f"prefill chunks cannot overlap)")
 
-    def _refuse_latent(self, lay, mesh, draft_cfg, spec_k, spec_tree,
-                       adapter_pool):
-        """What cannot work with latent rows and an expert share yet
+    def _refuse_share(self, mesh, draft_cfg, spec_k, spec_tree,
+                      adapter_pool):
+        """What cannot work with one chip's share of an expert layer yet
         raises at construction, naming the reason."""
         def no(what, why):
             raise NotImplementedError(
-                f"{what} with latent attention and an expert share "
-                f"(cfg.mla, cfg.experts) is not supported yet: {why}")
+                f"{what} with an expert share (cfg.experts) is not "
+                f"supported yet: {why}")
 
-        if lay != "paged":
-            no("layout='contiguous'", "the contiguous slab has no latent "
-               "row format; pass layout='paged'")
         if mesh is not None:
             no("mesh=", "no ep exchange is written: one chip runs its own "
                "share of the experts, and nothing stands in for the "
                "absent chips")
         if _speculation_asked(draft_cfg, spec_k, spec_tree):
             no("speculation (spec_k / spec_tree / draft_cfg)",
-               "the verify chunks know no latent row format, and a "
-               "chunk's tokens would select experts for rejected drafts")
+               "a verify chunk's tokens would select experts for "
+               "rejected drafts, and the verify chunks know neither a "
+               "latent row format nor a layer pattern")
         if adapter_pool is not None:
-            no("adapter_pool", "the adapter step kinds know no latent rows")
+            no("adapter_pool", "the adapter step kinds know no expert "
+               "share")
+
+    def _refuse_latent(self, lay):
+        """What cannot work with latent rows yet raises at construction,
+        naming the reason."""
+        def no(what, why):
+            raise NotImplementedError(
+                f"{what} with latent attention (cfg.mla) is not "
+                f"supported yet: {why}")
+
+        if lay != "paged":
+            no("layout='contiguous'", "the contiguous slab has no latent "
+               "row format; pass layout='paged'")
         if _flags.kv_spill_mb():
             no("the host spill tier (PADDLE_TPU_KV_SPILL_MB)",
                "it restores rows by inject_rows, and a latent row has no "
@@ -1163,7 +1184,7 @@ class DecodeServer:
         that decode (or feed their prompt token by token), not the free
         ones and not those mid-admission, whose state the prefill chunks
         own.  A no-op for a cache without the leaf."""
-        if not (self._recurrent or self._latent):
+        if not (self._recurrent or self._experts):
             return
         from . import kv_pool as _kv
 
@@ -3512,11 +3533,11 @@ class DecodeServer:
         if self._wedged:
             self._wedged = False
             _telemetry.clear_runtime_wedge()
-        if self._moe_stats is not None or self._latent:
+        if self._moe_stats is not None or self._experts:
             # publish the final routing totals before the accumulator
             # (and its device buffer) is dropped with the executables
             try:
-                if self._latent:
+                if self._experts:
                     self._drain_share_counts()
                 else:
                     self._moe_snapshot()
@@ -3714,7 +3735,7 @@ class DecodeServer:
                if self._moe_stats is not None else {}),
             # an expert share's selections by where they went, since the
             # server was built (the drain publishes the moe.* telemetry)
-            **(self._drain_share_counts() if self._latent else {}),
+            **(self._drain_share_counts() if self._experts else {}),
             # fleet tracing: spans ride the stats collection when asked
             **(dict(zip(("spans", "span_drops"), self.drain_spans()))
                if include_spans else {}),
@@ -4477,7 +4498,7 @@ class DecodeServer:
             self._adm.control_tick(
                 idle=not self._slots and not self._queue)
         self._rss_guard()
-        if self._latent:
+        if self._experts:
             self._share_ticks += 1
             if self._share_ticks >= self._share_drain_every:
                 self._drain_share_counts()

@@ -608,6 +608,78 @@ def test_latent_paged_step_holds_no_slice_and_no_whole_view(
     assert mem.peak_memory_in_bytes < 15.0e9
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill1024"])
+def test_pattern_step_holds_no_slice_of_either_pool_and_no_experts_copy(
+        one_chip, no_persistent_cache, as_on_tpu, purge_engine, program):
+    """The layer-pattern cell at its own size (64 slots, 16,384 blocks of
+    16 rows; nine mamba layers to one attention layer, 36 held experts a
+    layer): the K/V leaves are ONE layer deep and the state leaves nine;
+    the decode step takes the paged kernel once (the one layer that
+    attends, its place among the attention layers as the ``layer``
+    operand), no op has a whole K/V leaf or a copy of a layer's experts
+    (679 MB) as its result, every cache leaf is donated and written where
+    it is, and the temporaries hold at most one layer's state (the old
+    state a step's two selects read: 268 MB) and not the nine.  The
+    prefill gathers one slot's view of the one layer and cuts the slot's
+    state out of the leaf as it is stored."""
+    import json
+
+    from benchmarks.families import granite_moe_hybrid as fam
+    from paddle_tpu.text import engine, generate, kv_pool
+
+    with open("benchmarks/configs/granite-4.0-h-small-serve.json") as f:
+        config = json.load(f)
+    cfg = fam.gpt_config(config)
+    purge_engine(cfg)
+    a = config["entry_point"]["args"]
+    slots, blocks = a["max_batch"], a["num_blocks"]
+    params = _abstract(_param_shapes(cfg), one_chip, dtype=BF)
+    cache = _abstract(jax.eval_shape(lambda: generate.init_cache(
+        cfg, slots, a["max_len"], layout="paged", block_size=16,
+        num_blocks=blocks)), one_chip)
+    assert cache["k"].shape == (1, blocks, 16, 1024)
+    assert cache["ssm"].shape == (9, slots, 128, 64, 128)
+    pool_elems = blocks * 16 * 1024
+    experts_elems = 36 * 4096 * 768
+    layer_state = slots * 128 * 64 * 128 * 4
+    if program == "decode":
+        tok = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("step", engine.StepSpec(cfg=cfg, paged=True))
+        args = (params, cache, tok, tok)
+    else:
+        scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+        fn = engine.ENGINE.get("paged_prefill",
+                               engine.StepSpec(cfg=cfg, bucket=1024))
+        args = (params, cache, jax.ShapeDtypeStruct(
+            (1, 1024), I32, sharding=one_chip), scalar, scalar, scalar)
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert _holds_pool_slices(text, pool_elems, "bf16") == []
+    # a layer's experts are read where they are stored: no op's result is
+    # the size of one of a layer's three expert leaves, in either layout
+    assert [f for f in _holds_pool_slices(text, experts_elems, "bf16")
+            if f[1] in ("copy", "transpose")] == []
+    mem = compiled.memory_analysis()
+    held = sum(int(np.prod(cache[n].shape)) * cache[n].dtype.itemsize
+               for n in ("k", "v") + kv_pool.STATE_LEAVES)
+    assert mem.alias_size_in_bytes >= held
+    if program == "decode":
+        assert _kernel_calls("paged_decode_attention", text) == 1
+        assert mem.temp_size_in_bytes < layer_state + (64 << 20)
+        # the experts' and the shared expert's matmuls keep the program's
+        # scopes on the chip's compiler: the moe metrics find them by it
+        for dot, n in (("etd,edf->etf", 2), ("etf,efd->td", 1)):
+            assert text.count(f"moe/moe_experts/{dot}/dot_general") \
+                >= n * cfg.num_layers
+        assert text.count("moe/moe_shared/") >= 3 * cfg.num_layers
+        assert "ssm/ssm_update/" in text and "moe_zero" not in text
+    else:
+        assert mem.temp_size_in_bytes < 1 << 30
+        assert "ssm/ssm_scan/" in text and "moe/moe_shared/" in text
+    # fits the chip beside what else the process holds
+    assert mem.peak_memory_in_bytes < 15.0e9
+
+
 def _train_step(cfg, mesh, accum=1):
     from paddle_tpu.optimizer import AdamW
     from paddle_tpu.text import gpt_hybrid
