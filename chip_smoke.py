@@ -137,10 +137,10 @@ def phase_device(rehearse: bool, chips: int):
 def interpret_kernels():
     """Rehearsal only: the Pallas interpreter stands in for the chip."""
     from paddle_tpu.ops import (decode_attention, flash_attention, fused_ce,
-                                fused_norm, woq_matmul)
+                                fused_norm, ssm_update, woq_matmul)
 
     for m in (decode_attention, flash_attention, fused_ce, fused_norm,
-              woq_matmul):
+              ssm_update, woq_matmul):
         m._INTERPRET = True
 
 
@@ -170,6 +170,7 @@ def phase_kernels(sz: Sizes, seed: int):
     from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops import fused_ce as fce
     from paddle_tpu.ops import fused_norm as fnorm
+    from paddle_tpu.ops import ssm_update as su
     from paddle_tpu.ops import woq_matmul as wm
     from paddle_tpu.ops.attention import xla_attention
     from paddle_tpu.text.woq import pack_int4_halves
@@ -281,6 +282,34 @@ def phase_kernels(sz: Sizes, seed: int):
                                      None),
                 da._xla_paged(q, kp[li:li + 1], vp[li:li + 1], tables, pos,
                               0, ks1, vs1, None), 2e-2)
+
+    # the recurrent state advanced where it is stored (float32, the
+    # vector unit): 8 slots at the hybrid cell's heads, layer 1 of 2, five
+    # slots decoding of which two start from zero; the other slots and the
+    # other layer to the bit
+    Ls, Hm, P, Ns, G = 2, 32, 128, 256, 2
+    ks = jax.random.split(jax.random.fold_in(key, 30), 5)
+    leaf = jax.random.normal(ks[0], (Ls, B, Hm, P, Ns), jnp.float32)
+    dtx = jax.random.normal(ks[1], (B, Hm, P), jnp.float32)
+    decay = jax.random.uniform(ks[2], (B, Hm), jnp.float32)
+    bm = jax.random.normal(ks[3], (B, G, Ns), jnp.float32)
+    cm = jax.random.normal(ks[4], (B, G, Ns), jnp.float32)
+    live = jnp.asarray([1, 0, 1, 1, 0, 0, 1, 1], bool)
+    pos = jnp.asarray([4, 4, 0, 9, 0, 2, 0, 1], jnp.int32)
+    assert su.supported(leaf.shape, leaf.dtype, G)
+    y, new = jax.jit(su.state_update)(leaf, jnp.int32(1), live, pos, dtx,
+                                      decay, bm, cm)
+    s0 = jnp.where((pos == 0)[:, None, None, None], 0.0, leaf[1])
+    per_head = lambda a: jnp.repeat(a, Hm // G, axis=1)  # noqa: E731
+    want = (s0 * decay[..., None, None]
+            + dtx[..., None] * per_head(bm)[:, :, None])
+    on = np.asarray(live)
+    errs["ssm state"] = _close("ssm state", new[1][on], want[on], 1e-5)
+    errs["ssm y"] = _close(
+        "ssm y", y[on], jnp.sum(want * per_head(cm)[:, :, None], -1)[on],
+        1e-3)
+    assert (np.asarray(new[1])[~on] == np.asarray(leaf[1])[~on]).all()
+    assert (np.asarray(new[0]) == np.asarray(leaf[0])).all()
 
     for name, e in errs.items():
         log(f"[kernels] {name}: max abs err {e:.3g}")

@@ -184,29 +184,6 @@ def latent_lanes(cfg: gpt.GPTConfig) -> int:
     return -(-cfg.mla.row_width // 128) * 128
 
 
-def _per_slot(mask, leaf, axis: int):
-    """``mask`` [batch] shaped to broadcast against ``leaf``, whose batch
-    axis is ``axis``."""
-    return mask.reshape((1,) * axis + (-1,)
-                        + (1,) * (leaf.ndim - axis - 1))
-
-
-def _from_zero(state: dict, pos, axis: int) -> dict:
-    """The state a step continues: zero where the slot feeds a sequence's
-    first position (so a slot reused after retirement never sees the last
-    tenant's state), else what the cache holds."""
-    return {n: jnp.where(_per_slot(pos == 0, v, axis),
-                         jnp.zeros((), v.dtype), v)
-            for n, v in state.items()}
-
-
-def _keep_idle(new: dict, old: dict, live, axis: int) -> dict:
-    """A slot that does not decode in this step (free, admitting, between
-    the chunks of a prefill) keeps its state bit for bit."""
-    return {n: jnp.where(_per_slot(live, old[n], axis), new[n], old[n])
-            for n in new}
-
-
 def _geometry(cache: dict):
     """(num_blocks, block_size, nmax) of a pooled cache pytree."""
     leaf = cache["k"] if "k" in cache else cache[LATENT]
@@ -280,6 +257,32 @@ def _scatter_rows(cache: dict, rows: dict, phys) -> dict:
     return out
 
 
+def _kernel_route(cache, batch: int, cfg: gpt.GPTConfig) -> bool:
+    """Whether a K/V cache's decode step runs its layers at top level
+    around the paged kernel (:func:`_paged_step_kernel`) and not per slot
+    under a vmap: the flag, the static shapes and the backend."""
+    from ..ops import decode_attention as da
+
+    return _flags.flash_decode() and da.paged_available(
+        (batch, 1, cfg.num_heads, cfg.head_dim), cache["k"].shape)
+
+
+def state_in_place(cache, cfg: gpt.GPTConfig) -> bool:
+    """Whether the decode step of this cache advances the recurrent state
+    where it is stored, for the decoding slots alone
+    (``ssm.mixer_step_pooled`` through ``ops/ssm_update``), and does not
+    cut a layer's state out for every slot: what the step's own routing
+    reads, for the host's gauge ``kv_pool.state_walk_share``."""
+    from ..ops import ssm_update
+
+    if STATE_LEAVES[0] not in cache:
+        return False
+    leaf = cache[STATE_LEAVES[0]]
+    return (ssm_update.available(leaf.shape, leaf.dtype, cfg.ssm.n_groups)
+            and (cfg.layer_types is not None
+                 or _kernel_route(cache, leaf.shape[1], cfg)))
+
+
 def paged_decode_step_batched(params, cache, token, pos,
                               cfg: gpt.GPTConfig):
     """``serving.decode_step_batched`` on the pooled layout: token [B]
@@ -294,18 +297,13 @@ def paged_decode_step_batched(params, cache, token, pos,
     pool first, then ``ops/decode_attention.paged_decode_attention``
     copies each slot's live blocks inside that slot's grid cell — no
     [B, T] gather is ever materialized."""
-    from ..ops import decode_attention as da
-
     if LATENT in cache:
         return _latent_step(params, cache, token, pos, cfg)
     if cfg.layer_types is not None:
         return _pattern_step(params, cache, token, pos, cfg)
     N, bs, nmax = _geometry(cache)
     B = token.shape[0]
-    H, hd = cfg.num_heads, cfg.head_dim
-    use_kernel = (_flags.flash_decode()
-                  and da.paged_available((B, 1, H, hd), cache["k"].shape))
-    if use_kernel:
+    if _kernel_route(cache, B, cfg):
         return _paged_step_kernel(params, cache, token, pos, cfg)
 
     tables = cache["tables"]
@@ -313,7 +311,8 @@ def paged_decode_step_batched(params, cache, token, pos,
     state = None
     if STATE_LEAVES[0] in cache:
         with jax.named_scope("ssm"):
-            state = _from_zero({n: cache[n] for n in STATE_LEAVES}, pos, 1)
+            state = _ssm.from_zero({n: cache[n] for n in STATE_LEAVES},
+                                   pos, 1)
 
     def one(tok_b, pos_b, trow, st_b):
         dt = cfg.dtype
@@ -341,7 +340,7 @@ def paged_decode_step_batched(params, cache, token, pos,
         token, pos, tables, state)
     if new_state is not None:
         with jax.named_scope("ssm"):
-            cache = dict(cache, **_keep_idle(
+            cache = dict(cache, **_ssm.keep_idle(
                 new_state, {n: cache[n] for n in STATE_LEAVES},
                 cache[LIVE], 1))
     # rows leaves [B, L, 1, Hkv(, hd)] -> [L, B, Hkv(, hd)]; physical row
@@ -386,16 +385,8 @@ def _paged_step_kernel(params, cache, token, pos, cfg: gpt.GPTConfig):
         h = mix = None
         if state is not None:
             h = jax.vmap(lambda xb: gpt._norm(xb, p, "ln1", cfg))(x)
-            # the state's reads, selects and write-back are the mixer's
-            # work too: under its scope, so that its metrics hold them
-            with jax.named_scope("ssm"):
-                old = {n: v[li] for n, v in state.items()}
-                start = _from_zero(old, pos, 0)
-            mix, new = _ssm.mixer_step(h[:, 0], p, cfg, start)
-            with jax.named_scope("ssm"):
-                new = _keep_idle(new, old, cache[LIVE], 0)
-                state = {n: jax.lax.dynamic_update_index_in_dim(
-                             state[n], new[n], li, 0) for n in state}
+            mix, state = _ssm.mixer_step_pooled(h[:, 0], p, cfg, state, li,
+                                                cache[LIVE], pos)
             mix = mix[:, None]                           # [B, 1, 1, D]
 
         def pre(xb, pos_b, hb):
@@ -549,10 +540,12 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     """:func:`paged_decode_step_batched` for a stated layer pattern
     (``cfg.layer_types``): the whole batch a layer at a time ([B, D] rows
     through ``gpt.pattern_block``), so that the expert layer routes the
-    step's tokens together.  A mamba layer advances its own leaf of the
-    state (``state[n][i]``, i the layer's place among the mamba layers): a
-    slot that feeds a first position starts from zero, an idle slot keeps
-    its state bit for bit.  An attention layer writes its fresh rows into
+    step's tokens together.  A mamba layer advances its own layer of the
+    state leaves (i, the layer's place among the mamba layers;
+    ``ssm.mixer_step_pooled``: in place for the slots that decode where
+    ``ops/ssm_update`` runs): a slot that feeds a first position starts
+    from zero, an idle slot keeps its state bit for bit.  An attention
+    layer writes its fresh rows into
     its own leaf of the pool (``pool[n][j]``, j its place among the
     attention layers) and attends through the tables, no position applied
     to q or k: the paged kernel, each slot's live pages, where it runs;
@@ -581,17 +574,8 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
         p = gpt.pattern_layer(params["blocks"], cfg, li)
 
         def mamba(n, p=p, i=i):
-            # the state's reads, selects and write-back are the mixer's
-            # work too: under its scope, so that its metrics hold them
-            with jax.named_scope("ssm"):
-                old = {k: v[i] for k, v in box["state"].items()}
-                start = _from_zero(old, pos, 0)
-            out, new = _ssm.mixer_step(n[:, None], p, cfg, start)
-            with jax.named_scope("ssm"):
-                new = _keep_idle(new, old, live, 0)
-                box["state"] = {
-                    k: jax.lax.dynamic_update_index_in_dim(v, new[k], i, 0)
-                    for k, v in box["state"].items()}
+            out, box["state"] = _ssm.mixer_step_pooled(
+                n[:, None], p, cfg, box["state"], i, live, pos)
             return out[:, 0]
 
         def attention(n, p=p, i=i):

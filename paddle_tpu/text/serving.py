@@ -685,6 +685,9 @@ class DecodeServer:
                 block_size=block_size, num_blocks=num_blocks)
             self._pool = _kv.PagedAllocator(*_kv._geometry(self.cache),
                                             max_batch)
+            # whether a decode step advances the recurrent state where
+            # it is stored, the decoding slots alone
+            self._state_in_place = _kv.state_in_place(self.cache, cfg)
             if self._recurrent and self._tel:
                 _telemetry.gauge("kv_pool.state_bytes").set(sum(
                     self.cache[n].nbytes for n in _kv.STATE_LEAVES))
@@ -4143,6 +4146,15 @@ class DecodeServer:
                 sum(-(-(st["pos"] + 1) // bs)
                     for st in self._slots.values())
                 / (self.max_batch * self._pool.nmax))
+            if self._recurrent:
+                # the slots whose recurrent state the next decode step
+                # reads and writes, over all it holds: those that decode
+                # where the state is advanced in place, every slot where
+                # a layer's state is cut out and selected whole
+                _telemetry.set_gauge(
+                    "kv_pool.state_walk_share",
+                    float(self._moe_act().mean())
+                    if self._state_in_place else 1.0)
             _telemetry.set_gauge("kv_pool.host_spill_bytes",
                                  self._pool.host_spill_bytes)
             seen = self._pool.prefix_hits + self._pool.prefix_misses

@@ -22,7 +22,9 @@ Two entry points share the projections, the conv and the gated norm:
   ``length`` marks a padded tail: pads neither advance the state (their dt
   is zero) nor enter the conv window carried out.
 * :func:`mixer_step` — one token a sequence from its state: the plain
-  update.
+  update.  :func:`mixer_step_pooled` is the same step on the serving
+  cache's leaves as they are stored, for the slots that decode: the
+  state's update in place by ``ops/ssm_update`` where that kernel runs.
 
 The state of one sequence is ``{"ssm": [H, P, N] float32, "conv":
 [d_conv - 1, conv_dim]}``: fixed size, whatever the context — the serving
@@ -296,32 +298,129 @@ def mixer_chunk(n, p, cfg, state: dict, length=None):
 # ---------------------------------------------------------------------------
 
 
-def mixer_step(n, p, cfg, state: dict):
-    """The mixer on ONE position ``n`` [B, 1, D] from ``state``: roll the
-    conv window, one state update, one readout.  Returns (out [B, 1, D],
-    new state)."""
+def _step(n, p, cfg, conv, update):
+    """One position ``n`` [B, 1, D] through the mixer from the conv window
+    ``conv`` [B, d_conv - 1, conv_dim], around ``update``: (dt [B, G, Hg],
+    A [G, Hg], x [B, G, Hg, P], B and C [B, G, N], float32) -> (S C [B, G,
+    Hg, P] read off the state it advanced, that state in the form its
+    caller keeps).  Returns (out [B, 1, D], the state, the window rolled
+    on)."""
     s, dt_ = cfg.ssm, cfg.dtype
     B = n.shape[0]
     with jax.named_scope("ssm"):
         z, xBC, dt = _project(n, p, s, dt_)
         with jax.named_scope("ssm_conv"):
-            win = jnp.concatenate([state["conv"].astype(dt_), xBC], axis=1)
-            conv = jnp.einsum("bkc,ck->bc", win, p["ssm_conv_w"].astype(dt_))
-            xBC = jax.nn.silu(conv + p["ssm_conv_b"].astype(dt_))
-            new_conv = win[:, 1:]
+            win = jnp.concatenate([conv.astype(dt_), xBC], axis=1)
+            act = jnp.einsum("bkc,ck->bc", win, p["ssm_conv_w"].astype(dt_))
+            xBC = jax.nn.silu(act + p["ssm_conv_b"].astype(dt_))
         with jax.named_scope("ssm_update"):
             x, b, c = _split_xbc(xBC.astype(jnp.float32), s)   # [B, G, ..]
             dt, a = _dt_a(dt[:, 0], p, s)                      # [B, G, Hg]
-            G, Hg = s.n_groups, s.n_heads // s.n_groups
-            s0 = state["ssm"].astype(jnp.float32).reshape(
-                B, G, Hg, s.head_dim, s.d_state)
-            new_ssm = (s0 * jnp.exp(dt * a)[..., None, None]
-                       + jnp.einsum("bgh,bghp,bgn->bghpn", dt, x, b))
-            y = (jnp.einsum("bghpn,bgn->bghp", new_ssm, c)
-                 + x * p["ssm_D"].astype(jnp.float32).reshape(G, Hg)[
-                     ..., None])
+            y, state = update(dt, a, x, b, c)
+            y = y + x * p["ssm_D"].astype(jnp.float32).reshape(a.shape)[
+                ..., None]
         out = _gate_out(y.reshape(B, 1, s.d_ssm), z, p, s, dt_)
-    new = {"ssm": new_ssm.reshape(state["ssm"].shape).astype(
-               state["ssm"].dtype),
-           "conv": new_conv.astype(state["conv"].dtype)}
-    return out, new
+    return out, state, win[:, 1:].astype(conv.dtype)
+
+
+def mixer_step(n, p, cfg, state: dict):
+    """The mixer on ONE position ``n`` [B, 1, D] from ``state``: roll the
+    conv window, one state update, one readout.  Returns (out [B, 1, D],
+    new state)."""
+    s = cfg.ssm
+
+    def update(dt, a, x, b, c):
+        s0 = state["ssm"].astype(jnp.float32).reshape(
+            x.shape + (s.d_state,))
+        new = (s0 * jnp.exp(dt * a)[..., None, None]
+               + jnp.einsum("bgh,bghp,bgn->bghpn", dt, x, b))
+        return jnp.einsum("bghpn,bgn->bghp", new, c), new
+
+    out, new_ssm, new_conv = _step(n, p, cfg, state["conv"], update)
+    return out, {"ssm": new_ssm.reshape(state["ssm"].shape).astype(
+                     state["ssm"].dtype),
+                 "conv": new_conv}
+
+
+# ---------------------------------------------------------------------------
+# one token a slot from the serving cache's leaves (the paged decode step)
+# ---------------------------------------------------------------------------
+
+
+def _per_slot(mask, leaf, axis: int):
+    """``mask`` [batch] shaped to broadcast against ``leaf``, whose batch
+    axis is ``axis``."""
+    return mask.reshape((1,) * axis + (-1,)
+                        + (1,) * (leaf.ndim - axis - 1))
+
+
+def from_zero(state: dict, pos, axis: int) -> dict:
+    """The state a step continues: zero where the slot feeds a sequence's
+    first position (so a slot reused after retirement never sees the last
+    tenant's state), else what the cache holds."""
+    return {n: jnp.where(_per_slot(pos == 0, v, axis),
+                         jnp.zeros((), v.dtype), v)
+            for n, v in state.items()}
+
+
+def keep_idle(new: dict, old: dict, live, axis: int) -> dict:
+    """A slot that does not decode in this step (free, admitting, between
+    the chunks of a prefill) keeps its state bit for bit."""
+    return {n: jnp.where(_per_slot(live, old[n], axis), new[n], old[n])
+            for n in new}
+
+
+def _advance_layer(leaves: dict, layer, live, pos, step) -> tuple:
+    """``step`` (start state of every slot -> (result, new state)) on layer
+    ``layer`` of ``leaves`` ([L, batch, ...] each): the layer cut out, first
+    positions zeroed, idle slots put back, the layer written back.  Returns
+    (result, the leaves)."""
+    with jax.named_scope("ssm"):
+        old = {k: v[layer] for k, v in leaves.items()}
+        start = from_zero(old, pos, 0)
+    res, new = step(start)
+    with jax.named_scope("ssm"):
+        new = keep_idle(new, old, live, 0)
+        leaves = {k: jax.lax.dynamic_update_index_in_dim(v, new[k], layer, 0)
+                  for k, v in leaves.items()}
+    return res, leaves
+
+
+def mixer_step_pooled(n, p, cfg, leaves: dict, layer, live, pos):
+    """:func:`mixer_step` on the serving cache's state leaves as they are
+    stored ([L, slots, ...] each, ``STATE_LEAVES``), at layer ``layer``
+    (the layer's place in the leaves: a Python int or a traced scalar): the
+    slots ``live`` [slots] names advance by one position ``n`` [slots, 1,
+    D], from zero where ``pos`` [slots] is 0; every other slot keeps its
+    state bit for bit.  Returns (out [slots, 1, D], the leaves).
+
+    Where ``ops/ssm_update`` runs, the recurrent state never leaves its
+    leaf: the kernel reads and writes the decoding slots' state of this
+    layer in place and nothing here has a layer's state as its result; the
+    conv window (under a hundredth of the state) keeps the slice and
+    selects.
+    Elsewhere the layer of both leaves is cut out, stepped and written
+    back."""
+    from ..ops import ssm_update
+
+    s = cfg.ssm
+    if not ssm_update.available(leaves["ssm"].shape, leaves["ssm"].dtype,
+                                s.n_groups):
+        return _advance_layer(leaves, layer, live, pos,
+                              lambda start: mixer_step(n, p, cfg, start))
+    B = n.shape[0]
+
+    def update(dt, a, x, b, c):
+        y, leaf = ssm_update.state_update(
+            leaves["ssm"], layer, live, pos,
+            (dt[..., None] * x).reshape(B, s.n_heads, s.head_dim),
+            jnp.exp(dt * a).reshape(B, s.n_heads), b, c)
+        return y.reshape(x.shape), leaf
+
+    def step(start):
+        out, ssm_leaf, new_conv = _step(n, p, cfg, start["conv"], update)
+        return (out, ssm_leaf), {"conv": new_conv}
+
+    (out, ssm_leaf), conv = _advance_layer({"conv": leaves["conv"]}, layer,
+                                           live, pos, step)
+    return out, dict(conv, ssm=ssm_leaf)

@@ -203,6 +203,36 @@ def test_paged_decode_kernel(shape, kv, pool_shape, Tq):
     assert n == 1
 
 
+# the two state-space cells' leaves as their servers hold them (64 slots):
+# (layers, heads, head_dim, d_state, B/C groups)
+STATE_SHAPES = {"falconh1-serve-chat": (6, 32, 128, 256, 2),
+                "granite4h-serve-docqa": (9, 128, 64, 128, 1)}
+
+
+@pytest.mark.parametrize("cell", list(STATE_SHAPES))
+def test_ssm_state_update_kernel(shape, cell):
+    """The decode step's state update at the cell's full geometry: one
+    kernel, the donated leaf aliased to its output and nothing of a
+    layer's size beside it."""
+    from paddle_tpu.ops import ssm_update as su
+
+    L, Hm, P, N, G = STATE_SHAPES[cell]
+    slots = 64
+    leaf = shape((L, slots, Hm, P, N), F32)
+    assert su.supported(leaf.shape, leaf.dtype, G)
+    compiled = jax.jit(su.state_update, donate_argnums=(0,)).lower(
+        leaf, shape((), I32), shape((slots,), jnp.bool_),
+        shape((slots,), I32), shape((slots, Hm, P), F32),
+        shape((slots, Hm), F32), shape((slots, G, N), F32),
+        shape((slots, G, N), F32)).compile()
+    text = compiled.as_text()
+    assert _kernel_calls("ssm_state_update", text) == 1
+    assert text.count("tpu_custom_call") == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= L * slots * Hm * P * N * 4
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
 # --------------------------------------------------------------------------
 # whole programs at reduced depth, through the entry points' own builders
 # --------------------------------------------------------------------------
@@ -276,8 +306,11 @@ def test_hybrid_decode_step_and_prefill(one_chip, no_persistent_cache,
     widths of the benchmark's hybrid configuration (two layers of it, an
     eighth of the vocabulary): the paged kernel takes 20 query heads
     over 4 KV heads of 128, the state leaves ride the layer scan's carry
-    and are aliased to the outputs (donated with the pool), and the
-    mixer's ops carry the ``ssm`` scope the per-layer metrics select."""
+    and are aliased to the outputs (donated with the pool), the state is
+    advanced by the ``ssm_state_update`` kernel where it is stored (once
+    in the layer scan's body: no op's result is a layer's state), and the
+    mixer's ops, the kernel among them, carry the ``ssm`` scope the
+    per-layer metrics select."""
     import json
 
     from benchmarks.families import falcon_h1 as fam
@@ -298,16 +331,20 @@ def test_hybrid_decode_step_and_prefill(one_chip, no_persistent_cache,
     compiled = step.lower(params, cache, tok, tok).compile()
     text = compiled.as_text()
     assert _names_a_kernel("paged_decode_attention", text)
+    assert _kernel_calls("ssm_state_update", text) == 1   # the scan's body
+    assert _kernel_on_path("ssm_state_update", "/ssm/ssm_update/", text)
+    assert _holds_pool_slices(text, B * 32 * 128 * 256, "f32") == []
     paths = set(re.findall(r'op_name="([^"]*)"', text))
     for scope in ("/ssm/ssm_update/", "/ssm/ssm_conv/", "/attn/", "(mlp)/"):
         assert any(p.startswith("jit(<lambda>)/serving.step/")
                    and scope in p for p in paths), scope
-    # the state is written in place: no second copy of it in the step
+    # the state is written in place: no copy of it, or of a layer of it
+    # (33.5 MB at these 8 slots), in the step
     mem = compiled.memory_analysis()
     state = sum(int(np.prod(cache[n].shape)) * cache[n].dtype.itemsize
                 for n in ("ssm", "conv"))
     assert mem.alias_size_in_bytes >= state
-    assert mem.temp_size_in_bytes < state
+    assert mem.temp_size_in_bytes < 8 << 20
     scalar = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
     pf = engine.ENGINE.get("paged_prefill",
                            engine.StepSpec(cfg=cfg, bucket=256))
@@ -340,12 +377,34 @@ def _computations(text) -> dict:
     return out
 
 
+def _aliases_its_large_outputs(shape: str, rest: str, slice_elems: int,
+                               dtype: str) -> bool:
+    """Every output of a custom call's result ``shape`` that holds a
+    layer's slice is named in its ``output_to_operand_aliasing``."""
+    outs = re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", shape)
+    m = re.search(r"output_to_operand_aliasing=\{(.*?)\}, [a-z_]+=", rest)
+    aliased = set(re.findall(r"\{(\d*)\}: \(", m.group(1))) if m else set()
+    large = [i for i, (dt, dims) in enumerate(outs)
+             if dt == dtype and int(np.prod(
+                 [int(d) for d in dims.split(",") if d])) >= slice_elems]
+    one = len(outs) == 1
+    return all(("" if one else str(i)) in aliased for i in large)
+
+
+def _kernel_on_path(name: str, scope: str, text: str) -> bool:
+    """A custom call named after the kernel sits under ``scope``."""
+    return re.search(rf'custom-call\(.*op_name="[^"]*{scope}[^"]*\b{name}\)*'
+                     rf'/pallas_call"', text) is not None
+
+
 def _holds_pool_slices(text, slice_elems: int, dtype: str) -> list:
     """(name, opcode, shape) of every op whose result holds a whole number
     of layer slices of a K/V leaf (``slice_elems`` elements of ``dtype``
     each), one at least, and is not the leaf passed on as it is: a
-    parameter, a tuple or its element, the loop, a bitcast, or the row
-    scatter writing into the leaf it was given."""
+    parameter, a tuple or its element, the loop, a bitcast, the row
+    scatter writing into the leaf it was given, or a kernel whose output
+    of that size is one of its operands (``output_to_operand_aliasing``:
+    the state update writes the slots it visits where they are)."""
     comps = _computations(text)
     passed_on = {"parameter", "tuple", "get-tuple-element", "while",
                  "bitcast", "scatter"}
@@ -362,6 +421,9 @@ def _holds_pool_slices(text, slice_elems: int, dtype: str) -> list:
                                                 shape) if dt == dtype]
             if not any(n >= slice_elems and n % slice_elems == 0
                        for n in sizes) or opcode in passed_on:
+                continue
+            if opcode == "custom-call" and _aliases_its_large_outputs(
+                    shape, rest, slice_elems, dtype):
                 continue
             called = re.search(r"calls=%([\w.\-]+)", rest)
             body = comps.get(called.group(1), ()) if called else ()
@@ -420,6 +482,13 @@ def test_paged_step_holds_no_slice_of_the_pool(one_chip, no_persistent_cache,
         assert _names_a_kernel("paged_decode_attention", text)
     assert _holds_pool_slices(text, slice_elems, "bf16") == []
     mem = compiled.memory_analysis()
+    if cell == "falconh1":
+        # nor a layer's state: the kernel once in the scan's body, and of
+        # the parent's two float32 slices of 268 MB nothing is left
+        assert _kernel_calls("ssm_state_update", text) == 1
+        assert _holds_pool_slices(text, slots * 32 * 128 * 256, "f32") == []
+        assert mem.temp_size_in_bytes < 16 << 20
+        assert mem.peak_memory_in_bytes < PARENT_STEP_PEAK[cell] - 4e8
     # both leaves are donated and written where they are
     assert mem.alias_size_in_bytes >= 2 * cfg.num_layers * slice_elems * 2
     if cell == "gpt1p3b":
@@ -618,10 +687,11 @@ def test_pattern_step_holds_no_slice_of_either_pool_and_no_experts_copy(
     attends, its place among the attention layers as the ``layer``
     operand), no op has a whole K/V leaf or a copy of a layer's experts
     (679 MB) as its result, every cache leaf is donated and written where
-    it is, and the temporaries hold at most one layer's state (the old
-    state a step's two selects read: 268 MB) and not the nine.  The
-    prefill gathers one slot's view of the one layer and cuts the slot's
-    state out of the leaf as it is stored."""
+    it is, each mamba layer's state is advanced by one ``ssm_state_update``
+    kernel where it is stored, and no op's result is a layer's state (the
+    parent's step held one, 268 MB, among its temporaries).  The prefill
+    gathers one slot's view of the one layer and cuts the slot's state
+    out of the leaf as it is stored."""
     import json
 
     from benchmarks.families import granite_moe_hybrid as fam
@@ -665,7 +735,10 @@ def test_pattern_step_holds_no_slice_of_either_pool_and_no_experts_copy(
     assert mem.alias_size_in_bytes >= held
     if program == "decode":
         assert _kernel_calls("paged_decode_attention", text) == 1
-        assert mem.temp_size_in_bytes < layer_state + (64 << 20)
+        assert _kernel_calls("ssm_state_update", text) == 9
+        assert _kernel_on_path("ssm_state_update", "/ssm/ssm_update/", text)
+        assert _holds_pool_slices(text, layer_state // 4, "f32") == []
+        assert mem.temp_size_in_bytes < 32 << 20
         # the experts' and the shared expert's matmuls keep the program's
         # scopes on the chip's compiler: the moe metrics find them by it
         for dot, n in (("etd,edf->etf", 2), ("etf,efd->td", 1)):

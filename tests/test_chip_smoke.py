@@ -38,11 +38,11 @@ def rehearsal(smoke, monkeypatch):
     put back afterwards (the script sets them for the life of its own
     process)."""
     from paddle_tpu.ops import (decode_attention, flash_attention, fused_ce,
-                                fused_norm, woq_matmul)
+                                fused_norm, ssm_update, woq_matmul)
     from paddle_tpu.text import engine
 
     for m in (decode_attention, flash_attention, fused_ce, fused_norm,
-              woq_matmul):
+              ssm_update, woq_matmul):
         monkeypatch.setattr(m, "_INTERPRET", m._INTERPRET)
     for flag in ("PADDLE_TPU_FUSED_LN", "PADDLE_TPU_FUSED_CE"):
         monkeypatch.setenv(flag, os.environ.get(flag, ""))
@@ -56,9 +56,9 @@ def rehearsal(smoke, monkeypatch):
 def test_rehearsal_kernels_phase(rehearsal, capsys):
     rehearsal.phase_kernels(rehearsal.REHEARSAL, seed=0)
     out = capsys.readouterr().out
-    assert "19 checks passed" in out
+    assert "21 checks passed" in out
     for name in ("flash dq", "ln dg", "ce dlogits", "w4",
-                 "decode int8 Tq4", "paged int8 bs16"):
+                 "decode int8 Tq4", "paged int8 bs16", "ssm state", "ssm y"):
         assert f"[kernels] {name}: max abs err" in out
 
 
