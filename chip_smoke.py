@@ -162,7 +162,9 @@ def _close(name, got, want, tol):
 def phase_kernels(sz: Sizes, seed: int):
     """Tolerances are bf16-rounding scale (the MXU's fp32 dots use bf16
     passes and the two paths accumulate in different orders): 2e-2 on
-    outputs, 4x that on gradients, 3e-2 for the row-statistics kernels."""
+    outputs, 4x that on gradients, 3e-2 for the row-statistics kernels.
+    The flash check runs at ``kernel_rows`` (2,048 on the chip), where
+    ``_block_sizes`` gives each kernel tiles larger than 128."""
     import jax
     import jax.numpy as jnp
 
@@ -192,6 +194,7 @@ def phase_kernels(sz: Sizes, seed: int):
     errs["flash fwd"] = _close("flash fwd", out, ref, 2e-2)
     for n, g, r in zip(("dq", "dk", "dv"), vjp(do), rvjp(do)):
         errs[f"flash {n}"] = _close(f"flash {n}", g, r, 8e-2)
+    log(f"[kernels] flash tiles at {N} rows: {fa._block_sizes(N, N, hd, bf)}")
 
     # fused LayerNorm fwd + bwd
     ks = jax.random.split(jax.random.fold_in(key, 1), 4)

@@ -106,10 +106,17 @@ def _names_a_kernel(name: str, text: str) -> bool:
 # --------------------------------------------------------------------------
 
 
-def test_flash_fwd_bwd_causal(shape):
+@pytest.mark.parametrize("heads,hd,dtype", [
+    (H, HD, BF), (12, HD, BF),  # gpt_1p3b's heads; the train cell's
+    # every head size the gate takes, in both dtypes: the tiles the rule
+    # gives each fit the chip's VMEM (head size 256 in float32 shrinks dq)
+    *[(2, d, dt) for d in (64, 128, 256) for dt in (BF, F32)]])
+def test_flash_fwd_bwd_causal(shape, heads, hd, dtype):
+    """The three kernels at the tiles ``_block_sizes`` commits for a
+    2,048-token head compile under their names (no VMEM overflow)."""
     from paddle_tpu.ops import flash_attention as fa
 
-    q = shape((1, T, H, HD), BF)
+    q = shape((1, T, heads, hd), dtype)
     n = kernels_in(
         lambda q, k, v: jax.vjp(
             lambda a, b, c: fa._flash(a, b, c, True, None), q, k, v)[1](q),

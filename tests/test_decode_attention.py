@@ -465,25 +465,92 @@ def test_kv_dtype_part_of_jit_key(kv_env):
 # ---------------------------------------------------------------------------
 
 
-def test_prefill_flash_kernel_interpret_parity(interpret):
+@pytest.mark.parametrize("T,S,H,D,causal,dtype", [
+    (128, 128, 2, 64, True, "float32"),
+    # the large tiles: several cells a head, diagonal and interior tiles
+    *[(n, n, 1, d, c, "float32") for n in (512, 1024) for d in (64, 128)
+      for c in (True, False)],
+    # 1024 x 1024 forward and dq tiles: a dead, an interior and two
+    # diagonal cells a head
+    (2048, 2048, 1, 64, True, "float32"),
+    (512, 1024, 1, 128, False, "float32"),
+    (1024, 512, 1, 64, False, "float32"),
+    (1024, 1024, 1, 128, True, "bfloat16"),
+    (512, 512, 2, 64, False, "bfloat16"),
+    (1024, 512, 1, 128, False, "bfloat16"),
+])
+def test_prefill_flash_kernel_interpret_parity(interpret, T, S, H, D, causal,
+                                               dtype):
+    """Forward and vjp against ``xla_attention``.  float32 inputs are not
+    cast and match it to 1e-5 / 1e-4.  bf16 inputs feed the MXU bf16 as
+    the XLA path does: the kernel is held to that path on the same inputs,
+    within twice the XLA path's own distance from float32 at the shape,
+    never looser than chip_smoke's 2e-2 / 8e-2."""
     from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops.attention import xla_attention
 
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q, k, v = (jax.random.normal(kk, (1, 128, 2, 64), jnp.float32)
-               for kk in ks)
-    out = fa.flash_attention(q, k, v, causal=True)
-    ref = xla_attention(q, k, v, is_causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               atol=1e-5, rtol=1e-5)
-    # backward too: the custom_vjp kernels run interpreted
-    g = jax.vjp(lambda a, b, c: fa.flash_attention(a, b, c, causal=True),
-                q, k, v)[1](ref)
-    gr = jax.vjp(lambda a, b, c: xla_attention(a, b, c, is_causal=True),
-                 q, k, v)[1](ref)
-    for x, y in zip(g, gr):
-        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
-                                   atol=1e-4, rtol=1e-4)
+    ks = jax.random.split(jax.random.PRNGKey(T + S + D), 4)
+    q, k, v, do = (jax.random.normal(kk, (1, n, H, D), jnp.float32)
+                   .astype(dtype) for kk, n in zip(ks, (T, S, S, T)))
+
+    def both(attn, *args):
+        out, vjp = jax.vjp(lambda a, b, c: attn(a, b, c, causal), *args)
+        return [np.asarray(x, np.float32) for x in (out, *vjp(do))]
+
+    got = both(lambda a, b, c, m: fa.flash_attention(a, b, c, causal=m),
+               q, k, v)
+    want = both(lambda a, b, c, m: xla_attention(a, b, c, is_causal=m),
+                q, k, v)
+    if dtype == "float32":
+        for x, y, tol in zip(got, want, (1e-5, 1e-4, 1e-4, 1e-4)):
+            np.testing.assert_allclose(x, y, atol=tol, rtol=tol)
+        return
+    f32 = [a.astype(jnp.float32) for a in (q, k, v)]
+    do = do.astype(jnp.float32)
+    exact = both(lambda a, b, c, m: xla_attention(a, b, c, is_causal=m),
+                 *f32)
+    for name, x, y, z, cap in zip(("out", "dq", "dk", "dv"), got, want,
+                                  exact, (2e-2, 8e-2, 8e-2, 8e-2)):
+        tol = min(2 * float(np.max(np.abs(y - z))), cap)
+        assert float(np.max(np.abs(x - y))) <= tol, (name, tol)
+
+
+def test_flash_tiles_come_from_the_shape():
+    """The tile of each kernel is a function of (T, S, D, dtype) alone:
+    the train cell's 2,048-token bf16 heads take the tiles the chip sweep
+    supports, 128-token heads keep 128 x 128, and under ``causal`` a cell
+    above the diagonal names a block its row (column) already holds."""
+    from paddle_tpu.ops import flash_attention as fa
+
+    assert fa._block_sizes(2048, 2048, 128, jnp.bfloat16) == fa.Tiles(
+        fwd=(1024, 1024), dq=(1024, 1024), dkv=(512, 512))
+    # the one size at which the VMEM budget binds
+    assert fa._block_sizes(2048, 2048, 256, jnp.float32).dq == (1024, 512)
+    assert fa._block_sizes(128, 128, 64, jnp.float32) == fa.Tiles(
+        (128, 128), (128, 128), (128, 128))
+    assert fa._block_sizes(384, 128, 128, jnp.bfloat16).fwd == (128, 128)
+    # every kernel's cell fits the budget the rule holds it to
+    for T in (256, 1024, 4096, 8192):
+        for D in (64, 128, 256):
+            for dt in (jnp.bfloat16, jnp.float32):
+                tiles = fa._block_sizes(T, T, D, dt)
+                for name, (BQ, BK) in tiles._asdict().items():
+                    assert T % BQ == 0 and T % BK == 0
+                    assert fa._vmem_bytes(fa._USE[name], BQ, BK, D,
+                                          jnp.dtype(dt).itemsize) \
+                        <= fa._VMEM_BUDGET, (T, D, dt, name)
+    BQ, BK, n = 512, 256, 8
+    rows = fa._row_blocks(True, BQ, BK, n)
+    cols = fa._column_blocks(True, BQ, BK, 4)
+    for i in range(4):
+        for j in range(n):
+            live = j * BK <= i * BQ + BQ - 1
+            assert rows(0, i, j)[1] == (j if live else (i * BQ + BQ - 1) // BK)
+    for j in range(n):
+        for i in range(4):
+            live = i * BQ + BQ - 1 >= j * BK
+            assert cols(0, j, i)[1] == (i if live else j * BK // BQ)
+    assert fa._row_blocks(False, BQ, BK, n)(0, 3, 0) == (0, 0, 0)
 
 
 # ---------------------------------------------------------------------------
