@@ -185,7 +185,7 @@ def cfg_key(cfg):
     return (cfg.vocab_size, cfg.hidden_size, cfg.num_layers, cfg.num_heads,
             cfg.num_kv_heads,
             cfg.max_seq_len, cfg.ffn_ratio, str(cfg.dtype), cfg.use_flash,
-            cfg.pos_embed, cfg.norm, cfg.activation,
+            cfg.pos_embed, cfg.norm, cfg.rms_norm_eps, cfg.activation,
             moe_key,
             # explicit widths, the untied head, bias-free projections, the
             # rope base, the forward multipliers and the parallel mixer
@@ -197,8 +197,9 @@ def cfg_key(cfg):
              cfg.ssm.key() if cfg.ssm is not None else None,
              cfg.mla.key() if cfg.mla is not None else None,
              cfg.experts.key() if cfg.experts is not None else None,
-             # the layer pattern and its two forward scalars
-             cfg.layer_types, cfg.attention_multiplier,
+             # the layer pattern, its leading dense layers and its two
+             # forward scalars
+             cfg.layer_types, cfg.first_k_dense, cfg.attention_multiplier,
              cfg.residual_multiplier),
             # trace-time env routing flags (flags.decode_jit_key): an
             # executable BAKES these in — W4 kernel gate (woq.mm), fused

@@ -77,6 +77,7 @@ class GPTConfig:
     # causal mask, and any recurrent mixer, carry the order)
     pos_embed: str = "learned"
     norm: str = "layernorm"        # "layernorm" | "rmsnorm" (gain-only)
+    rms_norm_eps: float = 1e-5     # RMSNorm's epsilon, as published
     activation: str = "gelu"       # "gelu" | "swiglu" (gated FFN)
     # explicit widths: None keeps the classic derivations (head_dim =
     # hidden_size // num_heads, intermediate_size = ffn_ratio *
@@ -107,17 +108,22 @@ class GPTConfig:
     # FFNs' width; the cache holds a latent row a token a sublayer
     mla: Any = None
     experts: Any = None
-    # a LAYER PATTERN, one entry a layer, "mamba" or "attention": layer l
-    # runs ONE mixer (the ssm mixer or grouped-query attention, not both)
-    # and then the expert layer ``experts`` (:func:`pattern_block`).
-    # Stated once, here: the block, the cache's leaves (state for the
-    # mamba layers only, K/V rows for the attention layers only:
-    # :attr:`layer_slots`), the decode step and the prefill chunk read it.
-    # With it two forward scalars of the Granite-4.0-H family:
+    # a LAYER PATTERN, one entry a layer, "mamba", "attention" or "mla":
+    # layer l runs ONE mixer (the ssm mixer, grouped-query attention or
+    # one latent-attention sublayer ``mla``) and then the expert layer
+    # ``experts``, or, in its ``first_k_dense`` leading layers, the dense
+    # SwiGLU of ``intermediate_size`` (:func:`pattern_block`).  Stated
+    # once, here: the block, the cache's leaves (state for the mamba
+    # layers only, K/V rows for the attention layers only, latent rows for
+    # the mla layers only: :attr:`layer_slots`), the decode step and the
+    # prefill chunk read it.  The latent layers rotate their rope lanes
+    # (pos_embed "rope"); grouped-query layers of a pattern apply no
+    # position.  With it two forward scalars of the Granite-4.0-H family:
     # ``attention_multiplier`` is the softmax scale as stated (None:
     # 1 / sqrt(head_dim)); ``residual_multiplier`` scales both branches
     # before they join the residual stream
     layer_types: Any = None
+    first_k_dense: int = 0
     attention_multiplier: float | None = None
     residual_multiplier: float = 1.0
 
@@ -151,11 +157,12 @@ class GPTConfig:
                 "share and no layer pattern (layer_types), is not "
                 "implemented")
         elif (self.attention_multiplier is not None
-              or self.residual_multiplier != 1.0):
+              or self.residual_multiplier != 1.0 or self.first_k_dense):
             raise ValueError(
-                "attention_multiplier and residual_multiplier are applied "
-                "by the pattern block only (layer_types)")
-        if self.mla is not None and (
+                "attention_multiplier, residual_multiplier and "
+                "first_k_dense are applied by the pattern block only "
+                "(layer_types)")
+        if self.mla is not None and self.layer_types is None and (
                 self.pos_embed != "rope" or self.norm != "rmsnorm"
                 or self.activation != "swiglu" or self.bias
                 or self.tie_embeddings or self.moe is not None
@@ -177,24 +184,32 @@ class GPTConfig:
     def _check_pattern(self):
         """A stated layer pattern: what the pattern block is."""
         self.layer_types = tuple(self.layer_types)
+        kinds = set(self.layer_types)
         if (len(self.layer_types) != self.num_layers
-                or set(self.layer_types) - {"mamba", "attention"}):
+                or kinds - {"mamba", "attention", "mla"}):
             raise ValueError(
-                f"layer_types states one of 'mamba' / 'attention' a "
-                f"layer ({self.num_layers} layers), got {self.layer_types}")
-        if self.experts is None or self.mla is not None or (
-                self.ssm is None and "mamba" in self.layer_types):
+                f"layer_types states one of 'mamba' / 'attention' / 'mla' "
+                f"a layer ({self.num_layers} layers), got "
+                f"{self.layer_types}")
+        if (self.experts is None
+                or (self.ssm is None and "mamba" in kinds)
+                or (self.mla is None) == ("mla" in kinds)
+                or not 0 <= self.first_k_dense < self.num_layers):
             raise ValueError(
-                "a layer pattern runs one mixer a layer (ssm for its "
-                "mamba layers) and then an expert layer (experts), "
-                "without latent attention (mla)")
-        if (self.pos_embed != "none" or self.norm != "rmsnorm"
-                or self.activation != "swiglu" or self.bias
-                or self.moe is not None or self.num_kv_heads is None):
+                "a layer pattern runs one mixer a layer (ssm for its mamba "
+                "layers, mla for its mla layers and for them only) and "
+                "then an expert layer (experts), or in its first_k_dense "
+                "leading layers (fewer than all) the dense SwiGLU")
+        if (self.pos_embed != ("rope" if "mla" in kinds else "none")
+                or self.norm != "rmsnorm" or self.activation != "swiglu"
+                or self.bias or self.moe is not None
+                or (self.num_kv_heads is None and "attention" in kinds)):
             raise ValueError(
-                "the pattern block is position-free (pos_embed='none'), "
-                "RMSNorm, SwiGLU, bias-free, grouped-query (num_kv_heads "
-                "stated) and without cfg.moe (the GShard layer)")
+                "the pattern block is RMSNorm, SwiGLU, bias-free, without "
+                "cfg.moe (the GShard layer), grouped-query (num_kv_heads "
+                "stated) where it has attention layers, and rotates the "
+                "rope lanes of its latent layers only (pos_embed='rope' "
+                "with mla layers, 'none' without)")
 
     @property
     def layer_slots(self) -> tuple:
@@ -203,7 +218,7 @@ class GPTConfig:
         layer's place in the params' ``mamba`` / ``attn`` leaves and in
         the cache's state / K/V leaves, which are as deep as their kind
         has layers."""
-        seen = {"mamba": 0, "attention": 0}
+        seen = {"mamba": 0, "attention": 0, "mla": 0}
         out = []
         for kind in self.layer_types:
             out.append((kind, seen[kind]))
@@ -211,11 +226,19 @@ class GPTConfig:
         return tuple(out)
 
     def layers_of(self, kind: str) -> int:
-        """Layers of ``kind`` ("mamba" / "attention"): every layer counts
-        as both where no pattern is stated (the parallel hybrid block)."""
+        """Layers of ``kind`` ("mamba" / "attention" / "mla"): every layer
+        counts as each where no pattern is stated (the parallel hybrid
+        block, the latent block)."""
         if self.layer_types is None:
             return self.num_layers
         return sum(t == kind for t in self.layer_types)
+
+    @property
+    def latent_sublayers(self) -> int:
+        """Latent-attention sublayers a latent layer has: the latent
+        block's two, a pattern's mla layer's one.  The pool's latent leaf
+        is ``latent_sublayers * layers_of("mla")`` deep."""
+        return 2 if self.layer_types is None else 1
 
     @property
     def softmax_scale(self) -> float:
@@ -269,7 +292,7 @@ def init_params(cfg: GPTConfig, key) -> dict:
         from .moe import init_expert_share
 
         fk = jax.random.split(keys[9], 6)
-        ak = jax.random.split(keys[2], _mla.SUBLAYERS)
+        ak = jax.random.split(keys[2], cfg.latent_sublayers)
         blocks = {
             # the four norms of a layer: before each attention sublayer
             # (0, 2) and each dense FFN (1, 3); the expert layer reads
@@ -280,9 +303,10 @@ def init_params(cfg: GPTConfig, key) -> dict:
         # a sublayer's weights are leaves of their own, [L, ...]: a layer
         # step reads each where it is stored (a leaf [L, 2, ...] would be
         # copied out a layer at a time to be cut in two)
-        for i in range(_mla.SUBLAYERS):
+        for i in range(cfg.latent_sublayers):
             blocks[f"attn{i}"] = _mla.init_params(
-                cfg.mla, D, cfg.num_heads, L, ak[i], std=s)
+                cfg.mla, D, cfg.num_heads, L, ak[i], std=s,
+                out_std=s / math.sqrt(2 * cfg.latent_sublayers * L))
             blocks[f"ffn{i}"] = {
                 "gate_w": nrm(fk[3 * i], (L, D, F)),
                 "fc_w": nrm(fk[3 * i + 1], (L, D, F)),
@@ -365,24 +389,37 @@ def init_params(cfg: GPTConfig, key) -> dict:
 
 
 def _init_pattern_params(cfg: GPTConfig, keys, nrm) -> dict:
-    """The tree of a stated layer pattern: the two norms and the expert
-    layer of every layer ``[L, ...]`` (the routed experts a tuple of a
-    leaf a layer, ``moe.init_expert_share``), and each kind's mixer
-    leaves as deep as that kind has layers: ``mamba`` (``ssm.init_params``'
-    leaves, ``[Lm, ...]``) and ``attn`` (``q_w``, ``kv_w``, ``proj_w``,
-    ``[La, ...]``).  :func:`pattern_layer` cuts one layer's out."""
+    """The tree of a stated layer pattern: the two norms of every layer
+    ``[L, ...]``, the expert layer of every layer past the
+    ``first_k_dense`` leading ones (``moe``, ``[L - K, ...]``; the routed
+    experts a tuple of a leaf a layer, ``moe.init_expert_share``) and the
+    leading layers' dense SwiGLU (``dense``: ``gate_w``, ``fc_w``,
+    ``out_w``, ``[K, ...]``), and each kind's mixer leaves as deep as
+    that kind has layers: ``mamba`` (``ssm.init_params``' leaves,
+    ``[Lm, ...]``), ``attn`` (``q_w``, ``kv_w``, ``proj_w``, ``[La, ...]``)
+    and ``mla`` (``mla.init_params``' leaves, ``[Ll, ...]``).
+    :func:`pattern_layer` cuts one layer's out."""
+    from . import mla as _mla
     from . import ssm as _ssm
     from .moe import init_expert_share
 
-    D, L, V = cfg.hidden_size, cfg.num_layers, cfg.vocab_size
+    D, L, V, K = (cfg.hidden_size, cfg.num_layers, cfg.vocab_size,
+                  cfg.first_k_dense)
     Dq, Dkv = cfg.q_size, cfg.kv_heads * cfg.head_dim
-    Lm, La = cfg.layers_of("mamba"), cfg.layers_of("attention")
+    Lm, La, Ll = (cfg.layers_of("mamba"), cfg.layers_of("attention"),
+                  cfg.layers_of("mla"))
     ak = jax.random.split(keys[2], 3)
     blocks = {
         "ln1_g": jnp.ones((L, D), jnp.float32),
         "ln2_g": jnp.ones((L, D), jnp.float32),
-        "moe": init_expert_share(keys[3], D, cfg.experts, L),
+        "moe": init_expert_share(keys[3], D, cfg.experts, L - K),
     }
+    if K:
+        dk = jax.random.split(keys[4], 3)
+        F = cfg.ffn_size
+        blocks["dense"] = {
+            "gate_w": nrm(dk[0], (K, D, F)), "fc_w": nrm(dk[1], (K, D, F)),
+            "out_w": nrm(dk[2], (K, F, D), std=0.02 / math.sqrt(2 * L))}
     if Lm:
         blocks["mamba"] = _ssm.init_params(cfg.ssm, D, Lm, keys[9])
     if La:
@@ -391,6 +428,10 @@ def _init_pattern_params(cfg: GPTConfig, keys, nrm) -> dict:
             "kv_w": nrm(ak[1], (La, 2, D, Dkv)),
             "proj_w": nrm(ak[2], (La, Dq, D), std=0.02 / math.sqrt(2 * L)),
         }
+    if Ll:
+        blocks["mla"] = _mla.init_params(
+            cfg.mla, D, cfg.num_heads, Ll, keys[5],
+            out_std=0.02 / math.sqrt(2 * L))
     params = {"wte": nrm(keys[0], (V, D)),
               "ln_f_g": jnp.ones((D,), jnp.float32), "blocks": blocks}
     if not cfg.tie_embeddings:
@@ -400,15 +441,20 @@ def _init_pattern_params(cfg: GPTConfig, keys, nrm) -> dict:
 
 def pattern_layer(blocks: dict, cfg: GPTConfig, li: int) -> dict:
     """Layer ``li``'s weights of a pattern config's ``blocks``: its norms
-    and expert layer by the layer's number, its mixer's leaves by its
-    index among the layers of its kind (``cfg.layer_slots``); static
-    slices of the stacked leaves, a per-layer tuple's member."""
+    by the layer's number, its expert layer (``moe``) or, for one of the
+    ``first_k_dense`` leading layers, its dense SwiGLU (``dense``) by its
+    index among those, its mixer's leaves by its index among the layers
+    of its kind (``cfg.layer_slots``); static slices of the stacked
+    leaves, a per-layer tuple's member."""
     from .moe import layer_of
 
     kind, i = cfg.layer_slots[li]
-    mixer = blocks["mamba" if kind == "mamba" else "attn"]
+    mixer = blocks[{"mamba": "mamba", "attention": "attn",
+                    "mla": "mla"}[kind]]
+    K = cfg.first_k_dense
     return {"ln1_g": blocks["ln1_g"][li], "ln2_g": blocks["ln2_g"][li],
-            "moe": layer_of(blocks["moe"], li),
+            **({"dense": {k: v[li] for k, v in blocks["dense"].items()}}
+               if li < K else {"moe": layer_of(blocks["moe"], li - K)}),
             **{k: v[i] for k, v in mixer.items()}}
 
 
@@ -502,8 +548,8 @@ def _norm(x, p, prefix: str, cfg):
     dt = cfg.dtype
     with jax.named_scope("ln"):
         if cfg.norm == "rmsnorm":
-            return _rms_norm(x.astype(jnp.float32),
-                             p[prefix + "_g"]).astype(dt)
+            return _rms_norm(x.astype(jnp.float32), p[prefix + "_g"],
+                             cfg.rms_norm_eps).astype(dt)
         return _ln(x, p[prefix + "_g"], p[prefix + "_b"], dt)
 
 
@@ -743,26 +789,32 @@ def latent_block(x, p, cfg: GPTConfig, attend, valid=None):
     return h3 + _ffn_body(norm(h3, 3), p["ffn1"], cfg) + S, counts
 
 
+def _latent_attend_seqs(n, p, cfg: GPTConfig, B: int, T: int):
+    """One latent sublayer over whole sequences: normed rows ``n``
+    [B * T, D], each sequence's queries attending its own rows,
+    up-projected -> [B * T, D]."""
+    from . import mla as _mla
+
+    pos = jnp.arange(T)
+
+    def one(nb):
+        q_nope, q_rope, rows = _mla.project(nb, p, cfg, pos)
+        with jax.named_scope("attn"):
+            return _mla.attend_chunk(q_nope, q_rope, rows, 0, p, cfg)
+
+    attn = jax.vmap(one)(n.reshape(B, T, -1))
+    return _mla.out_proj(attn.reshape(B * T, -1), p, cfg)
+
+
 def _latent_forward_block(x, p, cfg: GPTConfig):
     """:func:`latent_block` over whole sequences [B, T, D]: the rows of
     all sequences go through the layer together (the expert layer
     takes rows, no batch axis); a sublayer's queries attend
     their own sequence's rows, up-projected."""
-    from . import mla as _mla
-
     B, T, D = x.shape
-    pos = jnp.arange(T)
-
-    def attend(i, n, p_i):
-        def one(nb):
-            q_nope, q_rope, rows = _mla.project(nb, p_i, cfg, pos)
-            with jax.named_scope("attn"):
-                return _mla.attend_chunk(q_nope, q_rope, rows, 0, p_i, cfg)
-
-        attn = jax.vmap(one)(n.reshape(B, T, D))
-        return _mla.out_proj(attn.reshape(B * T, -1), p_i, cfg)
-
-    out, _ = latent_block(x.reshape(B * T, D), p, cfg, attend)
+    out, _ = latent_block(
+        x.reshape(B * T, D), p, cfg,
+        lambda i, n, p_i: _latent_attend_seqs(n, p_i, cfg, B, T))
     return out.reshape(B, T, D)
 
 
@@ -773,12 +825,15 @@ def pattern_block(x, p, cfg: GPTConfig, mixer, valid=None):
         h1  = x  + residual_multiplier * mixer(norm_1(x))
         out = h1 + residual_multiplier * experts(norm_2(h1))
 
-    ``mixer(n)`` is the layer's ONE mixer on the normed rows, the ssm
-    mixer or attention by the layer's kind: the full forward, the prefill
-    chunk and the decode step differ in nothing else.  The residual
-    stream is float32 where the caller hands it in so (as
-    :func:`latent_block`'s).  ``valid`` [T]: rows that select experts.
-    Returns (out, the expert layer's counts)."""
+    with the dense SwiGLU in place of the experts in one of the
+    ``first_k_dense`` leading layers (its weights ``p["dense"]``, read
+    under ``mlp`` as every dense FFN is).  ``mixer(n)`` is the layer's ONE
+    mixer on the normed rows, the ssm mixer, attention or a latent
+    sublayer by the layer's kind: the full forward, the prefill chunk and
+    the decode step differ in nothing else.  The residual stream is
+    float32 where the caller hands it in so (as :func:`latent_block`'s).
+    ``valid`` [T]: rows that select experts.  Returns (out, the expert
+    layer's counts, None for a dense layer)."""
     from .moe import expert_share
 
     def scaled(y):
@@ -788,6 +843,9 @@ def pattern_block(x, p, cfg: GPTConfig, mixer, valid=None):
         return y
 
     h1 = x + scaled(mixer(_norm(x, p, "ln1", cfg)))
+    if "dense" in p:
+        return h1 + scaled(_ffn_body(_norm(h1, p, "ln2", cfg), p["dense"],
+                                     cfg)), None
     y, counts = expert_share(_norm(h1, p, "ln2", cfg), p["moe"],
                              cfg.experts, cfg.dtype, valid)
     return h1 + scaled(y), counts
@@ -797,10 +855,13 @@ def _pattern_forward_block(x, p, cfg: GPTConfig, kind: str):
     """:func:`pattern_block` over whole sequences [B, T, D]: the rows of
     all sequences go through the layer together; a mamba layer scans each
     sequence from the zero state, an attention layer's queries attend
-    their own sequence (no position applied to q or k)."""
+    their own sequence (no position applied to q or k), a latent layer's
+    theirs (rope on the rope lanes, up-projected)."""
     B, T, D = x.shape
 
     def mixer(n):
+        if kind == "mla":
+            return _latent_attend_seqs(n, p, cfg, B, T)
         n = n.reshape(B, T, D)
         if kind == "mamba":
             from . import ssm as _ssm
@@ -961,19 +1022,27 @@ def count_params(cfg: GPTConfig) -> int:
         from . import ssm as _ssm
         from .moe import count_expert_share
 
+        from . import mla as _mla
+
         outside, expert = count_expert_share(cfg.experts, D)
         attn = 2 * D * cfg.q_size + 2 * D * cfg.kv_heads * cfg.head_dim
         mamba = _ssm.count_params(cfg.ssm, D) if cfg.ssm is not None else 0
-        return (L * (2 * D + outside + cfg.experts.n_held * expert)
+        latent = (_mla.count_params(cfg.mla, D, cfg.num_heads)
+                  if cfg.mla is not None else 0)
+        K = cfg.first_k_dense
+        return (L * 2 * D + (L - K) * (outside + cfg.experts.n_held * expert)
+                + K * 3 * D * F
                 + cfg.layers_of("mamba") * mamba
                 + cfg.layers_of("attention") * attn
+                + cfg.layers_of("mla") * latent
                 + V * D * (1 if cfg.tie_embeddings else 2) + D)
     if cfg.mla is not None:
         from . import mla as _mla
         from .moe import count_expert_share
 
         router, expert = count_expert_share(cfg.experts, D)
-        outside = (2 * _mla.count_params(cfg.mla, D, cfg.num_heads)
+        outside = (cfg.latent_sublayers
+                   * _mla.count_params(cfg.mla, D, cfg.num_heads)
                    + 2 * 3 * D * F + 4 * D + router)
         return (L * (outside + cfg.experts.n_held * expert)
                 + 2 * V * D + D)

@@ -48,7 +48,6 @@ The contiguous layout stays the default (``PADDLE_TPU_KV_LAYOUT``).
 """
 from __future__ import annotations
 
-import math
 import zlib
 
 import jax
@@ -70,8 +69,9 @@ __all__ = [
 ]
 
 # a latent-attention config's pool: ONE leaf of latent rows
-# [2 * L, N, bs, lanes] in place of "k" and "v" (an attention sublayer,
-# not a layer, indexes its first axis; a row is [c | kr], zero-padded to
+# [sublayers * L, N, bs, lanes] in place of "k" and "v" (an attention
+# sublayer, not a layer, indexes its first axis: two a layer of the latent
+# block, one a mla layer of a pattern; a row is [c | kr], zero-padded to
 # whole lane tiles), and an expert share's device-side counts
 # (moe.SHARE_COUNTS) carried with the cache
 LATENT = "latent"
@@ -135,32 +135,31 @@ def init_paged_cache(cfg: gpt.GPTConfig, batch: int, max_len: int,
     N = batch * nmax if num_blocks is None else int(num_blocks)
     if N < 1:
         raise ValueError(f"num_blocks must be >= 1, got {N}")
-    L, H, hd = cfg.num_layers, cfg.kv_heads, cfg.head_dim
+    H, hd = cfg.kv_heads, cfg.head_dim
     dt = generate._kv_store_dtype(cfg)
-    if cfg.mla is not None:
-        if dt == jnp.int8:
-            raise NotImplementedError(
-                "an int8 pool of latent rows is not supported yet: the "
-                "scale planes are per head, and a latent row has none")
-        return {LATENT: jnp.zeros((_mla.SUBLAYERS * L, N, bs,
-                                   latent_lanes(cfg)), dt),
-                "tables": jnp.full((batch, nmax), -1, jnp.int32),
-                LIVE: jnp.zeros((batch,), bool),
-                COUNTS: jnp.zeros((len(_moe.SHARE_COUNTS),), jnp.int32)}
+    if cfg.mla is not None and dt == jnp.int8:
+        raise NotImplementedError(
+            "an int8 pool of latent rows is not supported yet: the "
+            "scale planes are per head, and a latent row has none")
+    La = cfg.layers_of("attention")
     if cfg.layer_types is not None:
         if dt == jnp.int8:
             raise NotImplementedError(
                 "an int8 pool under a layer pattern is not supported yet: "
                 "the pattern's prefill chunk and step were never held to "
                 "the scale planes")
-        if not cfg.layers_of("attention"):
+        if not La and cfg.mla is None:
             raise NotImplementedError(
-                "a layer pattern without an attention layer has no K/V "
-                "leaf for the block tables to map")
-    La = cfg.layers_of("attention")
-    shape = (La, N, bs, H * hd)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
-             "tables": jnp.full((batch, nmax), -1, jnp.int32)}
+                "a layer pattern without an attention or mla layer has no "
+                "K/V or latent leaf for the block tables to map")
+    cache = {"tables": jnp.full((batch, nmax), -1, jnp.int32)}
+    if cfg.mla is None or (cfg.layer_types is not None and La):
+        shape = (La, N, bs, H * hd)
+        cache.update(k=jnp.zeros(shape, dt), v=jnp.zeros(shape, dt))
+    if cfg.mla is not None:
+        cache[LATENT] = jnp.zeros(
+            (cfg.latent_sublayers * cfg.layers_of("mla"), N, bs,
+             latent_lanes(cfg)), dt)
     if dt == jnp.int8:
         cache["k_s"] = jnp.zeros((La, N, bs, H), jnp.float32)
         cache["v_s"] = jnp.zeros((La, N, bs, H), jnp.float32)
@@ -297,10 +296,10 @@ def paged_decode_step_batched(params, cache, token, pos,
     pool first, then ``ops/decode_attention.paged_decode_attention``
     copies each slot's live blocks inside that slot's grid cell — no
     [B, T] gather is ever materialized."""
-    if LATENT in cache:
-        return _latent_step(params, cache, token, pos, cfg)
     if cfg.layer_types is not None:
         return _pattern_step(params, cache, token, pos, cfg)
+    if LATENT in cache:
+        return _latent_step(params, cache, token, pos, cfg)
     N, bs, nmax = _geometry(cache)
     B = token.shape[0]
     if _kernel_route(cache, B, cfg):
@@ -430,27 +429,73 @@ def _pad_lanes(x, lanes: int):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - x.shape[-1])])
 
 
+def _latent_pool_attend(cache, B: int, cfg: gpt.GPTConfig):
+    """The absorbed attention over the pool's latent leaf a decode step
+    of ``B`` slots takes: the paged kernel on the shared row where it
+    runs, the per-slot whole view through the tables elsewhere."""
+    from ..ops import decode_attention as da
+
+    kernel = (_flags.flash_decode() and da.paged_available(
+        (B, 1, cfg.num_heads, cache[LATENT].shape[3]), cache[LATENT].shape,
+        cfg.mla.kv_lora_rank))
+    return da.paged_decode_attention if kernel else da._xla_paged
+
+
+def _latent_sublayer_step(n, p, cfg, pool, sub, pos, phys, tables,
+                          attend_pool):
+    """One latent sublayer of a decode step on the normed rows ``n``
+    [B, D] at ``pos`` [B]: its fresh rows written into the pool's latent
+    leaf ``pool`` at (``sub``, page), then the absorbed attention over
+    each slot's rows -> (out [B, D], pool)."""
+    m, lanes = cfg.mla, pool.shape[3]
+    q_nope, q_rope, rows = _mla.project(n, p, cfg, pos)
+    # scatter-then-attend, as the K/V kernel route does
+    with jax.named_scope("kv_gather"):
+        pool = _put_rows(pool, sub, phys, _pad_lanes(rows, lanes))
+    with jax.named_scope("attn"):
+        q_lat = _pad_lanes(_mla.absorb_q(q_nope, q_rope, p, cfg), lanes)
+        lat = attend_pool(
+            q_lat[:, None], pool, None, tables, pos,
+            jnp.asarray(sub, jnp.int32), None, None, _mla._scale(cfg),
+            m.kv_lora_rank)
+        out = _mla.absorb_out(lat[:, 0], p, cfg)
+    return _mla.out_proj(out, p, cfg), pool
+
+
+def _latent_sublayer_chunk(n, p, cfg, pool, sub, logi, pos0, trow, phys):
+    """One latent sublayer of a prefill chunk on the normed rows ``n``
+    [C, D] at positions ``logi``: the slot's table-gathered rows [0, pos0)
+    and the chunk's own attended up-projected, the chunk's rows written
+    through the table -> (out [C, D], pool)."""
+    from ..ops import decode_attention as da
+
+    q_nope, q_rope, rows = _mla.project(n, p, cfg, logi)
+    rows = _pad_lanes(rows, pool.shape[3]).astype(pool.dtype)
+    with jax.named_scope("kv_gather"):
+        view = da.gather_paged_view(pool, sub, trow[None])[0]
+        full = jax.lax.dynamic_update_slice(view, rows, (pos0, 0))
+        pool = _put_rows(pool, sub, phys, rows)
+    with jax.named_scope("attn"):
+        a = _mla.attend_chunk(q_nope, q_rope, full[:, :cfg.mla.row_width],
+                              pos0, p, cfg)
+    return _mla.out_proj(a, p, cfg), pool
+
+
 def _latent_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     """:func:`paged_decode_step_batched` for a latent-attention config:
     the whole batch a layer at a time ([B, D] rows through
     ``gpt.latent_block``), so that the expert layer routes the step's
     tokens together.  An attention sublayer writes its fresh latent rows
-    into the pool by (sublayer, page) and attends in the absorbed form:
-    the paged kernel, each slot's live pages and nothing else, where it
-    runs; the per-slot whole view through the tables elsewhere.  Slots
-    the ``live`` leaf does not name select no expert and count nowhere."""
-    from ..ops import decode_attention as da
-
+    into the pool by (sublayer, page) and attends in the absorbed form
+    (:func:`_latent_sublayer_step`).  Slots the ``live`` leaf does not
+    name select no expert and count nowhere."""
     N, bs, nmax = _geometry(cache)
     B = token.shape[0]
-    m, dt, H = cfg.mla, cfg.dtype, cfg.num_heads
+    dt = cfg.dtype
     tables, live = cache["tables"], cache[LIVE]
     tb = tables[jnp.arange(B), pos // bs]
     phys = jnp.where(tb >= 0, tb * bs + pos % bs, N * bs)
-    lanes = cache[LATENT].shape[3]
-    kernel = (_flags.flash_decode() and da.paged_available(
-        (B, 1, H, lanes), cache[LATENT].shape, m.kv_lora_rank))
-    attend_pool = da.paged_decode_attention if kernel else da._xla_paged
+    attend_pool = _latent_pool_attend(cache, B, cfg)
     # the residual stream in float32 (the sublayers' inputs are normed
     # into the compute dtype): [B, D]
     x = woq.embed(params, token, dt,
@@ -463,21 +508,10 @@ def _latent_step(params, cache, token, pos, cfg: gpt.GPTConfig):
         box = [pool]
 
         def attend(i, n, p_i, li=li):
-            sub = _mla.SUBLAYERS * li + i
-            q_nope, q_rope, rows = _mla.project(n, p_i, cfg, pos)
-            # scatter-then-attend, as the K/V kernel route does
-            with jax.named_scope("kv_gather"):
-                box[0] = _put_rows(box[0], sub, phys,
-                                   _pad_lanes(rows, lanes))
-            with jax.named_scope("attn"):
-                q_lat = _pad_lanes(_mla.absorb_q(q_nope, q_rope, p_i, cfg),
-                                   lanes)
-                lat = attend_pool(
-                    q_lat[:, None], box[0], None, tables, pos,
-                    jnp.asarray(sub, jnp.int32), None, None,
-                    1.0 / math.sqrt(m.qk_head_dim), m.kv_lora_rank)
-                out = _mla.absorb_out(lat[:, 0], p_i, cfg)
-            return _mla.out_proj(out, p_i, cfg)
+            out, box[0] = _latent_sublayer_step(
+                n, p_i, cfg, box[0], cfg.latent_sublayers * li + i, pos,
+                phys, tables, attend_pool)
+            return out
 
         x, c = gpt.latent_block(x, _moe.layer_of(params["blocks"], li),
                                 cfg, attend, valid=live)
@@ -492,15 +526,12 @@ def _latent_prefill_chunk(params, cache, tokens, pos0, length, slot,
                           cfg: gpt.GPTConfig):
     """:func:`paged_prefill_chunk` for a latent-attention config: the
     chunk's queries attend the slot's table-gathered latent rows [0, pos0)
-    and the chunk's own, up-projected (``mla.attend_chunk``); rows
-    [pos0, pos0 + length) are written through the table, a sublayer at a
-    time.  Padded positions select no expert and write no row."""
-    from ..ops import decode_attention as da
-
+    and the chunk's own, up-projected (:func:`_latent_sublayer_chunk`);
+    rows [pos0, pos0 + length) are written through the table, a sublayer
+    at a time.  Padded positions select no expert and write no row."""
     N, bs, nmax = _geometry(cache)
     trow = cache["tables"][slot]                          # [nmax]
     dt, C = cfg.dtype, tokens.shape[1]
-    lanes = cache[LATENT].shape[3]
     x = woq.embed(params, tokens[0], dt,
                   cfg.embedding_multiplier).astype(jnp.float32)    # [C, D]
     valid = jnp.arange(C) < length
@@ -514,18 +545,10 @@ def _latent_prefill_chunk(params, cache, tokens, pos0, length, slot,
         box = [pool]
 
         def attend(i, n, p_i, li=li):
-            sub = _mla.SUBLAYERS * li + i
-            q_nope, q_rope, rows = _mla.project(n, p_i, cfg, logi)
-            rows = _pad_lanes(rows, lanes).astype(pool.dtype)
-            with jax.named_scope("kv_gather"):
-                view = da.gather_paged_view(box[0], sub, trow[None])[0]
-                full = jax.lax.dynamic_update_slice(view, rows, (pos0, 0))
-                box[0] = _put_rows(box[0], sub, phys, rows)
-            with jax.named_scope("attn"):
-                a = _mla.attend_chunk(q_nope, q_rope,
-                                      full[:, :cfg.mla.row_width], pos0,
-                                      p_i, cfg)
-            return _mla.out_proj(a, p_i, cfg)
+            out, box[0] = _latent_sublayer_chunk(
+                n, p_i, cfg, box[0], cfg.latent_sublayers * li + i, logi,
+                pos0, trow, phys)
+            return out
 
         x, _ = gpt.latent_block(x, _moe.layer_of(params["blocks"], li),
                                 cfg, attend, valid=valid)
@@ -549,8 +572,11 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     its own leaf of the pool (``pool[n][j]``, j its place among the
     attention layers) and attends through the tables, no position applied
     to q or k: the paged kernel, each slot's live pages, where it runs;
-    the gathered view elsewhere.  Slots the ``live`` leaf does not name
-    select no expert and count nowhere."""
+    the gathered view elsewhere.  A mla layer writes its latent rows into
+    its own sublayer of the latent leaf (i, its place among the mla
+    layers) and attends in the absorbed form, rope on the rope lanes
+    (``_latent_sublayer_step``, as the latent block's).  Slots the
+    ``live`` leaf does not name select no expert and count nowhere."""
     from ..ops import decode_attention as da
 
     N, bs, nmax = _geometry(cache)
@@ -559,19 +585,28 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
     tables, live = cache["tables"], cache[LIVE]
     tb = tables[jnp.arange(B), pos // bs]
     phys = jnp.where(tb >= 0, tb * bs + pos % bs, N * bs)
-    kernel = (_flags.flash_decode()
-              and da.paged_available((B, 1, H, hd), cache["k"].shape))
-    attend_pool = da.paged_decode_attention if kernel else da._xla_paged
+    if "k" in cache:
+        kernel = (_flags.flash_decode()
+                  and da.paged_available((B, 1, H, hd), cache["k"].shape))
+        attend_pool = da.paged_decode_attention if kernel else da._xla_paged
+    if LATENT in cache:
+        attend_latent = _latent_pool_attend(cache, B, cfg)
     x = woq.embed(params, token, dt,
                   cfg.embedding_multiplier).astype(jnp.float32)    # [B, D]
 
     # the layers in a Python loop: a layer's weights, its leaf of the pool
     # and its leaf of the state are read and written where they are stored
-    box = {"pool": {n: cache[n] for n in ("k", "v")},
+    box = {"pool": {n: cache[n] for n in ("k", "v", LATENT) if n in cache},
            "state": {n: cache[n] for n in STATE_LEAVES if n in cache}}
     counts = cache[COUNTS]
     for li, (kind, i) in enumerate(cfg.layer_slots):
         p = gpt.pattern_layer(params["blocks"], cfg, li)
+
+        def mla(n, p=p, i=i):
+            out, box["pool"][LATENT] = _latent_sublayer_step(
+                n, p, cfg, box["pool"][LATENT], i, pos, phys, tables,
+                attend_latent)
+            return out
 
         def mamba(n, p=p, i=i):
             out, box["state"] = _ssm.mixer_step_pooled(
@@ -583,8 +618,9 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
             rows = generate._store_rows(k[:, 0], v[:, 0], cfg)
             # scatter-then-attend, as the K/V kernel route does
             with jax.named_scope("kv_gather"):
-                box["pool"] = {k_: _put_rows(box["pool"][k_], i, phys, val)
-                               for k_, val in rows.items()}
+                box["pool"].update(
+                    {k_: _put_rows(box["pool"][k_], i, phys, val)
+                     for k_, val in rows.items()})
             with jax.named_scope("attn"):
                 attn = attend_pool(
                     q, box["pool"]["k"], box["pool"]["v"], tables, pos,
@@ -593,8 +629,10 @@ def _pattern_step(params, cache, token, pos, cfg: gpt.GPTConfig):
             return gpt._attn_out(attn.astype(dt).reshape(B, H * hd), p, cfg)
 
         x, c = gpt.pattern_block(
-            x, p, cfg, mamba if kind == "mamba" else attention, valid=live)
-        counts = counts + c
+            x, p, cfg, {"mamba": mamba, "attention": attention,
+                        "mla": mla}[kind], valid=live)
+        if c is not None:
+            counts = counts + c
     x = gpt._norm(x, params, "ln_f", cfg)
     logits = woq.logits(x, params, dt, cfg.lm_head_multiplier)
     return logits.astype(jnp.float32), dict(
@@ -609,8 +647,10 @@ def _pattern_prefill_chunk(params, cache, tokens, pos0, length, slot,
     (from zero where the chunk opens the sequence) and writes it back; an
     attention layer's queries attend the slot's table-gathered rows
     [0, pos0) and the chunk's own, and rows [pos0, pos0 + length) are
-    written through the table.  Padded positions advance no state, write
-    no row and select no expert."""
+    written through the table; a mla layer's queries attend the slot's
+    latent rows and the chunk's own up-projected
+    (``_latent_sublayer_chunk``).  Padded positions advance no state,
+    write no row and select no expert."""
     N, bs, nmax = _geometry(cache)
     trow = cache["tables"][slot]                          # [nmax]
     dt, C = cfg.dtype, tokens.shape[1]
@@ -622,10 +662,15 @@ def _pattern_prefill_chunk(params, cache, tokens, pos0, length, slot,
     phys = jnp.where(valid & (tb >= 0) & (logi // bs < nmax),
                      tb * bs + logi % bs, N * bs)
 
-    box = {"pool": {n: cache[n] for n in ("k", "v")},
+    box = {"pool": {n: cache[n] for n in ("k", "v", LATENT) if n in cache},
            "state": {n: cache[n] for n in STATE_LEAVES if n in cache}}
     for li, (kind, i) in enumerate(cfg.layer_slots):
         p = gpt.pattern_layer(params["blocks"], cfg, li)
+
+        def mla(n, p=p, i=i):
+            out, box["pool"][LATENT] = _latent_sublayer_chunk(
+                n, p, cfg, box["pool"][LATENT], i, logi, pos0, trow, phys)
+            return out
 
         def mamba(n, p=p, i=i):
             with jax.named_scope("ssm"):
@@ -650,19 +695,21 @@ def _pattern_prefill_chunk(params, cache, tokens, pos0, length, slot,
         def attention(n, p=p, i=i):
             q, k, v = gpt._project_qkv(n[None], p, cfg, repeat_kv=False)
             rows = generate._store_rows(k, v, cfg)        # [1, C, Hkv, hd]
-            csl = _gather_slot(box["pool"], i, trow, cfg)
+            csl = _gather_slot({k_: box["pool"][k_] for k_ in ("k", "v")},
+                               i, trow, cfg)
             full = {k_: jax.lax.dynamic_update_slice(
                         csl[k_], val, (0, pos0, 0, 0))
                     for k_, val in rows.items()}
             with jax.named_scope("kv_gather"):
-                box["pool"] = {
-                    k_: _put_rows(box["pool"][k_], i, phys, val[0])
-                    for k_, val in rows.items()}
+                box["pool"].update(
+                    {k_: _put_rows(box["pool"][k_], i, phys, val[0])
+                     for k_, val in rows.items()})
             attn = generate._attend_cache(q, full, pos0, cfg)
             return gpt._attn_out(attn, p, cfg)[0]
 
         x, _ = gpt.pattern_block(
-            x, p, cfg, mamba if kind == "mamba" else attention, valid=valid)
+            x, p, cfg, {"mamba": mamba, "attention": attention,
+                        "mla": mla}[kind], valid=valid)
     last = jax.lax.dynamic_slice(x, (length - 1, 0), (1, cfg.hidden_size))
     last = gpt._norm(last, params, "ln_f", cfg)
     logits = woq.logits(last, params, dt, cfg.lm_head_multiplier)[0]
@@ -684,12 +731,12 @@ def paged_prefill_chunk(params, cache, tokens, pos0, length, slot,
     first unshared row — the shared blocks are ATTENDED through the
     gather but never recomputed, which is where the prefix cache's
     prefill FLOPs saving comes from."""
-    if LATENT in cache:
-        return _latent_prefill_chunk(params, cache, tokens, pos0, length,
-                                     slot, cfg)
     if cfg.layer_types is not None:
         return _pattern_prefill_chunk(params, cache, tokens, pos0, length,
                                       slot, cfg)
+    if LATENT in cache:
+        return _latent_prefill_chunk(params, cache, tokens, pos0, length,
+                                     slot, cfg)
     N, bs, nmax = _geometry(cache)
     tables = cache["tables"]
     trow = tables[slot]                                   # [nmax]

@@ -1,19 +1,22 @@
-"""Latent attention (MLA): low-rank query and key-value projections, one
-shared rope key, and a cache that holds the latent row, not per-head keys
-and values.
+"""Latent attention (MLA): a key-value projection through a low-rank
+latent, a query with or without one, one shared rope key, and a cache that
+holds the latent row, not per-head keys and values.
 
 A sublayer on a normed input ``n`` [.., D] (H heads; a head's query and
 key are ``[nope | rope]`` wide, its value ``v_head_dim``):
 
     cq       = RMSNorm(n Wqa)                        -> q_lora_rank
     q        = (cq Wqb) * sqrt(D / q_lora_rank)      -> H x [q_nope | q_rope]
+               (q_lora_rank None: q = n Wq, no stage and no factor)
     [c | kr] = n Wkva                                -> kv_lora_rank + rope
     c        = RMSNorm(c) * sqrt(D / kv_lora_rank)   (the latent only)
     kr       = rope(kr);  q_rope = rope(q_rope)      (one rope key, all heads)
     [k_nope | v] = c Wkvb                            -> H x (nope + v)
-    p        = softmax(causal((q_nope . k_nope + q_rope . kr)
-                              / sqrt(nope + rope)))
+    p        = softmax(causal((q_nope . k_nope + q_rope . kr) * s))
     out      = concat_heads(p v) Wo
+
+with s = 1 / sqrt(nope + rope), times YaRN's ``mscale(factor,
+mscale_all_dim)^2`` where the rope is scaled (:class:`YaRN`).
 
 The cache row of a token is ``[c | kr]`` (``row_width`` values a
 sublayer).  Two forms of the same numbers:
@@ -39,29 +42,86 @@ import math
 import jax
 import jax.numpy as jnp
 
+import numpy as np
+
 from . import gpt, woq
 
-# sublayers of the latent block (gpt.GPTConfig.mla): two attention
-# sublayers a layer, so the pool's first axis is 2 * num_layers
-SUBLAYERS = 2
 # query rows a tile of the prefill chunk's score tensor holds: the scores
 # of all heads against the whole window are [H, tile, T] float32
 Q_TILE = 256
 
 
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class YaRN:
+    """DeepSeek's YaRN rope scaling (``rope_scaling.type``
+    ``"deepseek_yarn"``) on the ``r`` rope lanes at base ``b``: with
+    ``f_i = b^(-2i/r)``, the rotations ``beta_fast`` and ``beta_slow``
+    bound a ramp over the frequency index,
+
+        low  = floor(r ln(original / (beta_fast 2 pi)) / (2 ln b))
+        high = ceil (r ln(original / (beta_slow 2 pi)) / (2 ln b))
+        ramp_i = clip((i - low) / (high - low), 0, 1)
+        inv_freq_i = f_i / factor * ramp_i + f_i * (1 - ramp_i)
+
+    cos and sin carry ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)`` and the softmax scale ``mscale(factor,
+    mscale_all_dim)^2``, where ``mscale(f, m) = 0.1 m ln f + 1``."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def ramp_range(self, r: int, base: float) -> tuple:
+        def dim(rot):
+            return (r * math.log(self.original_max_position
+                                 / (rot * 2 * math.pi))
+                    / (2 * math.log(base)))
+
+        return (max(math.floor(dim(self.beta_fast)), 0),
+                min(math.ceil(dim(self.beta_slow)), r - 1))
+
+    def inv_freq(self, r: int, base: float) -> np.ndarray:
+        """[r / 2] float32, the angle a position advances a pair."""
+        f = float(base) ** (-np.arange(0, r, 2, dtype=np.float64) / r)
+        low, high = self.ramp_range(r, base)
+        ramp = np.clip((np.arange(r // 2) - low) / max(high - low, 1e-3),
+                       0.0, 1.0)
+        return (f / self.factor * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+    @property
+    def cos_scale(self) -> float:
+        return (_yarn_mscale(self.factor, self.mscale)
+                / _yarn_mscale(self.factor, self.mscale_all_dim))
+
+    @property
+    def softmax_factor(self) -> float:
+        return (_yarn_mscale(self.factor, self.mscale_all_dim) ** 2
+                if self.mscale_all_dim else 1.0)
+
+
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int
+    q_lora_rank: int | None        # None: q = n Wq, no low-rank stage
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
     scale_q_lora: bool = True      # q times sqrt(D / q_lora_rank)
     scale_kv_lora: bool = True     # the normed latent times sqrt(D / kv rank)
+    rope_scaling: YaRN | None = None   # the rope's frequencies and s
 
     def __post_init__(self):
         if self.qk_rope_head_dim % 2:
             raise ValueError("rope needs an even qk_rope_head_dim")
+        if self.q_lora_rank is None and self.scale_q_lora:
+            raise ValueError("scale_q_lora scales the query's low-rank "
+                             "stage, and q_lora_rank None has none")
 
     @property
     def qk_head_dim(self) -> int:
@@ -77,53 +137,66 @@ class MLAConfig:
 
 
 def count_params(m: MLAConfig, hidden: int, heads: int) -> int:
-    """One sublayer: q_a, q_b, kv_a, kv_b, o and the two latent norms."""
-    return (hidden * m.q_lora_rank + m.q_lora_rank * heads * m.qk_head_dim
-            + hidden * m.row_width
+    """One sublayer: the query's projections (q_a, q_b and q_a's norm, or
+    q alone), kv_a, kv_b, o and the latent norm."""
+    q = (hidden * heads * m.qk_head_dim if m.q_lora_rank is None
+         else hidden * m.q_lora_rank + m.q_lora_rank * heads * m.qk_head_dim
+         + m.q_lora_rank)
+    return (q + hidden * m.row_width
             + m.kv_lora_rank * heads * (m.qk_nope_head_dim + m.v_head_dim)
-            + heads * m.v_head_dim * hidden
-            + m.q_lora_rank + m.kv_lora_rank)
+            + heads * m.v_head_dim * hidden + m.kv_lora_rank)
 
 
 def init_params(m: MLAConfig, hidden: int, heads: int, layers: int, key,
-                std: float = 0.02) -> dict:
-    """One attention sublayer's leaves of ``layers`` latent blocks,
-    ``[L, ...]``, float32."""
+                std: float = 0.02, out_std: float | None = None) -> dict:
+    """One attention sublayer's leaves of ``layers`` latent layers,
+    ``[L, ...]``, float32; the output projection N(0, ``out_std``)."""
     ks = jax.random.split(key, 5)
     lead = (layers,)
 
     def nrm(k, shape, s=std):
         return s * jax.random.normal(k, lead + shape, jnp.float32)
 
+    if m.q_lora_rank is None:
+        q = {"q_w": nrm(ks[0], (hidden, heads * m.qk_head_dim))}
+    else:
+        q = {"q_a_w": nrm(ks[0], (hidden, m.q_lora_rank)),
+             "q_a_ln_g": jnp.ones(lead + (m.q_lora_rank,), jnp.float32),
+             "q_b_w": nrm(ks[1], (m.q_lora_rank, heads * m.qk_head_dim))}
     return {
-        "q_a_w": nrm(ks[0], (hidden, m.q_lora_rank)),
-        "q_a_ln_g": jnp.ones(lead + (m.q_lora_rank,), jnp.float32),
-        "q_b_w": nrm(ks[1], (m.q_lora_rank, heads * m.qk_head_dim)),
+        **q,
         "kv_a_w": nrm(ks[2], (hidden, m.row_width)),
         "kv_a_ln_g": jnp.ones(lead + (m.kv_lora_rank,), jnp.float32),
         "kv_b_w": nrm(ks[3], (m.kv_lora_rank,
                               heads * (m.qk_nope_head_dim + m.v_head_dim))),
         "proj_w": nrm(ks[4], (heads * m.v_head_dim, hidden),
-                      std / math.sqrt(2 * SUBLAYERS * layers)),
+                      std if out_std is None else out_std),
     }
 
 
-def apply_rope(x, positions, base: float):
+def apply_rope(x, positions, base: float, scaling: YaRN | None = None):
     """Rotary embedding on [..., T, r] (r even) at ``positions`` [T],
-    pairing lanes (2i, 2i + 1); angles in float32."""
+    pairing lanes (2i, 2i + 1); angles in float32; the frequencies and
+    the cos / sin factor of ``scaling`` where the rope is scaled."""
     r = x.shape[-1]
-    freqs = float(base) ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    if scaling is None:
+        freqs = float(base) ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    else:
+        freqs = jnp.asarray(scaling.inv_freq(r, base))
     ang = positions.astype(jnp.float32)[:, None] * freqs       # [T, r/2]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if scaling is not None and scaling.cos_scale != 1.0:
+        cos, sin = cos * scaling.cos_scale, sin * scaling.cos_scale
     xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
     a, b = xf[..., 0], xf[..., 1]
     out = jnp.stack([a * cos - b * sin, b * cos + a * sin], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
 
 
-def _scaled_norm(x, g, scale: float, dt):
+def _scaled_norm(x, g, scale: float, cfg):
     """RMSNorm in float32 times a forward scale factor, one rounding."""
-    y = gpt._rms_norm(x.astype(jnp.float32), g)
+    dt = cfg.dtype
+    y = gpt._rms_norm(x.astype(jnp.float32), g, cfg.rms_norm_eps)
     return (y * scale if scale != 1.0 else y).astype(dt)
 
 
@@ -133,9 +206,13 @@ def project(n, p, cfg, positions):
     row [T, row_width] = ``[c | rope(kr)]`` in the compute dtype)."""
     m, dt, H = cfg.mla, cfg.dtype, cfg.num_heads
     D = cfg.hidden_size
-    with jax.named_scope("ln"):
-        cq = _scaled_norm(woq.mm(n, p, "q_a_w", dt), p["q_a_ln_g"], 1.0, dt)
-    q = woq.mm(cq, p, "q_b_w", dt)
+    if m.q_lora_rank is None:
+        q = woq.mm(n, p, "q_w", dt)
+    else:
+        with jax.named_scope("ln"):
+            cq = _scaled_norm(woq.mm(n, p, "q_a_w", dt), p["q_a_ln_g"],
+                              1.0, cfg)
+        q = woq.mm(cq, p, "q_b_w", dt)
     if m.scale_q_lora:
         q = q * jnp.asarray(math.sqrt(D / m.q_lora_rank), dt)
     q = q.reshape(n.shape[0], H, m.qk_head_dim)
@@ -144,11 +221,12 @@ def project(n, p, cfg, positions):
     with jax.named_scope("ln"):
         c = _scaled_norm(
             ckr[..., :m.kv_lora_rank], p["kv_a_ln_g"],
-            math.sqrt(D / m.kv_lora_rank) if m.scale_kv_lora else 1.0, dt)
-    kr = apply_rope(ckr[..., m.kv_lora_rank:], positions, cfg.rope_theta)
+            math.sqrt(D / m.kv_lora_rank) if m.scale_kv_lora else 1.0, cfg)
+    kr = apply_rope(ckr[..., m.kv_lora_rank:], positions, cfg.rope_theta,
+                    m.rope_scaling)
     # q_rope [T, H, r]: the head axis rides along (positions index axis 0)
     q_rope = apply_rope(q_rope.swapaxes(0, 1), positions,
-                        cfg.rope_theta).swapaxes(0, 1)
+                        cfg.rope_theta, m.rope_scaling).swapaxes(0, 1)
     return q_nope, q_rope, jnp.concatenate([c, kr], axis=-1)
 
 
@@ -160,7 +238,10 @@ def _kv_b(p, cfg):
 
 
 def _scale(cfg) -> float:
-    return 1.0 / math.sqrt(cfg.mla.qk_head_dim)
+    """The softmax scale: 1 / sqrt(nope + rope), times YaRN's factor."""
+    m = cfg.mla
+    s = 1.0 / math.sqrt(m.qk_head_dim)
+    return s if m.rope_scaling is None else s * m.rope_scaling.softmax_factor
 
 
 def attend_chunk(q_nope, q_rope, rows, pos0, p, cfg):

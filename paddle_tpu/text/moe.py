@@ -243,11 +243,16 @@ class ExpertShareConfig:
     and the identity part; what the absent experts would add is left out
     (their chips add it in a deployment: no ``ep`` exchange is written).
 
-    Two published score forms: ``"softmax"`` scores are the softmax over
-    ALL the router's outputs, the ``top_k`` best taken (a selection bias
-    added for the choice only) and not renormalised; ``"topk_softmax"``
-    takes the ``top_k`` largest LOGITS and a softmax over those alone
-    (they sum to 1; no selection bias).  ``shared_size`` > 0: a dense
+    Three published score forms: ``"softmax"`` scores are the softmax
+    over ALL the router's outputs, the ``top_k`` best taken (a selection
+    bias added for the choice only) and not renormalised;
+    ``"topk_softmax"`` takes the ``top_k`` largest LOGITS and a softmax
+    over those alone (they sum to 1; no selection bias); ``"sigmoid"``
+    scores each output by its own sigmoid, takes the ``top_k`` best of
+    score + selection bias (the bias chooses only) and renormalises the
+    selected scores to sum to 1 over all ``top_k``, held or not (the
+    DeepSeek-V3 form).  ``scaling`` multiplies the routed sum in every
+    form.  ``shared_size`` > 0: a dense
     SwiGLU expert of that width on every row, beside the routed sum
     (every chip of a deployment computes a token's once, on the token's
     own chip)."""
@@ -257,11 +262,11 @@ class ExpertShareConfig:
     expert_size: int              # a routed expert's SwiGLU width
     scaling: float = 1.0          # routed_scaling_factor on the whole sum
     held: tuple = (0, 0)          # [lo, hi) of the routed experts held here
-    score: str = "softmax"        # or "topk_softmax"
+    score: str = "softmax"        # or "topk_softmax" or "sigmoid"
     shared_size: int = 0          # the shared expert's SwiGLU width, or 0
 
     def __post_init__(self):
-        if self.score not in ("softmax", "topk_softmax"):
+        if self.score not in ("softmax", "topk_softmax", "sigmoid"):
             raise ValueError(f"unknown score form {self.score!r}")
         lo, hi = self.held
         if not 0 <= lo < hi <= self.n_routed:
@@ -280,8 +285,9 @@ class ExpertShareConfig:
 
     @property
     def selection_bias(self) -> bool:
-        """The ``router_b`` leaf: the softmax form's, added for the choice."""
-        return self.score == "softmax"
+        """The ``router_b`` leaf: the softmax and sigmoid forms', added for
+        the choice."""
+        return self.score in ("softmax", "sigmoid")
 
     def key(self) -> tuple:
         return dataclasses.astuple(self)
@@ -352,15 +358,21 @@ def route_share(m, p, ex: ExpertShareConfig, valid=None):
     """The router on rows ``m`` [T, D], in float32: (idx [T, k] the
     selected experts, w [T, k] their scores in the config's score form:
     the softmax over all outputs at the selected ones, not renormalised
-    (the selection bias chooses only), or the softmax over the selected
-    logits alone).  ``valid`` [T]: a row that is padding, or a slot that
-    holds no request, selects no expert (its scores are zero and its
-    selections count nowhere)."""
+    (the selection bias chooses only), the softmax over the selected
+    logits alone, or the selected sigmoids over their sum).  ``valid``
+    [T]: a row that is padding, or a slot that holds no request, selects
+    no expert (its scores are zero and its selections count nowhere)."""
     with jax.named_scope("moe_route"):
         logits = m.astype(jnp.float32) @ p["router_w"].astype(jnp.float32)
         if ex.score == "topk_softmax":
             top, idx = jax.lax.top_k(logits, ex.top_k)
             w = jax.nn.softmax(top, axis=-1)
+        elif ex.score == "sigmoid":
+            g = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(g + p["router_b"].astype(jnp.float32),
+                                   ex.top_k)
+            w = jnp.take_along_axis(g, idx, axis=-1)
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
         else:
             s = jax.nn.softmax(logits, axis=-1)
             _, idx = jax.lax.top_k(s + p["router_b"].astype(jnp.float32),

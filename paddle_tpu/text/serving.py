@@ -673,6 +673,13 @@ class DecodeServer:
         self._share_drain_every = (
             (1 << 28) // (max_batch * cfg.experts.top_k * cfg.num_layers)
             if self._experts else 0)
+        # the rows x held experts a decode step computes (every slot's row
+        # through every held expert of every expert layer): a number the
+        # host knows, counted at the drain for the steps since the last
+        self._share_step_rows = (
+            max_batch * cfg.experts.n_held
+            * (cfg.num_layers - cfg.first_k_dense) if self._experts else 0)
+        self._share_step0 = self._share_rows = 0
         if self._latent:
             self._refuse_latent(lay)
         if self._paged:
@@ -3889,14 +3896,22 @@ class DecodeServer:
         slots), zero the leaf, and publish what came since the last
         drain: counters ``moe.pairs_held`` / ``pairs_zero`` /
         ``pairs_absent`` (token-expert selections to an expert held
-        here, to an identity expert, to another chip's) and the gauge
+        here, to an identity expert, to another chip's), the gauge
         ``moe.experts_hit`` (distinct held experts hit a layer a step,
-        mean over what this server has run).  The fetch waits for the
-        step in flight (the span ``serving.drain_counts``).  Returns the
-        totals."""
+        mean over what this server has run), and the counter
+        ``moe.expert_rows``: the rows x held experts that the decode
+        steps dispatched since the last drain computed (every slot's row
+        runs through every held expert), counted from the host's step
+        number, not fetched.  The fetch waits for the step in flight
+        (the span ``serving.drain_counts``).  Returns the totals."""
         from . import kv_pool as _kv
 
         self._share_ticks = 0
+        steps = max(self._step_no - self._share_step0, 0)
+        self._share_step0 = self._step_no
+        self._share_rows += steps * self._share_step_rows
+        if self._tel and steps:
+            _telemetry.count("moe.expert_rows", steps * self._share_step_rows)
         if self.cache is not None:
             with _telemetry.span("serving.drain_counts"):
                 new = np.asarray(jax.device_get(self.cache[_kv.COUNTS]),
@@ -3916,7 +3931,8 @@ class DecodeServer:
             _telemetry.set_gauge("moe.experts_hit", hit / calls)
         return {"moe_pairs_held": held, "moe_pairs_zero": zero,
                 "moe_pairs_absent": absent,
-                "moe_experts_hit": hit / calls if calls else 0.0}
+                "moe_experts_hit": hit / calls if calls else 0.0,
+                "moe_expert_rows": self._share_rows}
 
     # -- multi-tenant serving: adapter gather + constraint masks ------------
 

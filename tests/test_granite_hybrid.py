@@ -595,7 +595,7 @@ def test_the_ten_scores_are_a_softmax_over_the_selected(cfg, params):
     assert moe.count_expert_share(cfg.experts, 128) == (
         128 * 8 + 3 * 128 * FS, 3 * 128 * FE)
     with pytest.raises(ValueError, match="score form"):
-        moe.ExpertShareConfig(8, 0, 3, FE, score="sigmoid", held=(0, 4))
+        moe.ExpertShareConfig(8, 0, 3, FE, score="sparsemax", held=(0, 4))
 
 
 def test_scopes_of_the_pattern_step_and_no_identity_term(cfg, params):
